@@ -68,8 +68,8 @@ def bench_pendulum(num_envs: int, steps: int) -> dict:
         "value": round(num_envs * steps / dt, 1),
         "unit": "agent steps/s",
         "num_envs": num_envs,
-        # Device path — automation gates on this being an on-chip number
-        # (scripts/tpu_campaign3.sh json_backend_ok).
+        # Device path: a reader must be able to tell an on-chip number
+        # from a CPU one.
         "backend": jax.default_backend(),
     }
 
@@ -78,9 +78,8 @@ def bench_native_pool(domain: str, task: str, num_envs: int, steps: int) -> dict
     """Whole-POOL physics throughput for a native-pool task (walker and
     humanoid supported).  The pool threads over min(cores, num_envs)
     workers, so this equals the per-core ceiling only on a 1-core host;
-    divide by the reported ``threads`` for per-core (the number the
-    humanoid scaling arithmetic in docs/RESULTS.md multiplies by host
-    cores)."""
+    divide by the reported ``threads`` for per-core (the number a
+    scaling estimate multiplies by host cores)."""
     import numpy as np
 
     from r2d2dpg_tpu.envs import native_pool

@@ -1,0 +1,465 @@
+#!/usr/bin/env python3
+"""The quickest proof that the system still starts on the chip.
+
+    python3 chip_smoke.py             # one chip: kernel, train, serve
+    python3 chip_smoke.py --chips 4   # one four-chip host: the mesh legs
+
+Drives the main path once through the entry points a user would call
+(``r2d2dpg_tpu.train.main``, ``r2d2dpg_tpu.serve.main``), at the full width
+of ``walker_r2d2`` — no env-count, batch, hidden or capacity override; only
+the number of train phases is cut — with random seeded weights, and checks
+what comes out by the repo's own means (backend stamp, learner step count,
+finite learn metrics, the compile sentinel, selftest answer codes).
+
+This process is the parent and never imports JAX: a chip belongs to one
+process at a time, so each leg is one child (``--leg NAME``), run in turn.
+Exit 0, with ``{"ok": true, "device": {...}}`` as the last line of stdout,
+only if every leg ran on a TPU and passed; progress goes to stderr.  With no
+TPU the first leg says which platform JAX resolved and nothing is trained.
+
+Work files (logs, checkpoints, one ``<leg>.json`` per leg, ``summary.json``)
+land in ``chiprun_out/chip_smoke/``.
+"""
+
+from __future__ import annotations
+
+import argparse
+import csv
+import json
+import math
+import os
+import shutil
+import signal
+import subprocess
+import sys
+import time
+import traceback
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+WORK = os.path.join(HERE, "chiprun_out", "chip_smoke")
+# The whole smoke must end inside the driver's 1200 s; legs share this.
+BUDGET_S = 1100.0
+# A leg's exit code when JAX resolved no TPU: nothing else is worth running.
+EXIT_NO_TPU = 3
+TRAIN_PHASES = 3
+# The capacities the configs use (pendulum_tiny, cheetah_pixels,
+# pendulum_*, walker/humanoid) at the learner batch they all share.
+SCATTER_CAPACITIES = (256, 8_000, 50_000, 100_000)
+SCATTER_BATCH = 64
+
+
+class LegFailed(Exception):
+    """A check did not hold."""
+
+
+def _require(cond: bool, what: str) -> None:
+    if not cond:
+        raise LegFailed(what)
+
+
+# ------------------------------------------------------------------ children
+def _samples(name: str) -> list:
+    """``[(labels, sample), ...]`` of one instrument in this process's
+    telemetry registry (empty if it was never registered)."""
+    from r2d2dpg_tpu import obs
+
+    entry = obs.get_registry().snapshot().get(name, {})
+    return [(s["labels"], s) for s in entry.get("samples", [])]
+
+
+def _train(work: str, config: str, *flags: str) -> dict:
+    """``python -m r2d2dpg_tpu.train --config <config> <flags>`` for
+    TRAIN_PHASES train phases (after the config's own warm-up and replay
+    fill), then the checks every train leg shares."""
+    from r2d2dpg_tpu import obs, train
+    from r2d2dpg_tpu.configs import get_config
+
+    logdir, ckpt = os.path.join(work, "log"), os.path.join(work, "ckpt")
+    train.main(
+        [
+            "--config", config, "--phases", str(TRAIN_PHASES),
+            "--log-every", "1", "--logdir", logdir,
+            "--checkpoint-dir", ckpt, "--checkpoint-light", *flags,
+        ]
+    )
+    with open(os.path.join(logdir, "backend.txt")) as f:
+        backend = f.read().strip()
+    _require(backend == "tpu", f"backend.txt says {backend!r}")
+
+    want_steps = get_config(config).trainer.learner_steps * TRAIN_PHASES
+    steps = [s["value"] for _, s in _samples("r2d2dpg_trainer_learner_steps")]
+    _require(steps == [want_steps], f"learner steps {steps}, want {want_steps}")
+
+    with open(os.path.join(logdir, "metrics.csv"), newline="") as f:
+        rows = list(csv.DictReader(f))
+    learn_rows = rows[-TRAIN_PHASES:]
+    _require(
+        len(learn_rows) == TRAIN_PHASES and "critic_loss" in learn_rows[0],
+        f"metrics.csv holds no learn rows ({len(rows)} rows)",
+    )
+    for row in learn_rows:
+        bad = {
+            k: v for k, v in row.items()
+            if v == "" or not math.isfinite(float(v))
+        }
+        _require(not bad, f"non-finite metrics at step {row['step']}: {bad}")
+
+    flight = obs.get_flight_recorder().events()
+    for kind in ("steady_recompile", "env_native_fallback"):
+        hits = [e for e in flight if e["kind"] == kind]
+        _require(not hits, f"{len(hits)} {kind} events: {hits[:2]}")
+    return {
+        "learner_steps": want_steps,
+        "final_metrics": {k: float(v) for k, v in learn_rows[-1].items()},
+        "checkpoint_dir": ckpt,
+    }
+
+
+def _require_native_pool() -> None:
+    """The walker legs stepped the C++ pool built on this machine."""
+    pools = {
+        labels["pool"]
+        for labels, s in _samples("r2d2dpg_envpool_step_seconds")
+        if s["count"]
+    }
+    _require(pools == {"native"}, f"env pools that stepped: {sorted(pools)}")
+
+
+def _fresh_native_build() -> None:
+    """``native/build/`` is ignored by git but present on a copied disk, and
+    ``make`` trusts timestamps: remove it so the pool this leg steps is
+    built here, from ``native/envpool/env_pool.cc`` as git has it."""
+    shutil.rmtree(os.path.join(HERE, "native", "build"), ignore_errors=True)
+
+
+def _serve(ckpt: str, *flags: str) -> tuple:
+    """``python -m r2d2dpg_tpu serve --config walker_r2d2 --selftest 64``
+    over a train leg's checkpoint; returns ``(health, service)``: the
+    selftest's health record and the service it drove."""
+    import contextlib
+    import io
+
+    from r2d2dpg_tpu import serve
+
+    built = []
+    build_service = serve.build_service
+
+    def build_and_keep(args):
+        service, env = build_service(args)
+        built.append(service)
+        return service, env
+
+    serve.build_service = build_and_keep
+    out = io.StringIO()
+    try:
+        with contextlib.redirect_stdout(out):
+            serve.main(
+                [
+                    "--config", "walker_r2d2", "--checkpoint-dir", ckpt,
+                    "--selftest", "64", *flags,
+                ]
+            )
+    finally:
+        serve.build_service = build_service
+        sys.stderr.write(out.getvalue())
+    health = json.loads(out.getvalue().strip().splitlines()[-1])
+    _require(health["codes"] == {"ok": 64}, f"codes {health['codes']}")
+    _require(health["worker_errors"] == 0, f"health {health}")
+    return health, built[0]
+
+
+def scatter_case(capacity: int) -> tuple:
+    """``(priority, indices, values, want)``: one seeded write-back at the
+    learner batch — both ends of the vector written, one slot written four
+    times — and what a sequential loop makes of it (the last write wins).
+    ``tests/test_replay.py`` checks the interpreted kernel on these same
+    cases, so the shapes the chip compiles are the shapes the CPU checks."""
+    import numpy as np
+
+    rng = np.random.default_rng(capacity)
+    priority = rng.random(capacity, dtype=np.float32)
+    indices = rng.integers(0, capacity, SCATTER_BATCH).astype(np.int32)
+    indices[:2] = (0, capacity - 1)  # both ends of the padded tile
+    indices[-3:] = indices[5]  # four writes to one slot
+    values = rng.random(SCATTER_BATCH, dtype=np.float32) + 1.0
+    want = priority.copy()
+    for i, v in zip(indices, values):
+        want[i] = v
+    return priority, indices, values, want
+
+
+def _leg_kernel(work: str) -> dict:
+    """``priority_scatter`` compiled by Mosaic (not interpreted, not XLA's
+    scatter) at the shapes the main path uses, against the sequential
+    reference."""
+    import jax
+    import numpy as np
+
+    from r2d2dpg_tpu.ops.pallas.scatter import priority_scatter
+
+    scatter = jax.jit(priority_scatter)
+    for capacity in SCATTER_CAPACITIES:
+        priority, indices, values, want = scatter_case(capacity)
+        hlo = scatter.lower(priority, indices, values).as_text()
+        _require(
+            "tpu_custom_call" in hlo,
+            f"capacity {capacity}: no Mosaic call in the lowered program",
+        )
+        got = np.asarray(scatter(priority, indices, values))
+        _require(
+            np.array_equal(got, want),
+            f"capacity {capacity}: {int((got != want).sum())} slots differ "
+            "from the sequential reference",
+        )
+    return {"capacities": list(SCATTER_CAPACITIES), "batch": SCATTER_BATCH}
+
+
+def _leg_train(work: str) -> dict:
+    """Base ``Trainer``: host MuJoCo pool through ordered ``io_callback``
+    inside the jitted phase, the HBM arena at capacity 100,000, the Pallas
+    write-back, donated state."""
+    _fresh_native_build()
+    checks = _train(work, "walker_r2d2")
+    _require_native_pool()
+    return checks
+
+
+def _leg_serve(work: str) -> dict:
+    """The orbax partial restore onto the chip, one pinned executable per
+    bucket, carries in device slabs."""
+    health, _ = _serve(os.path.join(WORK, "train", "ckpt"))
+    _require(health["last_reload_error"] is None, f"health {health}")
+    return {k: health[k] for k in ("codes", "params_step", "requests_ok")}
+
+
+def _require_spread(chips: int, arena_devices: list) -> dict:
+    """Proof of placement for a mesh train leg: the arena's leaves span
+    ``chips`` devices and every one of them holds live bytes."""
+    _require(
+        arena_devices and set(arena_devices) == {chips},
+        f"arena leaves span {sorted(set(arena_devices))} devices, "
+        f"want {chips}",
+    )
+    in_use = {
+        labels["device"]: s["value"]
+        for labels, s in _samples("r2d2dpg_device_hbm_bytes_in_use")
+    }
+    _require(
+        sum(v > 0 for v in in_use.values()) >= chips,
+        f"per-device HBM bytes in use: {in_use}",
+    )
+    return {"arena_leaf_devices": chips, "hbm_bytes_in_use": in_use}
+
+
+def _mesh_train(work: str, config: str, *flags: str) -> dict:
+    """A train leg on a four-device mesh, with its placement proof."""
+    import jax
+
+    from r2d2dpg_tpu import topology
+
+    arena_devices: list = []
+    build_trainer = topology.build_trainer
+
+    def build_and_watch(topo, cfg):
+        trainer = build_trainer(topo, cfg)
+        init = trainer.init
+
+        def init_and_note(*a, **kw):
+            state = init(*a, **kw)
+            arena_devices.extend(
+                len(leaf.sharding.device_set)
+                for leaf in jax.tree_util.tree_leaves(state.arena)
+            )
+            return state
+
+        trainer.init = init_and_note
+        return trainer
+
+    topology.build_trainer = build_and_watch
+    try:
+        checks = _train(work, config, *flags)
+    finally:
+        topology.build_trainer = build_trainer
+    checks.update(_require_spread(4, arena_devices))
+    return checks
+
+
+def _leg_spmd_pendulum(work: str) -> dict:
+    """``SPMDTrainer``: whole phases under ``shard_map``, the Pallas kernel
+    inside it, gradient ``pmean`` over ICI."""
+    return _mesh_train(work, "pendulum_r2d2", "--spmd", "4")
+
+
+def _leg_spmd_walker(work: str) -> dict:
+    """``HostSPMDTrainer``: host pool stepped from Python, device compute
+    laid out over the mesh."""
+    _fresh_native_build()
+    checks = _mesh_train(work, "walker_r2d2", "--spmd", "4")
+    _require_native_pool()
+    return checks
+
+
+def _leg_learner_dp(work: str) -> dict:
+    """``DPLearnerTrainer``: capacity-sharded arena, dp-sharded batch."""
+    return _mesh_train(work, "pendulum_r2d2", "--learner-dp", "4")
+
+
+def _leg_serve_workers(work: str) -> dict:
+    """Four routed workers, each with params and slabs on its own chip
+    (the router wraps round-robin silently when devices are short)."""
+    import jax
+
+    health, router = _serve(
+        os.path.join(WORK, "spmd_walker", "ckpt"), "--serve-workers", "4"
+    )
+    homes = []
+    for svc in router.services:
+        devices = {
+            d
+            for leaf in jax.tree_util.tree_leaves((svc._params, svc._slabs))
+            for d in leaf.devices()
+        }
+        _require(len(devices) == 1, f"worker {svc.worker_label}: {devices}")
+        homes.append(devices.pop().id)
+    _require(len(set(homes)) == 4, f"workers sit on devices {homes}")
+    errors = {
+        w: snap["last_reload_error"]
+        for w, snap in health["per_worker"].items()
+        if snap["last_reload_error"]
+    }
+    _require(not errors, f"reload errors {errors}")
+    return {
+        "codes": health["codes"],
+        "worker_devices": homes,
+        "per_worker_requests": {
+            w: snap["requests_ok"] for w, snap in health["per_worker"].items()
+        },
+    }
+
+
+LEGS = {
+    1: {"kernel": _leg_kernel, "train": _leg_train, "serve": _leg_serve},
+    4: {
+        "spmd_pendulum": _leg_spmd_pendulum,
+        "spmd_walker": _leg_spmd_walker,
+        "learner_dp": _leg_learner_dp,
+        "serve_workers": _leg_serve_workers,
+    },
+}
+
+
+def run_leg(chips: int, name: str) -> int:
+    """Child body: settle the cache and the device, run one leg, leave
+    ``<leg>.json``.  This is the only process that touches JAX."""
+    t0 = time.monotonic()
+    from r2d2dpg_tpu.utils.startup import enable_compile_cache, require_tpu
+
+    cache_dir = enable_compile_cache()
+    try:
+        device = require_tpu()
+    except SystemExit as e:
+        print(f"chip_smoke: {e}", file=sys.stderr)
+        return EXIT_NO_TPU
+    from r2d2dpg_tpu import obs
+
+    obs.get_device_monitor().install()  # counts every leg's compiles
+    result = {"leg": name, "device": device, "compile_cache": cache_dir}
+    work = os.path.join(WORK, name)
+    os.makedirs(work, exist_ok=True)
+    try:
+        _require(device["count"] >= chips, f"{device['count']} devices")
+        result["checks"] = LEGS[chips][name](work)
+        result["ok"] = True
+    except (Exception, SystemExit) as e:  # a leg's verdict, never a crash
+        traceback.print_exc()
+        result["ok"] = False
+        result["error"] = f"{type(e).__name__}: {e}"
+    compiles = [s for _, s in _samples("r2d2dpg_device_compile_seconds")]
+    result["compiles"] = sum(s["count"] for s in compiles)
+    result["compile_seconds"] = round(sum(s["total"] for s in compiles), 2)
+    result["seconds"] = round(time.monotonic() - t0, 1)
+    with open(os.path.join(WORK, f"{name}.json"), "w") as f:
+        json.dump(result, f, indent=1, default=str)
+    return 0 if result["ok"] else 1
+
+
+# -------------------------------------------------------------------- parent
+def _spawn(chips: int, name: str, timeout: float) -> dict:
+    """Run one leg as a child in its own process group; whatever happens,
+    nothing of it is left running."""
+    log_path = os.path.join(WORK, f"{name}.log")
+    cmd = [sys.executable, os.path.abspath(__file__),
+           "--chips", str(chips), "--leg", name]
+    with open(log_path, "w") as log:
+        proc = subprocess.Popen(
+            cmd, cwd=HERE, stdout=log, stderr=subprocess.STDOUT,
+            start_new_session=True,
+        )
+        try:
+            code = proc.wait(timeout=max(timeout, 1.0))
+        except subprocess.TimeoutExpired:
+            code = None
+        finally:
+            if proc.poll() is None:  # timed out, or this parent is dying
+                os.killpg(proc.pid, signal.SIGKILL)
+                proc.wait()
+    result = {"leg": name, "ok": False}
+    try:
+        with open(os.path.join(WORK, f"{name}.json")) as f:
+            result.update(json.load(f))
+    except (OSError, ValueError):
+        pass  # the leg died before its verdict: the log tail says how
+    result["exit"] = code
+    result["ok"] = result["ok"] and code == 0
+    if code is None:
+        result["error"] = f"timed out after {timeout:.0f}s"
+    if not result["ok"]:
+        with open(log_path, errors="replace") as log:
+            tail = log.read()[-6000:]
+        print(f"--- {name} log tail ---\n{tail}\n---", file=sys.stderr)
+    return result
+
+
+def main() -> int:
+    p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    p.add_argument("--chips", type=int, default=1, choices=sorted(LEGS))
+    p.add_argument("--leg", default=None, help=argparse.SUPPRESS)
+    args = p.parse_args()
+    if args.leg is not None:
+        return run_leg(args.chips, args.leg)
+
+    deadline = time.monotonic() + BUDGET_S
+    shutil.rmtree(WORK, ignore_errors=True)
+    os.makedirs(WORK)
+    results = []
+    for name in LEGS[args.chips]:
+        res = _spawn(args.chips, name, deadline - time.monotonic())
+        results.append(res)
+        print(
+            "chip_smoke: "
+            + json.dumps({k: v for k, v in res.items() if k != "checks"}),
+            file=sys.stderr,
+            flush=True,
+        )
+        if res["exit"] == EXIT_NO_TPU:
+            break
+    with open(os.path.join(WORK, "summary.json"), "w") as f:
+        json.dump(results, f, indent=1)
+    # A leg only passes after require_tpu() and the device count held in
+    # its own process; what is left to check here is that all legs ran,
+    # passed, and saw one and the same device.
+    devices = [r.get("device") for r in results]
+    if not (
+        len(results) == len(LEGS[args.chips])
+        and all(r["ok"] for r in results)
+        and all(d == devices[0] for d in devices)
+    ):
+        failed = [r["leg"] for r in results if not r["ok"]]
+        print(f"chip_smoke: FAILED legs {failed}", file=sys.stderr)
+        return 1
+    print(json.dumps({"ok": True, "device": devices[0]}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

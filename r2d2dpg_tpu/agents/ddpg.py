@@ -80,7 +80,7 @@ class AgentConfig:
     fused_burnin: bool = True
     # --- overestimation mitigations (round-3; the config-#5 CPU evidence
     # run collapsed from textbook DDPG critic overestimation — q_mean rose
-    # 0.15 -> 0.95 while eval return fell; docs/RESULTS.md).  Both default
+    # 0.15 -> 0.95 while eval return fell).  Both default
     # OFF so the baseline DDPG semantics (SURVEY §2.4) are unchanged.
     #
     # twin_critic: clipped double-Q (TD3) — two critics as a vmapped

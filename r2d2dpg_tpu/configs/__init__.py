@@ -208,19 +208,17 @@ PENDULUM_R2D2 = ExperimentConfig(
 
 # 3: the north-star metric config (walker-walk @ 30 min).
 #
-# n_step=3 (was 5): the round-3 4-probe sweep (docs/RESULTS.md "walker
-# plateau") showed the long-standing 160-250 return band was an
-# n-step-5 bootstrap-horizon cap, not a data wall — n-step 3 reached
-# 351.7 (20-ep eval, seed 3) vs the prior 198.9 best, still climbing at
-# the probe's 330k-step cutoff.
+# n_step=3 (was 5): the round-3 4-probe sweep showed the long-standing
+# 160-250 return band was an n-step-5 bootstrap-horizon cap, not a data
+# wall — n-step 3 reached 351.7 (20-ep eval, seed 3) vs the prior 198.9
+# best, still climbing at the probe's 330k-step cutoff.
 #
 # sigma_max=0.4 (round 5 reverted a round-4 flip to 0.8): the seed-4
-# combined-recipe probe (docs/RESULTS.md "combined-recipe probe")
-# measured n-step 3 + sigma 0.8 TOGETHER at 202 @ 247k steps / 220.7
-# final — far below n-step-3-alone's 334 @ 247k at equal steps — so the
-# round-3 "sigma 0.8 mildly ahead" single-change result does not
-# compose with the shorter bootstrap horizon, and the recorded recipe
-# stays n_step=3 + sigma_max=0.4.  BASELINE.json's literal n-step-5
+# combined-recipe probe measured n-step 3 + sigma 0.8 TOGETHER at 202 @
+# 247k steps / 220.7 final — far below n-step-3-alone's 334 @ 247k at
+# equal steps — so the round-3 "sigma 0.8 mildly ahead" single-change
+# result does not compose with the shorter bootstrap horizon, and the
+# recorded recipe stays n_step=3 + sigma_max=0.4.  BASELINE.json's n-step-5
 # spelling is preserved as walker_r2d2_ns5 below (VERDICT r3 "next" #1:
 # the recipe must live in tracked state, not a gitignored flags file).
 WALKER_R2D2 = ExperimentConfig(
@@ -300,7 +298,7 @@ CHEETAH_PIXELS = ExperimentConfig(
         # 5e-5 (was 1e-4): the round-2 evidence run collapsed from critic
         # overestimation at 1e-4 (eval 4.1 -> 1.5 by 94 min); the round-3
         # run at 5e-5 + batch 16 is monotone 0.8 -> 2.5 -> 4.3 through
-        # 102 min / 76k steps with no collapse (docs/RESULTS.md).  Twin
+        # 102 min / 76k steps with no collapse.  Twin
         # critic (clipped double-Q) remains the stronger, opt-in fix.
         actor_lr=5e-5,
         critic_lr=5e-4,

@@ -39,6 +39,7 @@ import dataclasses
 import functools
 import threading
 import time
+import warnings
 from concurrent.futures import ThreadPoolExecutor
 from typing import Optional, Tuple
 
@@ -49,6 +50,7 @@ from jax.experimental import io_callback
 
 from r2d2dpg_tpu.envs.core import EnvSpec, TimeStep
 from r2d2dpg_tpu.envs.native_pool import PoolObsMixin
+from r2d2dpg_tpu.obs import flight_event
 
 _PIXEL_HW = 64
 
@@ -306,10 +308,25 @@ class DMCHostEnv:
                 )
             try:
                 self._pool = native_pool.NativeEnvPool(domain, task)
-            except Exception:
+            except (OSError, RuntimeError, AttributeError) as e:
+                # make/g++ missing or failing, or a library that will not load.
                 if native:  # explicitly requested: surface the build error
                     raise
-                # Auto-select: fall back to the Python pool (e.g. no g++).
+                # Auto-select carries on with the Python pool, which is
+                # several times slower: never silently (warnings print a
+                # given message once; the flight ring keeps every one).
+                reason = f"{type(e).__name__}: {e}"
+                warnings.warn(
+                    f"native env pool unavailable for {domain}-{task}; "
+                    f"using the slower Python pool: {reason}",
+                    RuntimeWarning,
+                    stacklevel=2,
+                )
+                flight_event(
+                    "env_native_fallback",
+                    env=f"{domain}-{task}",
+                    error=reason[-2000:],
+                )
                 use_native = False
                 self._pool = _HostPool(domain, task, pixels, camera_id)
         else:

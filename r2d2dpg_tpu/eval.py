@@ -88,6 +88,9 @@ def main(argv=None) -> dict:
     import jax
 
     from r2d2dpg_tpu.training.evaluator import Evaluator
+    from r2d2dpg_tpu.utils.startup import enable_compile_cache
+
+    enable_compile_cache()
 
     cfg = get_config(args.config)
     if args.compute_dtype is not None:

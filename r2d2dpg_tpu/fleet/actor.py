@@ -1098,7 +1098,14 @@ def main(argv=None) -> None:
     import signal
 
     signal.signal(signal.SIGUSR1, lambda *_: actor.request_drain())
-    flight_event("actor_start", phase=0, address=args.connect)
+    # The backend is stamped so a post-mortem can confirm the actor stayed
+    # off the learner's chip (the supervisor pins JAX_PLATFORMS=cpu).
+    flight_event(
+        "actor_start",
+        phase=0,
+        address=args.connect,
+        backend=jax.default_backend(),
+    )
     try:
         actor.run(max_phases=args.phases)
     except _OrderlyShutdown:

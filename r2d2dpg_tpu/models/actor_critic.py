@@ -95,7 +95,7 @@ class _GateParams(nn.Module):
 class MixedPrecisionLSTMCell(nn.Module):
     """LSTM cell with ``dtype`` gate matmuls but FLOAT32 state arithmetic.
 
-    Motivation (docs/RESULTS.md round-3 dtype A/B): with flax's cell at
+    Motivation (round-3 dtype A/B): with flax's cell at
     ``dtype=bfloat16`` the carry itself is returned in bf16, so the cell
     state ``c`` accumulates rounding across every unroll step — walker
     learning fell ~3x behind fp32 while short-horizon pendulum masked it.
@@ -114,16 +114,14 @@ class MixedPrecisionLSTMCell(nn.Module):
     precision alone, and a checkpoint written under either dtype restores
     under the other.
 
-    Measured outcome (round-5 controlled A/B, docs/RESULTS.md
-    "Mixed-precision cell learning probe", taken on the fp32-CARRY
+    Measured outcome (round-5 controlled A/B, taken on the fp32-CARRY
     revision of this cell BEFORE the fp32-accumulator dots below): the
     fp32 carry alone did NOT recover walker learning parity — final
     146.6 vs the fp32 control's 351.7, within noise of the old
     truncated-carry cell's 145.5 — implicating the bf16-truncated matmul
     accumulator, which the ``preferred_element_type`` dots below remove
     (unrolled |h| error vs fp32 drops ~16x).  The accumulator variant's
-    round-5 measurement (RESULTS.md "fp32-accumulator cell probe"):
-    final 274.4 vs fp32's 351.7 — a ~60% recovery over the carry-only
+    round-5 measurement: final 274.4 vs fp32's 351.7 — a ~60% recovery over the carry-only
     cells (145.5/146.6) but still short of parity, so ``compute_dtype``
     defaults stay float32; the residual loss is bf16 rounding of the
     streamed operands themselves.
@@ -153,7 +151,7 @@ class MixedPrecisionLSTMCell(nn.Module):
         # whose MXU natively accumulates bf16 products into fp32; without
         # it XLA truncates the accumulator to bf16 at every step of the
         # recurrence, which the round-5 A/B implicates as the remaining
-        # compounding-error path (docs/RESULTS.md "Mixed-precision cell").
+        # compounding-error path.
         zx = jnp.matmul(
             x.astype(self.dtype),
             jnp.concatenate(wi, axis=1).astype(self.dtype),

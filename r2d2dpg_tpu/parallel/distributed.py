@@ -71,10 +71,7 @@ def initialize(
     if coordinator_address is None and not on_tpu_pod:
         return  # single-host: nothing to bring up
 
-    already_up = (
-        getattr(jax._src.distributed.global_state, "client", None) is not None
-    )
-    if already_up:
+    if jax.distributed.is_initialized():
         return
     jax.distributed.initialize(
         coordinator_address=coordinator_address,

@@ -45,7 +45,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from r2d2dpg_tpu.agents.ddpg import R2D2DPG
 from r2d2dpg_tpu.envs.core import Environment
-from r2d2dpg_tpu.parallel.mesh import DP_AXIS
+from r2d2dpg_tpu.parallel.mesh import (
+    DP_AXIS,
+    constrain_batch_sharded,
+    constrain_replicated,
+)
 from r2d2dpg_tpu.replay.arena import ArenaState
 from r2d2dpg_tpu.training.trainer import Trainer, TrainerConfig, TrainerState
 
@@ -188,22 +192,13 @@ class DPLearnerTrainer(Trainer):
         ``.at[idx].set`` local instead of routing rows between shards.
         ``with_sharding_constraint`` (not device_put): these hooks run
         INSIDE the jitted phase/drain programs."""
-        rep = lambda x: jax.lax.with_sharding_constraint(  # noqa: E731
-            x, self._replicated
-        )
-        return jax.tree_util.tree_map(rep, seq), rep(prios)
+        return constrain_replicated((seq, prios), self.mesh)
 
     def _reshard_batch(self, batch):
         """Shard the sampled batch over dp so the learner step's compute
         splits and XLA psums the gradients (params replicated + batch
         sharded — the pjit/GSPMD recipe)."""
-        return jax.tree_util.tree_map(
-            lambda x: jax.lax.with_sharding_constraint(
-                x,
-                NamedSharding(self.mesh, P(*([DP_AXIS] + [None] * (x.ndim - 1)))),
-            ),
-            batch,
-        )
+        return constrain_batch_sharded(batch, self.mesh)
 
     # ---------------------------------------------------------- fleet hooks
     def _put_staged(self, staged, axis: int = 0):

@@ -38,14 +38,13 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.6; on older jax device_put performs the same layout move
-    _reshard = jax.sharding.reshard
-except AttributeError:
-    _reshard = jax.device_put
-
 from r2d2dpg_tpu.agents.ddpg import R2D2DPG
 from r2d2dpg_tpu.envs.dmc_host import DMCHostEnv
-from r2d2dpg_tpu.parallel.mesh import DP_AXIS
+from r2d2dpg_tpu.parallel.mesh import (
+    DP_AXIS,
+    constrain_batch_sharded,
+    constrain_replicated,
+)
 from r2d2dpg_tpu.parallel.spmd import _state_spec
 from r2d2dpg_tpu.training.assembler import StepRecord, shift_in
 from r2d2dpg_tpu.training.trainer import Trainer, TrainerConfig, TrainerState
@@ -324,18 +323,12 @@ class HostSPMDTrainer(Trainer):
     def _reshard_add(self, seq, prios):
         """Replicate the E fresh sequences + priorities for the (replicated)
         arena add — after initial_priority ran on the dp-sharded layout."""
-        rep = lambda x: _reshard(x, self._replicated)  # noqa: E731
-        return jax.tree_util.tree_map(rep, seq), rep(prios)
+        return constrain_replicated((seq, prios), self.mesh)
 
     def _reshard_batch(self, batch):
         """Shard the sampled batch over dp so learner compute splits and XLA
         psums the gradients (params replicated + batch sharded)."""
-        return jax.tree_util.tree_map(
-            lambda x: _reshard(
-                x, NamedSharding(self.mesh, P(*([DP_AXIS] + [None] * (x.ndim - 1))))
-            ),
-            batch,
-        )
+        return constrain_batch_sharded(batch, self.mesh)
 
     # ------------------------------------------------------------ host loop
     def _put_fleet(self, x: np.ndarray) -> jnp.ndarray:

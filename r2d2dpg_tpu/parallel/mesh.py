@@ -17,7 +17,7 @@ from __future__ import annotations
 from typing import Optional, Sequence
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 DP_AXIS = "dp"
 
@@ -25,7 +25,13 @@ DP_AXIS = "dp"
 def make_mesh(
     n_devices: Optional[int] = None, devices: Optional[Sequence[jax.Device]] = None
 ) -> Mesh:
-    """A 1-D ``dp`` mesh over the first ``n_devices`` local devices."""
+    """A 1-D ``dp`` mesh over the first ``n_devices`` local devices.
+
+    The axis is *Auto*: all three mesh trainers are GSPMD-style (sharding
+    annotations via ``NamedSharding``/``with_sharding_constraint``, XLA
+    places the collectives), which an Explicit axis — ``jax.make_mesh``'s
+    default — rejects at trace time.
+    """
     if devices is None:
         devices = jax.devices()
     if n_devices is not None:
@@ -35,7 +41,10 @@ def make_mesh(
             )
         devices = devices[:n_devices]
     return jax.make_mesh(
-        (len(devices),), (DP_AXIS,), devices=list(devices)
+        (len(devices),),
+        (DP_AXIS,),
+        axis_types=(AxisType.Auto,),
+        devices=list(devices),
     )
 
 
@@ -46,3 +55,24 @@ def sharded(mesh: Mesh) -> NamedSharding:
 
 def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
+
+
+def constrain_replicated(tree, mesh: Mesh):
+    """Inside a jitted program: every leaf of ``tree`` replicated over
+    ``mesh`` (``with_sharding_constraint``, not ``device_put``)."""
+    rep = replicated(mesh)
+    return jax.tree_util.tree_map(
+        lambda x: jax.lax.with_sharding_constraint(x, rep), tree
+    )
+
+
+def constrain_batch_sharded(tree, mesh: Mesh):
+    """Inside a jitted program: every leaf's leading (batch) axis laid over
+    ``dp``, the rest unsharded — with replicated params this is what makes
+    XLA split the learner's compute and psum the gradients."""
+    return jax.tree_util.tree_map(
+        lambda x: jax.lax.with_sharding_constraint(
+            x, NamedSharding(mesh, P(DP_AXIS, *([None] * (x.ndim - 1))))
+        ),
+        tree,
+    )

@@ -38,20 +38,6 @@ from r2d2dpg_tpu.parallel.mesh import DP_AXIS
 from r2d2dpg_tpu.replay.arena import ArenaState, ReplayArena
 from r2d2dpg_tpu.training.trainer import Trainer, TrainerConfig, TrainerState
 
-try:  # jax >= 0.7 exposes shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-# The replication-check kwarg was renamed check_rep -> check_vma.
-import inspect as _inspect
-
-_CHECK_KW = (
-    {"check_vma": False}
-    if "check_vma" in _inspect.signature(shard_map).parameters
-    else {"check_rep": False}
-)
-
 
 def _state_spec() -> TrainerState:
     """PartitionSpec prefix-tree for TrainerState under the ``dp`` mesh."""
@@ -132,9 +118,9 @@ class SPMDTrainer(Trainer):
         mesh = self.mesh
 
         def wrap(fn, out_specs):
-            mapped = shard_map(
+            mapped = jax.shard_map(
                 fn, mesh=mesh, in_specs=(spec,), out_specs=out_specs,
-                **_CHECK_KW,
+                check_vma=False,
             )
             return jax.jit(mapped, donate_argnums=(0,))
 

@@ -12,8 +12,9 @@ pipe while the learner keeps writing new checkpoints into the same dir:
     {"cmd": "quit"}          -> exits after draining
 
 ``--selftest N`` instead drives N synthetic requests through the full
-stack (sessions x buckets x hot-reload poll) and prints the final health
-snapshot — a one-command smoke of the serving path on any box.
+stack (sessions x buckets x hot-reload poll), prints the final health
+snapshot and exits non-zero unless every request was answered ``ok`` — a
+one-command smoke of the serving path on any box.
 """
 
 from __future__ import annotations
@@ -24,6 +25,7 @@ import json
 import sys
 
 from r2d2dpg_tpu.configs import CONFIGS, get_config
+from r2d2dpg_tpu.utils.codes import OK
 
 
 def parse_args(argv=None) -> argparse.Namespace:
@@ -218,8 +220,9 @@ def _serve_stdio(service) -> None:
         print(json.dumps(out), flush=True)
 
 
-def _selftest(service, obs_shape, n: int) -> None:
-    """Drive n synthetic requests (8 interleaved sessions) and print health."""
+def _selftest(service, obs_shape, n: int) -> bool:
+    """Drive n synthetic requests (8 interleaved sessions) and print health.
+    True only when every request came back ``ok``."""
     import numpy as np
 
     rng = np.random.default_rng(0)
@@ -238,6 +241,7 @@ def _selftest(service, obs_shape, n: int) -> None:
         codes[req.code] = codes.get(req.code, 0) + 1
     print(json.dumps({"selftest": n, "codes": codes,
                       **_health_dict(service)}), flush=True)
+    return codes == {OK: n}
 
 
 def main(argv=None) -> None:
@@ -247,7 +251,9 @@ def main(argv=None) -> None:
     import jax
 
     from r2d2dpg_tpu import obs
+    from r2d2dpg_tpu.utils.startup import enable_compile_cache
 
+    enable_compile_cache()
     flight_path = args.flight_path or (
         os.path.join(args.logdir, "flight.jsonl")
         if args.logdir
@@ -273,11 +279,16 @@ def main(argv=None) -> None:
     service, env = build_service(args)
     # Same backend stamp train.py prints — automation gates on it.
     print(f"backend: {jax.default_backend()}", file=sys.stderr, flush=True)
+    passed = True
     with service:
         if args.selftest:
-            _selftest(service, tuple(env.spec.obs_shape), args.selftest)
+            passed = _selftest(
+                service, tuple(env.spec.obs_shape), args.selftest
+            )
         else:
             _serve_stdio(service)
+    if not passed:
+        raise SystemExit("selftest: not every request was answered ok")
 
 
 if __name__ == "__main__":

@@ -330,7 +330,7 @@ def parse_args(argv=None) -> argparse.Namespace:
     p.add_argument(
         "--device-peak-flops", type=float, default=0.0, metavar="FLOPS",
         help="the accelerator's peak FLOP/s for the r2d2dpg_device_mfu "
-        "gauge (e.g. 1.97e14 for a TPU v5p core-pair at bf16).  0 = "
+        "gauge (e.g. 1.97e14 for one TPU v5e chip at bf16).  0 = "
         "unknown: the gauge stays 0 rather than inventing a denominator"
     )
     p.add_argument("--nan-debug", action="store_true")
@@ -577,9 +577,10 @@ def run(args) -> dict:
 
     trainer = topology.build_trainer(topo, cfg)
 
-    # Stamp the resolved backend where automation can gate on it: a TPU
-    # campaign step that silently fell back to CPU must not be mistaken
-    # for an on-chip result (round-3 campaign gates .done markers on this).
+    # Stamp the resolved backend where automation can gate on it: a run
+    # that resolved to the CPU must not be mistaken for an on-chip result
+    # (chip_smoke.py refuses on it; the entry point itself carries on, since
+    # tests drive it on the CPU).
     backend = jax.default_backend()
     print(f"backend: {backend}", flush=True)
     print(f"topology: {topo.describe()}", flush=True)
@@ -1523,7 +1524,11 @@ def _run_fleet(
 
 
 def main(argv=None):
-    run(parse_args(argv))
+    args = parse_args(argv)
+    from r2d2dpg_tpu.utils.startup import enable_compile_cache
+
+    enable_compile_cache()
+    run(args)
 
 
 if __name__ == "__main__":

@@ -1,6 +1,6 @@
-"""Summarize a training run's metrics.csv as the markdown tables RESULTS.md uses.
+"""Summarize a training run's metrics.csv as markdown tables.
 
-Usage: python scripts/summarize_run.py runs/tpu/walker30 [--every N]
+Usage: python scripts/summarize_run.py <logdir> [--every N]
 
 Prints:
 - a curve table (wall min, env steps, eval return) from the deterministic

@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from chip_smoke import SCATTER_CAPACITIES, scatter_case
 from r2d2dpg_tpu.replay import ReplayArena, SequenceBatch
 
 L, OBS, ACT, HID = 4, 3, 2, 8
@@ -111,6 +112,19 @@ def test_priority_update_pallas_kernel():
     np.testing.assert_allclose(
         np.asarray(state.priority)[:4], [5.0, 1.0, 7.0, 1.0], rtol=1e-5
     )
+
+
+@pytest.mark.parametrize("capacity", SCATTER_CAPACITIES)
+def test_priority_scatter_at_config_capacities(capacity):
+    """The interpreted kernel on the very cases chip_smoke.py's kernel leg
+    compiles on the chip: every capacity a config uses (256, 8,000, 50,000,
+    100,000 — all but the first short of a whole 8x128 float32 tile) at the
+    learner batch of 64, with a slot written four times: last write wins."""
+    from r2d2dpg_tpu.ops.pallas.scatter import priority_scatter
+
+    priority, indices, values, want = scatter_case(capacity)
+    got = np.asarray(jax.jit(priority_scatter)(priority, indices, values))
+    np.testing.assert_array_equal(got, want)
 
 
 def test_priority_update_inside_jit():

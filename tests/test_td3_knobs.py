@@ -2,7 +2,7 @@
 double-Q) and target-policy smoothing.
 
 The config-#5 CPU evidence run collapsed from critic overestimation
-(docs/RESULTS.md: q_mean rose 0.15 -> 0.95 while eval return fell); these
+(q_mean rose 0.15 -> 0.95 while eval return fell); these
 knobs are the TD3-family fixes, implemented as a vmapped critic ensemble
 ([2] leading axis on critic leaves, TrainState structure unchanged) and
 clipped noise on the bootstrap action.  Both default OFF — the plain-DDPG
