@@ -42,6 +42,7 @@ from r2d2dpg_tpu.ops import (
     td_errors,
 )
 from r2d2dpg_tpu.replay.arena import SequenceBatch
+from r2d2dpg_tpu.utils.profiling import scope
 
 
 @jax.tree_util.register_dataclass
@@ -382,124 +383,131 @@ class R2D2DPG:
         cfg = self.config
         U = cfg.unroll
 
-        ca_on, ca_tg, cc_on, cc_tg = self._burn_in(state, batch)
+        # scope(): the stages of utils/profiling.py::LEARN_STAGES, which
+        # obs/stages.py reads back from the chip's trace.  ``forward`` wraps
+        # the two value_and_grad calls, so that the operations JAX names
+        # ``transpose(...)`` under it read as ``backward``.
+        with scope("burn_in"):
+            ca_on, ca_tg, cc_on, cc_tg = self._burn_in(state, batch)
 
-        # Training window: [burnin, burnin+U+n) — time-major for the scans.
-        w = slice(cfg.burnin, cfg.seq_len)
-        obs_w = _tm(batch.obs[:, w])
-        act_w = _tm(batch.action[:, w])
-        reset_w = _tm(batch.reset[:, w])
-        rew_w = batch.reward[:, w]  # batch-major [B, U+n]
-        disc_w = batch.discount[:, w]
+        with scope("forward"):
+            # Training window: [burnin, burnin+U+n) — time-major for the scans.
+            w = slice(cfg.burnin, cfg.seq_len)
+            obs_w = _tm(batch.obs[:, w])
+            act_w = _tm(batch.action[:, w])
+            reset_w = _tm(batch.reset[:, w])
+            rew_w = batch.reward[:, w]  # batch-major [B, U+n]
+            disc_w = batch.discount[:, w]
 
-        # --- n-step targets through the target nets (no gradient); plain
-        # DDPG fuses the policy and Q unrolls into one scan, the mitigation
-        # knobs (ensemble min / smoothing noise) reshape it in _target_q.
-        eps_w = None
-        if cfg.target_policy_sigma > 0:
-            if key is None:
-                raise ValueError(
-                    "AgentConfig.target_policy_sigma > 0 requires "
-                    "learner_step(..., key=...)"
-                )
-            eps_w = jnp.clip(
-                cfg.target_policy_sigma
-                * jax.random.normal(key, act_w.shape, act_w.dtype),
-                -cfg.target_policy_clip,
-                cfg.target_policy_clip,
-            )
-        q_tg_tm = self._target_q(state, ca_tg, cc_tg, obs_w, reset_w, eps_w)
-        y = lax.stop_gradient(
-            n_step_targets(
-                rew_w,
-                disc_w,
-                batch.reset[:, w],
-                _tm(q_tg_tm),
-                n=cfg.n_step,
-                gamma=cfg.gamma,
-            )
-        )  # [B, U]
-
-        # Online unrolls only need the U training steps (the n-step tail is
-        # exclusively for target bootstraps) — saves ~n/(U+n) hot-loop LSTM
-        # forward+backward compute.
-        obs_u, act_u, reset_u = obs_w[:U], act_w[:U], reset_w[:U]
-
-        # --- critic update (IS-weighted; SURVEY §2.4 "weighted by IS weights").
-        # Twin mode trains both members against the same min-bootstrapped y
-        # (TD3); td/q metrics and priorities come from member 0.
-        def critic_loss_fn(critic_params):
-            if cfg.twin_critic:
-                q_tm2, _ = jax.vmap(
-                    lambda p, c: self._unroll_critic(
-                        p, c, obs_u, act_u, reset_u
+            # --- n-step targets through the target nets (no gradient); plain
+            # DDPG fuses the policy and Q unrolls into one scan, the mitigation
+            # knobs (ensemble min / smoothing noise) reshape it in _target_q.
+            eps_w = None
+            if cfg.target_policy_sigma > 0:
+                if key is None:
+                    raise ValueError(
+                        "AgentConfig.target_policy_sigma > 0 requires "
+                        "learner_step(..., key=...)"
                     )
-                )(critic_params, cc_on)
-                q2 = jnp.swapaxes(q_tm2, 1, 2)  # [2, B, U]
-                td2 = jax.vmap(td_errors, in_axes=(0, None))(q2, y)
-                per_step = huber(td2) if cfg.use_huber else 0.5 * td2**2
-                # SUM over members (TD3's L = L1 + L2): each member's
-                # gradient matches what it would get as the single critic —
-                # a mean would silently halve the effective critic LR.
-                loss = (is_weights[:, None] * per_step.sum(axis=0)).mean()
-                spread = jnp.abs(q2[0] - q2[1]).mean()
-                return loss, (td2[0], q2[0], spread)
-            q_tm, _ = self._unroll_critic(critic_params, cc_on, obs_u, act_u, reset_u)
-            q = _tm(q_tm)  # [B, U]
-            td = td_errors(q, y)
-            per_step = huber(td) if cfg.use_huber else 0.5 * td**2
-            loss = (is_weights[:, None] * per_step).mean()
-            return loss, (td, q, None)
+                eps_w = jnp.clip(
+                    cfg.target_policy_sigma
+                    * jax.random.normal(key, act_w.shape, act_w.dtype),
+                    -cfg.target_policy_clip,
+                    cfg.target_policy_clip,
+                )
+            q_tg_tm = self._target_q(state, ca_tg, cc_tg, obs_w, reset_w, eps_w)
+            y = lax.stop_gradient(
+                n_step_targets(
+                    rew_w,
+                    disc_w,
+                    batch.reset[:, w],
+                    _tm(q_tg_tm),
+                    n=cfg.n_step,
+                    gamma=cfg.gamma,
+                )
+            )  # [B, U]
 
-        (critic_loss, (td, q_pred, q_spread)), critic_grads = jax.value_and_grad(
-            critic_loss_fn, has_aux=True
-        )(state.critic_params)
+            # Online unrolls only need the U training steps (the n-step tail is
+            # exclusively for target bootstraps) — saves ~n/(U+n) hot-loop LSTM
+            # forward+backward compute.
+            obs_u, act_u, reset_u = obs_w[:U], act_w[:U], reset_w[:U]
 
-        # --- actor update: -Q(s, mu(s)) through the frozen online critic
-        # (member 0 in twin mode, the TD3 convention).
-        cp_pi = (
-            _member(state.critic_params, 0) if cfg.twin_critic
-            else state.critic_params
-        )
-        cc_on_pi = _member(cc_on, 0) if cfg.twin_critic else cc_on
+            # --- critic update (IS-weighted; SURVEY §2.4 "weighted by IS weights").
+            # Twin mode trains both members against the same min-bootstrapped y
+            # (TD3); td/q metrics and priorities come from member 0.
+            def critic_loss_fn(critic_params):
+                if cfg.twin_critic:
+                    q_tm2, _ = jax.vmap(
+                        lambda p, c: self._unroll_critic(
+                            p, c, obs_u, act_u, reset_u
+                        )
+                    )(critic_params, cc_on)
+                    q2 = jnp.swapaxes(q_tm2, 1, 2)  # [2, B, U]
+                    td2 = jax.vmap(td_errors, in_axes=(0, None))(q2, y)
+                    per_step = huber(td2) if cfg.use_huber else 0.5 * td2**2
+                    # SUM over members (TD3's L = L1 + L2): each member's
+                    # gradient matches what it would get as the single critic —
+                    # a mean would silently halve the effective critic LR.
+                    loss = (is_weights[:, None] * per_step.sum(axis=0)).mean()
+                    spread = jnp.abs(q2[0] - q2[1]).mean()
+                    return loss, (td2[0], q2[0], spread)
+                q_tm, _ = self._unroll_critic(critic_params, cc_on, obs_u, act_u, reset_u)
+                q = _tm(q_tm)  # [B, U]
+                td = td_errors(q, y)
+                per_step = huber(td) if cfg.use_huber else 0.5 * td**2
+                loss = (is_weights[:, None] * per_step).mean()
+                return loss, (td, q, None)
 
-        def actor_loss_fn(actor_params):
-            _, q_pi_tm, _ = self._unroll_pi_q(
-                actor_params, cp_pi, ca_on, cc_on_pi, obs_u, reset_u
+            (critic_loss, (td, q_pred, q_spread)), critic_grads = jax.value_and_grad(
+                critic_loss_fn, has_aux=True
+            )(state.critic_params)
+
+            # --- actor update: -Q(s, mu(s)) through the frozen online critic
+            # (member 0 in twin mode, the TD3 convention).
+            cp_pi = (
+                _member(state.critic_params, 0) if cfg.twin_critic
+                else state.critic_params
             )
-            return -q_pi_tm.mean()
+            cc_on_pi = _member(cc_on, 0) if cfg.twin_critic else cc_on
 
-        actor_loss, actor_grads = jax.value_and_grad(actor_loss_fn)(
-            state.actor_params
-        )
+            def actor_loss_fn(actor_params):
+                _, q_pi_tm, _ = self._unroll_pi_q(
+                    actor_params, cp_pi, ca_on, cc_on_pi, obs_u, reset_u
+                )
+                return -q_pi_tm.mean()
+
+            actor_loss, actor_grads = jax.value_and_grad(actor_loss_fn)(
+                state.actor_params
+            )
 
         # --- gradient sync over the mesh (SURVEY §2.8: psum over ICI).
         if cfg.axis_name is not None:
             critic_grads = lax.pmean(critic_grads, cfg.axis_name)
             actor_grads = lax.pmean(actor_grads, cfg.axis_name)
 
-        critic_updates, critic_opt_state = self.critic_tx.update(
-            critic_grads, state.critic_opt_state, state.critic_params
-        )
-        critic_params = optax.apply_updates(state.critic_params, critic_updates)
-        actor_updates, actor_opt_state = self.actor_tx.update(
-            actor_grads, state.actor_opt_state, state.actor_params
-        )
-        actor_params = optax.apply_updates(state.actor_params, actor_updates)
+        with scope("optimizer"):
+            critic_updates, critic_opt_state = self.critic_tx.update(
+                critic_grads, state.critic_opt_state, state.critic_params
+            )
+            critic_params = optax.apply_updates(state.critic_params, critic_updates)
+            actor_updates, actor_opt_state = self.actor_tx.update(
+                actor_grads, state.actor_opt_state, state.actor_params
+            )
+            actor_params = optax.apply_updates(state.actor_params, actor_updates)
 
-        new_state = TrainState(
-            actor_params=actor_params,
-            critic_params=critic_params,
-            target_actor_params=polyak_update(
-                actor_params, state.target_actor_params, cfg.tau
-            ),
-            target_critic_params=polyak_update(
-                critic_params, state.target_critic_params, cfg.tau
-            ),
-            actor_opt_state=actor_opt_state,
-            critic_opt_state=critic_opt_state,
-            step=state.step + 1,
-        )
+            new_state = TrainState(
+                actor_params=actor_params,
+                critic_params=critic_params,
+                target_actor_params=polyak_update(
+                    actor_params, state.target_actor_params, cfg.tau
+                ),
+                target_critic_params=polyak_update(
+                    critic_params, state.target_critic_params, cfg.tau
+                ),
+                actor_opt_state=actor_opt_state,
+                critic_opt_state=critic_opt_state,
+                step=state.step + 1,
+            )
         priorities = sequence_priority(td, eta=cfg.eta)
         metrics = {
             "critic_loss": critic_loss,
@@ -530,7 +538,8 @@ class R2D2DPG:
         actor phase, with the current online/target nets.
         """
         cfg = self.config
-        ca_on, ca_tg, cc_on, cc_tg = self._burn_in(state, batch)
+        with scope("burn_in"):
+            ca_on, ca_tg, cc_on, cc_tg = self._burn_in(state, batch)
         w = slice(cfg.burnin, cfg.seq_len)
         obs_w = _tm(batch.obs[:, w])
         act_w = _tm(batch.action[:, w])

@@ -75,7 +75,7 @@ from r2d2dpg_tpu.fleet.transport import (
 )
 from r2d2dpg_tpu.obs import flight_event, get_registry, get_remote_mirror
 from r2d2dpg_tpu.obs import trace as obs_trace
-from r2d2dpg_tpu.obs.device import flops_of, get_device_monitor
+from r2d2dpg_tpu.obs.device import get_device_monitor
 from r2d2dpg_tpu.obs.quality import (
     get_quality_plane,
     policy_lags,
@@ -1135,9 +1135,6 @@ class FleetLearner:
         # READY the pull limit is clamped to the widths that are
         # (_coalesce_ready), so the drain never blocks on a width compile.
         self._drain_exec: Dict[int, Any] = {}  # total staged B -> compiled
-        # Per-width cost_analysis FLOPs (the warm thread fills it): the
-        # MFU accounting bills each coalesced dispatch its exact width.
-        self._drain_flops: Dict[int, float] = {}
         self._coalesce_ready = 1
         self._warm_thread: Optional[threading.Thread] = None
         # Set when the run is over: the warm thread checks it between
@@ -1241,8 +1238,7 @@ class FleetLearner:
         ``fleet_drain_warm``, so the compile histograms attribute them
         instead of leaving them invisible; each ``drain_width_ready``
         event carries the measured wall seconds of its width's
-        lower+compile, and the width's ``cost_analysis`` FLOPs feed the
-        MFU accounting exactly per dispatch width."""
+        lower+compile."""
         t = self.trainer
         mon = get_device_monitor()
         try:
@@ -1282,9 +1278,6 @@ class FleetLearner:
                         ls_avals, staged_avals
                     ).compile()
                 compile_s = time.monotonic() - t_compile
-                width_flops = flops_of(compiled)
-                if width_flops:
-                    self._drain_flops[w * b0] = width_flops
                 self._drain_exec[w * b0] = compiled
                 self._coalesce_ready = w
                 flight_event(
@@ -1508,26 +1501,12 @@ class FleetLearner:
                         int(np.shape(m_q["staged"].seq.reward)[0]),
                     )
                 exec_ = self._drain_exec.get(n_seqs)
-                note_width = getattr(t, "dp_note_learn_width", None)
+                note_width = getattr(t, "dp_set_learn_width", None)
                 if note_width is not None:
                     # The dp learner's dispatch-width gauge, set at the
                     # REAL drain site (host-known B — no fetch).
                     note_width(n_seqs)
                 mon.on_phase(drained + 1)
-                if drained == drained_at_start:
-                    # MFU numerator for the uncoalesced/width-1 path: one
-                    # lazy lower() at these avals on the log cadence (the
-                    # warm thread's per-width cost_analysis overrides per
-                    # dispatch where it ran).
-                    ls_avals_c, st_avals_c = (
-                        aval_tree(lstate), aval_tree(placed),
-                    )
-                    mon.set_learn_cost(
-                        lambda: flops_of(
-                            self._drain_prog.lower(ls_avals_c, st_avals_c)
-                        )
-                    )
-                mon.note_learn(self._drain_flops.get(n_seqs))
                 with t.arena.staged_writer(), mon.program("fleet_drain"):
                     if exec_ is not None:
                         # AOT-precompiled width (the warm thread's

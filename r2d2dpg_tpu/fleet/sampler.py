@@ -89,7 +89,7 @@ from r2d2dpg_tpu.fleet.ingest import (
 )
 from r2d2dpg_tpu.obs import flight_event, get_registry
 from r2d2dpg_tpu.obs import trace as obs_trace
-from r2d2dpg_tpu.obs.device import avals_of, flops_of, get_device_monitor
+from r2d2dpg_tpu.obs.device import get_device_monitor
 from r2d2dpg_tpu.obs.quality import (
     PROVENANCE_ABSENT,
     get_quality_plane,
@@ -1187,17 +1187,6 @@ class SamplerLearner:
                     # scalars would otherwise default single-device).
                     size = jax.device_put(size, self._replicated)
                 rng, key = jax.random.split(rng)
-                if drained == drained_at_start:
-                    # MFU numerator: one lazy lower() of the pull-learn
-                    # program at these avals, evaluated on the log
-                    # cadence — never a second backend compile.
-                    learn_avals = avals_of((train, seqs, probs, size, key))
-                    mon.set_learn_cost(
-                        lambda: flops_of(
-                            self._learn_prog.lower(*learn_avals)
-                        )
-                    )
-                mon.note_learn()
                 with mon.program("sampler_learn"):
                     train, prios_dev, last_metrics = self._learn_prog(
                         train, seqs, probs, size, key

@@ -20,9 +20,9 @@ One process-wide namespace for every subsystem's operator signals:
   recorder's ``trace.json`` dump.
 - ``device``    — the device plane (ISSUE 14): compile sentinel
   (``steady_recompile`` alarms on post-warm aval re-keys), per-device
-  HBM gauges, MFU against ``--device-peak-flops``, and
-  ``--profile-window`` profiler captures stamped into the fused
-  timeline.
+  HBM gauges, and ``--profile-window`` profiler captures stamped into
+  the fused timeline and reduced to device time by stage of the learner
+  call (``obs/stages.py``).
 - ``quality``   — the experience-quality plane (ISSUE 18): sequence
   provenance (behavior param version + collect phase) stamped at the
   actor and carried through wire/arena/shard slots, folded at batch

@@ -17,7 +17,7 @@ PR 9/11 ``out_shardings`` pins exist to prevent) becomes a runtime alarm
 (``steady_recompile`` flight event + ``r2d2dpg_device_steady_recompiles_
 total``), instead of a mystery 30 s stall in a bench trace.
 
-**Memory + utilization gauges.**  ``publish()`` — called from
+**Memory gauges.**  ``publish()`` — called from
 ``Trainer._obs_publish`` on the existing log cadence, so every loop gets
 it for free and no new device syncs enter the hot path — reads each local
 device's ``memory_stats()`` (``bytes_in_use`` / ``peak_bytes_in_use`` /
@@ -25,13 +25,10 @@ device's ``memory_stats()`` (``bytes_in_use`` / ``peak_bytes_in_use`` /
 backends without allocator stats (CPU) it falls back to summing
 ``jax.live_arrays()`` per device (peak maintained host-side), so the
 series exists everywhere and the /health ``hbm_pressure`` rule degrades
-to absence-of-evidence where no ``bytes_limit`` exists.  MFU rides the
-same cadence: the learn programs' FLOPs (``cost_analysis()`` on the AOT
-compiled drain widths, or ONE lazy ``jit.lower()`` of the loop's learn
-program — lowering only, never a second backend compile) accumulate per
-dispatch (``note_learn``), and ``r2d2dpg_device_mfu`` is the
-publish-window FLOP rate over ``--device-peak-flops`` (0 = unknown peak,
-gauge stays 0 — never a made-up denominator).
+to absence-of-evidence where no ``bytes_limit`` exists.  (The chip's
+utilization is not a gauge here: a compiled program's ``cost_analysis()``
+counts a scan body once, so it cannot be had from the program; the
+benchmark computes ``learn_mfu`` from shapes, ``chipbench/counts.py``.)
 
 **Profiler capture windows.**  ``--profile-window P:N`` arms a
 ``jax.profiler`` trace for train/drain phases P..P+N-1 in WHICHEVER loop
@@ -40,6 +37,11 @@ phase-locked path); ``profile_start``/``profile_stop`` flight events
 bracket the capture so ``obs.flight merge --trace-out`` stamps the window
 as a labelled ``profile_window`` span in the fused Perfetto timeline —
 the capture is findable from the run's own evidence, not tribal memory.
+When the window closes the capture is reduced to device seconds by stage
+of the learner call (``obs/stages.py::stage_table``: the
+``utils/profiling.py::LEARN_STAGES`` scopes as the chip's trace carries
+them), written to ``<logdir>/profile_window/stages.json`` and carried by
+the ``profile_stop`` event.
 
 Lifecycle: ``install()`` registers the (idempotent) listener;
 ``begin_run()`` opens a run window (baselines for ``run_stats()``, steady
@@ -51,10 +53,12 @@ test in a shared pytest process — must never alarm).  docs/OBSERVABILITY
 
 from __future__ import annotations
 
+import glob
+import json
 import os
 import threading
 import time
-from typing import Callable, Dict, Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 from r2d2dpg_tpu.obs.flight import flight_event
 from r2d2dpg_tpu.obs.registry import Registry, get_registry
@@ -69,9 +73,6 @@ METRIC_NAMES = (
     "r2d2dpg_device_hbm_bytes_in_use",
     "r2d2dpg_device_hbm_bytes_peak",
     "r2d2dpg_device_hbm_bytes_limit",
-    "r2d2dpg_device_learn_flops_total",
-    "r2d2dpg_device_mfu",
-    "r2d2dpg_device_peak_flops",
 )
 
 # The jax.monitoring event that IS "one XLA program compiled" (suffix
@@ -82,42 +83,6 @@ _COMPILE_EVENT_SUFFIX = "backend_compile_duration"
 _UNATTRIBUTED = "unattributed"
 
 _tls = threading.local()
-
-
-def flops_of(stage) -> Optional[float]:
-    """The ``flops`` entry of a ``jax.stages`` Lowered/Compiled cost
-    analysis, or None when the backend reports none.  Compiled objects
-    return a per-partition list; Lowered returns one dict — both shapes
-    are tolerated so the AOT drain widths and the lazy ``jit.lower``
-    default feed the same MFU accounting."""
-    try:
-        ca = stage.cost_analysis()
-    except Exception:  # noqa: BLE001 — cost analysis is best-effort
-        return None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
-    if not isinstance(ca, dict):
-        return None
-    try:
-        f = float(ca.get("flops", 0.0))
-    except (TypeError, ValueError):
-        return None
-    return f if f > 0.0 else None
-
-
-def avals_of(tree):
-    """ShapeDtypeStruct tree (shardings preserved) — what the loops
-    capture at their first dispatch so ``set_learn_cost``'s lazy
-    ``jit.lower`` can run later, after the real buffers were donated."""
-    import jax
-    import numpy as np
-
-    return jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(
-            np.shape(x), x.dtype, sharding=getattr(x, "sharding", None)
-        ),
-        tree,
-    )
 
 
 def parse_profile_window(spec: str) -> Tuple[int, int]:
@@ -142,7 +107,7 @@ def parse_profile_window(spec: str) -> Tuple[int, int]:
 
 
 class DeviceMonitor:
-    """Compile sentinel + HBM/MFU gauges + profiler windows (one object).
+    """Compile sentinel + HBM gauges + profiler windows (one object).
 
     The process singleton (``get_device_monitor``) is what the learner
     loops wire; tests construct private instances over their own
@@ -160,17 +125,11 @@ class DeviceMonitor:
         self._compile_seconds_total = 0.0
         self._steady_recompiles_total = 0
         self._base = (0, 0.0, 0)
-        # MFU accounting.
-        self._learn_flops_per_dispatch = 0.0
-        self._learn_cost_fn: Optional[Callable[[], Optional[float]]] = None
-        self._flops_total = 0.0
-        self._peak_flops = 0.0
-        self._pub_anchor: Optional[Tuple[float, float]] = None
         # Host-maintained HBM peaks (CPU fallback has no allocator peak).
         self._hbm_peak: Dict[str, float] = {}
         # Profiler window.
         self._profile: Optional[Tuple[int, int, str]] = None
-        self._profile_active_since: Optional[Tuple[int, float]] = None
+        self._profile_active_since: Optional[Tuple[int, float, str]] = None
 
         self._obs_compiles = reg.counter(
             "r2d2dpg_device_compile_total",
@@ -207,20 +166,6 @@ class DeviceMonitor:
             "per-device allocator capacity (absent where the backend "
             "reports none — the hbm_pressure rule stays disarmed there)",
             labelnames=("device",),
-        )
-        self._obs_flops = reg.counter(
-            "r2d2dpg_device_learn_flops_total",
-            "cost_analysis FLOPs of dispatched learn/drain programs",
-        )
-        self._obs_mfu = reg.gauge(
-            "r2d2dpg_device_mfu",
-            "learn-program FLOP rate over --device-peak-flops across the "
-            "last log-cadence window (0 while the peak is unknown)",
-        )
-        self._obs_peak_flops = reg.gauge(
-            "r2d2dpg_device_peak_flops",
-            "the --device-peak-flops denominator this run was told "
-            "(0 = unknown: MFU stays 0 rather than inventing a peak)",
         )
 
     # ------------------------------------------------------------- listener
@@ -324,7 +269,6 @@ class DeviceMonitor:
                 self._compile_seconds_total,
                 self._steady_recompiles_total,
             )
-            self._pub_anchor = None
             # Per-run peak: without this, a big previous run in the same
             # process would leak its peak into every later run's stats
             # column.  (On allocator backends peak_bytes_in_use is itself
@@ -355,8 +299,8 @@ class DeviceMonitor:
         """Since-``begin_run`` deltas — the stats()/bench columns.
 
         Refreshes the gauges first: a ``log_every=0`` run (every bench
-        leg) never hits the log-cadence ``publish()``, and the peak/MFU
-        ledger would otherwise read 0 at the end of a real run."""
+        leg) never hits the log-cadence ``publish()``, and the peak
+        would otherwise read 0 at the end of a real run."""
         self.publish()
         with self._lock:
             c0, s0, r0 = self._base
@@ -369,71 +313,15 @@ class DeviceMonitor:
                 "peak_hbm_bytes": max(self._hbm_peak.values(), default=0.0),
             }
 
-    # ------------------------------------------------------------------ MFU
-    def configure(self, peak_flops: float = 0.0) -> None:
-        self._peak_flops = max(float(peak_flops), 0.0)
-        self._obs_peak_flops.set(self._peak_flops)
-
-    def set_learn_cost(self, cost) -> None:
-        """The learn program's FLOPs per dispatch: a number, or a zero-arg
-        callable evaluated lazily at the next ``publish()`` (loops pass
-        ``lambda: flops_of(prog.lower(avals...))`` so the one-time trace
-        happens on the log cadence, never on the first hot dispatch)."""
-        if callable(cost):
-            self._learn_cost_fn = cost
-        else:
-            self._learn_flops_per_dispatch = max(float(cost or 0.0), 0.0)
-            self._learn_cost_fn = None
-
-    def note_learn(self, flops: Optional[float] = None) -> None:
-        """One learn/drain dispatch (host-side float adds, no fetch).
-        ``flops`` overrides the registered per-dispatch cost — the fleet
-        drain passes its exact per-width AOT cost."""
-        f = (
-            float(flops)
-            if flops
-            else self._learn_flops_per_dispatch
-        )
-        if f > 0.0:
-            with self._lock:
-                self._flops_total += f
-            self._obs_flops.inc(f)
-
-    def _maybe_eval_learn_cost(self) -> None:
-        fn = self._learn_cost_fn
-        if fn is None:
-            return
-        self._learn_cost_fn = None
-        try:
-            with self.expected("cost_analysis"), self.program(
-                "cost_analysis"
-            ):
-                f = fn()
-        except Exception:  # noqa: BLE001 — MFU is best-effort telemetry
-            f = None
-        if f:
-            self._learn_flops_per_dispatch = float(f)
-
     # --------------------------------------------------------------- gauges
     def publish(self) -> None:
-        """Refresh HBM gauges + the MFU window.  Rides the log cadence
+        """Refresh the HBM gauges.  Rides the log cadence
         (``Trainer._obs_publish``): host-side allocator reads only, no
         device syncs."""
-        self._maybe_eval_learn_cost()
         try:
             self._publish_memory()
         except Exception:  # noqa: BLE001 — telemetry never kills a run
             pass
-        now = time.monotonic()
-        with self._lock:
-            anchor = self._pub_anchor
-            total = self._flops_total
-            self._pub_anchor = (now, total)
-            peak = self._peak_flops
-        if anchor is None or now <= anchor[0]:
-            return
-        rate = (total - anchor[1]) / (now - anchor[0])
-        self._obs_mfu.set(rate / peak if peak > 0.0 else 0.0)
 
     def _publish_memory(self) -> None:
         import jax
@@ -515,7 +403,7 @@ class DeviceMonitor:
             )
             self._profile = None
             return
-        self._profile_active_since = (phase, time.time())
+        self._profile_active_since = (phase, time.time(), logdir)
         flight_event("profile_start", phase=phase, logdir=logdir)
 
     def _stop_profile(self, phase: Optional[int] = None, reason=None) -> None:
@@ -533,13 +421,44 @@ class DeviceMonitor:
                 "profile_failed", error=f"{type(e).__name__}: {e}"
             )
             return
+        seconds = round(time.time() - active[1], 3)
+        stages = None
+        try:
+            stages = self._reduce_capture(active[2])
+        except Exception as e:  # noqa: BLE001 — a reader fault is not the run's
+            flight_event(
+                "profile_failed",
+                error=f"stage table: {type(e).__name__}: {e}",
+            )
         flight_event(
             "profile_stop",
             phase=phase,
             start_phase=active[0],
-            seconds=round(time.time() - active[1], 3),
+            seconds=seconds,
             **({"reason": reason} if reason else {}),
+            **({"stages": stages} if stages else {}),
         )
+
+    @staticmethod
+    def _reduce_capture(logdir: str) -> Dict[str, float]:
+        """The capture just closed, as device seconds by stage of the
+        learner call (``obs/stages.py``): the whole table goes to
+        ``<logdir>/stages.json``, its seconds into ``profile_stop``."""
+        from r2d2dpg_tpu.obs.stages import stage_table
+
+        found = glob.glob(
+            os.path.join(logdir, "plugins", "profile", "*", "*.xplane.pb")
+        )
+        if not found:
+            raise FileNotFoundError(f"no .xplane.pb under {logdir}")
+        table = stage_table(max(found, key=os.path.getmtime))
+        with open(os.path.join(logdir, "stages.json"), "w") as f:
+            json.dump(table, f, indent=1)
+        return {
+            k: round(v, 9)
+            for k, v in table.items()
+            if isinstance(v, (int, float))
+        }
 
 
 _MONITOR = DeviceMonitor()

@@ -1,7 +1,7 @@
 """Experience-quality plane (ISSUE 18): read the run as an RL experiment.
 
 Every earlier plane watches the *system* — bytes, traces, verdicts,
-compiles/HBM/MFU.  None watches the *algorithm*: an Ape-X/R2D2-style
+compiles/HBM.  None watches the *algorithm*: an Ape-X/R2D2-style
 decoupled fleet (PAPERS.md 1803.00933) can be green on every scrape while
 training on stale, low-diversity experience, which is exactly the failure
 mode a shared replay service must surface (PAPERS.md 2110.13506).  This

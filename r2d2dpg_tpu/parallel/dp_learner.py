@@ -255,7 +255,7 @@ class DPLearnerTrainer(Trainer):
         return jax.tree_util.tree_map(put, staged)
 
     # ------------------------------------------------------------------ obs
-    def dp_note_learn_width(self, b: int) -> None:
+    def dp_set_learn_width(self, b: int) -> None:
         """Record the per-shard rows of a REAL drain-learn dispatch
         (called by the fleet drain loop at the dispatch site — not from
         ``_put_staged``, which also places warm-precompile dummies and

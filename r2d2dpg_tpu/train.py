@@ -323,15 +323,11 @@ def parse_args(argv=None) -> argparse.Namespace:
         "resolves to (phase-locked, pipelined, fleet drain, sampler "
         "pull).  profile_start/profile_stop flight events bracket the "
         "capture, and 'obs.flight merge --trace-out' stamps it as a "
-        "labelled profile_window span in the fused Perfetto timeline.  "
+        "labelled profile_window span in the fused Perfetto timeline; "
+        "the capture's device time by stage of the learner call lands in "
+        "<logdir>/profile_window/stages.json and in profile_stop.  "
         "Mutually exclusive with --profile-phases (one jax profiler "
         "session per process); requires --logdir"
-    )
-    p.add_argument(
-        "--device-peak-flops", type=float, default=0.0, metavar="FLOPS",
-        help="the accelerator's peak FLOP/s for the r2d2dpg_device_mfu "
-        "gauge (e.g. 1.97e14 for one TPU v5e chip at bf16).  0 = "
-        "unknown: the gauge stays 0 rather than inventing a denominator"
     )
     p.add_argument("--nan-debug", action="store_true")
     # Observability (docs/OBSERVABILITY.md).
@@ -598,10 +594,9 @@ def run(args) -> dict:
     registry = obs.get_registry()
     flight = obs.get_flight_recorder()
     # Device plane (ISSUE 14, docs/OBSERVABILITY.md "Device plane"):
-    # compile sentinel + HBM/MFU gauges are always armed (the listener is
+    # compile sentinel + HBM gauges are always armed (the listener is
     # ~free; gauges ride the log cadence); the profiler window is opt-in.
     device_mon = obs.get_device_monitor().install()
-    device_mon.configure(peak_flops=args.device_peak_flops)
     if args.profile_window is not None:
         if args.profile_phases:
             raise SystemExit(
@@ -774,18 +769,8 @@ def run(args) -> dict:
                     profiler_cm = profile_trace(f"{args.logdir}/profile")
                     profiler_cm.__enter__()
                 device_mon.on_phase(train_phases_done + 1)
-                if train_phases_done == 0:
-                    # MFU numerator: one lazy lower() of the fused phase
-                    # at these avals, evaluated on the log cadence.
-                    st_avals = obs.device.avals_of(state)
-                    device_mon.set_learn_cost(
-                        lambda: obs.device.flops_of(
-                            trainer.train_phase.lower(st_avals)
-                        )
-                    )
                 with device_mon.program("train_phase"):
                     state, last_learn = trainer.train_phase(state)
-                device_mon.note_learn()
                 train_phases_done += 1
                 if train_phases_done == 1:
                     # The fused phase program is warm: the compile

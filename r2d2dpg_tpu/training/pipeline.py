@@ -64,7 +64,7 @@ import numpy as np
 
 from r2d2dpg_tpu.obs import flight_event, get_registry
 from r2d2dpg_tpu.obs import trace as obs_trace
-from r2d2dpg_tpu.obs.device import avals_of, flops_of, get_device_monitor
+from r2d2dpg_tpu.obs.device import get_device_monitor
 from r2d2dpg_tpu.replay.arena import StagedSequences
 from r2d2dpg_tpu.training.assembler import emit
 from r2d2dpg_tpu.training.trainer import Trainer, TrainerState
@@ -657,21 +657,10 @@ class PipelineExecutor:
                 gphase, staged, ep_refs, tr = item
                 t_dequeue = time.time()
                 mon.on_phase(drained + 1)
-                if drained == 0:
-                    # MFU numerator: one lazy lower() at these avals,
-                    # evaluated on the log cadence — never a second
-                    # backend compile, never on this first hot dispatch.
-                    ls_avals, st_avals = avals_of(ls), avals_of(staged)
-                    mon.set_learn_cost(
-                        lambda: flops_of(
-                            self._drain_prog.lower(ls_avals, st_avals)
-                        )
-                    )
                 with annotate("pipeline/learn"), mon.program(
                     "pipeline_drain"
                 ):
                     ls, metrics = self._drain_prog(ls, staged)
-                mon.note_learn()
                 if tr is not None:
                     # Sampled batch: enqueue = staging-queue residency,
                     # arena_add = the drain call's dispatch window, learn =

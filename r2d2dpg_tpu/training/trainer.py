@@ -124,7 +124,7 @@ class Trainer:
         from r2d2dpg_tpu.obs.device import get_device_monitor
 
         # The device plane (ISSUE 14): ONE process monitor shared by every
-        # loop this trainer may run under — compile sentinel, HBM/MFU
+        # loop this trainer may run under — compile sentinel, HBM
         # gauges riding the log cadence via _obs_publish.
         self._device = get_device_monitor().install()
         reg = get_registry()
@@ -450,7 +450,10 @@ class Trainer:
             train, self._reshard_batch(res.batch), w, key=kl
         )
         if cfg.prioritized:
-            arena = self.arena.update_priorities(arena, res.indices, prios)
+            with scope("priority_update"):
+                arena = self.arena.update_priorities(
+                    arena, res.indices, prios
+                )
         # Experience-quality gauges (obs/quality.py) from values ALREADY
         # in the graph — they ride the metrics dict to the log cadence's
         # batched fetch, never a device sync of their own.  ESS/B uses
@@ -478,7 +481,8 @@ class Trainer:
         priority write-back.  Shared by the in-graph scan (``_learn``) and
         the hybrid trainer's interleaved substep jit, so sampling/anneal/
         write-back semantics cannot drift between the two paths."""
-        res = self.arena.sample(arena, key, self.config.batch_size)
+        with scope("replay_sample"):
+            res = self.arena.sample(arena, key, self.config.batch_size)
         return self._update_step(train, arena, res, key)
 
     def _learn_many(
@@ -509,13 +513,17 @@ class Trainer:
         else:
             # Batch k keeps its phase-locked sample key (keys[k]); only the
             # priorities it is drawn against are one write-back stale.
-            res0 = self.arena.sample(arena, keys[0], cfg.batch_size)
+            with scope("replay_sample"):
+                res0 = self.arena.sample(arena, keys[0], cfg.batch_size)
             next_keys = jnp.roll(keys, -1, axis=0)  # keys[k+1]; last unused
 
             def one_prefetch(carry, ks):
                 train, arena, res = carry
                 key, next_key = ks
-                next_res = self.arena.sample(arena, next_key, cfg.batch_size)
+                with scope("replay_sample"):
+                    next_res = self.arena.sample(
+                        arena, next_key, cfg.batch_size
+                    )
                 train, arena, metrics = self._update_step(train, arena, res, key)
                 return (train, arena, next_res), metrics
 
@@ -543,8 +551,9 @@ class Trainer:
     def _train_phase(
         self, state: TrainerState
     ) -> Tuple[TrainerState, Dict[str, jnp.ndarray]]:
-        # scope(): HLO-metadata names so the TB profiler timeline shows the
-        # collect/emit/learn stages of the fused phase (utils/profiling.py).
+        # scope(): HLO-metadata names so the profiler timeline shows the
+        # collect/emit/learn stages of the fused phase; the learner's own
+        # stages (utils/profiling.py::LEARN_STAGES) nest under ``learn``.
         if self.config.param_sync_every > 0:
             # Persist the snapshot *before* collecting (phase_idx is still
             # this phase's index), so the params _collect acts with are
@@ -637,7 +646,7 @@ class Trainer:
                 is_saturation=metrics.get("quality_is_saturation"),
                 replay_age_mean=metrics.get("quality_replay_age"),
             )
-        # Device-plane gauges (HBM in-use/peak, the MFU window) refresh on
+        # Device-plane gauges (HBM in-use/peak) refresh on
         # the same cadence — host-side allocator reads, no device syncs.
         self._device.publish()
 
@@ -669,23 +678,10 @@ class Trainer:
                         state = self.fill_phase(state)
                 else:
                     mon.on_phase(train_done + 1)
-                    if train_done == 0:
-                        from r2d2dpg_tpu.obs.device import flops_of
-
-                        # MFU numerator: ONE lazy lower() of the fused
-                        # train phase at these avals, evaluated on the log
-                        # cadence (never a second backend compile).
-                        st_avals = self._device_avals(state)
-                        mon.set_learn_cost(
-                            lambda: flops_of(
-                                self.train_phase.lower(st_avals)
-                            )
-                        )
                     with annotate("trainer/train_phase"), mon.program(
                         "train_phase"
                     ):
                         state, last_metrics = self.train_phase(state)
-                    mon.note_learn()
                     train_done += 1
                     if train_done == 1:
                         # The fused phase program is warm: any later
@@ -718,8 +714,3 @@ class Trainer:
             mon.end_run()
         return state
 
-    def _device_avals(self, tree):
-        """Aval capture for the device monitor's lazy cost analysis."""
-        from r2d2dpg_tpu.obs.device import avals_of
-
-        return avals_of(tree)

@@ -54,11 +54,34 @@ def annotate(name: str) -> Iterator[None]:
         yield
 
 
+# The stages of one learner update, in the order they run.  Each is a
+# ``scope`` at a boundary of the learner call (``Trainer._learn_step`` /
+# ``_update_step``, ``R2D2DPG.learner_step``); ``obs/stages.py`` folds a
+# capture's device time by them and derives ``backward`` from ``forward``
+# (JAX wraps the differentiated scope's name in ``transpose(...)``).  This
+# is the only list of the names.
+LEARN_STAGES = (
+    "replay_sample",
+    "burn_in",
+    "forward",
+    "optimizer",
+    "priority_update",
+)
+
+
 def scope(name: str):
     """Name a region of TRACED code: ops inside the block carry ``name`` in
-    their HLO metadata, so the TB profiler timeline groups a fused phase's
-    collect/emit/learn stages.  Safe under jit (this is ``jax.named_scope``);
-    pairs with ``annotate`` which covers the host side."""
+    their HLO metadata (``op_name``, a ``/``-separated path of the enclosing
+    scopes and transforms).  Safe under jit (this is ``jax.named_scope``);
+    pairs with ``annotate`` which covers the host side.
+
+    The names reach the chip's trace: the profiler stores each executed
+    instruction's path as the stat ``tf_op`` of its event metadata on the
+    device plane, and the program's whole ``Hlo Proto`` in the plane
+    ``/host:metadata`` (``jax.profiler.ProfileData`` shows neither).
+    ``obs/stages.py::stage_table`` reads them: it is what
+    ``--profile-window`` writes to ``stages.json`` and what the benchmark's
+    ``learn_stage_ms.*`` metrics report."""
     return jax.named_scope(name)
 
 

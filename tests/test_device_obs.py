@@ -2,7 +2,7 @@
 
 The sentinel's contract both ways: an injected aval re-key (changed batch
 width post-steady) fires EXACTLY one ``steady_recompile`` event, and
-warm-up / declared-window compiles never do.  Plus the HBM/MFU gauges'
+warm-up / declared-window compiles never do.  Plus the HBM gauges'
 CPU-fallback behavior, the profiler capture window through a real
 ``jax.profiler`` session, and the flight-merge fusion that stamps the
 window into the Perfetto timeline.
@@ -10,17 +10,15 @@ window into the Perfetto timeline.
 
 import json
 import os
-import time
 
 import jax
 import jax.numpy as jnp
 import pytest
 
 from r2d2dpg_tpu import obs
+from r2d2dpg_tpu.obs import stages
 from r2d2dpg_tpu.obs.device import (
     DeviceMonitor,
-    avals_of,
-    flops_of,
     get_device_monitor,
     parse_profile_window,
 )
@@ -142,40 +140,6 @@ def test_hbm_gauges_cpu_fallback_and_peak(monitor):
     assert mon.run_stats()["peak_hbm_bytes"] >= peak1
 
 
-def test_mfu_gauge_rate_over_declared_peak(monitor):
-    reg, mon = monitor
-    mon.configure(peak_flops=1000.0)
-    assert reg.get("r2d2dpg_device_peak_flops").value == 1000.0
-    mon.set_learn_cost(100.0)
-    mon.publish()  # opens the window
-    for _ in range(10):
-        mon.note_learn()
-    time.sleep(0.05)
-    mon.publish()
-    # 1000 FLOPs over >= 0.05 s against a 1000 FLOP/s peak: MFU in (0, 20].
-    mfu = reg.get("r2d2dpg_device_mfu").value
-    assert 0.0 < mfu <= 20000.0
-    assert reg.get("r2d2dpg_device_learn_flops_total").value == 1000.0
-    # Lazy cost callables evaluate at publish time, off the hot path.
-    mon.set_learn_cost(lambda: 7.0)
-    mon.publish()
-    mon.note_learn()
-    assert reg.get("r2d2dpg_device_learn_flops_total").value == 1007.0
-    # An explicit per-dispatch cost (the fleet's per-width AOT flops)
-    # overrides the default.
-    mon.note_learn(flops=50.0)
-    assert reg.get("r2d2dpg_device_learn_flops_total").value == 1057.0
-
-
-def test_flops_of_lowered_and_compiled():
-    f = jax.jit(lambda x: jnp.tanh(x @ x))
-    lowered = f.lower(avals_of(jnp.ones((8, 8))))
-    fl = flops_of(lowered)
-    assert fl is not None and fl > 0
-    assert flops_of(lowered.compile()) is not None
-    assert flops_of(object()) is None  # no cost_analysis: None, no raise
-
-
 def test_parse_profile_window_grammar():
     assert parse_profile_window("3:2") == (3, 2)
     for bad in ("3", "a:b", "0:2", "3:0", "1:2:3"):
@@ -202,6 +166,14 @@ def test_profile_window_start_stop_and_merge_fusion(tmp_path, monitor):
     assert new[0]["phase"] == 2 and new[1]["phase"] == 4
     assert new[1]["seconds"] >= 0.0
     assert os.path.isdir(logdir)  # the profiler wrote its session here
+    # The closed capture is reduced by stage of the learner call
+    # (obs/stages.py): the table is on disk and in profile_stop.  A CPU
+    # capture has no device plane, so every stage reads 0 there.
+    table = json.loads((logdir / "stages.json").read_text())
+    assert set(stages.table_keys()) | {"busy", "devices"} <= set(table)
+    assert table["devices"] == 0 and table["busy"] == 0.0
+    assert new[1]["stages"]["devices"] == 0
+    assert set(stages.table_keys()) <= set(new[1]["stages"])
     # The merge CLI pairs the events into a labelled span (ISSUE 14:
     # the capture window is visible IN the timeline it profiles).
     from r2d2dpg_tpu.obs import flight as flight_mod
@@ -217,6 +189,30 @@ def test_profile_window_start_stop_and_merge_fusion(tmp_path, monitor):
     spans = [e for e in doc["traceEvents"] if e["name"] == "profile_window"]
     assert len(spans) == 1
     assert spans[0]["dur"] >= 0 and spans[0]["args"]["phase"] == 2
+
+
+def test_profile_window_reader_fault_is_an_event_not_the_runs(
+    tmp_path, monitor, monkeypatch
+):
+    """A capture the stage reader cannot read costs the run nothing: a
+    ``profile_failed`` event, and ``profile_stop`` without a table."""
+    _reg, mon = monitor
+    rec = obs.get_flight_recorder()
+    n0 = len(rec.events())
+
+    def broken(path, stages=None):
+        raise ValueError("not an XSpace")
+
+    monkeypatch.setattr(stages, "stage_table", broken)
+    mon.arm_profile("1:1", str(tmp_path / "profile_window"))
+    for phase in (1, 2):
+        mon.on_phase(phase)
+    new = [e for e in rec.events()[n0:] if e["kind"].startswith("profile_")]
+    assert [e["kind"] for e in new] == [
+        "profile_start", "profile_failed", "profile_stop"
+    ]
+    assert "not an XSpace" in new[1]["error"]
+    assert "stages" not in new[2]
 
 
 def test_profile_window_span_pairing_unit():

@@ -146,7 +146,7 @@ def test_dp2_log_extra_refs_publish_shard_gauges():
     refs = t._log_extra_refs(lstate.arena)
     assert len(refs) == 1
     t._log_extra_publish(jax.device_get(refs))
-    t.dp_note_learn_width(4)  # the fleet drain site's dispatch-width note
+    t.dp_set_learn_width(4)  # the fleet drain site's dispatch-width note
     snap = get_registry().snapshot()
     samples = snap["r2d2dpg_dp_shard_occupancy"]["samples"]
     by_shard = {s["labels"]["shard"]: s["value"] for s in samples}
