@@ -24,6 +24,7 @@ land in ``chiprun_out/chip_smoke/``.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import csv
 import json
 import math
@@ -136,7 +137,6 @@ def _serve(ckpt: str, *flags: str) -> tuple:
     """``python -m r2d2dpg_tpu serve --config walker_r2d2 --selftest 64``
     over a train leg's checkpoint; returns ``(health, service)``: the
     selftest's health record and the service it drove."""
-    import contextlib
     import io
 
     from r2d2dpg_tpu import serve
@@ -214,13 +214,80 @@ def _leg_kernel(work: str) -> dict:
     return {"capacities": list(SCATTER_CAPACITIES), "batch": SCATTER_BATCH}
 
 
+@contextlib.contextmanager
+def _built_trainers():
+    """``[(trainer, state), ...]`` for every trainer ``train.main`` builds
+    inside the block, ``state`` holding the ``train``, ``arena`` and ``rng``
+    its ``init`` returned as shapes (dtype and sharding, no buffers: the
+    run's own state is donated away)."""
+    import types
+
+    import jax
+
+    from r2d2dpg_tpu import topology
+
+    built: list = []
+    build_trainer = topology.build_trainer
+
+    def build_and_watch(topo, cfg):
+        trainer = build_trainer(topo, cfg)
+        init = trainer.init
+
+        def init_and_note(*a, **kw):
+            state = init(*a, **kw)
+            shapes = jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(
+                    x.shape, x.dtype, sharding=x.sharding),
+                {"train": state.train, "arena": state.arena, "rng": state.rng},
+            )
+            built.append((trainer, types.SimpleNamespace(**shapes)))
+            return state
+
+        trainer.init = init_and_note
+        return trainer
+
+    topology.build_trainer = build_and_watch
+    try:
+        yield built
+    finally:
+        topology.build_trainer = build_trainer
+
+
+def _require_no_arena_convert(trainer, state) -> dict:
+    """The whole-arena convert guard (docs/OBSERVABILITY.md): the learner
+    call (``Trainer._learn_many``, state donated), compiled by this chip's
+    compiler at the run's own shapes, rounds no ``[capacity, ...]`` value.
+    Only the TPU compiler makes that rewrite, so only a chip run can check
+    that ``ReplayArena.sample``'s boundary still holds it off."""
+    import jax
+
+    from r2d2dpg_tpu.obs.hlo import arena_converts
+
+    call = jax.jit(trainer._learn_many, donate_argnums=(0, 1))
+    hlo = call.lower(state.train, state.arena, state.rng).compile().as_text()
+    found = arena_converts(hlo, trainer.arena.capacity)
+    _require(
+        not found,
+        f"the learner call converts the whole arena once a call: {found}",
+    )
+    return {
+        "learner_call_hlo_lines": hlo.count("\n"),
+        "arena_capacity": trainer.arena.capacity,
+        "arena_converts": found,
+    }
+
+
 def _leg_train(work: str) -> dict:
     """Base ``Trainer``: host MuJoCo pool through ordered ``io_callback``
     inside the jitted phase, the HBM arena at capacity 100,000, the Pallas
-    write-back, donated state."""
+    write-back, donated state; then the learner call alone, compiled for the
+    whole-arena convert guard."""
     _fresh_native_build()
-    checks = _train(work, "walker_r2d2")
+    with _built_trainers() as built:
+        checks = _train(work, "walker_r2d2")
     _require_native_pool()
+    _require(len(built) == 1, f"{len(built)} trainers were initialised")
+    checks["learner_call"] = _require_no_arena_convert(*built[0])
     return checks
 
 
@@ -255,31 +322,13 @@ def _mesh_train(work: str, config: str, *flags: str) -> dict:
     """A train leg on a four-device mesh, with its placement proof."""
     import jax
 
-    from r2d2dpg_tpu import topology
-
-    arena_devices: list = []
-    build_trainer = topology.build_trainer
-
-    def build_and_watch(topo, cfg):
-        trainer = build_trainer(topo, cfg)
-        init = trainer.init
-
-        def init_and_note(*a, **kw):
-            state = init(*a, **kw)
-            arena_devices.extend(
-                len(leaf.sharding.device_set)
-                for leaf in jax.tree_util.tree_leaves(state.arena)
-            )
-            return state
-
-        trainer.init = init_and_note
-        return trainer
-
-    topology.build_trainer = build_and_watch
-    try:
+    with _built_trainers() as built:
         checks = _train(work, config, *flags)
-    finally:
-        topology.build_trainer = build_trainer
+    arena_devices = [
+        len(leaf.sharding.device_set)
+        for _, state in built
+        for leaf in jax.tree_util.tree_leaves(state.arena)
+    ]
     checks.update(_require_spread(4, arena_devices))
     return checks
 

@@ -22,6 +22,16 @@ host round-trip ever touches the replay path:
 Sequence layout (SURVEY §2.2 "sequence format"): each slot stores a
 fixed-length window of ``burnin + unroll + n_step`` steps plus the initial
 recurrent carries of actor and critic nets captured at window start.
+
+The sampled batch is a boundary: ``sample`` hands its B rows back in the
+arena's own dtypes, behind ``_pin_storage_dtypes``.  Without it the TPU
+compiler rounds the whole arena once a call instead of the rows: the rows are
+operands of default-precision matmuls, so its bfloat16 propagation retypes
+the gather and puts the ``convert`` on the gather's ``[capacity, ...]``
+operand, outside the update loop.  At 524,288 walker sequences that was 3.2 GB
+read and 1.6 GB written in every call of 4 updates, 1.88 of 2.79 ms an update
+(PERF.md, PR 23 and PR 25), and no CPU test can see it:
+``chip_smoke.py``'s train leg guards it (``obs/hlo.py``).
 """
 
 from __future__ import annotations
@@ -33,6 +43,7 @@ from typing import Any, Dict, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from r2d2dpg_tpu.obs.quality import PROVENANCE_ABSENT
 from r2d2dpg_tpu.ops.priority import PRIORITY_EPS
@@ -189,6 +200,36 @@ class _StagedWriterClaim:
 
     def __exit__(self, *exc):
         self._lock.release()
+
+
+def _pin_storage_dtypes(batch: SequenceBatch) -> SequenceBatch:
+    """The identity on values, and the place where storage precision ends.
+
+    The float rows ``sample`` gathered are materialised here in the arena's
+    own dtypes, so no consumer's dtype wish can travel back through the
+    gather onto its ``[capacity, ...]`` operand (module docstring, "The
+    sampled batch is a boundary").  A bare ``optimization_barrier`` does not
+    hold: the TPU compiler's bfloat16 propagation retypes the barrier with
+    the gather behind it.  So a float leaf crosses the barrier as its bit
+    pattern, an unsigned integer of its own width, which no float type can
+    be propagated into; B rows are cast, never the arena (casting the arena
+    before the gather is hoisted out of the update loop and materialised: a
+    second copy of the replay).  Integer leaves (pixels) cannot be rounded
+    and pass untouched: a barrier on them only takes the choice of the
+    gather's output layout from the compiler (6.6 x on the pixel gather
+    jitted alone, PERF.md PR 26)."""
+
+    def pin(x):
+        if not jnp.issubdtype(x.dtype, jnp.floating):
+            return x
+        bits = lax.bitcast_convert_type(
+            x, jnp.dtype(f"uint{8 * x.dtype.itemsize}")
+        )
+        return lax.bitcast_convert_type(
+            lax.optimization_barrier(bits), x.dtype
+        )
+
+    return jax.tree_util.tree_map(pin, batch)
 
 
 class ReplayArena:
@@ -413,6 +454,11 @@ class ReplayArena:
 
         Caller must ensure the arena is non-empty (the training loop gates on
         a warm-up size; SURVEY §2.5 "Lifecycle" row).
+
+        The batch comes back through ``_pin_storage_dtypes``: the identity on
+        values, and not a no-op.  It keeps the chip's compiler from rounding
+        the whole arena to bfloat16 once a call (two thirds of walker's
+        learner time before it: 357.6 updates/s at 524,288 slots, PR 23).
         """
         size = self.size(state)
         if self.prioritized:
@@ -436,7 +482,9 @@ class ReplayArena:
             )
 
         batch = jax.tree_util.tree_map(lambda buf: buf[indices], state.data)
-        return SampleResult(batch=batch, indices=indices, probs=probs)
+        return SampleResult(
+            batch=_pin_storage_dtypes(batch), indices=indices, probs=probs
+        )
 
     # ------------------------------------------------------- priority update
     def update_priorities(

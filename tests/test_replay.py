@@ -424,3 +424,80 @@ def test_sampled_batch_contents_roundtrip():
     assert (np.asarray(res.indices) == 0).mean() > 0.9
     row0 = np.asarray(res.batch.reward)[np.asarray(res.indices) == 0]
     np.testing.assert_allclose(row0, 0.0)
+
+
+def _mixed_state(arena, n=12):
+    """An arena whose leaves are float32 and uint8 (pixel observations), with
+    bit patterns a rounding or a float compare would lose: a NaN payload,
+    -0.0, a subnormal, and mantissas bfloat16 cannot hold."""
+    rng = np.random.default_rng(0)
+    action = rng.standard_normal((n, L, ACT)).astype(np.float32)
+    action[0, 0] = np.array([0x7FC00123, 0x80000000], np.uint32).view(np.float32)
+    action[1, 0, 0] = np.float32(1e-45)
+
+    def carry():
+        return jnp.asarray(rng.standard_normal((n, HID)).astype(np.float32))
+
+    batch = SequenceBatch(
+        obs=jnp.asarray(rng.integers(0, 256, (n, L, 2, 2, 3), dtype=np.uint8)),
+        action=jnp.asarray(action),
+        reward=jnp.asarray(rng.random((n, L), dtype=np.float32)),
+        discount=jnp.ones((n, L)),
+        reset=jnp.zeros((n, L)),
+        carries={"actor": (carry(), carry()), "critic": (carry(), carry())},
+    )
+    state = arena.init_state(batch)
+    return arena.add(state, batch, jnp.asarray(rng.random(n) + 0.1, jnp.float32))
+
+
+def _bits(x):
+    x = np.asarray(x)
+    return x.view(f"uint{8 * x.dtype.itemsize}")
+
+
+@pytest.mark.parametrize("mode", ["eager", "jit", "scan", "vmap"])
+@pytest.mark.parametrize("prioritized", [False, True], ids=["uniform", "prioritized"])
+def test_sample_is_the_plain_gather_in_the_arenas_own_dtypes(
+    prioritized, mode, monkeypatch
+):
+    """The boundary ``sample`` puts after its gather is the identity on bits:
+    batch, indices and probs are what the sample without it returns, and the
+    batch is ``buf[indices]`` leaf by leaf, in the arena's dtypes."""
+    from r2d2dpg_tpu.replay import arena as arena_mod
+
+    B = 5
+    arena = ReplayArena(capacity=16, prioritized=prioritized)
+    state = _mixed_state(arena)
+    keys = jax.random.split(jax.random.PRNGKey(3), 2)
+
+    def draw():
+        def sample(s, k):
+            return arena.sample(s, k, B)
+
+        if mode == "eager":
+            return sample(state, keys[0])
+        if mode == "jit":
+            return jax.jit(sample)(state, keys[0])
+        if mode == "scan":
+            return jax.jit(
+                lambda s, ks: jax.lax.scan(lambda c, k: (c, sample(c, k)), s, ks)[1]
+            )(state, keys)
+        return jax.vmap(lambda k: sample(state, k))(keys)
+
+    got = draw()
+    monkeypatch.setattr(arena_mod, "_pin_storage_dtypes", lambda batch: batch)
+    plain = draw()
+
+    want = jax.tree_util.tree_map(lambda buf: buf[got.indices], state.data)
+    for g, w, buf in zip(
+        jax.tree_util.tree_leaves(got.batch),
+        jax.tree_util.tree_leaves(want),
+        jax.tree_util.tree_leaves(state.data),
+    ):
+        assert g.dtype == buf.dtype
+        np.testing.assert_array_equal(_bits(g), _bits(w))
+    assert {str(x.dtype) for x in jax.tree_util.tree_leaves(got.batch)} == {
+        "float32", "uint8"}
+    for g, p in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(plain)):
+        assert g.dtype == p.dtype
+        np.testing.assert_array_equal(_bits(g), _bits(p))
