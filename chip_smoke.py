@@ -277,17 +277,56 @@ def _require_no_arena_convert(trainer, state) -> dict:
     }
 
 
+def _sdar_learner_call() -> tuple:
+    """``humanoid_sdar_moe``'s trainer and the shapes of its learner call's
+    arguments, from the configuration alone: nothing is initialised (460 M
+    parameters and a 1.5 GB arena stay shapes), and the learner never
+    touches the environment, so a stand-in holds its two sizes."""
+    import types
+
+    import jax
+    import jax.numpy as jnp
+
+    from r2d2dpg_tpu.configs import get_config
+    from r2d2dpg_tpu.replay.arena import SequenceBatch
+    from r2d2dpg_tpu.training.trainer import Trainer
+
+    exp = get_config("humanoid_sdar_moe")
+    obs_dim, act_dim = 67, 21  # DM-Control humanoid-run
+    env = types.SimpleNamespace(
+        spec=types.SimpleNamespace(action_dim=act_dim, obs_shape=(obs_dim,)))
+    trainer = Trainer(env, exp.build_agent(env), exp.trainer)
+    L = exp.agent.seq_len
+
+    def arena(key):
+        z = lambda *shape: jnp.zeros((1, L) + shape, jnp.float32)  # noqa: E731
+        return trainer.arena.init_state(SequenceBatch(
+            obs=z(obs_dim), action=z(act_dim), reward=z(), discount=z(), reset=z(),
+            carries={"actor": (), "critic": ()}))
+
+    key = jax.random.PRNGKey(0)
+    shapes = jax.eval_shape(
+        lambda k: {
+            "train": trainer.agent.init(
+                k, jnp.zeros((1, obs_dim)), jnp.zeros((1, act_dim))),
+            "arena": arena(k), "rng": k},
+        key)
+    return trainer, types.SimpleNamespace(**shapes)
+
+
 def _leg_train(work: str) -> dict:
     """Base ``Trainer``: host MuJoCo pool through ordered ``io_callback``
     inside the jitted phase, the HBM arena at capacity 100,000, the Pallas
     write-back, donated state; then the learner call alone, compiled for the
-    whole-arena convert guard."""
+    whole-arena convert guard, for ``walker_r2d2`` and for the sequence
+    core's configuration ``humanoid_sdar_moe``."""
     _fresh_native_build()
     with _built_trainers() as built:
         checks = _train(work, "walker_r2d2")
     _require_native_pool()
     _require(len(built) == 1, f"{len(built)} trainers were initialised")
     checks["learner_call"] = _require_no_arena_convert(*built[0])
+    checks["learner_call_sdar_moe"] = _require_no_arena_convert(*_sdar_learner_call())
     return checks
 
 

@@ -34,6 +34,7 @@ import optax
 from jax import lax
 
 from r2d2dpg_tpu.models.actor_critic import ActorNet, Carry, CriticNet, unroll
+from r2d2dpg_tpu.models.sdar_moe import moe_metrics
 from r2d2dpg_tpu.ops import (
     huber,
     n_step_targets,
@@ -137,6 +138,17 @@ class R2D2DPG:
         self.actor = actor
         self.critic = critic
         self.config = config
+        # The sdar core is not stepped by the learner: the ``_unroll_*``
+        # helpers hand it whole sequences, the carry between burn-in and
+        # window is the prefix's keys and values, and what comes back where a
+        # scan returns its last carry are the pass's expert loads.
+        self.sdar = actor.sdar is not None
+        if self.sdar != (critic.sdar is not None):
+            raise ValueError("actor and critic must both have the sdar core, or neither")
+        if self.sdar and (config.twin_critic or config.target_policy_sigma > 0):
+            raise ValueError(
+                "twin_critic and target_policy_sigma are not wired for the sdar core"
+            )
 
         def tx(lr: float) -> optax.GradientTransformation:
             if config.grad_clip is not None:
@@ -186,11 +198,22 @@ class R2D2DPG:
 
     # --------------------------------------------------------------- unrolls
     def _unroll_actor(self, params, carry, obs_tm, reset_tm):
+        if self.sdar:
+            a, aux = self.actor.apply(
+                params, _tm(obs_tm), _tm(reset_tm), carry, method="sequence"
+            )
+            return _tm(a), aux["load"]
         return unroll(
             lambda c, o, r: self.actor.apply(params, o, c, r), carry, obs_tm, reset_tm
         )
 
     def _unroll_critic(self, params, carry, obs_tm, act_tm, reset_tm):
+        if self.sdar:
+            q, aux = self.critic.apply(
+                params, _tm(obs_tm), _tm(act_tm), _tm(reset_tm), carry,
+                method="sequence",
+            )
+            return _tm(q), aux["load"]
         return unroll(
             lambda c, o, a, r: self.critic.apply(params, o, a, c, r),
             carry,
@@ -209,6 +232,12 @@ class R2D2DPG:
         target pass and the actor loss) — per-step math is identical to the
         two-scan version, the cells just step together.
         """
+        if self.sdar:
+            a_tm, load_a = self._unroll_actor(actor_params, ca, obs_tm, reset_tm)
+            q_tm, load_c = self._unroll_critic(
+                critic_params, cc, obs_tm, a_tm, reset_tm
+            )
+            return a_tm, q_tm, (load_a, load_c)
 
         def step(carry, o, r):
             ca, cc = carry
@@ -237,15 +266,21 @@ class R2D2DPG:
         return q2.min(axis=0), carry
 
     def _target_q(self, state, ca_tg, cc_tg, obs_tm, reset_tm, eps_tm):
-        """Bootstrap Q through the target nets, time-major ``[T, B]``.
+        """Bootstrap Q through the target nets, time-major ``[T, B]``."""
+        return self._target_unroll(state, ca_tg, cc_tg, obs_tm, reset_tm, eps_tm)[0]
 
+    def _target_unroll(self, state, ca_tg, cc_tg, obs_tm, reset_tm, eps_tm):
+        """``_target_q``'s worker.
+
+        Returns it with what the unroll left behind (the last carries; the
+        sdar core's expert loads).
         Plain DDPG (twin off, sigma 0) takes the fused pi+Q scan unchanged;
         otherwise the per-step action is smoothed with the pre-drawn clipped
         noise ``eps_tm`` (TD3 target-policy smoothing) and/or Q is the min
         over the target-critic ensemble (clipped double-Q).
         """
         if not self.config.twin_critic and eps_tm is None:
-            _, q_tm, _ = self._unroll_pi_q(
+            _, q_tm, last = self._unroll_pi_q(
                 state.target_actor_params,
                 state.target_critic_params,
                 ca_tg,
@@ -253,7 +288,7 @@ class R2D2DPG:
                 obs_tm,
                 reset_tm,
             )
-            return q_tm
+            return q_tm, last
         ap, cp = state.target_actor_params, state.target_critic_params
 
         def step(carry, o, r, *e):
@@ -265,8 +300,7 @@ class R2D2DPG:
             return q, (ca, cc)
 
         xs = (obs_tm, reset_tm) + (() if eps_tm is None else (eps_tm,))
-        q_tm, _ = unroll(step, (ca_tg, cc_tg), *xs)
-        return q_tm
+        return unroll(step, (ca_tg, cc_tg), *xs)
 
     def _burn_in(
         self, state: TrainState, batch: SequenceBatch
@@ -277,6 +311,27 @@ class R2D2DPG:
         and target nets each burn in from the *stored* initial state.
         """
         cfg = self.config
+        if self.sdar:
+            # R2D2's burn-in in attention's terms: the prefix's keys and
+            # values in every layer, recomputed with today's weights, are the
+            # memory the window attends to (the replay stores no carry).
+            if cfg.burnin == 0:
+                return (), (), (), ()
+            pre = slice(0, cfg.burnin)
+            obs, act, reset = batch.obs[:, pre], batch.action[:, pre], batch.reset[:, pre]
+
+            def mem_a(p):
+                return self.actor.apply(
+                    p, obs, reset, memory_only=True, method="sequence")[1]
+
+            def mem_c(p):
+                return self.critic.apply(
+                    p, obs, act, reset, memory_only=True, method="sequence")[1]
+
+            return lax.stop_gradient((
+                mem_a(state.actor_params), mem_a(state.target_actor_params),
+                mem_c(state.critic_params), mem_c(state.target_critic_params),
+            ))
         nq = 2 if cfg.twin_critic else 1
         ca0, cc0 = batch.carries["actor"], batch.carries["critic"]
         # With twin critics the stored carry seeds BOTH members (collection
@@ -415,7 +470,9 @@ class R2D2DPG:
                     -cfg.target_policy_clip,
                     cfg.target_policy_clip,
                 )
-            q_tg_tm = self._target_q(state, ca_tg, cc_tg, obs_w, reset_w, eps_w)
+            q_tg_tm, last_tg = self._target_unroll(
+                state, ca_tg, cc_tg, obs_w, reset_w, eps_w
+            )
             y = lax.stop_gradient(
                 n_step_targets(
                     rew_w,
@@ -450,15 +507,17 @@ class R2D2DPG:
                     # a mean would silently halve the effective critic LR.
                     loss = (is_weights[:, None] * per_step.sum(axis=0)).mean()
                     spread = jnp.abs(q2[0] - q2[1]).mean()
-                    return loss, (td2[0], q2[0], spread)
-                q_tm, _ = self._unroll_critic(critic_params, cc_on, obs_u, act_u, reset_u)
+                    return loss, (td2[0], q2[0], spread, None)
+                q_tm, last = self._unroll_critic(
+                    critic_params, cc_on, obs_u, act_u, reset_u
+                )
                 q = _tm(q_tm)  # [B, U]
                 td = td_errors(q, y)
                 per_step = huber(td) if cfg.use_huber else 0.5 * td**2
                 loss = (is_weights[:, None] * per_step).mean()
-                return loss, (td, q, None)
+                return loss, (td, q, None, last if self.sdar else None)
 
-            (critic_loss, (td, q_pred, q_spread)), critic_grads = jax.value_and_grad(
+            (critic_loss, (td, q_pred, q_spread, load_q)), critic_grads = jax.value_and_grad(
                 critic_loss_fn, has_aux=True
             )(state.critic_params)
 
@@ -471,14 +530,14 @@ class R2D2DPG:
             cc_on_pi = _member(cc_on, 0) if cfg.twin_critic else cc_on
 
             def actor_loss_fn(actor_params):
-                _, q_pi_tm, _ = self._unroll_pi_q(
+                _, q_pi_tm, last = self._unroll_pi_q(
                     actor_params, cp_pi, ca_on, cc_on_pi, obs_u, reset_u
                 )
-                return -q_pi_tm.mean()
+                return -q_pi_tm.mean(), last if self.sdar else None
 
-            actor_loss, actor_grads = jax.value_and_grad(actor_loss_fn)(
-                state.actor_params
-            )
+            (actor_loss, load_pi), actor_grads = jax.value_and_grad(
+                actor_loss_fn, has_aux=True
+            )(state.actor_params)
 
         # --- gradient sync over the mesh (SURVEY §2.8: psum over ICI).
         if cfg.axis_name is not None:
@@ -524,6 +583,17 @@ class R2D2DPG:
         }
         if cfg.twin_critic:
             metrics["q_spread"] = q_spread  # |Q1-Q2|: overestimation proxy
+        if self.sdar:
+            # Routing counters, in MOE_PASSES' order (models/sdar_moe.py).
+            burn = {} if cfg.burnin == 0 else {
+                "burn_actor": ca_on["load"], "burn_target_actor": ca_tg["load"],
+                "burn_critic": cc_on["load"], "burn_target_critic": cc_tg["load"],
+            }
+            metrics.update(moe_metrics({
+                **burn,
+                "target_actor": last_tg[0], "target_critic": last_tg[1],
+                "critic": load_q, "actor": load_pi[0], "critic_pi": load_pi[1],
+            }))
         return new_state, priorities, metrics
 
     # ------------------------------------------------------- initial priority

@@ -13,16 +13,21 @@ constants in ``main.py``; here each BASELINE config is a named experiment
 |   |                   | spelling is `walker_r2d2_ns5`)                              |
 | 4 | humanoid_r2d2     | DM-Control Humanoid-run, 256 actors, seq-len 80, soft-update|
 | 5 | cheetah_pixels    | DM-Control Cheetah-run from pixels, CNN+LSTM, 256 actors    |
+
+Beside them: ``humanoid_sdar_moe``, config 4's task with SDAR-30B-A3B-Chat's
+decoder block (sparse experts, attention over the stored sequence) as the
+core, and its CPU-sized twin ``sdar_tiny`` (``models/sdar_moe.py``).
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Dict
+from typing import Callable, Dict, Optional
 
 from r2d2dpg_tpu.agents.ddpg import AgentConfig, R2D2DPG
 from r2d2dpg_tpu.envs.core import Environment
 from r2d2dpg_tpu.models import ActorNet, CriticNet
+from r2d2dpg_tpu.models.sdar_moe import SdarMoeConfig
 from r2d2dpg_tpu.training.trainer import Trainer, TrainerConfig
 
 
@@ -41,6 +46,10 @@ class ExperimentConfig:
     # Params, optimizer state, and losses stay float32 (flax mixed
     # precision); bfloat16 halves HBM traffic and doubles MXU rate.
     compute_dtype: str = "float32"
+    # The core is a stack of SDAR blocks of these sizes (then ``hidden`` is
+    # its width and ``use_lstm`` is not read); its acting ring is sized to
+    # the agent's sequences in ``build_agent``.
+    sdar: Optional[SdarMoeConfig] = None
 
     def build(self) -> Trainer:
         env = self.env_factory()
@@ -65,18 +74,23 @@ class ExperimentConfig:
         import jax.numpy as jnp
 
         dtype = jnp.dtype(self.compute_dtype)
+        sdar = self.sdar and dataclasses.replace(
+            self.sdar, ring=self.agent.seq_len - 1
+        )
         actor = ActorNet(
             action_dim=env.spec.action_dim,
             hidden=self.hidden,
             use_lstm=self.use_lstm,
             pixels=self.pixels,
             dtype=dtype,
+            sdar=sdar,
         )
         critic = CriticNet(
             hidden=self.hidden,
             use_lstm=self.use_lstm,
             pixels=self.pixels,
             dtype=dtype,
+            sdar=sdar,
         )
         agent_cfg = (
             dataclasses.replace(self.agent, axis_name=axis_name)
@@ -336,6 +350,32 @@ PENDULUM_TINY = ExperimentConfig(
     ),
 )
 
+# Config 4's task and recipe with SDAR-30B-A3B-Chat's decoder block as the
+# core, at every published width: 4 of its 48 layers, and of each layer's 128
+# routed experts the 8 that one of 16 expert-parallel chips holds (the router
+# keeps its 128 outputs and 8 a token).  460 M parameters: one chip's share
+# of the learner (chipbench/configs/humanoid_sdar_moe.json, PERF.md section 4).
+HUMANOID_SDAR_MOE = dataclasses.replace(
+    HUMANOID_R2D2,
+    name="humanoid_sdar_moe",
+    use_lstm=False,
+    hidden=2048,
+    sdar=SdarMoeConfig(),
+)
+
+# The same core at CPU size, for the tests and ``train --config sdar_tiny``.
+SDAR_TINY = dataclasses.replace(
+    PENDULUM_TINY,
+    name="sdar_tiny",
+    use_lstm=False,
+    hidden=64,
+    sdar=SdarMoeConfig(
+        hidden=64, layers=2, heads=4, kv_heads=2, head_dim=16,
+        router_experts=8, experts_per_token=4, expert_width=32,
+        expert_shards=2,
+    ),
+)
+
 CONFIGS: Dict[str, ExperimentConfig] = {
     c.name: c
     for c in (
@@ -346,6 +386,8 @@ CONFIGS: Dict[str, ExperimentConfig] = {
         HUMANOID_R2D2,
         CHEETAH_PIXELS,
         PENDULUM_TINY,
+        HUMANOID_SDAR_MOE,
+        SDAR_TINY,
     )
 }
 

@@ -24,13 +24,14 @@ throughout with float32 params.
 
 from __future__ import annotations
 
-from typing import Any, Callable, Tuple
+from typing import Any, Callable, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from r2d2dpg_tpu.models.sdar_moe import SdarMoeConfig, SdarMoeCore, initial_ring
 from r2d2dpg_tpu.models.torsos import (
     ConvTorso,
     MLPTorso,
@@ -171,14 +172,29 @@ class MixedPrecisionLSTMCell(nn.Module):
 
 
 class _Core(nn.Module):
-    """Shared recurrent-or-dense core: LSTM cell when ``use_lstm`` else Dense."""
+    """Shared core: a stack of ``sdar`` blocks when that is given, else an
+    LSTM cell when ``use_lstm``, else Dense.
+
+    The first two are stepped (``x [B, H]``, the learner scans them over
+    time).  The ``sdar`` stack is stepped only when acting; the learner
+    gives it ``x [B, T, H]`` whole (``sequence=True``: ``carry`` is then the
+    memory a prefix left, ``()`` for none, and what comes back beside ``y``
+    is that call's own memory and expert loads, ``models/sdar_moe.py``).
+    """
 
     hidden: int
     use_lstm: bool
     dtype: Any = jnp.float32
+    sdar: Optional[SdarMoeConfig] = None
 
     @nn.compact
-    def __call__(self, x: jnp.ndarray, carry: Carry, reset: jnp.ndarray):
+    def __call__(self, x: jnp.ndarray, carry: Carry, reset: jnp.ndarray, **seq):
+        if self.sdar is not None:
+            if not seq.get("sequence"):
+                carry = zeros_where_reset(carry, reset)
+            return SdarMoeCore(self.sdar, dtype=self.dtype, name="sdar")(
+                x, carry, reset, **seq
+            )
         if self.use_lstm:
             carry = zeros_where_reset(carry, reset)
             if self.dtype != jnp.float32:
@@ -216,10 +232,11 @@ class ActorNet(nn.Module):
     pixels: bool = False
     action_scale: float = 1.0
     dtype: Any = jnp.float32
+    sdar: Optional[SdarMoeConfig] = None
 
     def setup(self):
         self.torso = _make_torso(self.pixels, self.hidden, self.dtype)
-        self.core = _Core(self.hidden, self.use_lstm, self.dtype)
+        self.core = _Core(self.hidden, self.use_lstm, self.dtype, self.sdar)
         self.head = nn.Dense(
             self.action_dim, kernel_init=symmetric_uniform(3e-3), dtype=self.dtype
         )
@@ -233,8 +250,25 @@ class ActorNet(nn.Module):
         action = jnp.tanh(self.head(y)).astype(jnp.float32) * self.action_scale
         return action, carry
 
+    def sequence(self, obs, reset, memory=(), memory_only: bool = False):
+        """The ``sdar`` core over whole sequences: obs ``[B, T, ...]``, reset
+        ``[B, T]`` -> (actions ``[B, T, A]``, the call's memory and loads)."""
+        y, aux = self.core(
+            self.torso(obs), memory, reset, sequence=True, memory_only=memory_only
+        )
+        action = jnp.tanh(self.head(y)).astype(jnp.float32) * self.action_scale
+        return action, aux
+
     def initial_carry(self, batch_size: int) -> Carry:
+        """The carry the net ACTS with.  The ``sdar`` core's is its ring of
+        keys and values; the replay stores none of it (``stored_carry``)."""
+        if self.sdar is not None:
+            return initial_ring(self.sdar, batch_size)
         return lstm_initial_carry(batch_size, self.hidden, self.use_lstm)
+
+    def stored_carry(self, carry: Carry) -> Carry:
+        """What of an acting carry a sequence is stored with."""
+        return () if self.sdar is not None else carry
 
 
 class CriticNet(nn.Module):
@@ -244,13 +278,14 @@ class CriticNet(nn.Module):
     use_lstm: bool = True
     pixels: bool = False
     dtype: Any = jnp.float32
+    sdar: Optional[SdarMoeConfig] = None
 
     def setup(self):
         self.torso = _make_torso(self.pixels, self.hidden, self.dtype)
         self.mix = nn.Dense(
             self.hidden, kernel_init=fan_in_uniform(), dtype=self.dtype
         )
-        self.core = _Core(self.hidden, self.use_lstm, self.dtype)
+        self.core = _Core(self.hidden, self.use_lstm, self.dtype, self.sdar)
         self.head = nn.Dense(1, kernel_init=symmetric_uniform(3e-3), dtype=self.dtype)
 
     def __call__(
@@ -267,7 +302,19 @@ class CriticNet(nn.Module):
         q = self.head(y).astype(jnp.float32)
         return jnp.squeeze(q, axis=-1), carry
 
+    def sequence(self, obs, action, reset, memory=(), memory_only: bool = False):
+        """The ``sdar`` core over whole sequences -> (q ``[B, T]``, the call's
+        memory and loads)."""
+        x = self.torso(obs)
+        x = nn.relu(self.mix(jnp.concatenate([x, action.astype(x.dtype)], axis=-1)))
+        y, aux = self.core(x, memory, reset, sequence=True, memory_only=memory_only)
+        return jnp.squeeze(self.head(y).astype(jnp.float32), axis=-1), aux
+
     def initial_carry(self, batch_size: int) -> Carry:
+        """Nothing reads the critic's past while acting and the replay stores
+        no carry for the ``sdar`` core, so it acts with none."""
+        if self.sdar is not None:
+            return ()
         return lstm_initial_carry(batch_size, self.hidden, self.use_lstm)
 
 

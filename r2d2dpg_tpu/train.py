@@ -481,6 +481,7 @@ def run(args) -> dict:
         profile_trace,
     )
     from r2d2dpg_tpu.utils.checkpoint import resume_state
+    from r2d2dpg_tpu.utils.metrics import host_scalars
 
     if args.nan_debug:
         nan_debug(True)
@@ -799,9 +800,7 @@ def run(args) -> dict:
                     learn_np, lstep = jax.device_get(
                         (last_learn, state.train.step)
                     )
-                scalars.update(
-                    {k: float(v) for k, v in learn_np.items()}
-                )
+                scalars.update(host_scalars(learn_np))
                 trainer._obs_publish({"learner_steps": float(lstep)})
                 watch_scalars = dict(scalars)
                 scalars.update(

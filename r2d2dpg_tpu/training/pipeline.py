@@ -68,6 +68,7 @@ from r2d2dpg_tpu.obs.device import get_device_monitor
 from r2d2dpg_tpu.replay.arena import StagedSequences
 from r2d2dpg_tpu.training.assembler import emit
 from r2d2dpg_tpu.training.trainer import Trainer, TrainerState
+from r2d2dpg_tpu.utils.metrics import host_scalars
 from r2d2dpg_tpu.utils.profiling import annotate, scope
 
 # A single queue wait this long is operator-worthy: it lands in the flight
@@ -529,10 +530,7 @@ class PipelineExecutor:
             phase += 1
             if log_every and phase % log_every == 0:
                 state, ep = t.pop_episode_metrics(state)
-                scalars = {
-                    k: float(v)
-                    for k, v in jax.device_get(last_metrics).items()
-                }
+                scalars = host_scalars(jax.device_get(last_metrics))
                 emit_log(phase, ep, scalars)
         return state
 
@@ -718,9 +716,7 @@ class PipelineExecutor:
                             float(occ), float(psum), float(added)
                         )
                     t._obs_publish(ep)
-                    emit_log(
-                        gphase, ep, {k: float(v) for k, v in m.items()}
-                    )
+                    emit_log(gphase, ep, host_scalars(m))
         finally:
             stop.set()
             # Unblock a collector mid-put, then collect its state.

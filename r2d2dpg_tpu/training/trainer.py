@@ -39,6 +39,7 @@ from r2d2dpg_tpu.envs.core import Environment
 from r2d2dpg_tpu.ops import anneal_beta, gaussian_noise, importance_weights, ou_step, sigma_ladder
 from r2d2dpg_tpu.replay.arena import ArenaState, ReplayArena, SequenceBatch
 from r2d2dpg_tpu.training.assembler import StepRecord, emit, init_window, shift_in
+from r2d2dpg_tpu.utils.metrics import host_scalars
 from r2d2dpg_tpu.utils.profiling import annotate, scope
 
 
@@ -244,7 +245,7 @@ class Trainer:
             reward=ts.reward,
             discount=ts.discount,
             reset=ts.reset,
-            carries={"actor": actor_carry, "critic": critic_carry},
+            carries=self._stored_carries(actor_carry, critic_carry),
         )
         window = init_window(record, self.seq_len)
 
@@ -273,6 +274,12 @@ class Trainer:
         )
 
     # --------------------------------------------------------- phase pieces
+    def _stored_carries(self, a_carry, c_carry) -> Dict[str, Any]:
+        """What a sequence that starts at this step is stored with: the
+        carries before the step, less what the actor's core keeps to itself
+        (the sdar core's ring of keys and values is never stored)."""
+        return {"actor": self.agent.actor.stored_carry(a_carry), "critic": c_carry}
+
     def _behavior_params(self, state: TrainerState):
         if self.config.param_sync_every == 0:
             return state.train.actor_params
@@ -301,9 +308,10 @@ class Trainer:
             noise_st = ou_step(key, noise_st, sigmas)
             action = action + noise_st
         action = jnp.clip(action, -1.0, 1.0)
-        _, c_carry = self.agent.critic.apply(
-            critic_params, obs, action, c_carry, reset
-        )
+        if jax.tree_util.tree_leaves(c_carry):  # a critic that carries a past
+            _, c_carry = self.agent.critic.apply(
+                critic_params, obs, action, c_carry, reset
+            )
         return action, a_carry, c_carry, noise_st
 
     def _collect(
@@ -334,7 +342,7 @@ class Trainer:
 
         def step(carry, key):
             env_state, obs, reset, a_carry, c_carry, noise_st, ep_ret = carry
-            pre_carries = {"actor": a_carry, "critic": c_carry}
+            pre_carries = self._stored_carries(a_carry, c_carry)
 
             k_noise, k_env = jax.random.split(key)
             action, a_carry, c_carry, noise_st = self._policy_step(
@@ -530,7 +538,14 @@ class Trainer:
             (train, arena, _), metrics = lax.scan(
                 one_prefetch, (train, arena, res0), (keys, next_keys)
             )
-        metrics = jax.tree_util.tree_map(lambda m: self._pmean(m.mean()), metrics)
+        # The mean over the call's updates, a table along its own axes; a
+        # count (an integer: the sdar core's ``moe/tokens_per_expert``) is
+        # not averaged, it keeps one entry for each update.
+        metrics = jax.tree_util.tree_map(
+            lambda m: m if jnp.issubdtype(m.dtype, jnp.integer)
+            else self._pmean(m.mean(axis=0)),
+            metrics,
+        )
         return train, arena, metrics
 
     def _learn(self, state: TrainerState) -> Tuple[TrainerState, Dict[str, jnp.ndarray]]:
@@ -697,10 +712,7 @@ class Trainer:
                         # One batched fetch for the learn metrics too (a
                         # float() per metric would be N more blocking
                         # host syncs).
-                        scalars = {
-                            k: float(v)
-                            for k, v in jax.device_get(last_metrics).items()
-                        }
+                        scalars = host_scalars(jax.device_get(last_metrics))
                     log_fn(
                         f"phase {phase + 1}/{num_phases} "
                         f"env_steps {int(ep['env_steps'])} "

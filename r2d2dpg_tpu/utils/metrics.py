@@ -21,6 +21,15 @@ import threading
 import time
 from typing import Dict, Iterable, Optional, Tuple
 
+import numpy as np
+
+
+def host_scalars(metrics) -> Dict[str, float]:
+    """A fetched learner-metrics dict as floats for a log line.  An entry
+    that is a table (the sdar core's ``moe/tokens_per_expert``) is not a
+    column of a log: it is left to whoever reads the dict itself."""
+    return {k: float(v) for k, v in metrics.items() if np.size(v) == 1}
+
 
 class PercentileWindow:
     """Sliding window of scalar observations with percentile read-off.

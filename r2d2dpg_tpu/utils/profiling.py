@@ -69,6 +69,16 @@ LEARN_STAGES = (
 )
 
 
+# Scopes INSIDE the sequence core (``models/sdar_moe.py``), under whichever
+# learner stage runs it: attention (norm, projections, RoPE, scores, output
+# projection), routing (router, top-k, gates) and the held experts'
+# products.  ``stage_table(path, LEARN_STAGES + CORE_STAGES)``
+# folds both passes of a core scope into its stage (the innermost name wins)
+# and leaves ``forward`` / ``backward`` / ``burn_in`` what lies outside the
+# core; read with ``LEARN_STAGES`` alone the core's time stays in those.
+CORE_STAGES = ("core_attention", "moe_route", "moe_experts")
+
+
 def scope(name: str):
     """Name a region of TRACED code: ops inside the block carry ``name`` in
     their HLO metadata (``op_name``, a ``/``-separated path of the enclosing
