@@ -253,35 +253,44 @@ def _built_trainers():
         topology.build_trainer = build_trainer
 
 
-def _require_no_arena_convert(trainer, state) -> dict:
-    """The whole-arena convert guard (docs/OBSERVABILITY.md): the learner
-    call (``Trainer._learn_many``, state donated), compiled by this chip's
-    compiler at the run's own shapes, rounds no ``[capacity, ...]`` value.
-    Only the TPU compiler makes that rewrite, so only a chip run can check
-    that ``ReplayArena.sample``'s boundary still holds it off."""
+def _require_learner_call_guards(trainer, state) -> dict:
+    """The two compile-time guards of the learner call (docs/OBSERVABILITY.md):
+    ``Trainer._learn_many``, state donated, compiled by this chip's compiler
+    at the run's own shapes, rounds no ``[capacity, ...]`` value and inserts
+    no sequence into a batch-minor ``[batch, ...]`` buffer.  Only the TPU
+    compiler makes either choice, so only a chip run can check that
+    ``ReplayArena.sample`` still takes both from it."""
     import jax
 
-    from r2d2dpg_tpu.obs.hlo import arena_converts
+    from r2d2dpg_tpu.obs.hlo import arena_converts, batch_minor_writes
 
     call = jax.jit(trainer._learn_many, donate_argnums=(0, 1))
     hlo = call.lower(state.train, state.arena, state.rng).compile().as_text()
-    found = arena_converts(hlo, trainer.arena.capacity)
+    converts = arena_converts(hlo, trainer.arena.capacity)
     _require(
-        not found,
-        f"the learner call converts the whole arena once a call: {found}",
+        not converts,
+        f"the learner call converts the whole arena once a call: {converts}",
+    )
+    writes = batch_minor_writes(hlo, trainer.config.batch_size)
+    _require(
+        not writes,
+        f"the learner call writes its sampled batch batch-minor: {writes}",
     )
     return {
         "learner_call_hlo_lines": hlo.count("\n"),
         "arena_capacity": trainer.arena.capacity,
-        "arena_converts": found,
+        "arena_converts": converts,
+        "batch_size": trainer.config.batch_size,
+        "batch_minor_writes": writes,
     }
 
 
-def _sdar_learner_call() -> tuple:
-    """``humanoid_sdar_moe``'s trainer and the shapes of its learner call's
-    arguments, from the configuration alone: nothing is initialised (460 M
-    parameters and a 1.5 GB arena stay shapes), and the learner never
-    touches the environment, so a stand-in holds its two sizes."""
+def _learner_call_from_shapes(config: str, obs_shape: tuple, obs_dtype: str,
+                              act_dim: int) -> tuple:
+    """``config``'s trainer and the shapes of its learner call's arguments,
+    from the configuration alone: nothing is initialised (weights and arena
+    stay shapes), and the learner never touches the environment, so a
+    stand-in holds its two sizes."""
     import types
 
     import jax
@@ -291,42 +300,56 @@ def _sdar_learner_call() -> tuple:
     from r2d2dpg_tpu.replay.arena import SequenceBatch
     from r2d2dpg_tpu.training.trainer import Trainer
 
-    exp = get_config("humanoid_sdar_moe")
-    obs_dim, act_dim = 67, 21  # DM-Control humanoid-run
+    exp = get_config(config)
     env = types.SimpleNamespace(
-        spec=types.SimpleNamespace(action_dim=act_dim, obs_shape=(obs_dim,)))
+        spec=types.SimpleNamespace(action_dim=act_dim, obs_shape=obs_shape))
     trainer = Trainer(env, exp.build_agent(env), exp.trainer)
     L = exp.agent.seq_len
+    obs = jnp.zeros((1,) + obs_shape, jnp.dtype(obs_dtype))
 
     def arena(key):
         z = lambda *shape: jnp.zeros((1, L) + shape, jnp.float32)  # noqa: E731
+        agent = trainer.agent
         return trainer.arena.init_state(SequenceBatch(
-            obs=z(obs_dim), action=z(act_dim), reward=z(), discount=z(), reset=z(),
-            carries={"actor": (), "critic": ()}))
+            obs=jnp.zeros((1, L) + obs_shape, obs.dtype), action=z(act_dim),
+            reward=z(), discount=z(), reset=z(),
+            carries=trainer._stored_carries(
+                agent.actor.initial_carry(1), agent.critic.initial_carry(1))))
 
     key = jax.random.PRNGKey(0)
     shapes = jax.eval_shape(
         lambda k: {
-            "train": trainer.agent.init(
-                k, jnp.zeros((1, obs_dim)), jnp.zeros((1, act_dim))),
+            "train": trainer.agent.init(k, obs, jnp.zeros((1, act_dim))),
             "arena": arena(k), "rng": k},
         key)
     return trainer, types.SimpleNamespace(**shapes)
+
+
+# The configurations whose learner call the train leg compiles from shapes
+# beside ``walker_r2d2``'s own: the sequence core's (460 M parameters, a
+# 1.5 GB arena; DM-Control humanoid-run) and the pixel replay's (a rank-5
+# uint8 leaf, 552,960 bytes a sequence; DM-Control cheetah-run at 64x64x3).
+_LEARNER_CALLS_FROM_SHAPES = {
+    "learner_call_sdar_moe": ("humanoid_sdar_moe", (67,), "float32", 21),
+    "learner_call_pixels": ("cheetah_pixels", (64, 64, 3), "uint8", 6),
+}
 
 
 def _leg_train(work: str) -> dict:
     """Base ``Trainer``: host MuJoCo pool through ordered ``io_callback``
     inside the jitted phase, the HBM arena at capacity 100,000, the Pallas
     write-back, donated state; then the learner call alone, compiled for the
-    whole-arena convert guard, for ``walker_r2d2`` and for the sequence
-    core's configuration ``humanoid_sdar_moe``."""
+    whole-arena convert guard and the batch-minor write guard, for
+    ``walker_r2d2`` and, from shapes, for the sequence core's configuration
+    ``humanoid_sdar_moe`` and the pixel replay's ``cheetah_pixels``."""
     _fresh_native_build()
     with _built_trainers() as built:
         checks = _train(work, "walker_r2d2")
     _require_native_pool()
     _require(len(built) == 1, f"{len(built)} trainers were initialised")
-    checks["learner_call"] = _require_no_arena_convert(*built[0])
-    checks["learner_call_sdar_moe"] = _require_no_arena_convert(*_sdar_learner_call())
+    checks["learner_call"] = _require_learner_call_guards(*built[0])
+    for name, args in _LEARNER_CALLS_FROM_SHAPES.items():
+        checks[name] = _require_learner_call_guards(*_learner_call_from_shapes(*args))
     return checks
 
 

@@ -23,9 +23,10 @@ One process-wide namespace for every subsystem's operator signals:
   HBM gauges, and ``--profile-window`` profiler captures stamped into
   the fused timeline and reduced to device time by stage of the learner
   call (``obs/stages.py``).
-- ``hlo``       — what a compiled program does to the whole replay, read
-  from its HLO text: ``arena_converts``, behind ``chip_smoke.py``'s
-  whole-arena convert guard.
+- ``hlo``       — what a compiled program does to the whole replay and to
+  the batch drawn from it, read from its HLO text: ``arena_converts`` and
+  ``batch_minor_writes``, behind ``chip_smoke.py``'s two guards of the
+  learner call.
 - ``quality``   — the experience-quality plane (ISSUE 18): sequence
   provenance (behavior param version + collect phase) stamped at the
   actor and carried through wire/arena/shard slots, folded at batch

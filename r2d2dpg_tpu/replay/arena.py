@@ -31,12 +31,16 @@ the gather and puts the ``convert`` on the gather's ``[capacity, ...]``
 operand, outside the update loop.  At 524,288 walker sequences that was 3.2 GB
 read and 1.6 GB written in every call of 4 updates, 1.88 of 2.79 ms an update
 (PERF.md, PR 23 and PR 25), and no CPU test can see it:
-``chip_smoke.py``'s train leg guards it (``obs/hlo.py``).
+``chip_smoke.py``'s train leg guards it (``obs/hlo.py``).  The boundary also
+states where the compiler would choose badly: the device layout of large
+gathered rows is ``sample``'s, batch major-most (``_gather_rows``), not the
+arena's slot-minor order carried over to the batch.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import math
 import threading
 from typing import Any, Dict, Sequence, Tuple
 
@@ -44,6 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from r2d2dpg_tpu.obs.quality import PROVENANCE_ABSENT
 from r2d2dpg_tpu.ops.priority import PRIORITY_EPS
@@ -215,9 +220,9 @@ def _pin_storage_dtypes(batch: SequenceBatch) -> SequenceBatch:
     be propagated into; B rows are cast, never the arena (casting the arena
     before the gather is hoisted out of the update loop and materialised: a
     second copy of the replay).  Integer leaves (pixels) cannot be rounded
-    and pass untouched: a barrier on them only takes the choice of the
-    gather's output layout from the compiler (6.6 x on the pixel gather
-    jitted alone, PERF.md PR 26)."""
+    and pass untouched: their gather's output layout is ``_gather_rows``'s
+    to state, and a barrier here would only stand between that statement
+    and the consumer."""
 
     def pin(x):
         if not jnp.issubdtype(x.dtype, jnp.floating):
@@ -230,6 +235,36 @@ def _pin_storage_dtypes(batch: SequenceBatch) -> SequenceBatch:
         )
 
     return jax.tree_util.tree_map(pin, batch)
+
+
+# Rows of fewer elements the TPU compiler gathers in one fusion, with no
+# accumulator for a stated layout to help; the loop it expands ``buf[indices]``
+# into was seen from 105,840 elements a row up (compiles for a described v5e).
+_LOOPED_GATHER_ROW_ELEMENTS = 1 << 16
+
+
+def _gather_rows(buf: jnp.ndarray, indices: jnp.ndarray) -> jnp.ndarray:
+    """``buf[indices]``, with the device layout of large rows stated here:
+    batch major-most, then time, the two longest of the other dimensions
+    minor-most (least padding; pixels: ``[B, L, C, H, W]`` on the device).
+
+    The TPU compiler expands the gather of a large row into a loop of B
+    iterations over an accumulator ``[B, L, ...]``, and left to itself gives
+    that accumulator the arena's own order.  The arena lies slot minor-most,
+    so the batch does too: B of 128 lanes used, and every iteration rewrites
+    the whole buffer to fill one lane of each tile (``dynamic-update-slice``,
+    59 of ``cheetah_pixels``' 70 ms an update; PERF.md, PR 28).  Batch-major,
+    an iteration re-lays ONE sequence out and writes it once into a stretch
+    of its own.  The values are ``buf[indices]``'s, bit for bit; on the CPU
+    the constraint is the identity.  ``chip_smoke.py``'s train leg holds the
+    compiled learner call to it (``obs/hlo.py::batch_minor_writes``)."""
+    rows = buf[indices]
+    if math.prod(rows.shape[1:]) < _LOOPED_GATHER_ROW_ELEMENTS:
+        return rows
+    by_length = sorted(range(2, rows.ndim), key=lambda d: rows.shape[d])
+    return with_layout_constraint(
+        rows, Layout(major_to_minor=(0, 1, *by_length))
+    )
 
 
 class ReplayArena:
@@ -481,7 +516,9 @@ class ReplayArena:
                 (batch_size,), 1.0 / jnp.maximum(size.astype(jnp.float32), 1.0)
             )
 
-        batch = jax.tree_util.tree_map(lambda buf: buf[indices], state.data)
+        batch = jax.tree_util.tree_map(
+            lambda buf: _gather_rows(buf, indices), state.data
+        )
         return SampleResult(
             batch=_pin_storage_dtypes(batch), indices=indices, probs=probs
         )

@@ -1,10 +1,13 @@
-"""``obs/hlo.py::arena_converts``: the reader behind ``chip_smoke.py``'s
-whole-arena convert guard, on HLO text as the TPU compiler prints it.  Only
-the chip's compiler makes the rewrite, so the CPU can test the reader alone."""
+"""``obs/hlo.py``: the readers behind ``chip_smoke.py``'s two compile-time
+guards of the learner call (the whole-arena convert, the batch-minor write of
+the sampled batch), on HLO text as the TPU compiler prints it.  Only the
+chip's compiler makes either choice, so the CPU tests the readers alone, and
+the one thing that can be compiled here without a chip: ``ReplayArena.sample``
+for a described v5e."""
 
 import pytest
 
-from r2d2dpg_tpu.obs.hlo import arena_converts
+from r2d2dpg_tpu.obs.hlo import arena_converts, batch_minor_writes
 
 CAPACITY = 524288
 
@@ -58,3 +61,148 @@ def test_arena_converts_names_every_convert_with_the_capacity_leading(
     hlo, capacity, want
 ):
     assert arena_converts(hlo, capacity) == want
+
+
+# The pixel gather alone at ``cheetah_pixels``'s shapes, compiled for a
+# described v5e (JAX 0.9.0, libtpu 0.0.34), cut to the loop ``buf[indices]``
+# becomes.  Left to the compiler (the parent of PR 28) the accumulator takes
+# the arena's own order, batch minor-most, and every iteration is a
+# read-modify-write of all of it: the ledger's ``dynamic-update-slice.67``.
+BATCH_MINOR_GATHER = """\
+%fused_computation.clone.clone (param_0.15: u8[12288,45,64,64,3], param_1.18: s32[]) -> u8[1,45,64,64,3] {
+  %param_0.15 = u8[12288,45,64,64,3]{0,3,4,2,1:T(8,128)(4,1)} parameter(0)
+  %param_1.18 = s32[]{:T(128)} parameter(1)
+  %constant.36 = s32[]{:T(128)} constant(0)
+  ROOT %dynamic-slice.9 = u8[1,45,64,64,3]{0,3,4,2,1:T(8,128)(4,1)S(1)} dynamic-slice(%param_0.15, %param_1.18, %constant.36, %constant.36, %constant.36, /*index=5*/%constant.36), dynamic_slice_sizes={1,45,64,64,3}
+}
+
+%wide.while_body.sunk (wide.param.2: (s32[], u8[12288,45,64,64,3], s32[32,1], u8[32,45,64,64,3], s32[], /*index=5*/s32[])) -> (s32[], u8[12288,45,64,64,3], s32[32,1], u8[32,45,64,64,3], s32[], /*index=5*/s32[]) {
+  %get-tuple-element.57 = s32[32,1]{0,1:T(1,128)S(1)} get-tuple-element(%wide.param.2), index=2
+  %get-tuple-element.52 = u8[32,45,64,64,3]{0,3,4,2,1:T(8,128)(4,1)} get-tuple-element(%wide.param.2), index=3
+  %constant_dynamic-slice_fusion.5 = u8[1,45,64,64,3]{0,3,4,2,1:T(8,128)(4,1)S(1)} fusion(%get-tuple-element.56, %bitcast.4), kind=kLoop, calls=%fused_computation.clone.clone
+  %dynamic-update-slice.3 = u8[32,45,64,64,3]{0,3,4,2,1:T(8,128)(4,1)} dynamic-update-slice(%get-tuple-element.52, %constant_dynamic-slice_fusion.5, %get-tuple-element.49, %constant.7..sunk, %constant.7..sunk, /*index=5*/%constant.7..sunk, %constant.7..sunk), backend_config={"flag_configs":[]}
+  %dynamic-update-slice.9 = s32[32]{0:T(128)} dynamic-update-slice(%slots, %slot, %get-tuple-element.49)
+  ROOT %tuple.15 = (s32[]{:T(128)}, u8[12288,45,64,64,3]{0,3,4,2,1:T(8,128)(4,1)}, s32[32,1]{0,1:T(1,128)S(1)}, u8[32,45,64,64,3]{0,3,4,2,1:T(8,128)(4,1)}, s32[]{:T(128)}, /*index=5*/s32[]{:T(128)}) tuple(%add.8, %get-tuple-element.56, %get-tuple-element.57, %dynamic-update-slice.3, %get-tuple-element.59, /*index=5*/%get-tuple-element.60)
+}
+"""
+
+# The same gather with the rows' layout stated by ``sample``: the accumulator
+# is batch-major, the body re-lays ONE sequence out and a fused update writes
+# it into a stretch of its own.  The arena is read as before.
+BATCH_MAJOR_GATHER = """\
+%fused_computation.clone.clone (param_0.18: u8[32,45,64,64,3], param_1.21: u8[1,45,64,64,3], param_2.16: s32[]) -> u8[32,45,64,64,3] {
+  %param_0.18 = u8[32,45,64,64,3]{3,2,4,1,0:T(8,128)(4,1)S(1)} parameter(0)
+  %param_1.21 = u8[1,45,64,64,3]{3,2,4,1,0:T(8,128)(4,1)S(1)} parameter(1)
+  %param_2.16 = s32[]{:T(128)} parameter(2)
+  ROOT %dynamic-update-slice.4 = u8[32,45,64,64,3]{3,2,4,1,0:T(8,128)(4,1)S(1)} dynamic-update-slice(%param_0.18, %param_1.21, %param_2.16, %constant.37, %constant.37, /*index=5*/%constant.37, %constant.37), backend_config={"flag_configs":[]}
+}
+
+%wide.while_body.sunk (wide.param.2: (s32[], u8[12288,45,64,64,3], s32[32,1], u8[32,45,64,64,3], s32[])) -> (s32[], u8[12288,45,64,64,3], s32[32,1], u8[32,45,64,64,3], s32[]) {
+  %get-tuple-element.49 = u8[32,45,64,64,3]{3,2,4,1,0:T(8,128)(4,1)S(1)} get-tuple-element(%wide.param.2), index=3
+  %constant_dynamic-slice_fusion.5 = u8[1,45,64,64,3]{0,3,4,2,1:T(8,128)(4,1)S(1)} fusion(%get-tuple-element.52, %bitcast.4), kind=kLoop, calls=%fused_computation.1.clone.clone
+  %copy.4 = u8[1,45,64,64,3]{3,2,4,1,0:T(8,128)(4,1)S(1)} copy(%constant_dynamic-slice_fusion.5)
+  %constant_dynamic-update-slice_fusion.2 = u8[32,45,64,64,3]{3,2,4,1,0:T(8,128)(4,1)S(1)} fusion(%get-tuple-element.49, %copy.4, %get-tuple-element.46), kind=kLoop, calls=%fused_computation.clone.clone
+  ROOT %tuple.14 = (s32[]{:T(128)}, u8[12288,45,64,64,3]{0,3,4,2,1:T(8,128)(4,1)}, s32[32,1]{0,1:T(1,128)S(1)}, u8[32,45,64,64,3]{3,2,4,1,0:T(8,128)(4,1)S(1)}, s32[]{:T(128)}) tuple(%add.8, %get-tuple-element.52, %get-tuple-element.53, %constant_dynamic-update-slice_fusion.2, %get-tuple-element.55)
+}
+"""
+
+
+@pytest.mark.parametrize(
+    "hlo, batch, want",
+    [
+        (BATCH_MINOR_GATHER, 32, [
+            ("dynamic-update-slice.3", "u8[32,45,64,64,3]{0,3,4,2,1}"),
+        ]),
+        (BATCH_MAJOR_GATHER, 32, []),
+        (BATCH_MINOR_GATHER, 45, []),
+        (HOISTED, 64, []),
+        ("", 32, []),
+    ],
+    ids=["batch_minor", "batch_major", "another_batch", "no_update_slice", "empty"],
+)
+def test_batch_minor_writes_names_every_update_slice_into_a_batch_minor_buffer(
+    hlo, batch, want
+):
+    """A rank-1 ``[batch]`` update (the fixture's ``dynamic-update-slice.9``)
+    is no such write: its one dimension is minor-most by having no other."""
+    assert batch_minor_writes(hlo, batch) == want
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described v5e chip's sharding; skips where the TPU compiler cannot
+    describe one (nothing of it runs while this module is imported)."""
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    os.environ.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+    os.environ.setdefault("TPU_WORKER_HOSTNAMES", "localhost")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here: nothing to test
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_compile_cache():
+    """An ahead-of-time TPU program cannot be read back without a chip: keep
+    it out of the persistent cache."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
+
+
+@pytest.mark.parametrize("consumer", ["alone", "frames_as_floats"])
+def test_sample_compiled_for_v5e_writes_no_batch_minor_buffer(
+    consumer, one_chip, no_compile_cache
+):
+    """``ReplayArena.sample`` at ``cheetah_pixels``'s row shape and batch,
+    compiled for a described v5e: no sequence is inserted into a batch-minor
+    buffer.  The capacity is cut to compile in seconds but is still the
+    largest dimension, so the arena lies slot-minor as the cell's does.
+    Alone, the batch is the program's result and takes the result's layout
+    whoever states it (the benchmark's probe ``jit_replay_sample`` has always
+    read that program); it is a consumer that takes the frames as floats, as
+    the conv torso does, that pulls an unstated layout back to the arena's
+    order.  Nothing runs: a compile says nothing about results or times."""
+    import jax
+    import jax.numpy as jnp
+
+    from r2d2dpg_tpu.replay.arena import ReplayArena, SequenceBatch
+
+    capacity, B, L, hidden = 256, 32, 45, 256
+
+    def z(*shape, dtype=jnp.float32):
+        return jnp.zeros((1,) + shape, dtype)
+
+    def carry():
+        return (z(hidden), z(hidden))
+
+    arena = ReplayArena(capacity)
+    example = SequenceBatch(
+        obs=z(L, 64, 64, 3, dtype=jnp.uint8), action=z(L, 6), reward=z(L),
+        discount=z(L), reset=z(L), carries={"actor": carry(), "critic": carry()})
+    state, key = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: (arena.init_state(example), jax.random.PRNGKey(0))))
+
+    def program(s, k):
+        res = arena.sample(s, k, B)
+        if consumer == "alone":
+            return res
+        return res, (res.batch.obs.astype(jnp.float32) / 255.0).sum()
+
+    hlo = jax.jit(program).trace(state, key).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    assert f"u8[{capacity},{L},64,64,3]{{0," in hlo  # slot-minor, as the cell's arena
+    assert batch_minor_writes(hlo, B) == []

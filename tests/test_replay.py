@@ -1,6 +1,7 @@
 """Replay arena: ring overwrite, prioritized sampling distribution, priority
 write-back via the Pallas kernel (interpret mode) — SURVEY.md §4.1/§4.5."""
 
+import dataclasses
 import os
 
 os.environ["R2D2DPG_PALLAS_INTERPRET"] = "1"  # exercise the kernel on CPU
@@ -426,7 +427,7 @@ def test_sampled_batch_contents_roundtrip():
     np.testing.assert_allclose(row0, 0.0)
 
 
-def _mixed_state(arena, n=12):
+def _mixed_state(arena, n=12, frame=(2, 2, 3)):
     """An arena whose leaves are float32 and uint8 (pixel observations), with
     bit patterns a rounding or a float compare would lose: a NaN payload,
     -0.0, a subnormal, and mantissas bfloat16 cannot hold."""
@@ -439,7 +440,7 @@ def _mixed_state(arena, n=12):
         return jnp.asarray(rng.standard_normal((n, HID)).astype(np.float32))
 
     batch = SequenceBatch(
-        obs=jnp.asarray(rng.integers(0, 256, (n, L, 2, 2, 3), dtype=np.uint8)),
+        obs=jnp.asarray(rng.integers(0, 256, (n, L) + frame, dtype=np.uint8)),
         action=jnp.asarray(action),
         reward=jnp.asarray(rng.random((n, L), dtype=np.float32)),
         discount=jnp.ones((n, L)),
@@ -457,17 +458,24 @@ def _bits(x):
 
 @pytest.mark.parametrize("mode", ["eager", "jit", "scan", "vmap"])
 @pytest.mark.parametrize("prioritized", [False, True], ids=["uniform", "prioritized"])
+@pytest.mark.parametrize("frame", [(2, 2, 3), (64, 96, 3)], ids=["frame2x2", "frame64x96"])
 def test_sample_is_the_plain_gather_in_the_arenas_own_dtypes(
-    prioritized, mode, monkeypatch
+    frame, prioritized, mode, monkeypatch
 ):
-    """The boundary ``sample`` puts after its gather is the identity on bits:
-    batch, indices and probs are what the sample without it returns, and the
-    batch is ``buf[indices]`` leaf by leaf, in the arena's dtypes."""
+    """What ``sample`` does to the rows it gathers (their device layout
+    stated, the float ones pinned to the arena's dtypes) is the identity on
+    bits: batch, indices and probs are what the sample without the pin
+    returns, and the batch is ``buf[indices]`` leaf by leaf, in the arena's
+    shapes and dtypes, for a rank-5 pixel leaf beside the float and carry
+    leaves: small frames, whose gather is left alone, and frames of unequal
+    sides (a transposed row would show) large enough for the stated layout."""
     from r2d2dpg_tpu.replay import arena as arena_mod
 
     B = 5
     arena = ReplayArena(capacity=16, prioritized=prioritized)
-    state = _mixed_state(arena)
+    state = _mixed_state(arena, frame=frame)
+    stated = L * np.prod(frame) >= arena_mod._LOOPED_GATHER_ROW_ELEMENTS
+    assert stated == (frame != (2, 2, 3))
     keys = jax.random.split(jax.random.PRNGKey(3), 2)
 
     def draw():
@@ -489,15 +497,63 @@ def test_sample_is_the_plain_gather_in_the_arenas_own_dtypes(
     plain = draw()
 
     want = jax.tree_util.tree_map(lambda buf: buf[got.indices], state.data)
+    lead = (2, B) if mode in ("scan", "vmap") else (B,)
     for g, w, buf in zip(
         jax.tree_util.tree_leaves(got.batch),
         jax.tree_util.tree_leaves(want),
         jax.tree_util.tree_leaves(state.data),
     ):
         assert g.dtype == buf.dtype
+        assert g.shape == lead + buf.shape[1:]
         np.testing.assert_array_equal(_bits(g), _bits(w))
     assert {str(x.dtype) for x in jax.tree_util.tree_leaves(got.batch)} == {
         "float32", "uint8"}
     for g, p in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(plain)):
         assert g.dtype == p.dtype
         np.testing.assert_array_equal(_bits(g), _bits(p))
+
+
+@pytest.mark.parametrize("how", ["named_sharding", "shard_map"])
+def test_sample_states_its_rows_layout_under_a_sharded_arena(how):
+    """The mesh trainers' two ways of holding the arena (``parallel/hybrid``
+    and ``dp_learner``: an explicit ``NamedSharding`` over the slot axis;
+    ``parallel/spmd``: each device's own slots under ``shard_map``) take the
+    stated layout of a large pixel row: the draw is still ``buf[indices]``."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    devices = jax.devices()[:4]
+    if len(devices) < 4:
+        pytest.skip("needs four devices")
+    n, B = 8, 3
+    per = n // len(devices)
+    arena = ReplayArena(capacity=n, prioritized=True, use_pallas=False)
+    state = _mixed_state(arena, n=n, frame=(64, 96, 3))
+    pixels = np.asarray(state.data.obs)
+    mesh = Mesh(np.array(devices), ("dp",))
+
+    def slots(x):
+        return x.ndim > 0 and x.shape[0] == n
+
+    key = jax.random.PRNGKey(5)
+    if how == "named_sharding":
+        sharded = jax.device_put(state, jax.tree_util.tree_map(
+            lambda x: NamedSharding(mesh, P("dp") if slots(x) else P()), state))
+        res = jax.jit(lambda s, k: arena.sample(s, k, B))(sharded, key)
+        np.testing.assert_array_equal(
+            np.asarray(res.batch.obs), pixels[np.asarray(res.indices)])
+        return
+
+    local = ReplayArena(capacity=per, prioritized=True, use_pallas=False)
+
+    def draw(s, k):
+        s = dataclasses.replace(s, total_added=jnp.minimum(s.total_added, per))
+        res = local.sample(s, k[0], B)
+        return res.batch.obs, res.indices
+
+    specs = jax.tree_util.tree_map(lambda x: P("dp") if slots(x) else P(), state)
+    obs, idx = jax.jit(jax.shard_map(
+        draw, mesh=mesh, in_specs=(specs, P("dp")), out_specs=(P("dp"), P("dp")),
+        check_vma=False))(state, jax.random.split(key, len(devices)))
+    obs, idx = np.asarray(obs).reshape(len(devices), B, *pixels.shape[1:]), np.asarray(idx)
+    for d in range(len(devices)):
+        np.testing.assert_array_equal(obs[d], pixels[d * per + idx[d * B:(d + 1) * B]])
