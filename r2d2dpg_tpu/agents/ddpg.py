@@ -33,8 +33,7 @@ import jax.numpy as jnp
 import optax
 from jax import lax
 
-from r2d2dpg_tpu.models.actor_critic import ActorNet, Carry, CriticNet, unroll
-from r2d2dpg_tpu.models.sdar_moe import moe_metrics
+from r2d2dpg_tpu.models.sequence import sequence_runner
 from r2d2dpg_tpu.ops import (
     huber,
     n_step_targets,
@@ -75,11 +74,6 @@ class AgentConfig:
     use_huber: bool = True
     grad_clip: Optional[float] = 40.0
     axis_name: Optional[str] = None  # mesh axis for gradient sync (SPMD)
-    # Burn both nets' online+target cores in ONE vmapped scan over stacked
-    # params (halves the sequential scan count of the burn-in prefix; the
-    # two matmuls per step become one batched dot on the MXU).  Numerically
-    # identical to the unfused path up to matmul reassociation.
-    fused_burnin: bool = True
     # --- overestimation mitigations (round-3; the config-#5 CPU evidence
     # run collapsed from textbook DDPG critic overestimation — q_mean rose
     # 0.15 -> 0.95 while eval return fell).  Both default
@@ -109,46 +103,22 @@ def _tm(x: jnp.ndarray) -> jnp.ndarray:
     return jnp.swapaxes(x, 0, 1)
 
 
-def _stack_n(tree: Any, n: int) -> Any:
-    """Tile a pytree along a new leading ensemble axis of size ``n``."""
-    return jax.tree_util.tree_map(lambda x: jnp.stack([x] * n), tree)
-
-
 def _member(tree: Any, i: int) -> Any:
     """Member ``i`` of an ensemble-stacked pytree."""
     return jax.tree_util.tree_map(lambda x: x[i], tree)
 
 
-def _stack2(a: Any, b: Any) -> Any:
-    """Stack two same-structure pytrees along a new leading axis of size 2."""
-    return jax.tree_util.tree_map(lambda x, y: jnp.stack([x, y]), a, b)
-
-
-def _unstack2(t: Any) -> Tuple[Any, Any]:
-    return (
-        jax.tree_util.tree_map(lambda x: x[0], t),
-        jax.tree_util.tree_map(lambda x: x[1], t),
-    )
-
-
 class R2D2DPG:
     """Agent: networks + optimizers + the learner step (pure functions)."""
 
-    def __init__(self, actor: ActorNet, critic: CriticNet, config: AgentConfig):
+    def __init__(self, actor, critic, config: AgentConfig):
         self.actor = actor
         self.critic = critic
         self.config = config
-        # The sdar core is not stepped by the learner: the ``_unroll_*``
-        # helpers hand it whole sequences, the carry between burn-in and
-        # window is the prefix's keys and values, and what comes back where a
-        # scan returns its last carry are the pass's expert loads.
-        self.sdar = actor.sdar is not None
-        if self.sdar != (critic.sdar is not None):
-            raise ValueError("actor and critic must both have the sdar core, or neither")
-        if self.sdar and (config.twin_critic or config.target_policy_sigma > 0):
-            raise ValueError(
-                "twin_critic and target_policy_sigma are not wired for the sdar core"
-            )
+        # How the nets' core is run over a stored sequence (burn-in, the
+        # unrolls, what they leave behind): models/sequence.py decides it
+        # from the nets; nothing below asks what the core is.
+        self.seq = sequence_runner(actor, critic, config)
 
         def tx(lr: float) -> optax.GradientTransformation:
             if config.grad_clip is not None:
@@ -196,58 +166,6 @@ class R2D2DPG:
             step=jnp.zeros((), jnp.int32),
         )
 
-    # --------------------------------------------------------------- unrolls
-    def _unroll_actor(self, params, carry, obs_tm, reset_tm):
-        if self.sdar:
-            a, aux = self.actor.apply(
-                params, _tm(obs_tm), _tm(reset_tm), carry, method="sequence"
-            )
-            return _tm(a), aux["load"]
-        return unroll(
-            lambda c, o, r: self.actor.apply(params, o, c, r), carry, obs_tm, reset_tm
-        )
-
-    def _unroll_critic(self, params, carry, obs_tm, act_tm, reset_tm):
-        if self.sdar:
-            q, aux = self.critic.apply(
-                params, _tm(obs_tm), _tm(act_tm), _tm(reset_tm), carry,
-                method="sequence",
-            )
-            return _tm(q), aux["load"]
-        return unroll(
-            lambda c, o, a, r: self.critic.apply(params, o, a, c, r),
-            carry,
-            obs_tm,
-            act_tm,
-            reset_tm,
-        )
-
-    def _unroll_pi_q(
-        self, actor_params, critic_params, ca, cc, obs_tm, reset_tm
-    ):
-        """Actor and critic advanced in ONE scan: a_t = mu(o_t), q_t = Q(o_t, a_t).
-
-        Halves the sequential-scan count of the two places that unroll the
-        policy and then re-unroll the critic over its actions (the n-step
-        target pass and the actor loss) — per-step math is identical to the
-        two-scan version, the cells just step together.
-        """
-        if self.sdar:
-            a_tm, load_a = self._unroll_actor(actor_params, ca, obs_tm, reset_tm)
-            q_tm, load_c = self._unroll_critic(
-                critic_params, cc, obs_tm, a_tm, reset_tm
-            )
-            return a_tm, q_tm, (load_a, load_c)
-
-        def step(carry, o, r):
-            ca, cc = carry
-            a, ca = self.actor.apply(actor_params, o, ca, r)
-            q, cc = self.critic.apply(critic_params, o, a, cc, r)
-            return (a, q), (ca, cc)
-
-        (a_tm, q_tm), carry = unroll(step, (ca, cc), obs_tm, reset_tm)
-        return a_tm, q_tm, carry
-
     def behavior_critic_params(self, state: TrainState):
         """Critic params for the collection-time carry advance: member 0 in
         twin mode (the stored carry seeds both members at burn-in, so one
@@ -256,164 +174,28 @@ class R2D2DPG:
             return _member(state.critic_params, 0)
         return state.critic_params
 
-    def _apply_critic_ens(self, params, o, a, carry, r):
-        """One critic forward, min-reduced over the ensemble when twin."""
-        if not self.config.twin_critic:
-            return self.critic.apply(params, o, a, carry, r)
-        q2, carry = jax.vmap(
-            lambda p, c: self.critic.apply(p, o, a, c, r)
-        )(params, carry)
-        return q2.min(axis=0), carry
-
     def _target_q(self, state, ca_tg, cc_tg, obs_tm, reset_tm, eps_tm):
         """Bootstrap Q through the target nets, time-major ``[T, B]``."""
         return self._target_unroll(state, ca_tg, cc_tg, obs_tm, reset_tm, eps_tm)[0]
 
     def _target_unroll(self, state, ca_tg, cc_tg, obs_tm, reset_tm, eps_tm):
-        """``_target_q``'s worker.
+        """``_target_q``'s worker: returns it with what the unroll left behind.
 
-        Returns it with what the unroll left behind (the last carries; the
-        sdar core's expert loads).
-        Plain DDPG (twin off, sigma 0) takes the fused pi+Q scan unchanged;
+        Plain DDPG (twin off, sigma 0) is the fused pi+Q unroll unchanged;
         otherwise the per-step action is smoothed with the pre-drawn clipped
         noise ``eps_tm`` (TD3 target-policy smoothing) and/or Q is the min
         over the target-critic ensemble (clipped double-Q).
         """
-        if not self.config.twin_critic and eps_tm is None:
-            _, q_tm, last = self._unroll_pi_q(
-                state.target_actor_params,
-                state.target_critic_params,
-                ca_tg,
-                cc_tg,
-                obs_tm,
-                reset_tm,
-            )
-            return q_tm, last
-        ap, cp = state.target_actor_params, state.target_critic_params
-
-        def step(carry, o, r, *e):
-            ca, cc = carry
-            a, ca = self.actor.apply(ap, o, ca, r)
-            if e:
-                a = jnp.clip(a + e[0], -1.0, 1.0)
-            q, cc = self._apply_critic_ens(cp, o, a, cc, r)
-            return q, (ca, cc)
-
-        xs = (obs_tm, reset_tm) + (() if eps_tm is None else (eps_tm,))
-        return unroll(step, (ca_tg, cc_tg), *xs)
-
-    def _burn_in(
-        self, state: TrainState, batch: SequenceBatch
-    ) -> Tuple[Carry, Carry, Carry, Carry]:
-        """Warm all four nets' carries over the burn-in prefix, no gradient.
-
-        SURVEY §3.3 hot loop: `no_grad: (h,c) = burn_in(seq[:B_len])` — online
-        and target nets each burn in from the *stored* initial state.
-        """
-        cfg = self.config
-        if self.sdar:
-            # R2D2's burn-in in attention's terms: the prefix's keys and
-            # values in every layer, recomputed with today's weights, are the
-            # memory the window attends to (the replay stores no carry).
-            if cfg.burnin == 0:
-                return (), (), (), ()
-            pre = slice(0, cfg.burnin)
-            obs, act, reset = batch.obs[:, pre], batch.action[:, pre], batch.reset[:, pre]
-
-            def mem_a(p):
-                return self.actor.apply(
-                    p, obs, reset, memory_only=True, method="sequence")[1]
-
-            def mem_c(p):
-                return self.critic.apply(
-                    p, obs, act, reset, memory_only=True, method="sequence")[1]
-
-            return lax.stop_gradient((
-                mem_a(state.actor_params), mem_a(state.target_actor_params),
-                mem_c(state.critic_params), mem_c(state.target_critic_params),
-            ))
-        nq = 2 if cfg.twin_critic else 1
-        ca0, cc0 = batch.carries["actor"], batch.carries["critic"]
-        # With twin critics the stored carry seeds BOTH members (collection
-        # tracks one critic carry; each member warms its own state from it
-        # during burn-in because its params differ).
-        cc0e = _stack_n(cc0, nq) if cfg.twin_critic else cc0
-        if cfg.burnin == 0 or not (self.actor.use_lstm or self.critic.use_lstm):
-            return ca0, ca0, cc0e, cc0e
-        obs_b = _tm(batch.obs[:, : cfg.burnin])
-        act_b = _tm(batch.action[:, : cfg.burnin])
-        reset_b = _tm(batch.reset[:, : cfg.burnin])
-        ca_on = ca_tg = ca0
-        cc_on = cc_tg = cc0e
-        if cfg.fused_burnin:
-            # One scan per net: online+target param ensembles concatenated
-            # on the leading axis ([2] plain, [4] twin), the cell step
-            # vmapped over that axis; only the final carry is kept.
-            # ``carry_step(params, carry, *xs_t) -> carry``.
-            def fused(carry_step, p_all, c0_single, n_all, xs):
-                cN = _stack_n(c0_single, n_all)
-                v = jax.vmap(
-                    carry_step, in_axes=(0, 0) + (None,) * len(xs)
-                )
-                cN, _ = lax.scan(lambda c, inp: (v(p_all, c, *inp), ()), cN, xs)
-                return cN
-
-            if self.actor.use_lstm:
-                c2 = fused(
-                    lambda p, c, o, r: self.actor.apply(p, o, c, r)[1],
-                    _stack2(state.actor_params, state.target_actor_params),
-                    ca0,
-                    2,
-                    (obs_b, reset_b),
-                )
-                ca_on, ca_tg = _unstack2(c2)
-            if self.critic.use_lstm:
-                cat = lambda on, tg: jax.tree_util.tree_map(  # noqa: E731
-                    lambda x, y: jnp.concatenate([x, y]), on, tg
-                )
-                p_all = (
-                    cat(state.critic_params, state.target_critic_params)
-                    if cfg.twin_critic
-                    else _stack2(state.critic_params, state.target_critic_params)
-                )
-                cN = fused(
-                    lambda p, c, o, a, r: self.critic.apply(p, o, a, c, r)[1],
-                    p_all,
-                    cc0,
-                    2 * nq,
-                    (obs_b, act_b, reset_b),
-                )
-                if cfg.twin_critic:
-                    cc_on = jax.tree_util.tree_map(lambda x: x[:nq], cN)
-                    cc_tg = jax.tree_util.tree_map(lambda x: x[nq:], cN)
-                else:
-                    cc_on, cc_tg = _unstack2(cN)
-        else:
-            if self.actor.use_lstm:
-                _, ca_on = self._unroll_actor(
-                    state.actor_params, ca0, obs_b, reset_b
-                )
-                _, ca_tg = self._unroll_actor(
-                    state.target_actor_params, ca0, obs_b, reset_b
-                )
-            if self.critic.use_lstm:
-                if cfg.twin_critic:
-                    vunroll = jax.vmap(
-                        lambda p, c: self._unroll_critic(
-                            p, c, obs_b, act_b, reset_b
-                        )[1]
-                    )
-                    cc_on = vunroll(state.critic_params, cc0e)
-                    cc_tg = vunroll(state.target_critic_params, cc0e)
-                else:
-                    _, cc_on = self._unroll_critic(
-                        state.critic_params, cc0, obs_b, act_b, reset_b
-                    )
-                    _, cc_tg = self._unroll_critic(
-                        state.target_critic_params, cc0, obs_b, act_b, reset_b
-                    )
-        sg = lax.stop_gradient
-        return sg(ca_on), sg(ca_tg), sg(cc_on), sg(cc_tg)
+        return self.seq.unroll_pi_q(
+            state.target_actor_params,
+            state.target_critic_params,
+            ca_tg,
+            cc_tg,
+            obs_tm,
+            reset_tm,
+            eps_tm=eps_tm,
+            q_min=self.config.twin_critic,
+        )[1:]
 
     # ---------------------------------------------------------- learner step
     def learner_step(
@@ -443,7 +225,7 @@ class R2D2DPG:
         # the two value_and_grad calls, so that the operations JAX names
         # ``transpose(...)`` under it read as ``backward``.
         with scope("burn_in"):
-            ca_on, ca_tg, cc_on, cc_tg = self._burn_in(state, batch)
+            ca_on, ca_tg, cc_on, cc_tg = self.seq.burn_in(state, batch)
 
         with scope("forward"):
             # Training window: [burnin, burnin+U+n) — time-major for the scans.
@@ -470,7 +252,7 @@ class R2D2DPG:
                     -cfg.target_policy_clip,
                     cfg.target_policy_clip,
                 )
-            q_tg_tm, last_tg = self._target_unroll(
+            q_tg_tm, left_tg = self._target_unroll(
                 state, ca_tg, cc_tg, obs_w, reset_w, eps_w
             )
             y = lax.stop_gradient(
@@ -494,8 +276,8 @@ class R2D2DPG:
             # (TD3); td/q metrics and priorities come from member 0.
             def critic_loss_fn(critic_params):
                 if cfg.twin_critic:
-                    q_tm2, _ = jax.vmap(
-                        lambda p, c: self._unroll_critic(
+                    q_tm2, left = jax.vmap(
+                        lambda p, c: self.seq.unroll_critic(
                             p, c, obs_u, act_u, reset_u
                         )
                     )(critic_params, cc_on)
@@ -507,17 +289,17 @@ class R2D2DPG:
                     # a mean would silently halve the effective critic LR.
                     loss = (is_weights[:, None] * per_step.sum(axis=0)).mean()
                     spread = jnp.abs(q2[0] - q2[1]).mean()
-                    return loss, (td2[0], q2[0], spread, None)
-                q_tm, last = self._unroll_critic(
+                    return loss, (td2[0], q2[0], spread, left)
+                q_tm, left = self.seq.unroll_critic(
                     critic_params, cc_on, obs_u, act_u, reset_u
                 )
                 q = _tm(q_tm)  # [B, U]
                 td = td_errors(q, y)
                 per_step = huber(td) if cfg.use_huber else 0.5 * td**2
                 loss = (is_weights[:, None] * per_step).mean()
-                return loss, (td, q, None, last if self.sdar else None)
+                return loss, (td, q, None, left)
 
-            (critic_loss, (td, q_pred, q_spread, load_q)), critic_grads = jax.value_and_grad(
+            (critic_loss, (td, q_pred, q_spread, left_q)), critic_grads = jax.value_and_grad(
                 critic_loss_fn, has_aux=True
             )(state.critic_params)
 
@@ -530,12 +312,12 @@ class R2D2DPG:
             cc_on_pi = _member(cc_on, 0) if cfg.twin_critic else cc_on
 
             def actor_loss_fn(actor_params):
-                _, q_pi_tm, last = self._unroll_pi_q(
+                _, q_pi_tm, left = self.seq.unroll_pi_q(
                     actor_params, cp_pi, ca_on, cc_on_pi, obs_u, reset_u
                 )
-                return -q_pi_tm.mean(), last if self.sdar else None
+                return -q_pi_tm.mean(), left
 
-            (actor_loss, load_pi), actor_grads = jax.value_and_grad(
+            (actor_loss, left_pi), actor_grads = jax.value_and_grad(
                 actor_loss_fn, has_aux=True
             )(state.actor_params)
 
@@ -583,17 +365,11 @@ class R2D2DPG:
         }
         if cfg.twin_critic:
             metrics["q_spread"] = q_spread  # |Q1-Q2|: overestimation proxy
-        if self.sdar:
-            # Routing counters, in MOE_PASSES' order (models/sdar_moe.py).
-            burn = {} if cfg.burnin == 0 else {
-                "burn_actor": ca_on["load"], "burn_target_actor": ca_tg["load"],
-                "burn_critic": cc_on["load"], "burn_target_critic": cc_tg["load"],
-            }
-            metrics.update(moe_metrics({
-                **burn,
-                "target_actor": last_tg[0], "target_critic": last_tg[1],
-                "critic": load_q, "actor": load_pi[0], "critic_pi": load_pi[1],
-            }))
+        # What the passes left behind (a core's counters; nothing for a scan).
+        metrics.update(self.seq.metrics(
+            burn=(ca_on, ca_tg, cc_on, cc_tg),
+            target=left_tg, critic=left_q, pi=left_pi,
+        ))
         return new_state, priorities, metrics
 
     # ------------------------------------------------------- initial priority
@@ -609,7 +385,7 @@ class R2D2DPG:
         """
         cfg = self.config
         with scope("burn_in"):
-            ca_on, ca_tg, cc_on, cc_tg = self._burn_in(state, batch)
+            ca_on, ca_tg, cc_on, cc_tg = self.seq.burn_in(state, batch)
         w = slice(cfg.burnin, cfg.seq_len)
         obs_w = _tm(batch.obs[:, w])
         act_w = _tm(batch.action[:, w])
@@ -627,7 +403,7 @@ class R2D2DPG:
             n=cfg.n_step,
             gamma=cfg.gamma,
         )
-        q_tm, _ = self._unroll_critic(
+        q_tm, _ = self.seq.unroll_critic(
             _member(state.critic_params, 0) if cfg.twin_critic
             else state.critic_params,
             _member(cc_on, 0) if cfg.twin_critic else cc_on,

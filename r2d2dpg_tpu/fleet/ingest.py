@@ -1126,8 +1126,8 @@ class FleetLearner:
             lambda ls, st: drain_staged(trainer, ls, st, learn=False),
             **drain_kwargs,
         )
-        # Coalesce-width precompile (ISSUE 9 satellite — the BENCH_FLEET
-        # coalesce regression): every power-of-two bucket width is a
+        # Coalesce-width precompile (ISSUE 9 satellite — the coalesce
+        # regression): every power-of-two bucket width is a
         # distinct drain program, and compiling one MID-RUN stalls the
         # drain for tens of seconds — long enough to fill the queue and
         # shed.  A background thread AOT-compiles the widths during the
@@ -1419,7 +1419,7 @@ class FleetLearner:
                 # whose drain program is READY (precompiled by the warm
                 # thread below): a mid-run width compile stalls the drain
                 # long enough to fill the queue and shed — the
-                # BENCH_FLEET coalesce regression this clamp removes.
+                # coalesce regression this clamp removes.
                 # Absorb-phase pulls clamp to 1 outright: only the
                 # drain-LEARN widths are warmed, and a wide pull there
                 # would compile an absorb program used for seconds and
@@ -1718,7 +1718,7 @@ class FleetLearner:
                 # sequence: under the central drain, EVERY collected
                 # sequence crosses the wire into the arena whether or not
                 # it is ever sampled — the in-network sampler's headline
-                # comparison (bench.py fleet_sampler; docs/REPLAY.md).
+                # comparison (docs/REPLAY.md).
                 "bytes_per_trained_seq": (
                     srv.seqs_bytes_total
                     / max(

@@ -49,7 +49,7 @@ accounting is the honest cross-process cost, and moving a shard out of
 the learner process is a listening socket away, not a format change
 (docs/REPLAY.md "Topology").  The headline this buys: only SAMPLED
 sequences cross the sampling boundary into training
-(``bytes_per_trained_seq`` — ``bench.py fleet_sampler``).
+(``bytes_per_trained_seq``).
 
 ``--replay-shards 1 --actors 0`` routes the untouched phase-locked loop
 (nothing to shard without a fleet) and is pinned bit-identical to
@@ -1303,8 +1303,8 @@ class SamplerLearner:
                     drained_here * cfg.learner_steps / wall
                 ),
                 # The headline boundary: only SAMPLED sequences cross
-                # into training (bench.py fleet_sampler compares this
-                # against the central drain's bytes_per_trained_seq).
+                # into training (compare the central drain's
+                # bytes_per_trained_seq, fleet/ingest.py).
                 "sample_bytes_total": float(self.sample_bytes_total),
                 "bytes_per_trained_seq": (
                     self.sample_bytes_total / max(trained, 1)

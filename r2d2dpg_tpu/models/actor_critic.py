@@ -180,12 +180,34 @@ class _Core(nn.Module):
     gives it ``x [B, T, H]`` whole (``sequence=True``: ``carry`` is then the
     memory a prefix left, ``()`` for none, and what comes back beside ``y``
     is that call's own memory and expert loads, ``models/sdar_moe.py``).
+
+    The one place here that tells the cores apart: the nets ask it for their
+    carries and for how the learner runs them (``_core_of``).
     """
 
     hidden: int
     use_lstm: bool
     dtype: Any = jnp.float32
     sdar: Optional[SdarMoeConfig] = None
+
+    @property
+    def whole_sequence(self) -> bool:
+        """Whether the learner hands this core whole sequences
+        (``models/sequence.py::Whole``) or scans its steps (``Stepped``)."""
+        return self.sdar is not None
+
+    def acting_carry(self, batch_size: int, reads_past: bool) -> Carry:
+        """The carry a net ACTS with.  The ``sdar`` core's is its ring of keys
+        and values, and none for a net whose past nothing reads while acting
+        (the critic: the replay stores no carry for this core)."""
+        if self.sdar is not None:
+            return initial_ring(self.sdar, batch_size) if reads_past else ()
+        return lstm_initial_carry(batch_size, self.hidden, self.use_lstm)
+
+    def stored_carry(self, carry: Carry) -> Carry:
+        """What of an acting carry a sequence is stored with: the ``sdar``
+        core's memory is recomputed from the burn-in prefix, so nothing."""
+        return () if self.whole_sequence else carry
 
     @nn.compact
     def __call__(self, x: jnp.ndarray, carry: Carry, reset: jnp.ndarray, **seq):
@@ -217,6 +239,12 @@ class _Core(nn.Module):
         return y, carry
 
 
+def _core_of(net: nn.Module, **kwargs) -> _Core:
+    """``net``'s core from its fields.  With ``parent=None`` it is a
+    description to ask outside ``apply`` (``net.core`` exists only inside)."""
+    return _Core(net.hidden, net.use_lstm, net.dtype, net.sdar, **kwargs)
+
+
 def _make_torso(pixels: bool, hidden: int, dtype: Any) -> nn.Module:
     if pixels:
         return ConvTorso(out_size=hidden, dtype=dtype)
@@ -236,7 +264,7 @@ class ActorNet(nn.Module):
 
     def setup(self):
         self.torso = _make_torso(self.pixels, self.hidden, self.dtype)
-        self.core = _Core(self.hidden, self.use_lstm, self.dtype, self.sdar)
+        self.core = _core_of(self)
         self.head = nn.Dense(
             self.action_dim, kernel_init=symmetric_uniform(3e-3), dtype=self.dtype
         )
@@ -259,16 +287,17 @@ class ActorNet(nn.Module):
         action = jnp.tanh(self.head(y)).astype(jnp.float32) * self.action_scale
         return action, aux
 
+    @property
+    def whole_sequence(self) -> bool:
+        return _core_of(self, parent=None).whole_sequence
+
     def initial_carry(self, batch_size: int) -> Carry:
-        """The carry the net ACTS with.  The ``sdar`` core's is its ring of
-        keys and values; the replay stores none of it (``stored_carry``)."""
-        if self.sdar is not None:
-            return initial_ring(self.sdar, batch_size)
-        return lstm_initial_carry(batch_size, self.hidden, self.use_lstm)
+        """The carry the net ACTS with."""
+        return _core_of(self, parent=None).acting_carry(batch_size, reads_past=True)
 
     def stored_carry(self, carry: Carry) -> Carry:
         """What of an acting carry a sequence is stored with."""
-        return () if self.sdar is not None else carry
+        return _core_of(self, parent=None).stored_carry(carry)
 
 
 class CriticNet(nn.Module):
@@ -285,7 +314,7 @@ class CriticNet(nn.Module):
         self.mix = nn.Dense(
             self.hidden, kernel_init=fan_in_uniform(), dtype=self.dtype
         )
-        self.core = _Core(self.hidden, self.use_lstm, self.dtype, self.sdar)
+        self.core = _core_of(self)
         self.head = nn.Dense(1, kernel_init=symmetric_uniform(3e-3), dtype=self.dtype)
 
     def __call__(
@@ -310,12 +339,13 @@ class CriticNet(nn.Module):
         y, aux = self.core(x, memory, reset, sequence=True, memory_only=memory_only)
         return jnp.squeeze(self.head(y).astype(jnp.float32), axis=-1), aux
 
+    @property
+    def whole_sequence(self) -> bool:
+        return _core_of(self, parent=None).whole_sequence
+
     def initial_carry(self, batch_size: int) -> Carry:
-        """Nothing reads the critic's past while acting and the replay stores
-        no carry for the ``sdar`` core, so it acts with none."""
-        if self.sdar is not None:
-            return ()
-        return lstm_initial_carry(batch_size, self.hidden, self.use_lstm)
+        """Nothing reads the critic's past while acting."""
+        return _core_of(self, parent=None).acting_carry(batch_size, reads_past=False)
 
 
 def policy_step_fn(actor: "ActorNet") -> Callable[..., Tuple[jnp.ndarray, Carry]]:

@@ -1,0 +1,259 @@
+"""How a core is run over a stored sequence: the learner's one seam to its nets.
+
+R2D2's learner warms its nets over a burn-in prefix and unrolls them over the
+training window (SURVEY.md §3.3).  HOW depends on the core, and it is decided
+here and nowhere else: ``agents/ddpg.py`` holds one of these objects
+(``sequence_runner`` picks it from the nets it is given) and calls its five
+operations —
+
+- ``unroll_actor`` / ``unroll_critic``: one net over time-major inputs from a
+  carry; back come the outputs ``[T, B, ...]`` and what the pass left behind;
+- ``unroll_pi_q``: the actor, then the critic on the actor's actions;
+- ``burn_in``: the four nets' carries (online and target actor, online and
+  target critic) into the window, from a batch's prefix, no gradient;
+- ``metrics``: what the update reports from what its passes left behind.
+
+``Stepped`` (LSTM, Dense, any net whose ``apply`` is one step) scans single
+steps from the carry the replay stored; what a pass leaves is its last carry.
+``Whole`` (the sdar core) hands the net whole sequences; its carry is the
+memory the prefix left and what a pass leaves are its expert loads.  A new
+stepped core, whatever its carry's shape, needs nothing here; a new kind is one
+more class with these operations.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from r2d2dpg_tpu.models.actor_critic import Carry, time_major, unroll
+from r2d2dpg_tpu.models.sdar_moe import moe_metrics
+
+
+def _stack_n(tree: Any, n: int) -> Any:
+    """Tile a pytree along a new leading ensemble axis of size ``n``."""
+    return jax.tree_util.tree_map(lambda x: jnp.stack([x] * n), tree)
+
+
+def _stack2(a: Any, b: Any) -> Any:
+    """Stack two same-structure pytrees along a new leading axis of size 2."""
+    return jax.tree_util.tree_map(lambda x, y: jnp.stack([x, y]), a, b)
+
+
+def _unstack2(t: Any) -> Tuple[Any, Any]:
+    return (
+        jax.tree_util.tree_map(lambda x: x[0], t),
+        jax.tree_util.tree_map(lambda x: x[1], t),
+    )
+
+
+class Stepped:
+    """``lax.scan`` of single steps from the stored carry.
+
+    Asks the nets for ``apply`` alone (``actor.apply(params, obs, carry,
+    reset)``, ``critic.apply(params, obs, action, carry, reset)``, each one
+    step returning its output and the new carry); a net that has nothing to
+    warm is one whose stored carry has no leaves.
+    """
+
+    def __init__(self, actor, critic, config):
+        self.actor, self.critic, self.config = actor, critic, config
+
+    def unroll_actor(self, params, carry, obs_tm, reset_tm):
+        return unroll(
+            lambda c, o, r: self.actor.apply(params, o, c, r), carry, obs_tm, reset_tm
+        )
+
+    def unroll_critic(self, params, carry, obs_tm, act_tm, reset_tm):
+        return unroll(
+            lambda c, o, a, r: self.critic.apply(params, o, a, c, r),
+            carry,
+            obs_tm,
+            act_tm,
+            reset_tm,
+        )
+
+    def unroll_pi_q(
+        self, actor_params, critic_params, ca, cc, obs_tm, reset_tm,
+        eps_tm=None, q_min=False,
+    ):
+        """Actor and critic advanced in ONE scan: a_t = mu(o_t), q_t = Q(o_t, a_t).
+
+        Halves the sequential-scan count of the two places that unroll the
+        policy and then re-unroll the critic over its actions (the n-step
+        target pass and the actor loss) — per-step math is identical to the
+        two-scan version, the cells just step together.  The TD3 knobs of
+        the target pass: ``eps_tm`` is added to each step's action (clipped
+        back into range) before the critic sees it; ``q_min`` takes
+        ``critic_params`` and ``cc`` with a leading ensemble axis and returns
+        the least member's Q.
+        """
+
+        def step(carry, o, r, *e):
+            ca, cc = carry
+            a, ca = self.actor.apply(actor_params, o, ca, r)
+            if e:
+                a = jnp.clip(a + e[0], -1.0, 1.0)
+            if q_min:
+                q2, cc = jax.vmap(
+                    lambda p, c: self.critic.apply(p, o, a, c, r)
+                )(critic_params, cc)
+                q = q2.min(axis=0)
+            else:
+                q, cc = self.critic.apply(critic_params, o, a, cc, r)
+            return (a, q), (ca, cc)
+
+        xs = (obs_tm, reset_tm) + (() if eps_tm is None else (eps_tm,))
+        (a_tm, q_tm), carry = unroll(step, (ca, cc), *xs)
+        return a_tm, q_tm, carry
+
+    def burn_in(self, state, batch) -> Tuple[Carry, Carry, Carry, Carry]:
+        """SURVEY §3.3 hot loop: `no_grad: (h,c) = burn_in(seq[:B_len])` — online
+        and target nets each burn in from the *stored* initial state.
+
+        One scan per net: online+target param ensembles concatenated on the
+        leading axis ([2] plain, [4] twin), the cell step vmapped over that
+        axis (the two matmuls per step become one batched dot on the MXU);
+        only the final carry is kept.
+        """
+        cfg = self.config
+        nq = 2 if cfg.twin_critic else 1
+        ca0, cc0 = batch.carries["actor"], batch.carries["critic"]
+        # With twin critics the stored carry seeds BOTH members (collection
+        # tracks one critic carry; each member warms its own state from it
+        # during burn-in because its params differ).
+        cc0e = _stack_n(cc0, nq) if cfg.twin_critic else cc0
+        if cfg.burnin == 0:
+            return ca0, ca0, cc0e, cc0e
+        obs_b = time_major(batch.obs[:, : cfg.burnin])
+        act_b = time_major(batch.action[:, : cfg.burnin])
+        reset_b = time_major(batch.reset[:, : cfg.burnin])
+        ca_on = ca_tg = ca0
+        cc_on = cc_tg = cc0e
+
+        # ``carry_step(params, carry, *xs_t) -> carry``.
+        def fused(carry_step, p_all, c0_single, n_all, xs):
+            cN = _stack_n(c0_single, n_all)
+            v = jax.vmap(carry_step, in_axes=(0, 0) + (None,) * len(xs))
+            cN, _ = lax.scan(lambda c, inp: (v(p_all, c, *inp), ()), cN, xs)
+            return cN
+
+        if jax.tree_util.tree_leaves(ca0):
+            c2 = fused(
+                lambda p, c, o, r: self.actor.apply(p, o, c, r)[1],
+                _stack2(state.actor_params, state.target_actor_params),
+                ca0,
+                2,
+                (obs_b, reset_b),
+            )
+            ca_on, ca_tg = _unstack2(c2)
+        if jax.tree_util.tree_leaves(cc0):
+            cat = lambda on, tg: jax.tree_util.tree_map(  # noqa: E731
+                lambda x, y: jnp.concatenate([x, y]), on, tg
+            )
+            p_all = (
+                cat(state.critic_params, state.target_critic_params)
+                if cfg.twin_critic
+                else _stack2(state.critic_params, state.target_critic_params)
+            )
+            cN = fused(
+                lambda p, c, o, a, r: self.critic.apply(p, o, a, c, r)[1],
+                p_all,
+                cc0,
+                2 * nq,
+                (obs_b, act_b, reset_b),
+            )
+            if cfg.twin_critic:
+                cc_on = jax.tree_util.tree_map(lambda x: x[:nq], cN)
+                cc_tg = jax.tree_util.tree_map(lambda x: x[nq:], cN)
+            else:
+                cc_on, cc_tg = _unstack2(cN)
+        return lax.stop_gradient((ca_on, ca_tg, cc_on, cc_tg))
+
+    def metrics(self, burn, target, critic, pi) -> Dict[str, jnp.ndarray]:
+        """Last carries say nothing worth a log line."""
+        return {}
+
+
+class Whole:
+    """Whole sequences through the nets' ``sequence`` method, no scan over time.
+
+    Asks the nets for ``apply(..., method="sequence")`` (``ActorNet.sequence``,
+    ``CriticNet.sequence``): batch-major inputs and a memory in, the outputs
+    and the call's own memory and expert loads out.  The replay stores no
+    carry for such a core; a pass leaves its loads ``[L, E]`` behind.
+    """
+
+    def __init__(self, actor, critic, config):
+        if config.twin_critic or config.target_policy_sigma > 0:
+            raise ValueError(
+                "twin_critic and target_policy_sigma are not wired for the sdar core"
+            )
+        self.actor, self.critic, self.config = actor, critic, config
+
+    def unroll_actor(self, params, memory, obs_tm, reset_tm):
+        a, aux = self.actor.apply(
+            params, time_major(obs_tm), time_major(reset_tm), memory,
+            method="sequence",
+        )
+        return time_major(a), aux["load"]
+
+    def unroll_critic(self, params, memory, obs_tm, act_tm, reset_tm):
+        q, aux = self.critic.apply(
+            params, time_major(obs_tm), time_major(act_tm), time_major(reset_tm),
+            memory, method="sequence",
+        )
+        return time_major(q), aux["load"]
+
+    def unroll_pi_q(
+        self, actor_params, critic_params, ma, mc, obs_tm, reset_tm,
+        eps_tm=None, q_min=False,
+    ):
+        """``eps_tm`` and ``q_min`` are what ``__init__`` refuses."""
+        a_tm, load_a = self.unroll_actor(actor_params, ma, obs_tm, reset_tm)
+        q_tm, load_c = self.unroll_critic(critic_params, mc, obs_tm, a_tm, reset_tm)
+        return a_tm, q_tm, (load_a, load_c)
+
+    def burn_in(self, state, batch) -> Tuple[Carry, Carry, Carry, Carry]:
+        """R2D2's burn-in in attention's terms: the prefix's keys and values
+        in every layer, recomputed with today's weights, are the memory the
+        window attends to."""
+        n = self.config.burnin
+        if n == 0:
+            return (), (), (), ()
+        obs, act, reset = batch.obs[:, :n], batch.action[:, :n], batch.reset[:, :n]
+
+        def mem_a(p):
+            return self.actor.apply(
+                p, obs, reset, memory_only=True, method="sequence")[1]
+
+        def mem_c(p):
+            return self.critic.apply(
+                p, obs, act, reset, memory_only=True, method="sequence")[1]
+
+        return lax.stop_gradient((
+            mem_a(state.actor_params), mem_a(state.target_actor_params),
+            mem_c(state.critic_params), mem_c(state.target_critic_params),
+        ))
+
+    def metrics(self, burn, target, critic, pi) -> Dict[str, jnp.ndarray]:
+        """Routing counters, in ``MOE_PASSES``' order (models/sdar_moe.py)."""
+        names = ("burn_actor", "burn_target_actor", "burn_critic", "burn_target_critic")
+        return moe_metrics({
+            **{name: mem["load"] for name, mem in zip(names, burn) if mem},
+            "target_actor": target[0], "target_critic": target[1],
+            "critic": critic, "actor": pi[0], "critic_pi": pi[1],
+        })
+
+
+def sequence_runner(actor, critic, config):
+    """The runner for a pair of nets, from what the nets are: ``Whole`` for
+    nets that say they take whole sequences (``whole_sequence``), else
+    ``Stepped``."""
+    whole = [bool(getattr(net, "whole_sequence", False)) for net in (actor, critic)]
+    if whole[0] != whole[1]:
+        raise ValueError("actor and critic must both have the sdar core, or neither")
+    return (Whole if whole[0] else Stepped)(actor, critic, config)

@@ -1,10 +1,8 @@
 """Data-parallel multi-chip learner: dp-sharded replay + batch, fed by ingest.
 
-ISSUE 9 tentpole / ROADMAP "Break the learner ceiling": BENCH_FLEET.json
-shows the fleet's single-chip learner STARVES at every fleet size
-(learner_wait_p99 ~0.5 s, arena-add seqs/s flat from 1 to 3 actors) —
-ingest stopped being the bottleneck in PR 5, the learner is.  This trainer
-scales the learner side over the existing ``parallel/`` dp mesh in the
+ISSUE 9 tentpole / ROADMAP "Break the learner ceiling": a fleet's
+single-chip learner is the stage every actor count waits on (seen on one
+shared CPU core only; no chip number).  This trainer scales the learner side over the existing ``parallel/`` dp mesh in the
 pjit layout style (annotate shardings, let GSPMD place the collectives —
 the same recipe as ``HostSPMDTrainer``), while collection stays wherever
 it already lives (fleet actor subprocesses under ``--actors N``, or the

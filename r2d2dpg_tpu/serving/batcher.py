@@ -4,8 +4,7 @@ Podracer's TPU lesson (arxiv 2104.06272) applies to inference too: the chip
 is efficient only at batch, so single-request policy steps waste it.  The
 batcher coalesces whatever requests are in flight into ONE policy step,
 padded up to a fixed bucket size so there is exactly one XLA compile per
-bucket (the same pad-to-bucket discipline bench.py's fixed shapes use) —
-never one per observed batch size.
+bucket — never one per observed batch size.
 
 Latency discipline: the first request of a batch starts a flush deadline
 (``flush_ms``); the batch launches when the largest bucket fills OR the

@@ -502,8 +502,8 @@ class PipelineExecutor:
         """Run exactly ``n`` TRAIN phases from ``state`` — pipelined when
         enabled, phase-locked otherwise.  No warm-up/fill bookkeeping: the
         replay arena must already hold ``min_replay`` sequences.  The
-        measurement/test entry point (bench.py's pipelined probe, the
-        overlap smoke test); ``run`` drives the full schedule."""
+        measurement/test entry point (the overlap smoke test); ``run``
+        drives the full schedule."""
 
         def emit_log(phase, ep, scalars):
             log_fn(f"train phase {phase}/{n} " + " ".join(

@@ -17,8 +17,8 @@ DEFAULT_CACHE_DIR = os.path.join(_CHECKOUT, ".jax_cache")
 def enable_compile_cache() -> str:
     """Point JAX at the compile cache; returns the directory in use.
 
-    Called first thing by ``train``, ``serve``, ``eval``, ``bench.py`` and
-    ``chip_smoke.py``'s legs, so consecutive processes of one checkout share
+    Called first thing by ``train``, ``serve``, ``eval``,
+    ``chipbench/run.py`` and ``chip_smoke.py``'s legs, so consecutive processes of one checkout share
     compiled programs.  The directory is part of the cache key, so it must
     not move between processes: it is either the one
     ``JAX_COMPILATION_CACHE_DIR`` names (JAX reads that variable itself;

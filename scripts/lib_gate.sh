@@ -630,8 +630,8 @@ quality_gate() {
 }
 
 # Serving scale-out gate (ISSUE 20): an evidence dir produced with
-# --serve-workers N (N >= 2, e.g. a BENCH_SERVE traffic run or a routed
-# serve deployment's obs capture) may only be blessed if the off-setting
+# --serve-workers N (N >= 2, e.g. a routed serve deployment's obs
+# capture) may only be blessed if the off-setting
 # anchors pass on this checkout — the 1-worker router path bit-identical
 # to the PR-1 PolicyService through the serve CLI, interleaved routed
 # traffic bit-identical per session to sequential rollouts, and the
@@ -642,7 +642,7 @@ quality_gate() {
 # beside the other topology stamps, so a blessed number always says how
 # many workers served it.  Same stamping discipline as fleet_gate;
 # single-worker runs pass through untouched.
-#   serve_gate <dir> <serve/bench args...>
+#   serve_gate <dir> <serve args...>
 serve_gate() {
   local dir=$1
   shift

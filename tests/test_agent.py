@@ -1,14 +1,16 @@
 """Learner-step tests: loss directions, target updates, priorities, burn-in
 correctness (SURVEY.md §4.1 — "the §4.1 unit tests before anything learns")."""
 
+import dataclasses
+
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from r2d2dpg_tpu.agents import AgentConfig, R2D2DPG
-from r2d2dpg_tpu.agents.ddpg import TrainState
-from r2d2dpg_tpu.models import ActorNet, CriticNet
+from r2d2dpg_tpu.models import ActorNet, CriticNet, unroll
 from r2d2dpg_tpu.replay.arena import SequenceBatch
 
 B, OBS, ACT, HID = 4, 3, 2, 16
@@ -170,49 +172,127 @@ def test_initial_priority_matches_learner_td():
     )
 
 
-def test_fused_burnin_matches_unfused():
-    """The stacked-params fused burn-in must produce the same warmed carries
-    (and hence the same learner step) as four separate unrolls."""
-    fused = make_agent(use_lstm=True, burnin=4, fused_burnin=True)
-    plain = make_agent(use_lstm=True, burnin=4, fused_burnin=False)
-    batch = make_batch(fused, key=3)
-    # Non-trivial stored carries + a mid-burnin reset row.
-    h = jax.random.normal(jax.random.PRNGKey(9), (B, HID))
-    batch = SequenceBatch(
-        obs=batch.obs,
-        action=batch.action,
-        reward=batch.reward,
-        discount=batch.discount,
-        reset=batch.reset.at[1, 2].set(1.0),
-        carries={"actor": (h, 0.5 * h), "critic": (-h, 0.25 * h)},
-    )
-    state = fused.init(jax.random.PRNGKey(0), batch.obs[:, 0], batch.action[:, 0])
-    # Desync targets from online so fused/unfused disagreement would show.
-    state = TrainState(
-        actor_params=state.actor_params,
-        critic_params=state.critic_params,
+def unfused_burn_in(agent, state, batch):
+    """The reference burn-in: each of the four nets unrolled alone over the
+    prefix, from the stored carry."""
+    n = agent.config.burnin
+    tm = lambda x: jnp.swapaxes(x[:, :n], 0, 1)  # noqa: E731
+    obs, act, reset = tm(batch.obs), tm(batch.action), tm(batch.reset)
+
+    def actor(p):
+        return unroll(lambda c, o, r: agent.actor.apply(p, o, c, r),
+                      batch.carries["actor"], obs, reset)[1]
+
+    def critic(p):
+        return unroll(lambda c, o, a, r: agent.critic.apply(p, o, a, c, r),
+                      batch.carries["critic"], obs, act, reset)[1]
+
+    return (actor(state.actor_params), actor(state.target_actor_params),
+            critic(state.critic_params), critic(state.target_critic_params))
+
+
+def desynced(state):
+    """Targets moved off the online nets, so that a carry warmed with the
+    wrong net's weights would show."""
+    return dataclasses.replace(
+        state,
         target_actor_params=jax.tree_util.tree_map(
             lambda x: x + 0.1, state.actor_params
         ),
         target_critic_params=jax.tree_util.tree_map(
             lambda x: x - 0.1, state.critic_params
         ),
-        actor_opt_state=state.actor_opt_state,
-        critic_opt_state=state.critic_opt_state,
-        step=state.step,
     )
-    got = fused._burn_in(state, batch)
-    want = plain._burn_in(state, batch)
+
+
+def test_fused_burnin_matches_unfused():
+    """The stacked-params fused burn-in must produce the same warmed carries
+    as four separate unrolls."""
+    agent = make_agent(use_lstm=True, burnin=4)
+    batch = make_batch(agent, key=3)
+    # Non-trivial stored carries + a mid-burnin reset row.
+    h = jax.random.normal(jax.random.PRNGKey(9), (B, HID))
+    batch = dataclasses.replace(
+        batch, reset=batch.reset.at[1, 2].set(1.0),
+        carries={"actor": (h, 0.5 * h), "critic": (-h, 0.25 * h)},
+    )
+    state = desynced(
+        agent.init(jax.random.PRNGKey(0), batch.obs[:, 0], batch.action[:, 0])
+    )
+    got = agent.seq.burn_in(state, batch)
+    want = unfused_burn_in(agent, state, batch)
+    assert not np.allclose(want[0][1], want[1][1])  # online and target part
     for g, w in zip(got, want):
         jax.tree_util.tree_map(
             lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6),
             g,
             w,
         )
-    # And the full learner step agrees.
-    w_is = jnp.ones((B,))
-    _, p_f, m_f = fused.learner_step(state, batch, w_is)
-    _, p_p, m_p = plain.learner_step(state, batch, w_is)
-    np.testing.assert_allclose(np.asarray(p_f), np.asarray(p_p), rtol=1e-5)
-    for k in m_f:
-        np.testing.assert_allclose(float(m_f[k]), float(m_p[k]), rtol=1e-4)
+
+
+class LeakyActor(nn.Module):
+    """A stepped net that is not ``ActorNet``: its carry is ONE array, a
+    leaky integrator of the encoded observation."""
+
+    action_dim: int
+    hidden: int
+
+    @nn.compact
+    def __call__(self, obs, carry, reset):
+        carry = jnp.where(reset[:, None] > 0, 0.0, carry)
+        carry = 0.8 * carry + 0.2 * jnp.tanh(nn.Dense(self.hidden)(obs))
+        return jnp.tanh(nn.Dense(self.action_dim)(carry)), carry
+
+    def initial_carry(self, batch_size):
+        return jnp.zeros((batch_size, self.hidden))
+
+
+class LeakyCritic(nn.Module):
+    hidden: int
+
+    @nn.compact
+    def __call__(self, obs, action, carry, reset):
+        carry = jnp.where(reset[:, None] > 0, 0.0, carry)
+        x = nn.Dense(self.hidden)(jnp.concatenate([obs, action], axis=-1))
+        carry = 0.8 * carry + 0.2 * jnp.tanh(x)
+        return jnp.squeeze(nn.Dense(1)(carry), axis=-1), carry
+
+    def initial_carry(self, batch_size):
+        return jnp.zeros((batch_size, self.hidden))
+
+
+def test_a_stepped_core_defined_outside_the_package_needs_no_edit_to_the_learner():
+    """What adding a stepped core costs: the nets.  The learner is given two
+    modules it has never seen, whose carry is not ``(c, h)``, and burns in,
+    updates and ranks fresh sequences with them."""
+    agent = R2D2DPG(
+        LeakyActor(action_dim=ACT, hidden=HID),
+        LeakyCritic(hidden=HID),
+        AgentConfig(burnin=3, unroll=3, n_step=2),
+    )
+    batch = make_batch(agent, key=5)
+    h = jax.random.normal(jax.random.PRNGKey(11), (B, HID))
+    batch = dataclasses.replace(
+        batch, reset=batch.reset.at[2, 1].set(1.0),
+        carries={"actor": h, "critic": -h},
+    )
+    state = desynced(
+        agent.init(jax.random.PRNGKey(0), batch.obs[:, 0], batch.action[:, 0])
+    )
+    got = agent.seq.burn_in(state, batch)
+    for g, w in zip(got, unfused_burn_in(agent, state, batch)):
+        assert g.shape == (B, HID)
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-6)
+    assert not np.allclose(got[0], h) and not np.allclose(got[0], got[1])
+    assert np.all(np.asarray(got[0][2]) != 0)  # the row reset mid-prefix warmed again
+
+    p_init = agent.initial_priority(state, batch)
+    new_state, prios, metrics = jax.jit(agent.learner_step)(state, batch, jnp.ones(B))
+    assert np.all(np.isfinite(prios)) and np.all(np.asarray(prios) > 0)
+    np.testing.assert_allclose(p_init, prios, rtol=1e-4, atol=1e-5)
+    assert all(np.isfinite(v) for v in jax.device_get(metrics).values())
+    for before, after in ((state.actor_params, new_state.actor_params),
+                          (state.critic_params, new_state.critic_params)):
+        moved = jax.tree_util.tree_map(
+            lambda a, b: float(jnp.abs(a - b).max()), before, after)
+        assert all(m > 0 for m in jax.tree_util.tree_leaves(moved))

@@ -2,7 +2,7 @@
 
 Covers the dp-sharded drain/learn path on the virtual CPU mesh, the
 ``--learner-dp`` CLI wiring + refused knob combos, the coalesce-width
-precompile (the BENCH_FLEET ``fleet_coalesce`` regression fix), and the
+precompile (the ``fleet_coalesce`` regression fix), and the
 determinism anchor extending the ``--actors 0`` bit-identical contract to
 ``--learner-dp 1`` — ``scripts/lib_gate.sh learner_dp_gate`` refuses to
 bless ``--learner-dp N`` evidence dirs unless that anchor passes.

@@ -21,6 +21,7 @@ if REPO not in sys.path:
 from chipbench import compare, harness, reference_sdar_moe as ref_moe  # noqa: E402
 from r2d2dpg_tpu.configs import SDAR_TINY  # noqa: E402
 from r2d2dpg_tpu.models import policy_step_fn, sdar_moe  # noqa: E402
+from r2d2dpg_tpu.models.sequence import Stepped, Whole  # noqa: E402
 from r2d2dpg_tpu.obs.stages import stage_of, table_keys  # noqa: E402
 from r2d2dpg_tpu.replay.arena import SequenceBatch  # noqa: E402
 from r2d2dpg_tpu.utils.metrics import host_scalars  # noqa: E402
@@ -235,9 +236,9 @@ def test_gradients_vanish_on_burn_in_positions(agent, weights, rows):
 
     def q_sum(obs):
         batch = SequenceBatch(**dict(rows, obs=obs))
-        _, _, cc_on, _ = agent._burn_in(state, batch)
+        _, _, cc_on, _ = agent.seq.burn_in(state, batch)
         tm = lambda x: jnp.swapaxes(x[:, BURNIN:BURNIN + UNROLL], 0, 1)  # noqa: E731
-        q, _ = agent._unroll_critic(critic, cc_on, tm(obs), tm(batch.action), tm(batch.reset))
+        q, _ = agent.seq.unroll_critic(critic, cc_on, tm(obs), tm(batch.action), tm(batch.reset))
         return q.sum()
 
     g = np.asarray(jax.grad(q_sum)(rows["obs"]))
@@ -298,4 +299,17 @@ def test_lstm_and_dense_cores_refuse_sequence_arguments_and_keep_their_trees():
         st = jax.eval_shape(lambda k: agent.init(k, jnp.zeros((1, 3)), jnp.zeros((1, 1))),
                             jax.random.PRNGKey(0))
         assert set(st.actor_params["params"]["core"]) == core
-        assert not agent.sdar and agent.actor.stored_carry("c") == "c"
+        assert isinstance(agent.seq, Stepped) and agent.actor.stored_carry("c") == "c"
+
+
+@pytest.mark.parametrize("knob", [{"twin_critic": True}, {"target_policy_sigma": 0.2}],
+                         ids=["twin_critic", "target_policy_sigma"])
+def test_the_whole_sequence_kind_refuses_the_td3_knobs_at_construction(agent, knob):
+    """The ensemble min and the smoothing noise are steps of the stepped
+    kind's scan; the whole-sequence kind has neither, and says so before
+    anything is traced."""
+    assert isinstance(agent.seq, Whole)
+    exp = dataclasses.replace(
+        SDAR_TINY, agent=dataclasses.replace(SDAR_TINY.agent, **knob))
+    with pytest.raises(ValueError, match="not wired for the sdar core"):
+        exp.build_agent(SDAR_TINY.env_factory())

@@ -9,13 +9,15 @@ clipped noise on the bootstrap action.  Both default OFF — the plain-DDPG
 path (SURVEY.md §2.4) must be bit-for-bit unaffected.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from r2d2dpg_tpu.agents import AgentConfig, R2D2DPG
-from r2d2dpg_tpu.models import ActorNet, CriticNet
+from r2d2dpg_tpu.models import ActorNet, CriticNet, unroll
 from r2d2dpg_tpu.replay.arena import SequenceBatch
 
 B, OBS, ACT, HID = 4, 3, 2, 16
@@ -102,7 +104,7 @@ def test_twin_min_bootstrap_lowers_targets():
     w = slice(agent.config.burnin, agent.config.seq_len)
     obs_w = jnp.swapaxes(batch.obs[:, w], 0, 1)
     reset_w = jnp.swapaxes(batch.reset[:, w], 0, 1)
-    ca, ca_tg, cc, cc_tg = agent._burn_in(state, batch)
+    ca, ca_tg, cc, cc_tg = agent.seq.burn_in(state, batch)
     q_twin = agent._target_q(state, ca_tg, cc_tg, obs_w, reset_w, None)
     # Plain agent with member 0's params only.
     member0 = jax.tree_util.tree_map(lambda x: x[0], state.critic_params)
@@ -119,20 +121,46 @@ def test_twin_min_bootstrap_lowers_targets():
         critic_opt_state=None,
         step=state.step,
     )
-    ca0, ca_tg0, cc0, cc_tg0 = plain._burn_in(state0, batch)
+    ca0, ca_tg0, cc0, cc_tg0 = plain.seq.burn_in(state0, batch)
     q_plain = plain._target_q(state0, ca_tg0, cc_tg0, obs_w, reset_w, None)
     assert np.all(np.asarray(q_twin) <= np.asarray(q_plain) + 1e-6)
 
 
 def test_twin_fused_and_unfused_burnin_agree():
-    agent_f = make_agent(use_lstm=True, twin_critic=True, fused_burnin=True)
-    agent_u = make_agent(use_lstm=True, twin_critic=True, fused_burnin=False)
-    state = init_state(agent_f)
-    batch = make_batch(agent_f)
-    out_f = agent_f._burn_in(state, batch)
-    out_u = agent_u._burn_in(state, batch)
+    """The twin agent's four burn-in carries against a reference built here,
+    net by net and member by member: each unrolled alone over the prefix from
+    the stored carry, the members stacked."""
+    agent = make_agent(use_lstm=True, twin_critic=True)
+    state = init_state(agent)
+    batch = make_batch(agent)
+    h = jax.random.normal(jax.random.PRNGKey(9), (B, HID))
+    batch = dataclasses.replace(
+        batch, reset=batch.reset.at[1, 1].set(1.0),
+        carries={"actor": (h, 0.5 * h), "critic": (-h, 0.25 * h)},
+    )
+    n = agent.config.burnin
+    tm = lambda x: jnp.swapaxes(x[:, :n], 0, 1)  # noqa: E731
+    obs, act, reset = tm(batch.obs), tm(batch.action), tm(batch.reset)
+
+    def actor(p):
+        return unroll(lambda c, o, r: agent.actor.apply(p, o, c, r),
+                      batch.carries["actor"], obs, reset)[1]
+
+    def critics(p2):
+        members = [
+            unroll(lambda c, o, a, r: agent.critic.apply(p, o, a, c, r),
+                   batch.carries["critic"], obs, act, reset)[1]
+            for p in (jax.tree_util.tree_map(lambda x: x[i], p2) for i in range(2))
+        ]
+        return jax.tree_util.tree_map(lambda a, b: jnp.stack([a, b]), *members)
+
+    want = (actor(state.actor_params), actor(state.target_actor_params),
+            critics(state.critic_params), critics(state.target_critic_params))
+    got = agent.seq.burn_in(state, batch)
+    assert jax.tree_util.tree_structure(got) == jax.tree_util.tree_structure(want)
+    assert not np.allclose(want[2][1][0], want[2][1][1])  # the members part
     for a, b in zip(
-        jax.tree_util.tree_leaves(out_f), jax.tree_util.tree_leaves(out_u)
+        jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)
     ):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
 
