@@ -254,15 +254,22 @@ def _built_trainers():
 
 
 def _require_learner_call_guards(trainer, state) -> dict:
-    """The two compile-time guards of the learner call (docs/OBSERVABILITY.md):
-    ``Trainer._learn_many``, state donated, compiled by this chip's compiler
-    at the run's own shapes, rounds no ``[capacity, ...]`` value and inserts
-    no sequence into a batch-minor ``[batch, ...]`` buffer.  Only the TPU
-    compiler makes either choice, so only a chip run can check that
-    ``ReplayArena.sample`` still takes both from it."""
+    """The three compile-time guards of the learner call
+    (docs/OBSERVABILITY.md): ``Trainer._learn_many``, state donated, compiled
+    by this chip's compiler at the run's own shapes, rounds no
+    ``[capacity, ...]`` value, inserts no sequence into a batch-minor
+    ``[batch, ...]`` buffer, and runs no image convolution inside a scan of an
+    update.  Only the TPU compiler makes the first two choices, so only a chip
+    run can check that ``ReplayArena.sample`` still takes both from it; the
+    third says that ``models/sequence.py::Stepped`` took the pixel torso out
+    of its scans (trivially so for a configuration without one)."""
     import jax
 
-    from r2d2dpg_tpu.obs.hlo import arena_converts, batch_minor_writes
+    from r2d2dpg_tpu.obs.hlo import (
+        arena_converts,
+        batch_minor_writes,
+        loop_convolutions,
+    )
 
     call = jax.jit(trainer._learn_many, donate_argnums=(0, 1))
     hlo = call.lower(state.train, state.arena, state.rng).compile().as_text()
@@ -276,12 +283,24 @@ def _require_learner_call_guards(trainer, state) -> dict:
         not writes,
         f"the learner call writes its sampled batch batch-minor: {writes}",
     )
+    # The call is itself a loop over its updates (``_learn_many``'s scan,
+    # which the compiler keeps where there are two or more), so an update's
+    # own operations sit that one loop deep; deeper is a scan inside it.
+    call_loops = int(trainer.config.learner_steps > 1)
+    convolutions = loop_convolutions(hlo)
+    in_scans = [c for c in convolutions if c[3] > call_loops]
+    _require(
+        not in_scans,
+        f"the learner call runs an image convolution once a scan step: {in_scans}",
+    )
     return {
         "learner_call_hlo_lines": hlo.count("\n"),
         "arena_capacity": trainer.arena.capacity,
         "arena_converts": converts,
         "batch_size": trainer.config.batch_size,
         "batch_minor_writes": writes,
+        "loop_convolutions": convolutions,
+        "convolutions_in_scans": in_scans,
     }
 
 
@@ -339,7 +358,8 @@ def _leg_train(work: str) -> dict:
     """Base ``Trainer``: host MuJoCo pool through ordered ``io_callback``
     inside the jitted phase, the HBM arena at capacity 100,000, the Pallas
     write-back, donated state; then the learner call alone, compiled for the
-    whole-arena convert guard and the batch-minor write guard, for
+    whole-arena convert guard, the batch-minor write guard and the
+    convolution-in-a-scan guard, for
     ``walker_r2d2`` and, from shapes, for the sequence core's configuration
     ``humanoid_sdar_moe`` and the pixel replay's ``cheetah_pixels``."""
     _fresh_native_build()

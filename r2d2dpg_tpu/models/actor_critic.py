@@ -15,11 +15,16 @@ Reference parity: SURVEY.md §2.1 / §3.4 —
 - Episode boundaries: the carry is zeroed where ``reset`` is set *before* the
   cell runs (SURVEY §2.1 "per-step hidden-state reset on episode boundary").
 
-TPU notes: the single-step call is what the actor phase vmaps over envs; the
-learner unrolls it with ``lax.scan`` over time (SURVEY §2.9 — burn-in+unroll
-as one jitted scan instead of cuDNN LSTM calls).  All matmuls are MXU-shaped
-([B, hidden] x [hidden, 4*hidden]); ``dtype=bfloat16`` is supported
-throughout with float32 params.
+TPU notes: the single-step call (``__call__``) is what the actor phase vmaps
+over envs.  It is ``readout(step(encode(obs), carry, reset))``: ``encode`` is
+what does not depend on the carry (torso; the LSTM's input projection; the
+critic's ``mix`` where the action is the replay's) and takes any leading
+dimensions, so the learner runs it once over a whole ``[T, B, ...]`` input,
+scans ``step`` alone with ``lax.scan`` over time (SURVEY §2.9 — burn-in+unroll
+as jitted scans instead of cuDNN LSTM calls) and reads the stacked outputs out
+once (``models/sequence.py::Stepped``).  The hoisted matmuls and convolutions
+see T·B rows, the step's [B, hidden] x [hidden, 4*hidden];
+``dtype=bfloat16`` is supported throughout with float32 params.
 """
 
 from __future__ import annotations
@@ -75,16 +80,13 @@ class _GateParams(nn.Module):
     mixed cell's checkpoint tree is leaf-for-leaf identical to the stock
     cell's and fp32<->bf16 checkpoints interchange (VERDICT r3 weak #1)."""
 
-    in_features: int
     features: int
     use_bias: bool
     kernel_init: Any
 
     @nn.compact
-    def __call__(self):
-        kernel = self.param(
-            "kernel", self.kernel_init, (self.in_features, self.features)
-        )
+    def __call__(self, in_features: int):
+        kernel = self.param("kernel", self.kernel_init, (in_features, self.features))
         bias = (
             self.param("bias", nn.initializers.zeros_init(), (self.features,))
             if self.use_bias
@@ -94,16 +96,19 @@ class _GateParams(nn.Module):
 
 
 class MixedPrecisionLSTMCell(nn.Module):
-    """LSTM cell with ``dtype`` gate matmuls but FLOAT32 state arithmetic.
+    """The LSTM cell of every net: gate matmuls in ``dtype``, FLOAT32 state
+    arithmetic, and its step split where the carry comes in.
 
-    Motivation (round-3 dtype A/B): with flax's cell at
-    ``dtype=bfloat16`` the carry itself is returned in bf16, so the cell
-    state ``c`` accumulates rounding across every unroll step — walker
-    learning fell ~3x behind fp32 while short-horizon pendulum masked it.
-    Here the two gate projections (the MXU work, >95% of the FLOPs) run in
-    ``dtype`` while the state update ``c' = f*c + i*g`` and the carry stay
-    float32, targeting exactly the compounding path at ~none of the
-    throughput cost.
+    ``project`` is the input half of the gates, ``x·[W_ii|W_if|W_ig|W_io]``:
+    it depends on nothing the recurrence carries and takes any leading
+    dimensions, so the learner computes it ONCE over a whole ``[T, B, ...]``
+    input before its scan (``models/sequence.py::Stepped``).  ``step`` is what
+    needs the carry: ``h·W_h + b``, the gates, the state update.  ``__call__``
+    is ``step(carry, project(x))``, the single step that acting uses.  flax's
+    stock ``OptimizedLSTMCell`` offers no such split, so float32 runs through
+    this cell too (PR 30): the same products at the same (JAX default)
+    precision, summed in flax's order, ``(zh + b) + zx`` (until PR 30 this
+    cell summed ``zx + zh + b``: float32 rounding of one add apart).
 
     Semantics AND param tree mirror flax's OptimizedLSTMCell exactly —
     gate order (i, f, g, o), zero-init recurrent biases with NO extra
@@ -111,9 +116,18 @@ class MixedPrecisionLSTMCell(nn.Module):
     orthogonal recurrent kernels ``hi/hf/hg/ho`` (with bias) — declared as
     per-gate ``_GateParams`` leaves and fused into one [in, 4H] / [H, 4H]
     matmul pair at apply time (loop-invariant: XLA hoists the concat out
-    of the unroll scan).  A bf16-vs-fp32 comparison therefore measures
-    precision alone, and a checkpoint written under either dtype restores
-    under the other.
+    of the unroll scan).  ``_Core`` pins the cell to the name the stock cell
+    got by auto-naming (``OptimizedLSTMCell_0``).  A bf16-vs-fp32 comparison
+    therefore measures precision alone, and a checkpoint written under
+    either dtype, or by the stock cell, restores under the other.
+
+    Why the state stays float32 under ``dtype=bfloat16`` (round-3 dtype A/B):
+    with flax's cell at ``dtype=bfloat16`` the carry itself is returned in
+    bf16, so the cell state ``c`` accumulates rounding across every unroll
+    step — walker learning fell ~3x behind fp32 while short-horizon pendulum
+    masked it.  Here the two gate projections (the MXU work, >95% of the
+    FLOPs) run in ``dtype`` while the state update ``c' = f*c + i*g`` and the
+    carry stay float32.
 
     Measured outcome (round-5 controlled A/B, taken on the fp32-CARRY
     revision of this cell BEFORE the fp32-accumulator dots below): the
@@ -131,44 +145,56 @@ class MixedPrecisionLSTMCell(nn.Module):
     hidden: int
     dtype: Any = jnp.bfloat16
 
-    @nn.compact
-    def __call__(self, carry: Carry, x: jnp.ndarray):
-        c, h = carry  # float32 by contract (lstm_initial_carry)
+    def setup(self):
         lecun = nn.initializers.lecun_normal()
         orth = nn.initializers.orthogonal()
-        wi, wh, bh = [], [], []
         for g in "ifgo":
-            k, _ = _GateParams(
-                x.shape[-1], self.hidden, False, lecun, name=f"i{g}"
-            )()
-            wi.append(k)
-            k, b = _GateParams(
-                self.hidden, self.hidden, True, orth, name=f"h{g}"
-            )()
-            wh.append(k)
-            bh.append(b)
+            setattr(self, f"i{g}", _GateParams(self.hidden, False, lecun))
+            setattr(self, f"h{g}", _GateParams(self.hidden, True, orth))
+
+    def _dot(self, x: jnp.ndarray, kernels) -> jnp.ndarray:
         # Operands stream in ``dtype`` (the HBM/MXU win) but the dot
         # ACCUMULATES in fp32 via preferred_element_type — free on TPU,
         # whose MXU natively accumulates bf16 products into fp32; without
         # it XLA truncates the accumulator to bf16 at every step of the
         # recurrence, which the round-5 A/B implicates as the remaining
         # compounding-error path.
-        zx = jnp.matmul(
+        return jnp.matmul(
             x.astype(self.dtype),
-            jnp.concatenate(wi, axis=1).astype(self.dtype),
+            jnp.concatenate(kernels, axis=1).astype(self.dtype),
             preferred_element_type=jnp.float32,
         )
-        zh = jnp.matmul(
-            h.astype(self.dtype),
-            jnp.concatenate(wh, axis=1).astype(self.dtype),
-            preferred_element_type=jnp.float32,
+
+    def project(self, x: jnp.ndarray) -> jnp.ndarray:
+        """``x [..., in] -> zx [..., 4H]`` float32: the gates' input half."""
+        return self._dot(
+            x, [getattr(self, f"i{g}")(x.shape[-1])[0] for g in "ifgo"]
         )
-        # Gate math + state update in fp32 (bias join included).
-        z = zx + zh + jnp.concatenate(bh, axis=0)
-        i, f, g, o = jnp.split(z, 4, axis=-1)
+
+    def step(self, carry: Carry, zx: jnp.ndarray):
+        """``(c, h), zx [B, 4H] -> ((c, h), y [B, H])``: the recurrence."""
+        c, h = carry  # float32 by contract (lstm_initial_carry)
+        wh, bh = zip(*(getattr(self, f"h{g}")(self.hidden) for g in "ifgo"))
+        # Gate math + state update in fp32 (bias join included), summed as
+        # flax's cell sums them: ``(zh + b) + zx``, the second join gate by
+        # gate on slices.  Joined whole (``zx + zh + b``) XLA's CPU backend
+        # folds ``zx +`` into the matmul of a one-row batch as its
+        # accumulator's start, and a served session's actions then depend on
+        # the bucket it was batched into (tests/test_serving.py); joined on
+        # slices throughout, the chip loses the bias as the matmul's epilogue
+        # (walker 1,313 / 1,350 / 1,369 steps/s: slices, this, whole; PERF.md
+        # PR 30).
+        zh = self._dot(h, wh) + jnp.concatenate(bh, axis=0)
+        i, f, g, o = (
+            h_ + x_
+            for x_, h_ in zip(jnp.split(zx, 4, axis=-1), jnp.split(zh, 4, axis=-1))
+        )
         c = nn.sigmoid(f) * c + nn.sigmoid(i) * jnp.tanh(g)
         h = nn.sigmoid(o) * jnp.tanh(c)
         return (c, h), h.astype(self.dtype)
+
+    def __call__(self, carry: Carry, x: jnp.ndarray):
+        return self.step(carry, self.project(x))
 
 
 class _Core(nn.Module):
@@ -181,6 +207,12 @@ class _Core(nn.Module):
     memory a prefix left, ``()`` for none, and what comes back beside ``y``
     is that call's own memory and expert loads, ``models/sdar_moe.py``).
 
+    A step is ``step(project(x), carry, reset)``.  ``project`` is what of it
+    does not depend on the carry and takes any leading dimensions (the LSTM's
+    input projection; all of the Dense core, which carries nothing; nothing of
+    an ``sdar`` step), so that the learner can run it once over a whole
+    sequence and scan ``step`` alone.
+
     The one place here that tells the cores apart: the nets ask it for their
     carries and for how the learner runs them (``_core_of``).
     """
@@ -189,6 +221,22 @@ class _Core(nn.Module):
     use_lstm: bool
     dtype: Any = jnp.float32
     sdar: Optional[SdarMoeConfig] = None
+
+    def setup(self):
+        # The names are the tree paths of every checkpoint and of
+        # chipbench/reference.py::init_state: ``OptimizedLSTMCell_0`` and
+        # ``Dense_0`` are what auto-naming called flax's stock modules here.
+        if self.sdar is not None:
+            self.block = SdarMoeCore(self.sdar, dtype=self.dtype, name="sdar")
+        elif self.use_lstm:
+            self.cell = MixedPrecisionLSTMCell(
+                self.hidden, dtype=self.dtype, name="OptimizedLSTMCell_0"
+            )
+        else:
+            self.dense = nn.Dense(
+                self.hidden, kernel_init=fan_in_uniform(), dtype=self.dtype,
+                name="Dense_0",
+            )
 
     @property
     def whole_sequence(self) -> bool:
@@ -209,34 +257,25 @@ class _Core(nn.Module):
         core's memory is recomputed from the burn-in prefix, so nothing."""
         return () if self.whole_sequence else carry
 
-    @nn.compact
-    def __call__(self, x: jnp.ndarray, carry: Carry, reset: jnp.ndarray, **seq):
+    def project(self, x: jnp.ndarray) -> jnp.ndarray:
+        if self.sdar is not None:
+            return x
+        if self.use_lstm:
+            return self.cell.project(x)
+        return nn.relu(self.dense(x))
+
+    def step(self, z: jnp.ndarray, carry: Carry, reset: jnp.ndarray, **seq):
         if self.sdar is not None:
             if not seq.get("sequence"):
                 carry = zeros_where_reset(carry, reset)
-            return SdarMoeCore(self.sdar, dtype=self.dtype, name="sdar")(
-                x, carry, reset, **seq
-            )
+            return self.block(z, carry, reset, **seq)
         if self.use_lstm:
-            carry = zeros_where_reset(carry, reset)
-            if self.dtype != jnp.float32:
-                # Reduced-precision mode routes through the fp32-carry cell
-                # (see MixedPrecisionLSTMCell); the fp32 default keeps the
-                # stock flax cell bit-for-bit.  The explicit name pins the
-                # mixed cell to the tree path the stock cell gets by
-                # auto-naming, so checkpoints interchange across dtypes.
-                carry, y = MixedPrecisionLSTMCell(
-                    self.hidden, dtype=self.dtype, name="OptimizedLSTMCell_0"
-                )(carry, x)
-            else:
-                carry, y = nn.OptimizedLSTMCell(self.hidden, dtype=self.dtype)(
-                    carry, x
-                )
+            carry, y = self.cell.step(zeros_where_reset(carry, reset), z)
             return y, carry
-        y = nn.relu(
-            nn.Dense(self.hidden, kernel_init=fan_in_uniform(), dtype=self.dtype)(x)
-        )
-        return y, carry
+        return z, carry
+
+    def __call__(self, x: jnp.ndarray, carry: Carry, reset: jnp.ndarray, **seq):
+        return self.step(self.project(x), carry, reset, **seq)
 
 
 def _core_of(net: nn.Module, **kwargs) -> _Core:
@@ -273,10 +312,23 @@ class ActorNet(nn.Module):
         self, obs: jnp.ndarray, carry: Carry, reset: jnp.ndarray
     ) -> Tuple[jnp.ndarray, Carry]:
         """Single step: obs [B, ...], reset [B] -> (action [B, A], new carry)."""
-        x = self.torso(obs)
-        y, carry = self.core(x, carry, reset)
-        action = jnp.tanh(self.head(y)).astype(jnp.float32) * self.action_scale
-        return action, carry
+        y, carry = self.step(self.encode(obs), carry, reset)
+        return self.readout(y), carry
+
+    def encode(self, obs: jnp.ndarray) -> jnp.ndarray:
+        """What of a step does not depend on the carry: the torso and the
+        core's input projection.  ``obs [..., *obs_shape]``, any leading
+        dimensions: the learner runs it once over ``[T, B]``."""
+        return self.core.project(self.torso(obs))
+
+    def step(self, z: jnp.ndarray, carry: Carry, reset: jnp.ndarray):
+        """What needs the carry: ``encode``'s rows of one step ``[B, ...]`` ->
+        (the core's output ``y [B, H]``, new carry)."""
+        return self.core.step(z, carry, reset)
+
+    def readout(self, y: jnp.ndarray) -> jnp.ndarray:
+        """``y [..., H]`` -> action ``[..., A]``."""
+        return jnp.tanh(self.head(y)).astype(jnp.float32) * self.action_scale
 
     def sequence(self, obs, reset, memory=(), memory_only: bool = False):
         """The ``sdar`` core over whole sequences: obs ``[B, T, ...]``, reset
@@ -284,8 +336,7 @@ class ActorNet(nn.Module):
         y, aux = self.core(
             self.torso(obs), memory, reset, sequence=True, memory_only=memory_only
         )
-        action = jnp.tanh(self.head(y)).astype(jnp.float32) * self.action_scale
-        return action, aux
+        return self.readout(y), aux
 
     @property
     def whole_sequence(self) -> bool:
@@ -325,19 +376,48 @@ class CriticNet(nn.Module):
         reset: jnp.ndarray,
     ) -> Tuple[jnp.ndarray, Carry]:
         """Single step -> (q [B], new carry)."""
+        y, carry = self.step(self.encode(obs, action), carry, reset)
+        return self.readout(y), carry
+
+    def _mixed(self, x: jnp.ndarray, action: jnp.ndarray) -> jnp.ndarray:
+        return nn.relu(self.mix(jnp.concatenate([x, action.astype(x.dtype)], axis=-1)))
+
+    def encode(
+        self, obs: jnp.ndarray, action: Optional[jnp.ndarray] = None
+    ) -> jnp.ndarray:
+        """What of a step does not depend on the carry, over any leading
+        dimensions.  With the action (the replay's): torso, ``mix`` and the
+        core's input projection.  Without (the action is a policy's, made in
+        the same step): the torso's features alone, and ``step`` takes the
+        action."""
         x = self.torso(obs)
-        x = nn.relu(self.mix(jnp.concatenate([x, action.astype(x.dtype)], axis=-1)))
-        y, carry = self.core(x, carry, reset)
-        q = self.head(y).astype(jnp.float32)
-        return jnp.squeeze(q, axis=-1), carry
+        return x if action is None else self.core.project(self._mixed(x, action))
+
+    def step(
+        self,
+        z: jnp.ndarray,
+        carry: Carry,
+        reset: jnp.ndarray,
+        action: Optional[jnp.ndarray] = None,
+    ):
+        """What needs the carry: ``encode``'s rows of one step -> (the core's
+        output ``y [B, H]``, new carry).  ``action`` goes with rows encoded
+        without one; ``mix`` then runs whole here (its matmul is not split by
+        columns: that would reorder a reduction)."""
+        if action is not None:
+            z = self.core.project(self._mixed(z, action))
+        return self.core.step(z, carry, reset)
+
+    def readout(self, y: jnp.ndarray) -> jnp.ndarray:
+        """``y [..., H]`` -> q ``[...]``."""
+        return jnp.squeeze(self.head(y).astype(jnp.float32), axis=-1)
 
     def sequence(self, obs, action, reset, memory=(), memory_only: bool = False):
         """The ``sdar`` core over whole sequences -> (q ``[B, T]``, the call's
         memory and loads)."""
-        x = self.torso(obs)
-        x = nn.relu(self.mix(jnp.concatenate([x, action.astype(x.dtype)], axis=-1)))
+        x = self._mixed(self.torso(obs), action)
         y, aux = self.core(x, memory, reset, sequence=True, memory_only=memory_only)
-        return jnp.squeeze(self.head(y).astype(jnp.float32), axis=-1), aux
+        return self.readout(y), aux
 
     @property
     def whole_sequence(self) -> bool:

@@ -15,10 +15,14 @@ operations —
 
 ``Stepped`` (LSTM, Dense, any net whose ``apply`` is one step) scans single
 steps from the carry the replay stored; what a pass leaves is its last carry.
-``Whole`` (the sdar core) hands the net whole sequences; its carry is the
-memory the prefix left and what a pass leaves are its expert loads.  A new
-stepped core, whatever its carry's shape, needs nothing here; a new kind is one
-more class with these operations.
+What of a step does not depend on the carry (a net's ``encode``: the torso, the
+LSTM's input projection) it computes once over the whole time-major input
+before the scan, and the scan keeps what needs the carry (PR 30: the conv
+torso saw 32 frames a step, now 640-800 a pass).  ``Whole`` (the sdar core)
+hands the net whole sequences; its carry is the memory the prefix left and
+what a pass leaves are its expert loads.  A new stepped core, whatever its
+carry's shape, needs nothing here (``apply`` alone is scanned whole); a new
+kind is one more class with these operations.
 """
 
 from __future__ import annotations
@@ -50,31 +54,66 @@ def _unstack2(t: Any) -> Tuple[Any, Any]:
     )
 
 
-class Stepped:
-    """``lax.scan`` of single steps from the stored carry.
+def _parts(net):
+    """``net`` as ``(encode, step, readout)``, each taking the parameters first.
 
-    Asks the nets for ``apply`` alone (``actor.apply(params, obs, carry,
-    reset)``, ``critic.apply(params, obs, action, carry, reset)``, each one
-    step returning its output and the new carry); a net that has nothing to
-    warm is one whose stored carry has no leaves.
+    A net that says what of its step does not depend on the carry (``encode``,
+    with ``step`` on the encoded rows and ``readout`` on the core's outputs:
+    ``ActorNet``, ``CriticNet``) is taken apart there.  A net that offers
+    ``apply`` alone is all step: its "encoded" rows are its inputs as they
+    are, and its outputs are final.
+    """
+    if hasattr(net, "encode"):
+        return (
+            lambda p, *xs: net.apply(p, *xs, method="encode"),
+            lambda p, z, c, r, *a: net.apply(p, z, c, r, *a, method="step"),
+            lambda p, y: net.apply(p, y, method="readout"),
+        )
+    return (
+        lambda p, *xs: xs,
+        lambda p, xs, c, r, *a: net.apply(p, *xs, *a, c, r),
+        lambda p, y: y,
+    )
+
+
+class Stepped:
+    """``lax.scan`` of single steps from the stored carry, with what does not
+    depend on the carry taken out of the scan.
+
+    A step of a net is ``readout(step(encode(inputs), carry, reset))``
+    (``_parts``).  ``encode`` (torso; for the LSTM core its input projection;
+    for the critic on the replay's actions ``mix`` too) runs ONCE over the
+    whole time-major ``[T, B, ...]`` input, so its matmuls and convolutions
+    see T·B rows; the scan body keeps the reset, ``h·W_h + b``, the gates and,
+    where the critic follows the actor, the actor's head and the critic's
+    ``mix``; ``readout`` runs once over the stacked outputs.  Same products,
+    same precision: only the order of the sums over rows changes.  A net
+    offering ``apply`` alone (``actor.apply(params, obs, carry, reset)``,
+    ``critic.apply(params, obs, action, carry, reset)``, each one step
+    returning its output and the new carry) is scanned whole; a net that has
+    nothing to warm is one whose stored carry has no leaves.
     """
 
     def __init__(self, actor, critic, config):
         self.actor, self.critic, self.config = actor, critic, config
+        self._actor, self._critic = _parts(actor), _parts(critic)
 
-    def unroll_actor(self, params, carry, obs_tm, reset_tm):
-        return unroll(
-            lambda c, o, r: self.actor.apply(params, o, c, r), carry, obs_tm, reset_tm
-        )
-
-    def unroll_critic(self, params, carry, obs_tm, act_tm, reset_tm):
-        return unroll(
-            lambda c, o, a, r: self.critic.apply(params, o, a, c, r),
+    @staticmethod
+    def _unroll(parts, params, carry, reset_tm, *inputs_tm):
+        encode, step, readout = parts
+        y_tm, carry = unroll(
+            lambda c, z, r: step(params, z, c, r),
             carry,
-            obs_tm,
-            act_tm,
+            encode(params, *inputs_tm),
             reset_tm,
         )
+        return readout(params, y_tm), carry
+
+    def unroll_actor(self, params, carry, obs_tm, reset_tm):
+        return self._unroll(self._actor, params, carry, reset_tm, obs_tm)
+
+    def unroll_critic(self, params, carry, obs_tm, act_tm, reset_tm):
+        return self._unroll(self._critic, params, carry, reset_tm, obs_tm, act_tm)
 
     def unroll_pi_q(
         self, actor_params, critic_params, ca, cc, obs_tm, reset_tm,
@@ -85,29 +124,41 @@ class Stepped:
         Halves the sequential-scan count of the two places that unroll the
         policy and then re-unroll the critic over its actions (the n-step
         target pass and the actor loss) — per-step math is identical to the
-        two-scan version, the cells just step together.  The TD3 knobs of
-        the target pass: ``eps_tm`` is added to each step's action (clipped
-        back into range) before the critic sees it; ``q_min`` takes
-        ``critic_params`` and ``cc`` with a leading ensemble axis and returns
-        the least member's Q.
+        two-scan version, the cells just step together.  The critic's
+        ``encode`` is its torso alone here: the action is made in the step,
+        so ``mix`` stays there.  The TD3 knobs of the target pass: ``eps_tm``
+        is added to each step's action (clipped back into range) before the
+        critic sees it; ``q_min`` takes ``critic_params`` and ``cc`` with a
+        leading ensemble axis and returns the least member's Q.
         """
+        a_encode, a_step, a_readout = self._actor
+        c_encode, c_step, c_readout = self._critic
 
-        def step(carry, o, r, *e):
+        def step(carry, za, zc, r, *e):
             ca, cc = carry
-            a, ca = self.actor.apply(actor_params, o, ca, r)
+            y, ca = a_step(actor_params, za, ca, r)
+            a = a_readout(actor_params, y)
             if e:
                 a = jnp.clip(a + e[0], -1.0, 1.0)
             if q_min:
-                q2, cc = jax.vmap(
-                    lambda p, c: self.critic.apply(p, o, a, c, r)
-                )(critic_params, cc)
-                q = q2.min(axis=0)
+                yq, cc = jax.vmap(lambda p, z, c: c_step(p, z, c, r, a))(
+                    critic_params, zc, cc
+                )
             else:
-                q, cc = self.critic.apply(critic_params, o, a, cc, r)
-            return (a, q), (ca, cc)
+                yq, cc = c_step(critic_params, zc, cc, r, a)
+            return (a, yq), (ca, cc)
 
-        xs = (obs_tm, reset_tm) + (() if eps_tm is None else (eps_tm,))
-        (a_tm, q_tm), carry = unroll(step, (ca, cc), *xs)
+        if q_min:  # members on axis 1 of the encoded rows: the scan runs over axis 0
+            zc_tm = jax.vmap(lambda p: c_encode(p, obs_tm), out_axes=1)(critic_params)
+        else:
+            zc_tm = c_encode(critic_params, obs_tm)
+        xs = (a_encode(actor_params, obs_tm), zc_tm, reset_tm)
+        xs += () if eps_tm is None else (eps_tm,)
+        (a_tm, yq_tm), carry = unroll(step, (ca, cc), *xs)
+        if q_min:
+            q_tm = jax.vmap(c_readout, in_axes=(0, 1))(critic_params, yq_tm).min(axis=0)
+        else:
+            q_tm = c_readout(critic_params, yq_tm)
         return a_tm, q_tm, carry
 
     def burn_in(self, state, batch) -> Tuple[Carry, Carry, Carry, Carry]:
@@ -115,9 +166,10 @@ class Stepped:
         and target nets each burn in from the *stored* initial state.
 
         One scan per net: online+target param ensembles concatenated on the
-        leading axis ([2] plain, [4] twin), the cell step vmapped over that
-        axis (the two matmuls per step become one batched dot on the MXU);
-        only the final carry is kept.
+        leading axis ([2] plain, [4] twin), ``encode`` vmapped over that axis
+        once over the whole prefix and the step vmapped over it inside the
+        scan (the matmuls of a step become one batched dot on the MXU); only
+        the final carry is kept.
         """
         cfg = self.config
         nq = 2 if cfg.twin_critic else 1
@@ -134,20 +186,24 @@ class Stepped:
         ca_on = ca_tg = ca0
         cc_on = cc_tg = cc0e
 
-        # ``carry_step(params, carry, *xs_t) -> carry``.
-        def fused(carry_step, p_all, c0_single, n_all, xs):
-            cN = _stack_n(c0_single, n_all)
-            v = jax.vmap(carry_step, in_axes=(0, 0) + (None,) * len(xs))
-            cN, _ = lax.scan(lambda c, inp: (v(p_all, c, *inp), ()), cN, xs)
+        def fused(parts, p_all, c0_single, n_all, *inputs_tm):
+            encode, step, _ = parts
+            z_tm = jax.vmap(lambda p: encode(p, *inputs_tm), out_axes=1)(p_all)
+            v = jax.vmap(lambda p, z, c, r: step(p, z, c, r)[1], in_axes=(0, 0, 0, None))
+            cN, _ = lax.scan(
+                lambda c, inp: (v(p_all, inp[0], c, inp[1]), ()),
+                _stack_n(c0_single, n_all),
+                (z_tm, reset_b),
+            )
             return cN
 
         if jax.tree_util.tree_leaves(ca0):
             c2 = fused(
-                lambda p, c, o, r: self.actor.apply(p, o, c, r)[1],
+                self._actor,
                 _stack2(state.actor_params, state.target_actor_params),
                 ca0,
                 2,
-                (obs_b, reset_b),
+                obs_b,
             )
             ca_on, ca_tg = _unstack2(c2)
         if jax.tree_util.tree_leaves(cc0):
@@ -159,13 +215,7 @@ class Stepped:
                 if cfg.twin_critic
                 else _stack2(state.critic_params, state.target_critic_params)
             )
-            cN = fused(
-                lambda p, c, o, a, r: self.critic.apply(p, o, a, c, r)[1],
-                p_all,
-                cc0,
-                2 * nq,
-                (obs_b, act_b, reset_b),
-            )
+            cN = fused(self._critic, p_all, cc0, 2 * nq, obs_b, act_b)
             if cfg.twin_critic:
                 cc_on = jax.tree_util.tree_map(lambda x: x[:nq], cN)
                 cc_tg = jax.tree_util.tree_map(lambda x: x[nq:], cN)

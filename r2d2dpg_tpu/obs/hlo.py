@@ -18,12 +18,20 @@ of each tile (59 of cheetah's 70 ms an update, PERF.md PR 28).  A layout is
 stated or it is not, and only the chip's compiler lays a loop out:
 ``batch_minor_writes`` reads it from the same text, and the same leg requires
 that list to be empty too.
+
+``models/sequence.py::Stepped`` takes what of a net's step does not depend on
+the carry out of its scans, so that a pixel torso's convolutions run once over
+the T·B frames of a pass and not once a step over B (5.9 of cheetah's 16.5 ms
+an update, PERF.md PR 30).  Whether a net's prefix was taken out is a fact of
+the compiled program: ``loop_convolutions`` lists the image convolutions that
+sit inside ``while`` bodies and how many loops deep, and the same leg requires
+that none lies deeper than the learner call's own loop over its updates.
 """
 
 from __future__ import annotations
 
 import re
-from typing import List, Tuple
+from typing import Dict, List, Tuple
 
 # ``  %convert.390 = bf16[524288,43,24]{0,2,1:T(8,128)(2,1)} convert(%x), ...``
 # (``ROOT`` before the name inside a fusion, no ``%`` in some printers).
@@ -60,3 +68,78 @@ def batch_minor_writes(hlo_text: str, batch: int) -> List[Tuple[str, str]]:
         and m["rest"]
         and (m["order"] or "").split(",")[0] == "0"
     ]
+
+
+# ``%wide.region_3.12 (wide.param: (s32[], ...)) -> (s32[], ...) {`` opens a
+# computation (``ENTRY`` before the program's own); a ``}`` at column 0 ends it.
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?(?P<name>[\w.\-]+)\s+\(.*\{\s*$")
+# The computations an instruction runs: ``body=%b``, ``calls=%f``,
+# ``to_apply=%r``, ``branch_computations={%a, %b}``...
+_CALLED = re.compile(
+    r"\b(?P<how>body|condition|calls|to_apply|\w+_computations?)="
+    r"(?:\{(?P<many>[^}]*)\}|%?(?P<one>[\w.\-]+))")
+_WINDOW = re.compile(
+    r"window=\{size=(?P<size>\d+(?:x\d+)*)(?:[^}]*?lhs_dilate=(?P<dilate>\d+(?:x\d+)*))?")
+
+
+def loop_convolutions(hlo_text: str) -> List[Tuple[str, str, str, int]]:
+    """``(name, shape, window size, loops around it)`` of every image
+    ``convolution`` in ``hlo_text`` that sits inside a ``while`` body, in a
+    fusion or a call made from one or directly, in the order printed.
+
+    The TPU compiler prints every matmul as a ``convolution`` too: with no
+    window, or, where it is batched (``vmap``, attention heads), with each
+    batch dimension as a window dimension dilated by its own size
+    (``size=2x1 lhs_dilate=2x1``, ``size=64x4x8 lhs_dilate=64x4x8``).  An
+    image convolution is one whose window, those dimensions left out, spans
+    more than one position in two dimensions or more (``size=8x8``;
+    ``size=4x4x2 ... lhs_dilate=1x1x2`` for one batched over stacked
+    parameters).  ``loops around it`` counts the ``while`` bodies between the
+    program's entry and the instruction, the most over the ways it is
+    reached."""
+    lines: Dict[str, List[str]] = {}
+    current = None
+    for line in hlo_text.splitlines():
+        opened = _COMPUTATION.match(line)
+        if opened:
+            current = opened["name"]
+            lines[current] = []
+        elif line.startswith("}"):
+            current = None
+        elif current is not None:
+            lines[current].append(line)
+
+    # Loops around each computation: a walk down from the computations that
+    # nothing calls (the entry; more in a text cut out of a program).
+    called = {
+        name: [
+            (callee, m["how"] in ("body", "condition"))
+            for line in body
+            for m in _CALLED.finditer(line)
+            for callee in re.findall(r"[\w.\-]+", m["many"] or m["one"])
+        ]
+        for name, body in lines.items()
+    }
+    callees = {callee for edges in called.values() for callee, _ in edges}
+    depth: Dict[str, int] = {}
+    stack = [(name, 0) for name in lines if name not in callees]
+    while stack:
+        name, d = stack.pop()
+        if name not in lines or depth.get(name, -1) >= d:
+            continue
+        depth[name] = d
+        stack.extend((callee, d + loop) for callee, loop in called[name])
+
+    found = []
+    for name, body in lines.items():
+        if depth.get(name, 0) == 0:
+            continue
+        for line in body:
+            m, w = _INSTRUCTION.match(line), _WINDOW.search(line)
+            if not (m and w and m["opcode"] == "convolution"):
+                continue
+            size = w["size"].split("x")
+            dilate = (w["dilate"] or "x".join("1" * len(size))).split("x")
+            if sum(int(n) > 1 and n != d for n, d in zip(size, dilate)) >= 2:
+                found.append((m["name"], m["shape"], w["size"], depth[name]))
+    return found

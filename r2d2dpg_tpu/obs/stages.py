@@ -14,7 +14,11 @@ path of an executed instruction is in the capture (``.xplane.pb``, an
 An instruction the compiler inserted at top level (a layout copy, the bf16
 rounding of a matmul operand hoisted out of every loop) has neither, and is
 ``unscoped``; what it inserts inside a loop the TPU compiler itself names by
-the loop (``jit(f)/while``), a path with no stage on it.
+the loop (``jit(f)/while``, a path with no stage on it), or leaves without a
+name: the reader then names it by the loop too, from the ``while`` event its
+own event lies in (the slice, the re-lay and the write of the loop a large
+gather becomes carried ``.../replay_sample/gather`` in one compiled program
+and nothing in the next, PERF.md PR 30).
 
 ``stage_table`` is the one reader: ``DeviceMonitor`` writes its table for a
 ``--profile-window`` capture, and the benchmark's ``learn_stage_ms.*`` metrics
@@ -242,7 +246,8 @@ def stage_table(
     Per event of the line ``XLA Ops`` of each device plane: its self time
     (the events inside its interval taken out, so that a ``while`` keeps
     only what its body does not account for), attributed to the stage of its
-    path; waits for the host are left out.  Returns, in seconds averaged over
+    path (an event without one takes the path of the event it lies in);
+    waits for the host are left out.  Returns, in seconds averaged over
     the device planes: one entry per key of ``table_keys(stages)``, ``busy``
     (their sum: the time the chip spent in operations, which is the union of
     their intervals less the waits for the host inside them), and for the
@@ -284,11 +289,11 @@ def stage_table(
             ops[k] = (short, path, bool(HOST_WAIT.match(short)))
 
         events = plane.events(OPS_LINE)
-        stack: List[List[int]] = []  # [end, id, duration, children]
+        stack: List[list] = []  # [end, id, duration, children, path]
 
         def close():
-            _, k, dur, kids = stack.pop()
-            short, path, host_wait = ops[k]
+            _, k, dur, kids, path = stack.pop()
+            short, _, host_wait = ops[k]
             if host_wait:
                 return
             own = max(dur - kids, 0)
@@ -302,9 +307,11 @@ def stage_table(
         for s, e, k in sorted(events, key=lambda x: (x[0], -x[1])):
             while stack and s >= stack[-1][0]:
                 close()
+            path = ops[k][1]
             if stack:
                 stack[-1][3] += e - s
-            stack.append([e, k, e - s, 0])
+                path = path or stack[-1][4]  # unnamed inside a loop: the loop's
+            stack.append([e, k, e - s, 0, path])
         while stack:
             close()
 

@@ -296,3 +296,151 @@ def test_a_stepped_core_defined_outside_the_package_needs_no_edit_to_the_learner
         moved = jax.tree_util.tree_map(
             lambda a, b: float(jnp.abs(a - b).max()), before, after)
         assert all(m > 0 for m in jax.tree_util.tree_leaves(moved))
+
+
+# ------------------------------------------------- the prefix / step seam
+# ``models/sequence.py::Stepped`` runs what of a net's step does not depend on
+# the carry once over [T, B] and scans the rest.  Every operation, against
+# ``models.unroll`` of the net's own single step (``apply``), net by net.
+
+T_SEAM, FRAME = 5, 36  # 36 x 36 is the least frame the three VALID convs take
+
+SEAM_NETS = {
+    "mlp_lstm_f32": dict(),
+    "mlp_lstm_bf16": dict(dtype=jnp.bfloat16),
+    "conv_lstm_f32": dict(pixels=True),
+    "conv_lstm_bf16": dict(pixels=True, dtype=jnp.bfloat16),
+    "mlp_dense_f32": dict(use_lstm=False),
+    "mlp_dense_bf16": dict(use_lstm=False, dtype=jnp.bfloat16),
+    "apply_alone": None,  # PR 29's Leaky nets: no ``encode`` to take out
+}
+SEAM_OPS = (
+    "unroll_actor", "unroll_critic", "unroll_pi_q", "unroll_pi_q_eps",
+    "unroll_pi_q_min", "burn_in",
+)
+
+
+def seam_case(kind):
+    """``(runner, state, batch)``: nets of ``kind`` under a ``Stepped``,
+    desynced targets, stored carries that are not zero, one reset in
+    mid-prefix and one in mid-window."""
+    from r2d2dpg_tpu.models.sequence import Stepped
+
+    kw = SEAM_NETS[kind]
+    if kw is None:
+        actor, critic = LeakyActor(action_dim=ACT, hidden=HID), LeakyCritic(hidden=HID)
+    else:
+        actor, critic = ActorNet(action_dim=ACT, hidden=HID, **kw), CriticNet(hidden=HID, **kw)
+    agent = R2D2DPG(
+        actor, critic,
+        AgentConfig(burnin=3, unroll=T_SEAM - 1, n_step=1),
+    )
+    assert isinstance(agent.seq, Stepped)
+    L = agent.config.seq_len
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    if kw and kw.get("pixels"):
+        obs = jax.random.randint(ks[0], (B, L, FRAME, FRAME, 3), 0, 256).astype(jnp.uint8)
+    else:
+        obs = jax.random.normal(ks[0], (B, L, OBS))
+    h = jax.random.normal(ks[1], (B, HID))
+    carry = lambda net, x: jax.tree_util.tree_map(  # noqa: E731
+        lambda z: x + z, net.initial_carry(B))
+    batch = SequenceBatch(
+        obs=obs,
+        action=jax.random.uniform(ks[2], (B, L, ACT), minval=-1, maxval=1),
+        reward=jnp.zeros((B, L)), discount=jnp.ones((B, L)),
+        reset=jnp.zeros((B, L)).at[1, 1].set(1.0).at[2, 5].set(1.0),
+        carries={"actor": carry(actor, h), "critic": carry(critic, -0.5 * h)},
+    )
+    state = desynced(agent.init(jax.random.PRNGKey(0), obs[:, 0], batch.action[:, 0]))
+    return agent.seq, state, batch
+
+
+def seam_run(op, seq, state, batch, hoisted):
+    """``op`` over the batch's window through ``seq`` (``hoisted``) or through
+    ``unroll`` of single steps; ``(outputs and carries, gradients)``."""
+    cfg = seq.config
+    tm = lambda x: jnp.swapaxes(x[:, cfg.burnin:], 0, 1)  # noqa: E731
+    obs, act, reset = tm(batch.obs), tm(batch.action), tm(batch.reset)
+    ca, cc = batch.carries["actor"], batch.carries["critic"]
+    eps = 0.3 * jax.random.normal(jax.random.PRNGKey(5), act.shape)
+    a_step = lambda p: lambda c, o, r: seq.actor.apply(p, o, c, r)  # noqa: E731
+    q_step = lambda p: lambda c, o, a, r: seq.critic.apply(p, o, a, c, r)  # noqa: E731
+
+    if op == "burn_in":
+        if hoisted:
+            return seq.burn_in(state, batch), ()
+        return unfused_burn_in(seq, state, batch), ()
+    if op == "unroll_actor":
+        def f(pa):
+            if hoisted:
+                out = seq.unroll_actor(pa, ca, obs, reset)
+            else:
+                out = unroll(a_step(pa), ca, obs, reset)
+            return (out[0] ** 2).sum(), out
+        (_, out), grads = jax.value_and_grad(f, has_aux=True)(state.actor_params)
+        return out, grads
+    if op == "unroll_critic":
+        def f(pc):
+            if hoisted:
+                out = seq.unroll_critic(pc, cc, obs, act, reset)
+            else:
+                out = unroll(q_step(pc), cc, obs, act, reset)
+            return (out[0] ** 2).sum(), out
+        (_, out), grads = jax.value_and_grad(f, has_aux=True)(state.critic_params)
+        return out, grads
+
+    e = eps if op == "unroll_pi_q_eps" else None
+    q_min = op == "unroll_pi_q_min"
+    pc0 = state.critic_params
+    if q_min:  # two members: the online critic and one moved off it
+        stack = lambda x, y: jax.tree_util.tree_map(  # noqa: E731
+            lambda u, v: jnp.stack([u, v]), x, y)
+        pc0 = stack(pc0, state.target_critic_params)
+        cc = stack(cc, jax.tree_util.tree_map(lambda x: 0.5 * x, cc))
+
+    def f(params):
+        pa, pc = params
+        if hoisted:
+            out = seq.unroll_pi_q(pa, pc, ca, cc, obs, reset, eps_tm=e, q_min=q_min)
+        else:
+            # Two scans, net by net: the actor, then the critic on its actions.
+            a, ca_n = unroll(a_step(pa), ca, obs, reset)
+            if e is not None:
+                a = jnp.clip(a + e, -1.0, 1.0)
+            if q_min:
+                q2, cc_n = jax.vmap(
+                    lambda p, c: unroll(q_step(p), c, obs, a, reset))(pc, cc)
+                q = q2.min(axis=0)
+            else:
+                q, cc_n = unroll(q_step(pc), cc, obs, a, reset)
+            out = (a, q, (ca_n, cc_n))
+        return -out[1].mean(), out
+    (_, out), grads = jax.value_and_grad(f, has_aux=True)((state.actor_params, pc0))
+    return out, grads
+
+
+@pytest.mark.parametrize("kind", list(SEAM_NETS))
+@pytest.mark.parametrize("op", SEAM_OPS)
+def test_hoisted_pass_equals_the_scan_of_single_steps(op, kind):
+    """Outputs, last carries and the gradients of a loss on the outputs (the
+    critic's through ``unroll_critic``, the actor's through ``unroll_pi_q``).
+    The forward pass is the same products row by row, in either dtype.  The
+    gradients sum them in another order: float32 rounding; under bfloat16 a
+    weight's gradient is rounded once over T·B rows where the scan rounded it
+    a step (the biases move most, by a few bfloat16 ulps of their largest)."""
+    seq, state, batch = seam_case(kind)
+    got, got_g = jax.jit(lambda s, b: seam_run(op, seq, s, b, True))(state, batch)
+    want, want_g = jax.jit(lambda s, b: seam_run(op, seq, s, b, False))(state, batch)
+    f32 = lambda t: jax.tree_util.tree_map(  # noqa: E731
+        lambda x: np.asarray(x, np.float32), t)
+    assert jax.tree_util.tree_structure(got) == jax.tree_util.tree_structure(want)
+    assert jax.tree_util.tree_leaves(got) or (op, kind[:9]) == ("burn_in", "mlp_dense")
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6),
+        f32(got), f32(want))
+    tol = dict(rtol=2e-2, atol=2e-3) if "bf16" in kind else dict(rtol=1e-5, atol=1e-6)
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_allclose(a, b, **tol), f32(got_g), f32(want_g))
+    if op != "burn_in":
+        assert any(np.abs(g).max() > 0 for g in jax.tree_util.tree_leaves(f32(got_g)))

@@ -1,13 +1,14 @@
-"""``obs/hlo.py``: the readers behind ``chip_smoke.py``'s two compile-time
+"""``obs/hlo.py``: the readers behind ``chip_smoke.py``'s three compile-time
 guards of the learner call (the whole-arena convert, the batch-minor write of
-the sampled batch), on HLO text as the TPU compiler prints it.  Only the
+the sampled batch, an image convolution run once a scan step), on HLO text as
+the TPU compiler prints it.  Only the
 chip's compiler makes either choice, so the CPU tests the readers alone, and
 the one thing that can be compiled here without a chip: ``ReplayArena.sample``
 for a described v5e."""
 
 import pytest
 
-from r2d2dpg_tpu.obs.hlo import arena_converts, batch_minor_writes
+from r2d2dpg_tpu.obs.hlo import arena_converts, batch_minor_writes, loop_convolutions
 
 CAPACITY = 524288
 
@@ -126,6 +127,82 @@ def test_batch_minor_writes_names_every_update_slice_into_a_batch_minor_buffer(
     """A rank-1 ``[batch]`` update (the fixture's ``dynamic-update-slice.9``)
     is no such write: its one dimension is minor-most by having no other."""
     assert batch_minor_writes(hlo, batch) == want
+
+
+# ``cheetah_pixels``' learner call compiled for a described v5e (JAX 0.9.0,
+# libtpu 0.0.34), cut to its loops and convolutions.  The call is a loop over
+# its updates (``region_0``).  In the parent of PR 30 the scans inside an
+# update (``region_3``) ran the conv torso a step, on 32 frames: directly and
+# as the root of a fusion.  The compiler prints matmuls as ``convolution`` too,
+# plain (no window) and batched (each batch dimension a window dimension
+# dilated by its own size: ``vmap``'s two members, attention's 64 x 4 heads).
+CONV_IN_SCAN = """\
+%fused_computation.9 (param_0.1: f32[32,15,15,32], param_1.1: f32[4,4,32,64]) -> f32[32,6,6,64] {
+  %param_0.1 = f32[32,15,15,32]{0,3,2,1:T(8,128)} parameter(0)
+  %param_1.1 = f32[4,4,32,64]{3,2,1,0:T(8,128)} parameter(1)
+  ROOT %conv_general_dilated.173 = f32[32,6,6,64]{0,3,2,1:T(8,128)} convolution(%param_0.1, %param_1.1), window={size=4x4 stride=2x2}, dim_labels=b01f_01io->b01f
+}
+
+%region_3.12 (param.3: (s32[], f32[32,64,64,3], f32[32,256])) -> (s32[], f32[32,64,64,3], f32[32,256]) {
+  %conv_general_dilated.166 = f32[32,15,15,32]{0,3,2,1:T(8,128)} convolution(%frames.1, %kernel.1), window={size=8x8 stride=4x4}, dim_labels=b01f_01io->b01f, metadata={op_name="jit(timed)/while/body/closed_call/forward/while/body/ConvTorso/Conv_0"}
+  %fusion.9 = f32[32,6,6,64]{0,3,2,1:T(8,128)} fusion(%conv_general_dilated.166, %kernel.2), kind=kOutput, calls=%fused_computation.9
+  %convolution.31 = f32[32,1024]{1,0:T(8,128)} convolution(%h.1, %wh.1), dim_labels=bf_io->bf
+  %convolution-base-dilated.124 = f32[2,1,32,1024]{3,2,1,0:T(8,128)} convolution(%h.2, %wh.2), window={size=2x1 lhs_dilate=2x1}, dim_labels=01bf_0io1->01bf
+  %convolution-base-dilated.438 = bf16[64,4,128,8,40]{4,2,3,1,0:T(8,128)(2,1)} convolution(%q.1, %k.1), window={size=64x4x8 stride=63x3x1 lhs_dilate=64x4x1}, dim_labels=01b2f_01i2o->01b2f
+  ROOT %tuple.3 = (s32[], f32[32,64,64,3], f32[32,256]) tuple(%add.3, %frames.1, %convolution.31)
+}
+
+%region_4.13 (param.4: (s32[], f32[32,64,64,3], f32[32,256])) -> pred[] {
+  ROOT %lt.4 = pred[] compare(%i.4, %n.4), direction=LT
+}
+
+%region_0.229 (param.0: (s32[], f32[640,64,64,3])) -> (s32[], f32[640,64,64,3]) {
+  %conv_general_dilated.350 = f32[640,15,15,32]{0,3,2,1:T(8,128)} convolution(%frames.0, %kernel.1), window={size=8x8 stride=4x4}, dim_labels=b01f_01io->b01f
+  %convolution-base-dilated.121 = f32[2,640,6,6,64]{1,4,0,3,2:T(8,128)} convolution(%fusion.1103, %fusion.1104), window={size=4x4x2 stride=2x2x1 lhs_dilate=1x1x2}, dim_labels=b012f_201io->2b01f
+  %while.3 = (s32[], f32[32,64,64,3], f32[32,256]) while(%tuple.0), condition=%region_4.13, body=%region_3.12
+  ROOT %tuple.0 = (s32[], f32[640,64,64,3]) tuple(%add.0, %frames.0)
+}
+
+%region_1.230 (param.1: (s32[], f32[640,64,64,3])) -> pred[] {
+  ROOT %lt.1 = pred[] compare(%i.1, %n.1), direction=LT
+}
+
+ENTRY %main.1 (frames: f32[640,64,64,3]) -> f32[] {
+  %conv_general_dilated.1 = f32[640,15,15,32]{0,3,2,1:T(8,128)} convolution(%frames, %kernel.0), window={size=8x8 stride=4x4}, dim_labels=b01f_01io->b01f
+  %while.1 = (s32[], f32[640,64,64,3]) while(%tuple.1), condition=%region_1.230, body=%region_0.229
+}
+"""
+
+
+@pytest.mark.parametrize(
+    "hlo, want",
+    [
+        (CONV_IN_SCAN, [
+            ("conv_general_dilated.173", "f32[32,6,6,64]", "4x4", 2),
+            ("conv_general_dilated.166", "f32[32,15,15,32]", "8x8", 2),
+            ("conv_general_dilated.350", "f32[640,15,15,32]", "8x8", 1),
+            ("convolution-base-dilated.121", "f32[2,640,6,6,64]", "4x4x2", 1),
+        ]),
+        # The same text without the call's own loop: what was one loop deep
+        # is at top level, and only the scan's convolutions are in a loop.
+        (CONV_IN_SCAN.replace("body=%region_0.229", "to_apply=%region_0.229"), [
+            ("conv_general_dilated.173", "f32[32,6,6,64]", "4x4", 1),
+            ("conv_general_dilated.166", "f32[32,15,15,32]", "8x8", 1),
+        ]),
+        (BATCH_MAJOR_GATHER, []),
+        ("", []),
+    ],
+    ids=["in_a_scan_and_in_the_call", "scan_alone", "a_loop_without_one", "empty"],
+)
+def test_loop_convolutions_names_every_image_convolution_inside_a_while_body(
+    hlo, want
+):
+    """A convolution at top level (``conv_general_dilated.1``) is no finding;
+    one in a loop body is, fused or not, with the loops around it; a matmul
+    printed as a ``convolution`` is not, plain or batched (the sdar core's
+    attention, ``size=64x4x8 lhs_dilate=64x4x1``, read as 139 image
+    convolutions before its batch dimensions were left out)."""
+    assert loop_convolutions(hlo) == want
 
 
 @pytest.fixture(scope="module")

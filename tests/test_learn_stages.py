@@ -106,10 +106,11 @@ def _msg(*fields):
 
 
 def test_reader_on_a_capture_with_a_host_wait_inside_a_loop(tmp_path):
-    """A ``while`` of 1000 ns that holds a fusion of ``forward`` (300 ns) and
-    a ``recv-done`` (500 ns): the wait counts nowhere, the loop keeps its own
-    200 ns under the path its program's Hlo Proto gives it, and a top-level
-    copy without a path is unscoped."""
+    """A ``while`` of 1000 ns that holds a fusion of ``forward`` (300 ns), a
+    ``recv-done`` (500 ns) and a copy the compiler left without a name
+    (60 ns): the wait counts nowhere, the loop keeps its own 140 ns under the
+    path its program's Hlo Proto gives it, the unnamed copy inside it is named
+    by the loop, and a top-level copy without a path is unscoped."""
     from r2d2dpg_tpu.obs.stages import stage_table
 
     TF_OP, PROGRAM, HLO = 1, 2, 3  # stat metadata ids
@@ -126,13 +127,14 @@ def test_reader_on_a_capture_with_a_host_wait_inside_a_loop(tmp_path):
         return (4, _msg((1, mid), (2, start_ns * 1000), (3, dur_ns * 1000)))
 
     line = _msg((2, b"XLA Ops"), event(1, 0, 1000), event(2, 100, 300),
-                event(3, 400, 500), event(4, 1000, 50))
+                event(3, 400, 500), event(5, 920, 60), event(4, 1000, 50))
     device = _msg((2, b"/device:TPU:0"), (3, line)) + b"".join(stat_names) + b"".join([
         op(1, b"%while.7 = (s32[]) while(%t), body=%b"),
         op(2, b"%fusion.1 = f32[8]{0} fusion(%p)", b"jit(f)/learn/forward/while/body/add:add"),
         op(3, b"%recv-done.2 = (f32[8]{0}, token[]) recv-done(%recv.2)",
            b"jit(f)/learn/forward/while/body/io_callback:"),
         op(4, b"%copy.3 = f32[8]{0} copy(%q)"),
+        op(5, b"%copy.9 = f32[8]{0} copy(%r)"),
     ])
     instr = _msg((1, b"while.7"), (2, b"while"), (7, _msg((2, b"jit(f)/learn/forward/while"))))
     hlo = _msg((1, _msg((1, b"jit_f"), (3, _msg((1, b"main"), (2, instr))))))
@@ -144,7 +146,9 @@ def test_reader_on_a_capture_with_a_host_wait_inside_a_loop(tmp_path):
 
     t = stage_table(str(path))
     assert t["devices"] == 1
-    assert t["forward"] == pytest.approx(500e-9)  # the fusion and the loop's own
-    assert t["unscoped"] == pytest.approx(50e-9) and t["unscoped_ops"][0][0] == "copy.3"
+    # The fusion, the loop's own and the copy inside the loop.
+    assert t["forward"] == pytest.approx(500e-9)
+    assert t["unscoped"] == pytest.approx(50e-9)
+    assert [name for name, _ in t["unscoped_ops"]] == ["copy.3"]
     assert t["rest"] == 0.0 and t["backward"] == 0.0
     assert t["busy"] == pytest.approx(550e-9)  # 1050 ns of intervals, 500 waiting
