@@ -253,7 +253,7 @@ def _built_trainers():
         topology.build_trainer = build_trainer
 
 
-def _require_learner_call_guards(trainer, state) -> dict:
+def _require_learner_call_guards(trainer, state, rolled_width=None) -> dict:
     """The three compile-time guards of the learner call
     (docs/OBSERVABILITY.md): ``Trainer._learn_many``, state donated, compiled
     by this chip's compiler at the run's own shapes, rounds no
@@ -262,13 +262,20 @@ def _require_learner_call_guards(trainer, state) -> dict:
     update.  Only the TPU compiler makes the first two choices, so only a chip
     run can check that ``ReplayArena.sample`` still takes both from it; the
     third says that ``models/sequence.py::Stepped`` took the pixel torso out
-    of its scans (trivially so for a configuration without one)."""
+    of its scans (trivially so for a configuration without one).
+
+    With ``rolled_width`` (the inner width of a looped stack's MLP) a fourth:
+    the products of that width lie inside the stack's two scans (over the
+    layers, inside over the loop steps), a copy a pass and not one an
+    application: a block written out sixteen times compiles sixteen times as
+    long, in every process's set-up."""
     import jax
 
     from r2d2dpg_tpu.obs.hlo import (
         arena_converts,
         batch_minor_writes,
         loop_convolutions,
+        loop_products,
     )
 
     call = jax.jit(trainer._learn_many, donate_argnums=(0, 1))
@@ -293,7 +300,20 @@ def _require_learner_call_guards(trainer, state) -> dict:
         not in_scans,
         f"the learner call runs an image convolution once a scan step: {in_scans}",
     )
+    rolled = {}
+    if rolled_width is not None:
+        products = loop_products(hlo, rolled_width)
+        outside = [p for p in products if p[2] < call_loops + 2]
+        # A pass holds the gate and up products, again for the rematerialised
+        # forward, and their gradients: eight at most; an update has nine passes.
+        _require(
+            products and not outside and len(products) <= 9 * 8,
+            f"the looped stack is not rolled: {len(products)} products of width "
+            f"{rolled_width}, outside its two scans: {outside}",
+        )
+        rolled = {"rolled_width": rolled_width, "loop_products": products}
     return {
+        **rolled,
         "learner_call_hlo_lines": hlo.count("\n"),
         "arena_capacity": trainer.arena.capacity,
         "arena_converts": converts,
@@ -345,12 +365,15 @@ def _learner_call_from_shapes(config: str, obs_shape: tuple, obs_dtype: str,
 
 
 # The configurations whose learner call the train leg compiles from shapes
-# beside ``walker_r2d2``'s own: the sequence core's (460 M parameters, a
-# 1.5 GB arena; DM-Control humanoid-run) and the pixel replay's (a rank-5
-# uint8 leaf, 552,960 bytes a sequence; DM-Control cheetah-run at 64x64x3).
+# beside ``walker_r2d2``'s own: the whole-sequence cores' (460 M and 416 M
+# parameters, a 1.5 GB arena; DM-Control humanoid-run) and the pixel replay's
+# (a rank-5 uint8 leaf, 552,960 bytes a sequence; DM-Control cheetah-run at
+# 64x64x3).  Last, where the core is a looped stack, the inner width of its
+# MLP: the products the rolled-stack guard looks for.
 _LEARNER_CALLS_FROM_SHAPES = {
-    "learner_call_sdar_moe": ("humanoid_sdar_moe", (67,), "float32", 21),
-    "learner_call_pixels": ("cheetah_pixels", (64, 64, 3), "uint8", 6),
+    "learner_call_sdar_moe": ("humanoid_sdar_moe", (67,), "float32", 21, None),
+    "learner_call_pixels": ("cheetah_pixels", (64, 64, 3), "uint8", 6, None),
+    "learner_call_ouro_loop": ("humanoid_ouro_loop", (67,), "float32", 21, 5632),
 }
 
 
@@ -360,16 +383,19 @@ def _leg_train(work: str) -> dict:
     write-back, donated state; then the learner call alone, compiled for the
     whole-arena convert guard, the batch-minor write guard and the
     convolution-in-a-scan guard, for
-    ``walker_r2d2`` and, from shapes, for the sequence core's configuration
-    ``humanoid_sdar_moe`` and the pixel replay's ``cheetah_pixels``."""
+    ``walker_r2d2`` and, from shapes, for the whole-sequence cores'
+    configurations ``humanoid_sdar_moe`` and ``humanoid_ouro_loop`` (the
+    latter also for the rolled-stack guard) and the pixel replay's
+    ``cheetah_pixels``."""
     _fresh_native_build()
     with _built_trainers() as built:
         checks = _train(work, "walker_r2d2")
     _require_native_pool()
     _require(len(built) == 1, f"{len(built)} trainers were initialised")
     checks["learner_call"] = _require_learner_call_guards(*built[0])
-    for name, args in _LEARNER_CALLS_FROM_SHAPES.items():
-        checks[name] = _require_learner_call_guards(*_learner_call_from_shapes(*args))
+    for name, (*args, rolled_width) in _LEARNER_CALLS_FROM_SHAPES.items():
+        checks[name] = _require_learner_call_guards(
+            *_learner_call_from_shapes(*args), rolled_width=rolled_width)
     return checks
 
 

@@ -14,9 +14,12 @@ constants in ``main.py``; here each BASELINE config is a named experiment
 | 4 | humanoid_r2d2     | DM-Control Humanoid-run, 256 actors, seq-len 80, soft-update|
 | 5 | cheetah_pixels    | DM-Control Cheetah-run from pixels, CNN+LSTM, 256 actors    |
 
-Beside them: ``humanoid_sdar_moe``, config 4's task with SDAR-30B-A3B-Chat's
-decoder block (sparse experts, attention over the stored sequence) as the
-core, and its CPU-sized twin ``sdar_tiny`` (``models/sdar_moe.py``).
+Beside them, config 4's task with a whole-sequence core: ``humanoid_sdar_moe``
+(SDAR-30B-A3B-Chat's decoder block: sparse experts, attention over the stored
+sequence; ``models/sdar_moe.py``) and ``humanoid_ouro_loop`` (Ouro-2.6B's
+looped stack: 4 layers run 4 times with one set of weights;
+``models/ouro_loop.py``), with their CPU-sized twins ``sdar_tiny`` and
+``ouro_tiny``.
 """
 
 from __future__ import annotations
@@ -27,6 +30,7 @@ from typing import Callable, Dict, Optional
 from r2d2dpg_tpu.agents.ddpg import AgentConfig, R2D2DPG
 from r2d2dpg_tpu.envs.core import Environment
 from r2d2dpg_tpu.models import ActorNet, CriticNet
+from r2d2dpg_tpu.models.ouro_loop import OuroLoopConfig
 from r2d2dpg_tpu.models.sdar_moe import SdarMoeConfig
 from r2d2dpg_tpu.training.trainer import Trainer, TrainerConfig
 
@@ -46,10 +50,12 @@ class ExperimentConfig:
     # Params, optimizer state, and losses stay float32 (flax mixed
     # precision); bfloat16 halves HBM traffic and doubles MXU rate.
     compute_dtype: str = "float32"
-    # The core is a stack of SDAR blocks of these sizes (then ``hidden`` is
-    # its width and ``use_lstm`` is not read); its acting ring is sized to
-    # the agent's sequences in ``build_agent``.
+    # A whole-sequence core of these sizes, at most one of the two: a stack of
+    # SDAR blocks, or Ouro's looped stack (then ``hidden`` is its width and
+    # ``use_lstm`` is not read); its acting ring is sized to the agent's
+    # sequences in ``build_agent``.
     sdar: Optional[SdarMoeConfig] = None
+    ouro: Optional[OuroLoopConfig] = None
 
     def build(self) -> Trainer:
         env = self.env_factory()
@@ -74,23 +80,24 @@ class ExperimentConfig:
         import jax.numpy as jnp
 
         dtype = jnp.dtype(self.compute_dtype)
-        sdar = self.sdar and dataclasses.replace(
-            self.sdar, ring=self.agent.seq_len - 1
-        )
+        if self.sdar is not None and self.ouro is not None:
+            raise ValueError("an experiment has one core: sdar or ouro, not both")
+        core = self.sdar or self.ouro
+        core = core and dataclasses.replace(core, ring=self.agent.seq_len - 1)
         actor = ActorNet(
             action_dim=env.spec.action_dim,
             hidden=self.hidden,
             use_lstm=self.use_lstm,
             pixels=self.pixels,
             dtype=dtype,
-            sdar=sdar,
+            sequence_core=core,
         )
         critic = CriticNet(
             hidden=self.hidden,
             use_lstm=self.use_lstm,
             pixels=self.pixels,
             dtype=dtype,
-            sdar=sdar,
+            sequence_core=core,
         )
         agent_cfg = (
             dataclasses.replace(self.agent, axis_name=axis_name)
@@ -376,6 +383,29 @@ SDAR_TINY = dataclasses.replace(
     ),
 )
 
+# Config 4's task and recipe with Ouro-2.6B's looped decoder stack as the
+# core, at every published width: 4 of its 48 layers, applied 4 times with one
+# set of weights.  416 M parameters in the two nets: one chip's stage of the
+# learner (chipbench/configs/humanoid_ouro_loop.json, PERF.md section 4).
+HUMANOID_OURO_LOOP = dataclasses.replace(
+    HUMANOID_R2D2,
+    name="humanoid_ouro_loop",
+    use_lstm=False,
+    hidden=2048,
+    ouro=OuroLoopConfig(),
+)
+
+# The same core at CPU size, for the tests and ``train --config ouro_tiny``.
+OURO_TINY = dataclasses.replace(
+    PENDULUM_TINY,
+    name="ouro_tiny",
+    use_lstm=False,
+    hidden=64,
+    ouro=OuroLoopConfig(
+        hidden=64, layers=2, heads=4, head_dim=16, mlp_width=96, loop_steps=3,
+    ),
+)
+
 CONFIGS: Dict[str, ExperimentConfig] = {
     c.name: c
     for c in (
@@ -388,6 +418,8 @@ CONFIGS: Dict[str, ExperimentConfig] = {
         PENDULUM_TINY,
         HUMANOID_SDAR_MOE,
         SDAR_TINY,
+        HUMANOID_OURO_LOOP,
+        OURO_TINY,
     )
 }
 

@@ -36,7 +36,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from r2d2dpg_tpu.models.sdar_moe import SdarMoeConfig, SdarMoeCore, initial_ring
 from r2d2dpg_tpu.models.torsos import (
     ConvTorso,
     MLPTorso,
@@ -198,37 +197,26 @@ class MixedPrecisionLSTMCell(nn.Module):
 
 
 class _Core(nn.Module):
-    """Shared core: a stack of ``sdar`` blocks when that is given, else an
-    LSTM cell when ``use_lstm``, else Dense.
-
-    The first two are stepped (``x [B, H]``, the learner scans them over
-    time).  The ``sdar`` stack is stepped only when acting; the learner
-    gives it ``x [B, T, H]`` whole (``sequence=True``: ``carry`` is then the
-    memory a prefix left, ``()`` for none, and what comes back beside ``y``
-    is that call's own memory and expert loads, ``models/sdar_moe.py``).
+    """A stepped core: an LSTM cell when ``use_lstm``, else Dense.  The
+    learner scans it over time (``models/sequence.py::Stepped``).
 
     A step is ``step(project(x), carry, reset)``.  ``project`` is what of it
     does not depend on the carry and takes any leading dimensions (the LSTM's
-    input projection; all of the Dense core, which carries nothing; nothing of
-    an ``sdar`` step), so that the learner can run it once over a whole
-    sequence and scan ``step`` alone.
-
-    The one place here that tells the cores apart: the nets ask it for their
-    carries and for how the learner runs them (``_core_of``).
+    input projection; all of the Dense core, which carries nothing), so that
+    the learner can run it once over a whole sequence and scan ``step`` alone.
     """
 
     hidden: int
     use_lstm: bool
     dtype: Any = jnp.float32
-    sdar: Optional[SdarMoeConfig] = None
+
+    whole_sequence = False
 
     def setup(self):
         # The names are the tree paths of every checkpoint and of
         # chipbench/reference.py::init_state: ``OptimizedLSTMCell_0`` and
         # ``Dense_0`` are what auto-naming called flax's stock modules here.
-        if self.sdar is not None:
-            self.block = SdarMoeCore(self.sdar, dtype=self.dtype, name="sdar")
-        elif self.use_lstm:
+        if self.use_lstm:
             self.cell = MixedPrecisionLSTMCell(
                 self.hidden, dtype=self.dtype, name="OptimizedLSTMCell_0"
             )
@@ -238,50 +226,82 @@ class _Core(nn.Module):
                 name="Dense_0",
             )
 
-    @property
-    def whole_sequence(self) -> bool:
-        """Whether the learner hands this core whole sequences
-        (``models/sequence.py::Whole``) or scans its steps (``Stepped``)."""
-        return self.sdar is not None
-
     def acting_carry(self, batch_size: int, reads_past: bool) -> Carry:
-        """The carry a net ACTS with.  The ``sdar`` core's is its ring of keys
-        and values, and none for a net whose past nothing reads while acting
-        (the critic: the replay stores no carry for this core)."""
-        if self.sdar is not None:
-            return initial_ring(self.sdar, batch_size) if reads_past else ()
+        """The carry a net ACTS with."""
         return lstm_initial_carry(batch_size, self.hidden, self.use_lstm)
 
     def stored_carry(self, carry: Carry) -> Carry:
-        """What of an acting carry a sequence is stored with: the ``sdar``
-        core's memory is recomputed from the burn-in prefix, so nothing."""
-        return () if self.whole_sequence else carry
+        """What of an acting carry a sequence is stored with: all of it."""
+        return carry
 
     def project(self, x: jnp.ndarray) -> jnp.ndarray:
-        if self.sdar is not None:
-            return x
         if self.use_lstm:
             return self.cell.project(x)
         return nn.relu(self.dense(x))
 
-    def step(self, z: jnp.ndarray, carry: Carry, reset: jnp.ndarray, **seq):
-        if self.sdar is not None:
-            if not seq.get("sequence"):
-                carry = zeros_where_reset(carry, reset)
-            return self.block(z, carry, reset, **seq)
+    def step(self, z: jnp.ndarray, carry: Carry, reset: jnp.ndarray):
         if self.use_lstm:
             carry, y = self.cell.step(zeros_where_reset(carry, reset), z)
             return y, carry
         return z, carry
 
+    def __call__(self, x: jnp.ndarray, carry: Carry, reset: jnp.ndarray):
+        return self.step(self.project(x), carry, reset)
+
+
+class _WholeCore(nn.Module):
+    """A whole-sequence core: the learner gives it ``x [B, T, H]`` whole
+    (``sequence=True``: ``carry`` is then the memory a prefix left, ``()`` for
+    none, and what comes back beside ``y`` is that call's own memory and what
+    the pass leaves to report; ``models/sequence.py::Whole``); it is stepped
+    only when acting, through a carry of its own.
+
+    Which core is the business of ``config`` (``models/sdar_moe.py``,
+    ``models/ouro_loop.py``), which answers for it: ``build(dtype)`` is the
+    module, ``acting_carry(batch_size)`` a cleared acting carry, and
+    ``pass_metrics(passes)`` what a learner update reports from what its
+    passes left.  Nothing of a step is independent of the carry.
+    """
+
+    config: Any
+    dtype: Any = jnp.float32
+
+    whole_sequence = True
+
+    def setup(self):
+        self.block = self.config.build(self.dtype)
+
+    def acting_carry(self, batch_size: int, reads_past: bool) -> Carry:
+        """The carry a net ACTS with: none for a net whose past nothing reads
+        while acting (the critic: the replay stores no carry for this core)."""
+        return self.config.acting_carry(batch_size) if reads_past else ()
+
+    def stored_carry(self, carry: Carry) -> Carry:
+        """The memory is recomputed from the burn-in prefix: nothing."""
+        return ()
+
+    def pass_metrics(self, passes) -> Any:
+        return self.config.pass_metrics(passes)
+
+    def project(self, x: jnp.ndarray) -> jnp.ndarray:
+        return x
+
+    def step(self, z: jnp.ndarray, carry: Carry, reset: jnp.ndarray, **seq):
+        if not seq.get("sequence"):
+            carry = zeros_where_reset(carry, reset)
+        return self.block(z, carry, reset, **seq)
+
     def __call__(self, x: jnp.ndarray, carry: Carry, reset: jnp.ndarray, **seq):
-        return self.step(self.project(x), carry, reset, **seq)
+        return self.step(x, carry, reset, **seq)
 
 
-def _core_of(net: nn.Module, **kwargs) -> _Core:
-    """``net``'s core from its fields.  With ``parent=None`` it is a
-    description to ask outside ``apply`` (``net.core`` exists only inside)."""
-    return _Core(net.hidden, net.use_lstm, net.dtype, net.sdar, **kwargs)
+def _core_of(net: nn.Module, **kwargs) -> nn.Module:
+    """``net``'s core from its fields: the one place that asks which kind it
+    is.  With ``parent=None`` it is a description to ask outside ``apply``
+    (``net.core`` exists only inside)."""
+    if net.sequence_core is not None:
+        return _WholeCore(net.sequence_core, net.dtype, **kwargs)
+    return _Core(net.hidden, net.use_lstm, net.dtype, **kwargs)
 
 
 def _make_torso(pixels: bool, hidden: int, dtype: Any) -> nn.Module:
@@ -299,7 +319,9 @@ class ActorNet(nn.Module):
     pixels: bool = False
     action_scale: float = 1.0
     dtype: Any = jnp.float32
-    sdar: Optional[SdarMoeConfig] = None
+    # A whole-sequence core's sizes (``_WholeCore``); then ``hidden`` is its
+    # width and ``use_lstm`` is not read.
+    sequence_core: Optional[Any] = None
 
     def setup(self):
         self.torso = _make_torso(self.pixels, self.hidden, self.dtype)
@@ -331,8 +353,8 @@ class ActorNet(nn.Module):
         return jnp.tanh(self.head(y)).astype(jnp.float32) * self.action_scale
 
     def sequence(self, obs, reset, memory=(), memory_only: bool = False):
-        """The ``sdar`` core over whole sequences: obs ``[B, T, ...]``, reset
-        ``[B, T]`` -> (actions ``[B, T, A]``, the call's memory and loads)."""
+        """A whole-sequence core over obs ``[B, T, ...]``, reset ``[B, T]`` ->
+        (actions ``[B, T, A]``, the call's memory and what the pass leaves)."""
         y, aux = self.core(
             self.torso(obs), memory, reset, sequence=True, memory_only=memory_only
         )
@@ -350,6 +372,11 @@ class ActorNet(nn.Module):
         """What of an acting carry a sequence is stored with."""
         return _core_of(self, parent=None).stored_carry(carry)
 
+    def pass_metrics(self, passes) -> Any:
+        """What a learner update reports from what its passes through a
+        whole-sequence core left (both nets' passes: the cores are one kind)."""
+        return _core_of(self, parent=None).pass_metrics(passes)
+
 
 class CriticNet(nn.Module):
     """Q(obs, action) with optional LSTM core; action concatenated after layer 1."""
@@ -358,7 +385,9 @@ class CriticNet(nn.Module):
     use_lstm: bool = True
     pixels: bool = False
     dtype: Any = jnp.float32
-    sdar: Optional[SdarMoeConfig] = None
+    # A whole-sequence core's sizes (``_WholeCore``); then ``hidden`` is its
+    # width and ``use_lstm`` is not read.
+    sequence_core: Optional[Any] = None
 
     def setup(self):
         self.torso = _make_torso(self.pixels, self.hidden, self.dtype)
@@ -413,8 +442,8 @@ class CriticNet(nn.Module):
         return jnp.squeeze(self.head(y).astype(jnp.float32), axis=-1)
 
     def sequence(self, obs, action, reset, memory=(), memory_only: bool = False):
-        """The ``sdar`` core over whole sequences -> (q ``[B, T]``, the call's
-        memory and loads)."""
+        """A whole-sequence core over whole sequences -> (q ``[B, T]``, the
+        call's memory and what the pass leaves)."""
         x = self._mixed(self.torso(obs), action)
         y, aux = self.core(x, memory, reset, sequence=True, memory_only=memory_only)
         return self.readout(y), aux

@@ -90,6 +90,19 @@ class SdarMoeConfig:
     def experts_held(self) -> int:
         return self.router_experts // self.expert_shards
 
+    def build(self, dtype) -> nn.Module:
+        return SdarMoeCore(self, dtype=dtype, name="sdar")
+
+    def acting_carry(self, batch_size: int) -> Dict[str, Any]:
+        return cleared_ring(batch_size, self.layers, self.ring, self.kv_heads,
+                            self.head_dim)
+
+    @staticmethod
+    def pass_metrics(passes: Dict[str, Any]) -> Dict[str, jnp.ndarray]:
+        """What one learner update reports from what its passes left (by pass
+        name): the routing counters."""
+        return moe_metrics({name: left["load"] for name, left in passes.items()})
+
 
 # ------------------------------------------------------------------ pieces
 def rms_norm(x, scale, eps):
@@ -123,6 +136,45 @@ def attend(q, k, v, mask):
     p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
     o = jnp.einsum("bkgts,bskd->btkgd", p, v)
     return o.reshape(B, T, Hq * D)
+
+
+def sequence_mask(reset, memory=()):
+    """Who sees whom over ``reset [B, T]`` after the steps a ``memory`` holds
+    (its ``seg [B, M]``): step t sees step s iff s <= t and no reset lies in
+    (s, t].  Returns the episode index of every step ``seg [B, T]``, counted
+    on from the memory's, and ``mask [B, T, M + T]``."""
+    T = reset.shape[1]
+    seg = jnp.cumsum(reset.astype(jnp.int32), axis=1)
+    mask = (seg[:, :, None] == seg[:, None, :]) & jnp.tril(jnp.ones((T, T), bool))
+    if memory:
+        seg = seg + memory["seg"][:, -1:]
+        mask = jnp.concatenate(
+            [seg[:, :, None] == memory["seg"][:, None, :], mask], axis=-1)
+    return seg, mask
+
+
+def cleared_ring(batch_size: int, stacks: int, size: int, kv_heads: int,
+                 head_dim: int) -> Dict[str, Any]:
+    """A cleared acting carry, a ring of the last ``size`` steps' rotated keys
+    and values for each of ``stacks`` attention layers: all zeros, which is
+    also what ``zeros_where_reset`` leaves of it."""
+    kv = (batch_size, stacks, size, kv_heads, head_dim)
+    return {
+        "k": jnp.zeros(kv, jnp.float32),
+        "v": jnp.zeros(kv, jnp.float32),
+        "valid": jnp.zeros((batch_size, size), jnp.float32),
+        "count": jnp.zeros((batch_size,), jnp.int32),
+    }
+
+
+def ring_slot(ring, size: int):
+    """Where a step lands in an acting ring of ``size`` steps (``slot [B,
+    size]``, one-hot) and what it sees: the valid steps and itself, ``mask
+    [B, 1, size + 1]``."""
+    slot = (ring["count"] % size)[:, None] == jnp.arange(size)
+    mask = jnp.concatenate(
+        [ring["valid"] > 0, jnp.ones_like(slot[:, :1])], axis=1)[:, None]
+    return slot, mask
 
 
 def router_probs(h2, w_router):
@@ -166,20 +218,8 @@ def moe(cfg: SdarMoeConfig, p: Dict[str, Any], h2) -> Tuple[Any, Any]:
     return out, sizes
 
 
-def _fan_in_normal(key, shape, dtype=jnp.float32):
+def fan_in_normal(key, shape, dtype=jnp.float32):
     return jax.random.normal(key, shape, dtype) * shape[-2] ** -0.5
-
-
-def initial_ring(cfg: SdarMoeConfig, batch_size: int) -> Dict[str, Any]:
-    """A cleared acting carry: all zeros, which is also what
-    ``zeros_where_reset`` leaves of it."""
-    kv = (batch_size, cfg.layers, cfg.ring, cfg.kv_heads, cfg.head_dim)
-    return {
-        "k": jnp.zeros(kv, jnp.float32),
-        "v": jnp.zeros(kv, jnp.float32),
-        "valid": jnp.zeros((batch_size, cfg.ring), jnp.float32),
-        "count": jnp.zeros((batch_size,), jnp.int32),
-    }
 
 
 # -------------------------------------------------------------------- core
@@ -192,7 +232,7 @@ class SdarMoeCore(nn.Module):
     def setup(self):
         c = self.cfg
         H, D, E, W = c.hidden, c.head_dim, c.experts_held, c.expert_width
-        ones, kernel = nn.initializers.ones_init(), _fan_in_normal
+        ones, kernel = nn.initializers.ones_init(), fan_in_normal
         shapes = {
             "norm1": (ones, (H,)), "norm2": (ones, (H,)),
             "q_norm": (ones, (D,)), "k_norm": (ones, (D,)),
@@ -238,16 +278,9 @@ class SdarMoeCore(nn.Module):
         the tokens each held expert received ``[L, E]``."""
         c = self.cfg
         x = x.astype(self.dtype)
-        T = x.shape[1]
         M = memory["seg"].shape[1] if memory else 0
-        seg = jnp.cumsum(reset.astype(jnp.int32), axis=1)
-        mask = (seg[:, :, None] == seg[:, None, :]) & jnp.tril(
-            jnp.ones((T, T), bool))
-        if M:
-            seg = seg + memory["seg"][:, -1:]
-            mask = jnp.concatenate(
-                [seg[:, :, None] == memory["seg"][:, None, :], mask], axis=-1)
-        pos = M + jnp.arange(T)
+        seg, mask = sequence_mask(reset, memory)
+        pos = M + jnp.arange(x.shape[1])
         ks, vs, loads = [], [], []
         for i, p in enumerate(self.blocks):
             with scope("core_attention"):
@@ -277,9 +310,7 @@ class SdarMoeCore(nn.Module):
             return y[:, 0], ring
         x = x.astype(self.dtype)[:, None]
         pos = ring["count"][:, None]
-        slot = (ring["count"] % c.ring)[:, None] == jnp.arange(c.ring)  # [B, R]
-        mask = jnp.concatenate(
-            [ring["valid"] > 0, jnp.ones_like(slot[:, :1])], axis=1)[:, None]
+        slot, mask = ring_slot(ring, c.ring)
         ks, vs = [], []
         for i, p in enumerate(self.blocks):
             with scope("core_attention"):
@@ -305,24 +336,14 @@ class SdarMoeCore(nn.Module):
         return self.step(x, carry)
 
 
-# The passes of one learner update through a net's core, in the order
-# ``moe/tokens_per_expert`` stacks them: the four burn-in prefixes (absent
-# at burn-in 0), the two target passes over the window, the critic's and the
-# actor's of the losses, the critic's on the policy's actions.
-MOE_PASSES = (
-    "burn_actor", "burn_target_actor", "burn_critic", "burn_target_critic",
-    "target_actor", "target_critic", "critic", "actor", "critic_pi",
-)
-
-
 def moe_metrics(loads: Dict[str, Any]) -> Dict[str, jnp.ndarray]:
     """The routing counters of one learner update from each pass's ``load``
-    (``[L, E]``, by pass name): every pass's table stacked in
-    ``MOE_PASSES``' order (counts: integers, which a caller that averages
-    metrics over updates leaves one an update), the pairs routed here, and
-    the fullest held expert over the mean one, averaged over the layers that
-    ran experts."""
-    counts = jnp.stack([loads[name] for name in MOE_PASSES if name in loads])
+    (``[L, E]``, by pass name): every pass's table stacked in the order the
+    learner hands them (``models/sequence.py::PASSES``; counts: integers,
+    which a caller that averages metrics over updates leaves one an update),
+    the pairs routed here, and the fullest held expert over the mean one,
+    averaged over the layers that ran experts."""
+    counts = jnp.stack(list(loads.values()))
     table = counts.astype(jnp.float32)  # [P, L, E]
     total = table.sum(axis=-1)
     ran = total > 0

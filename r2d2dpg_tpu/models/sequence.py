@@ -18,11 +18,14 @@ steps from the carry the replay stored; what a pass leaves is its last carry.
 What of a step does not depend on the carry (a net's ``encode``: the torso, the
 LSTM's input projection) it computes once over the whole time-major input
 before the scan, and the scan keeps what needs the carry (PR 30: the conv
-torso saw 32 frames a step, now 640-800 a pass).  ``Whole`` (the sdar core)
-hands the net whole sequences; its carry is the memory the prefix left and
-what a pass leaves are its expert loads.  A new stepped core, whatever its
+torso saw 32 frames a step, now 640-800 a pass).  ``Whole`` (a whole-sequence
+core: ``models/sdar_moe.py``, ``models/ouro_loop.py``) hands the net whole
+sequences; its carry is the memory the prefix left, and what a pass leaves
+and what the update reports from it are the core's own (expert loads; how
+far the last loop step moved the state).  A new stepped core, whatever its
 carry's shape, needs nothing here (``apply`` alone is scanned whole); a new
-kind is one more class with these operations.
+whole-sequence core needs nothing here either; a new kind is one more class
+with these operations.
 """
 
 from __future__ import annotations
@@ -34,7 +37,16 @@ import jax.numpy as jnp
 from jax import lax
 
 from r2d2dpg_tpu.models.actor_critic import Carry, time_major, unroll
-from r2d2dpg_tpu.models.sdar_moe import moe_metrics
+
+# The passes of one learner update through a net's core, in the order
+# ``Whole.metrics`` names them to the core: the four burn-in prefixes (absent
+# at burn-in 0), the two target passes over the window, the critic's and the
+# actor's of the losses, the critic's on the policy's actions.
+PASSES = (
+    "burn_actor", "burn_target_actor", "burn_critic", "burn_target_critic",
+    "target_actor", "target_critic", "critic", "actor", "critic_pi",
+)
+_MEMORY = ("k", "v", "seg")  # of a call's aux: what the next call attends to
 
 
 def _stack_n(tree: Any, n: int) -> Any:
@@ -233,44 +245,53 @@ class Whole:
 
     Asks the nets for ``apply(..., method="sequence")`` (``ActorNet.sequence``,
     ``CriticNet.sequence``): batch-major inputs and a memory in, the outputs
-    and the call's own memory and expert loads out.  The replay stores no
-    carry for such a core; a pass leaves its loads ``[L, E]`` behind.
+    and the call's aux out: its own memory (``k``, ``v``, ``seg``) and, under
+    the core's own names, what the pass leaves to report.  The replay stores
+    no carry for such a core; what the update reports from what its passes
+    left is the core's to say (``pass_metrics``).
     """
 
     def __init__(self, actor, critic, config):
         if config.twin_critic or config.target_policy_sigma > 0:
             raise ValueError(
-                "twin_critic and target_policy_sigma are not wired for the sdar core"
+                "twin_critic and target_policy_sigma are not wired for a "
+                "whole-sequence core"
             )
         self.actor, self.critic, self.config = actor, critic, config
+
+    @staticmethod
+    def _left(aux):
+        return {k: v for k, v in aux.items() if k not in _MEMORY}
 
     def unroll_actor(self, params, memory, obs_tm, reset_tm):
         a, aux = self.actor.apply(
             params, time_major(obs_tm), time_major(reset_tm), memory,
             method="sequence",
         )
-        return time_major(a), aux["load"]
+        return time_major(a), self._left(aux)
 
     def unroll_critic(self, params, memory, obs_tm, act_tm, reset_tm):
         q, aux = self.critic.apply(
             params, time_major(obs_tm), time_major(act_tm), time_major(reset_tm),
             memory, method="sequence",
         )
-        return time_major(q), aux["load"]
+        return time_major(q), self._left(aux)
 
     def unroll_pi_q(
         self, actor_params, critic_params, ma, mc, obs_tm, reset_tm,
         eps_tm=None, q_min=False,
     ):
         """``eps_tm`` and ``q_min`` are what ``__init__`` refuses."""
-        a_tm, load_a = self.unroll_actor(actor_params, ma, obs_tm, reset_tm)
-        q_tm, load_c = self.unroll_critic(critic_params, mc, obs_tm, a_tm, reset_tm)
-        return a_tm, q_tm, (load_a, load_c)
+        a_tm, left_a = self.unroll_actor(actor_params, ma, obs_tm, reset_tm)
+        q_tm, left_c = self.unroll_critic(critic_params, mc, obs_tm, a_tm, reset_tm)
+        return a_tm, q_tm, (left_a, left_c)
 
     def burn_in(self, state, batch) -> Tuple[Carry, Carry, Carry, Carry]:
         """R2D2's burn-in in attention's terms: the prefix's keys and values
         in every layer, recomputed with today's weights, are the memory the
-        window attends to."""
+        window attends to.  All four memories are made before the first
+        window pass reads one (``agents/ddpg.py::learner_step``'s order), so
+        the four are alive at once."""
         n = self.config.burnin
         if n == 0:
             return (), (), (), ()
@@ -290,13 +311,10 @@ class Whole:
         ))
 
     def metrics(self, burn, target, critic, pi) -> Dict[str, jnp.ndarray]:
-        """Routing counters, in ``MOE_PASSES``' order (models/sdar_moe.py)."""
-        names = ("burn_actor", "burn_target_actor", "burn_critic", "burn_target_critic")
-        return moe_metrics({
-            **{name: mem["load"] for name, mem in zip(names, burn) if mem},
-            "target_actor": target[0], "target_critic": target[1],
-            "critic": critic, "actor": pi[0], "critic_pi": pi[1],
-        })
+        """The core's own counters from what each pass left, by ``PASSES``'
+        names: a burn-in pass leaves its memory whole."""
+        left = dict(zip(PASSES, tuple(burn) + tuple(target) + (critic,) + tuple(pi)))
+        return self.actor.pass_metrics({n: v for n, v in left.items() if v})
 
 
 def sequence_runner(actor, critic, config):
@@ -305,5 +323,6 @@ def sequence_runner(actor, critic, config):
     ``Stepped``."""
     whole = [bool(getattr(net, "whole_sequence", False)) for net in (actor, critic)]
     if whole[0] != whole[1]:
-        raise ValueError("actor and critic must both have the sdar core, or neither")
+        raise ValueError(
+            "actor and critic must both have a whole-sequence core, or neither")
     return (Whole if whole[0] else Stepped)(actor, critic, config)

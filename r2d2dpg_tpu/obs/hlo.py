@@ -26,6 +26,12 @@ an update, PERF.md PR 30).  Whether a net's prefix was taken out is a fact of
 the compiled program: ``loop_convolutions`` lists the image convolutions that
 sit inside ``while`` bodies and how many loops deep, and the same leg requires
 that none lies deeper than the learner call's own loop over its updates.
+
+``models/ouro_loop.py`` runs 4 layers 4 times by a scan inside a scan, so that
+the compiled learner call holds one copy of a block a pass and not sixteen
+(its compile is part of every process's set-up).  ``loop_products`` lists the
+products of a given width with the loops around each; the same leg requires
+the looped configuration's to lie inside both scans, and to be few.
 """
 
 from __future__ import annotations
@@ -82,21 +88,10 @@ _WINDOW = re.compile(
     r"window=\{size=(?P<size>\d+(?:x\d+)*)(?:[^}]*?lhs_dilate=(?P<dilate>\d+(?:x\d+)*))?")
 
 
-def loop_convolutions(hlo_text: str) -> List[Tuple[str, str, str, int]]:
-    """``(name, shape, window size, loops around it)`` of every image
-    ``convolution`` in ``hlo_text`` that sits inside a ``while`` body, in a
-    fusion or a call made from one or directly, in the order printed.
-
-    The TPU compiler prints every matmul as a ``convolution`` too: with no
-    window, or, where it is batched (``vmap``, attention heads), with each
-    batch dimension as a window dimension dilated by its own size
-    (``size=2x1 lhs_dilate=2x1``, ``size=64x4x8 lhs_dilate=64x4x8``).  An
-    image convolution is one whose window, those dimensions left out, spans
-    more than one position in two dimensions or more (``size=8x8``;
-    ``size=4x4x2 ... lhs_dilate=1x1x2`` for one batched over stacked
-    parameters).  ``loops around it`` counts the ``while`` bodies between the
-    program's entry and the instruction, the most over the ways it is
-    reached."""
+def _computations(hlo_text: str) -> Tuple[Dict[str, List[str]], Dict[str, int]]:
+    """The lines of every computation of ``hlo_text`` by its name, and the
+    ``while`` bodies between the program's entry and each computation, the
+    most over the ways it is reached."""
     lines: Dict[str, List[str]] = {}
     current = None
     for line in hlo_text.splitlines():
@@ -129,6 +124,25 @@ def loop_convolutions(hlo_text: str) -> List[Tuple[str, str, str, int]]:
             continue
         depth[name] = d
         stack.extend((callee, d + loop) for callee, loop in called[name])
+    return lines, depth
+
+
+def loop_convolutions(hlo_text: str) -> List[Tuple[str, str, str, int]]:
+    """``(name, shape, window size, loops around it)`` of every image
+    ``convolution`` in ``hlo_text`` that sits inside a ``while`` body, in a
+    fusion or a call made from one or directly, in the order printed.
+
+    The TPU compiler prints every matmul as a ``convolution`` too: with no
+    window, or, where it is batched (``vmap``, attention heads), with each
+    batch dimension as a window dimension dilated by its own size
+    (``size=2x1 lhs_dilate=2x1``, ``size=64x4x8 lhs_dilate=64x4x8``).  An
+    image convolution is one whose window, those dimensions left out, spans
+    more than one position in two dimensions or more (``size=8x8``;
+    ``size=4x4x2 ... lhs_dilate=1x1x2`` for one batched over stacked
+    parameters).  ``loops around it`` counts the ``while`` bodies between the
+    program's entry and the instruction, the most over the ways it is
+    reached."""
+    lines, depth = _computations(hlo_text)
 
     found = []
     for name, body in lines.items():
@@ -142,4 +156,29 @@ def loop_convolutions(hlo_text: str) -> List[Tuple[str, str, str, int]]:
             dilate = (w["dilate"] or "x".join("1" * len(size))).split("x")
             if sum(int(n) > 1 and n != d for n, d in zip(size, dilate)) >= 2:
                 found.append((m["name"], m["shape"], w["size"], depth[name]))
+    return found
+
+
+def loop_products(hlo_text: str, width: int) -> List[Tuple[str, str, int]]:
+    """``(name, shape, loops around it)`` of every matrix product in
+    ``hlo_text`` (``dot``; the TPU compiler prints it as a ``convolution``)
+    whose result has ``width`` among its dimensions, fused or not, in the
+    order printed.
+
+    A stack whose layers are scanned (``models/ouro_loop.py``: a scan over
+    the layers inside a scan over the loop steps) compiles to ONE copy of a
+    block's products a pass, inside the loops; the same stack written out in
+    Python compiles to a copy for every application, at the depth of the
+    call.  ``width`` picks a block's products out of the others (its MLP's
+    inner width, which no other tensor of the program has)."""
+    lines, depth = _computations(hlo_text)
+    found = []
+    for name, body in lines.items():
+        for line in body:
+            m = _INSTRUCTION.match(line)
+            if not (m and m["opcode"] in ("convolution", "dot")):
+                continue
+            dims = [int(m["lead"])] + [int(d) for d in m["rest"].split(",") if d]
+            if width in dims:
+                found.append((m["name"], m["shape"], depth.get(name, 0)))
     return found

@@ -69,14 +69,16 @@ LEARN_STAGES = (
 )
 
 
-# Scopes INSIDE the sequence core (``models/sdar_moe.py``), under whichever
-# learner stage runs it: attention (norm, projections, RoPE, scores, output
-# projection), routing (router, top-k, gates) and the held experts'
-# products.  ``stage_table(path, LEARN_STAGES + CORE_STAGES)``
+# Scopes INSIDE a whole-sequence core (``models/sdar_moe.py``,
+# ``models/ouro_loop.py``), under whichever learner stage runs it: attention
+# (norms, projections, RoPE, scores, output projection); the sdar core's
+# routing (router, top-k, gates) and held experts' products; the looped
+# stack's dense MLP (its norms and three products).
+# ``stage_table(path, LEARN_STAGES + CORE_STAGES)``
 # folds both passes of a core scope into its stage (the innermost name wins)
 # and leaves ``forward`` / ``backward`` / ``burn_in`` what lies outside the
 # core; read with ``LEARN_STAGES`` alone the core's time stays in those.
-CORE_STAGES = ("core_attention", "moe_route", "moe_experts")
+CORE_STAGES = ("core_attention", "moe_route", "moe_experts", "core_mlp")
 
 
 def scope(name: str):

@@ -16,7 +16,7 @@ pytestmark = pytest.mark.slow
 
 
 @pytest.mark.parametrize(
-    "config", ["walker_r2d2", "humanoid_r2d2", "cheetah_pixels", "sdar_tiny"]
+    "config", ["walker_r2d2", "humanoid_r2d2", "cheetah_pixels", "sdar_tiny", "ouro_tiny"]
 )
 def test_config_cli_smoke(config, tmp_path):
     args = parse_args(

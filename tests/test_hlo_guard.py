@@ -1,6 +1,7 @@
 """``obs/hlo.py``: the readers behind ``chip_smoke.py``'s three compile-time
 guards of the learner call (the whole-arena convert, the batch-minor write of
-the sampled batch, an image convolution run once a scan step), on HLO text as
+the sampled batch, an image convolution run once a scan step) and the fourth
+(a looped stack's products inside its loops, one copy a pass), on HLO text as
 the TPU compiler prints it.  Only the
 chip's compiler makes either choice, so the CPU tests the readers alone, and
 the one thing that can be compiled here without a chip: ``ReplayArena.sample``
@@ -8,7 +9,12 @@ for a described v5e."""
 
 import pytest
 
-from r2d2dpg_tpu.obs.hlo import arena_converts, batch_minor_writes, loop_convolutions
+from r2d2dpg_tpu.obs.hlo import (
+    arena_converts,
+    batch_minor_writes,
+    loop_convolutions,
+    loop_products,
+)
 
 CAPACITY = 524288
 
@@ -203,6 +209,86 @@ def test_loop_convolutions_names_every_image_convolution_inside_a_while_body(
     attention, ``size=64x4x8 lhs_dilate=64x4x1``, read as 139 image
     convolutions before its batch dimensions were left out)."""
     assert loop_convolutions(hlo) == want
+
+
+# The looped stack's learner call as the TPU compiler prints it: the call's
+# own loop over its updates, in it a pass's scan over the loop steps, in that
+# the scan over the layers, whose body holds ONE copy of the block's products
+# (the MLP's gate and up at the inner width 5632, one of them in a fusion;
+# the down projection's result has no such dimension); and the same products
+# written out, an application each, at the depth of the call.
+ROLLED_STACK = """\
+%fused_computation.4 (param_0.7: f32[64,40,2048], param_1.7: f32[2048,5632]) -> f32[64,40,5632] {
+  ROOT %convolution.597 = f32[64,40,5632]{2,1,0:T(8,128)} convolution(%param_0.7, %param_1.7), dim_labels=0bf_io0->0bf
+}
+
+%layers_body.3 (param.3: (s32[], f32[64,40,2048])) -> (s32[], f32[64,40,2048]) {
+  %convolution.596 = f32[64,40,5632]{2,1,0:T(8,128)} convolution(%h.3, %w_gate.3), dim_labels=0bf_io0->0bf, metadata={op_name="jit(timed)/while/body/closed_call/forward/while/body/closed_call/while/body/closed_call/checkpoint/core_mlp/dot_general"}
+  %fusion.4 = f32[64,40,5632]{2,1,0:T(8,128)} fusion(%h.3, %w_up.3), kind=kOutput, calls=%fused_computation.4
+  %convolution.598 = f32[64,40,2048]{2,1,0:T(8,128)} convolution(%m.3, %w_down.3), dim_labels=0bf_io0->0bf
+  %convolution.601 = f32[2048,5632]{1,0:T(8,128)} convolution(%h.3, %g.3), dim_labels=0bf_0io->bf
+  ROOT %tuple.3 = (s32[], f32[64,40,2048]) tuple(%add.3, %convolution.598)
+}
+
+%layers_cond.3 (param.4: (s32[], f32[64,40,2048])) -> pred[] {
+  ROOT %lt.4 = pred[] compare(%i.4, %n.4), direction=LT
+}
+
+%steps_body.2 (param.2: (s32[], f32[64,40,2048])) -> (s32[], f32[64,40,2048]) {
+  %while.3 = (s32[], f32[64,40,2048]) while(%tuple.2), condition=%layers_cond.3, body=%layers_body.3
+  ROOT %tuple.2b = (s32[], f32[64,40,2048]) tuple(%add.2, %norm.2)
+}
+
+%steps_cond.2 (param.5: (s32[], f32[64,40,2048])) -> pred[] {
+  ROOT %lt.5 = pred[] compare(%i.5, %n.5), direction=LT
+}
+
+%updates_body.1 (param.1: (s32[], f32[64,40,2048])) -> (s32[], f32[64,40,2048]) {
+  %while.2 = (s32[], f32[64,40,2048]) while(%tuple.1), condition=%steps_cond.2, body=%steps_body.2
+  %dot.9 = f32[64,40,5632]{2,1,0} dot(%x.1, %w.1), lhs_contracting_dims={2}, rhs_contracting_dims={0}
+  ROOT %tuple.1b = (s32[], f32[64,40,2048]) tuple(%add.1, %x.1)
+}
+
+%updates_cond.1 (param.6: (s32[], f32[64,40,2048])) -> pred[] {
+  ROOT %lt.6 = pred[] compare(%i.6, %n.6), direction=LT
+}
+
+ENTRY %main.1 (x: f32[64,40,2048]) -> f32[] {
+  %while.1 = (s32[], f32[64,40,2048]) while(%tuple.0), condition=%updates_cond.1, body=%updates_body.1
+}
+"""
+
+
+@pytest.mark.parametrize(
+    "hlo, width, want",
+    [
+        (ROLLED_STACK, 5632, [
+            ("convolution.597", "f32[64,40,5632]", 3),
+            ("convolution.596", "f32[64,40,5632]", 3),
+            ("convolution.601", "f32[2048,5632]", 3),
+            ("dot.9", "f32[64,40,5632]", 1),
+        ]),
+        # The layers' scan written out in Python: its products sit in the
+        # loop over the loop steps, one loop up.
+        (ROLLED_STACK.replace("body=%layers_body.3", "to_apply=%layers_body.3"), 5632, [
+            ("convolution.597", "f32[64,40,5632]", 2),
+            ("convolution.596", "f32[64,40,5632]", 2),
+            ("convolution.601", "f32[2048,5632]", 2),
+            ("dot.9", "f32[64,40,5632]", 1),
+        ]),
+        (ROLLED_STACK, 768, []),
+        ("", 5632, []),
+    ],
+    ids=["rolled", "layers_written_out", "another_width", "empty"],
+)
+def test_loop_products_names_every_product_of_a_width_with_the_loops_around_it(
+    hlo, width, want
+):
+    """A product counts by a dimension of its RESULT (the down projection
+    ``convolution.598`` gives the hidden width back and is none), fused or
+    not, ``dot`` or the TPU printer's ``convolution``, and carries the
+    ``while`` bodies between the entry and itself."""
+    assert loop_products(hlo, width) == want
 
 
 @pytest.fixture(scope="module")

@@ -21,7 +21,7 @@ if REPO not in sys.path:
 from chipbench import compare, harness, reference_sdar_moe as ref_moe  # noqa: E402
 from r2d2dpg_tpu.configs import SDAR_TINY  # noqa: E402
 from r2d2dpg_tpu.models import policy_step_fn, sdar_moe  # noqa: E402
-from r2d2dpg_tpu.models.sequence import Stepped, Whole  # noqa: E402
+from r2d2dpg_tpu.models.sequence import PASSES, Stepped, Whole  # noqa: E402
 from r2d2dpg_tpu.obs.stages import stage_of, table_keys  # noqa: E402
 from r2d2dpg_tpu.replay.arena import SequenceBatch  # noqa: E402
 from r2d2dpg_tpu.utils.metrics import host_scalars  # noqa: E402
@@ -114,7 +114,7 @@ def test_update_weights_and_targets_match_the_reference(one_update, weights):
 
 def test_routing_counters_are_the_references_own_routing(one_update):
     (_, _, metrics), (_, _, losses) = one_update
-    assert sdar_moe.MOE_PASSES == ref_moe.PASSES
+    assert PASSES == ref_moe.PASSES
     table = np.asarray(metrics["moe/tokens_per_expert"])
     np.testing.assert_array_equal(table, np.asarray(losses["loads"]))
     assert float(metrics["moe/pairs_here"]) == table.sum()
@@ -254,7 +254,8 @@ def test_core_scopes_reach_the_learner_calls_hlo_and_fold_both_passes():
                                         ).compile().as_text()
     paths = set(re.findall(r'op_name="([^"]*)"', text))
     both = LEARN_STAGES + CORE_STAGES
-    assert set(CORE_STAGES) <= {stage_of(p, both) for p in paths}
+    own = set(CORE_STAGES) - {"core_mlp"}  # the looped stack's dense MLP: not this core's
+    assert own <= {stage_of(p, both) for p in paths}
     experts = [p for p in paths if stage_of(p, both) == "moe_experts"]
     assert any("transpose(" in p for p in experts) and any("transpose(" not in p for p in experts)
     assert any("/burn_in/" in p for p in experts)
@@ -271,7 +272,7 @@ def test_learner_call_keeps_one_routing_table_for_each_update():
     _, _, metrics = jax.jit(t._learn_many)(s.train, s.arena, jax.random.PRNGKey(0))
     table = np.asarray(metrics["moe/tokens_per_expert"])
     c = SDAR_TINY.sdar
-    assert table.shape == (t.config.learner_steps, len(sdar_moe.MOE_PASSES),
+    assert table.shape == (t.config.learner_steps, len(PASSES),
                            c.layers, c.experts_held)
     assert np.issubdtype(table.dtype, np.integer)  # counts: never averaged
     # The floats are means over the call's updates, the counts behind them not.
@@ -311,5 +312,5 @@ def test_the_whole_sequence_kind_refuses_the_td3_knobs_at_construction(agent, kn
     assert isinstance(agent.seq, Whole)
     exp = dataclasses.replace(
         SDAR_TINY, agent=dataclasses.replace(SDAR_TINY.agent, **knob))
-    with pytest.raises(ValueError, match="not wired for the sdar core"):
+    with pytest.raises(ValueError, match="not wired for a whole-sequence core"):
         exp.build_agent(SDAR_TINY.env_factory())
