@@ -254,17 +254,19 @@ def _built_trainers():
 
 
 def _require_learner_call_guards(trainer, state, rolled_width=None) -> dict:
-    """The three compile-time guards of the learner call
+    """The four compile-time guards of the learner call
     (docs/OBSERVABILITY.md): ``Trainer._learn_many``, state donated, compiled
     by this chip's compiler at the run's own shapes, rounds no
     ``[capacity, ...]`` value, inserts no sequence into a batch-minor
-    ``[batch, ...]`` buffer, and runs no image convolution inside a scan of an
-    update.  Only the TPU compiler makes the first two choices, so only a chip
-    run can check that ``ReplayArena.sample`` still takes both from it; the
-    third says that ``models/sequence.py::Stepped`` took the pixel torso out
-    of its scans (trivially so for a configuration without one).
+    ``[batch, ...]`` buffer, keeps no running sum as long as the arena, and
+    runs no image convolution inside a scan of an update.  Only the TPU
+    compiler makes the first two choices and gives the third its cost (128
+    adds an element), so only a chip run can check that ``ReplayArena.sample``
+    still takes all three from it; the fourth says that
+    ``models/sequence.py::Stepped`` took the pixel torso out of its scans
+    (trivially so for a configuration without one).
 
-    With ``rolled_width`` (the inner width of a looped stack's MLP) a fourth:
+    With ``rolled_width`` (the inner width of a looped stack's MLP) a fifth:
     the products of that width lie inside the stack's two scans (over the
     layers, inside over the loop steps), a copy a pass and not one an
     application: a block written out sixteen times compiles sixteen times as
@@ -274,6 +276,7 @@ def _require_learner_call_guards(trainer, state, rolled_width=None) -> dict:
     from r2d2dpg_tpu.obs.hlo import (
         arena_converts,
         batch_minor_writes,
+        capacity_scans,
         loop_convolutions,
         loop_products,
     )
@@ -289,6 +292,11 @@ def _require_learner_call_guards(trainer, state, rolled_width=None) -> dict:
     _require(
         not writes,
         f"the learner call writes its sampled batch batch-minor: {writes}",
+    )
+    scans = capacity_scans(hlo, trainer.arena.capacity)
+    _require(
+        not scans,
+        f"the learner call keeps a running sum as long as the arena: {scans}",
     )
     # The call is itself a loop over its updates (``_learn_many``'s scan,
     # which the compiler keeps where there are two or more), so an update's
@@ -319,6 +327,7 @@ def _require_learner_call_guards(trainer, state, rolled_width=None) -> dict:
         "arena_converts": converts,
         "batch_size": trainer.config.batch_size,
         "batch_minor_writes": writes,
+        "capacity_scans": scans,
         "loop_convolutions": convolutions,
         "convolutions_in_scans": in_scans,
     }
@@ -381,8 +390,8 @@ def _leg_train(work: str) -> dict:
     """Base ``Trainer``: host MuJoCo pool through ordered ``io_callback``
     inside the jitted phase, the HBM arena at capacity 100,000, the Pallas
     write-back, donated state; then the learner call alone, compiled for the
-    whole-arena convert guard, the batch-minor write guard and the
-    convolution-in-a-scan guard, for
+    whole-arena convert guard, the batch-minor write guard, the
+    capacity-long running sum guard and the convolution-in-a-scan guard, for
     ``walker_r2d2`` and, from shapes, for the whole-sequence cores'
     configurations ``humanoid_sdar_moe`` and ``humanoid_ouro_loop`` (the
     latter also for the rolled-stack guard) and the pixel replay's

@@ -25,9 +25,9 @@ One process-wide namespace for every subsystem's operator signals:
   call (``obs/stages.py``).
 - ``hlo``       — what a compiled program does to the whole replay and to
   the batch drawn from it, and where its image convolutions run, read
-  from its HLO text: ``arena_converts``, ``batch_minor_writes`` and
-  ``loop_convolutions``, behind ``chip_smoke.py``'s three guards of the
-  learner call.
+  from its HLO text: ``arena_converts``, ``batch_minor_writes``,
+  ``capacity_scans`` and ``loop_convolutions``, behind ``chip_smoke.py``'s
+  four guards of the learner call.
 - ``quality``   — the experience-quality plane (ISSUE 18): sequence
   provenance (behavior param version + collect phase) stamped at the
   actor and carried through wire/arena/shard slots, folded at batch

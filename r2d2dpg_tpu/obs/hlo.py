@@ -19,6 +19,14 @@ stated or it is not, and only the chip's compiler lays a loop out:
 ``batch_minor_writes`` reads it from the same text, and the same leg requires
 that list to be empty too.
 
+``sample`` draws its B slots from the priority vector in two levels: one
+pass for the sums of blocks of slots, a CDF over those, a running sum inside
+the B drawn blocks alone (``replay/arena.py::_draw_proportional``).  A running
+sum over the vector itself the TPU compiler turns into a ``reduce-window`` of
+128 adds an element: 13 % of walker's update at 524,288 slots, under no
+scope's name (PERF.md PR 32).  ``capacity_scans`` lists the running sums as
+long as the arena, and the same leg requires that there is none.
+
 ``models/sequence.py::Stepped`` takes what of a net's step does not depend on
 the carry out of its scans, so that a pixel torso's convolutions run once over
 the T·B frames of a pass and not once a step over B (5.9 of cheetah's 16.5 ms
@@ -36,6 +44,7 @@ the looped configuration's to lie inside both scans, and to be few.
 
 from __future__ import annotations
 
+import math
 import re
 from typing import Dict, List, Tuple
 
@@ -47,6 +56,11 @@ _INSTRUCTION = re.compile(
     r"(?:\{(?P<order>[\d,]*)[^}\s]*\})?\S*\s+(?P<opcode>[\w\-]+)\(",
     re.MULTILINE,
 )
+
+
+def _dims(m: "re.Match[str]") -> List[int]:
+    """The dimensions of a matched instruction's result."""
+    return [int(m["lead"])] + [int(d) for d in m["rest"].split(",") if d]
 
 
 def arena_converts(hlo_text: str, capacity: int) -> List[Tuple[str, str]]:
@@ -127,6 +141,46 @@ def _computations(hlo_text: str) -> Tuple[Dict[str, List[str]], Dict[str, int]]:
     return lines, depth
 
 
+# ``%while.3 = (s32[], f32[524288]) while(%tuple.2), condition=%cond.3, body=%body.3``
+# and, in its condition, the constant the counter is held to:
+# ``%constant.2 = s32[]{:T(128)} constant(524288)``.
+_LOOP = re.compile(
+    r"^\s*(?:ROOT\s+)?%?(?P<name>[\w.\-]+)\s*=.*\swhile\(.*"
+    r"\bcondition=%?(?P<condition>[\w.\-]+)")
+_BOUND = re.compile(r"=\s*[su]\d+\[\]\S*\s+constant\((?P<bound>\d+)\)")
+
+
+def capacity_scans(hlo_text: str, capacity: int) -> List[Tuple[str, str]]:
+    """``(name, what)`` of every running sum in ``hlo_text`` that is as long
+    as the arena, in the order printed:
+
+    - a ``reduce-window`` over more than one position whose result holds
+      ``capacity`` elements or more, fused or not (a running sum's result has
+      its operand's shape; ``what`` is the shape and the window,
+      ``f32[4096,128] window 1x128``: the TPU compiler's ``cumsum`` of a
+      ``[524288]`` vector, 128 adds an element);
+    - a ``while`` whose condition holds its counter to a constant of
+      ``capacity`` or more (``what`` is ``loop of 524288 steps``): a sum
+      carried through a loop a slot at a time."""
+    lines, _ = _computations(hlo_text)
+    found = []
+    for line in hlo_text.splitlines():
+        m, loop = _INSTRUCTION.match(line), _LOOP.match(line)
+        if m and m["opcode"] == "reduce-window" and math.prod(_dims(m)) >= capacity:
+            w = _WINDOW.search(line)
+            if w and any(int(n) > 1 for n in w["size"].split("x")):
+                found.append((m["name"], f"{m['shape']} window {w['size']}"))
+        if loop:
+            bounds = [
+                int(b["bound"])
+                for held in lines.get(loop["condition"], [])
+                for b in _BOUND.finditer(held)
+            ]
+            if bounds and max(bounds) >= capacity:
+                found.append((loop["name"], f"loop of {max(bounds)} steps"))
+    return found
+
+
 def loop_convolutions(hlo_text: str) -> List[Tuple[str, str, str, int]]:
     """``(name, shape, window size, loops around it)`` of every image
     ``convolution`` in ``hlo_text`` that sits inside a ``while`` body, in a
@@ -178,7 +232,6 @@ def loop_products(hlo_text: str, width: int) -> List[Tuple[str, str, int]]:
             m = _INSTRUCTION.match(line)
             if not (m and m["opcode"] in ("convolution", "dot")):
                 continue
-            dims = [int(m["lead"])] + [int(d) for d in m["rest"].split(",") if d]
-            if width in dims:
+            if width in _dims(m):
                 found.append((m["name"], m["shape"], depth.get(name, 0)))
     return found

@@ -12,10 +12,11 @@ pure functions that live *inside* the outer jitted training program, so no
 host round-trip ever touches the replay path:
 
 - ``add``: batched scatter of B sequences at the ring cursor.
-- ``sample``: proportional sampling by inverse-CDF over a ``cumsum`` of
-  ``p^alpha`` (O(C) on the VPU, no sum-tree needed — XLA fuses the power,
-  cumsum and searchsorted into a handful of HBM passes) or uniform over the
-  valid prefix.
+- ``sample``: proportional sampling by inverse CDF of ``p^alpha`` in two
+  levels (``_draw_proportional``: one pass over the vector for the sums of
+  blocks of 128 slots, a CDF over those, a running sum inside the B drawn
+  blocks alone; no state beside ``priority``, no sum-tree) or uniform over
+  the valid prefix.
 - ``update_priorities``: scatter write-back (Pallas kernel on TPU — see
   ``ops/pallas/scatter.py`` — with an XLA ``.at[].set`` fallback).
 
@@ -267,6 +268,57 @@ def _gather_rows(buf: jnp.ndarray, indices: jnp.ndarray) -> jnp.ndarray:
     )
 
 
+# Slots to a block of the two-level draw: the lane width, so that the vector
+# viewed as ``[blocks, 128]`` keeps its order in memory.
+_DRAW_BLOCK = 128
+
+
+def _first_above(cdf: jnp.ndarray, u: jnp.ndarray) -> jnp.ndarray:
+    """For each ``u`` (not below nought), the first entry of ``cdf`` (from
+    nought up, non-decreasing along its last axis) above it: ``searchsorted(cdf, u, side="right")``, so an entry
+    that adds no mass is never the answer.  Held to the entry at which
+    ``cdf`` reaches its last value: a ``u`` that rounding put at or past the
+    total mass gets the last entry WITH mass, not whatever lies at the end."""
+    return jnp.minimum(
+        (cdf <= u[..., None]).sum(axis=-1, dtype=jnp.int32),
+        (cdf < cdf[..., -1:]).sum(axis=-1, dtype=jnp.int32),
+    )
+
+
+def _draw_proportional(
+    scaled: jnp.ndarray, key: jax.Array, batch_size: int
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``batch_size`` slots drawn in proportion to ``scaled`` (``[capacity]``,
+    non-negative), and the total mass they were drawn against.
+
+    The inverse CDF in two levels, because B draws need B entries of the CDF
+    and not ``capacity``: a running sum over the whole vector compiles on the
+    TPU to a ``reduce-window`` of 128 adds an element (13 % of walker's
+    update at 524,288 slots, PERF.md PR 32).  The block of a draw comes from
+    the CDF over the sums of blocks of ``_DRAW_BLOCK`` slots, the slot from
+    the running sum inside that block alone.  The same distribution and, for
+    the same uniforms, the slot a flat CDF gives up to the rounding of a
+    float32 partial sum; the number of blocks follows from the shape."""
+    capacity = scaled.shape[0]
+    blocks = -(-capacity // _DRAW_BLOCK)
+    rows = jnp.pad(scaled, (0, blocks * _DRAW_BLOCK - capacity)).reshape(
+        blocks, _DRAW_BLOCK
+    )
+    block_sum = rows.sum(axis=1)
+    block_cdf = jnp.cumsum(block_sum)
+    total = block_cdf[-1]
+    u = jax.random.uniform(key, (batch_size,)) * total
+    block = _first_above(block_cdf, u)
+    # The mass before the block, then the running sum inside it; ``u`` is
+    # held to the block's own range where the two sums round differently.
+    before = block_cdf[block] - block_sum[block]
+    lane = _first_above(
+        jnp.cumsum(rows[block], axis=1), jnp.maximum(u - before, 0.0)
+    )
+    indices = jnp.clip(block * _DRAW_BLOCK + lane, 0, capacity - 1)
+    return indices, total
+
+
 class ReplayArena:
     """Static replay configuration + pure state-transition functions.
 
@@ -501,12 +553,7 @@ class ReplayArena:
             scaled = jnp.where(
                 state.priority > 0.0, state.priority**self.alpha, 0.0
             )
-            total = scaled.sum()
-            cdf = jnp.cumsum(scaled)
-            u = jax.random.uniform(key, (batch_size,)) * total
-            indices = jnp.clip(
-                jnp.searchsorted(cdf, u, side="right"), 0, self.capacity - 1
-            )
+            indices, total = _draw_proportional(scaled, key, batch_size)
             probs = scaled[indices] / jnp.maximum(total, 1e-12)
         else:
             indices = jax.random.randint(

@@ -1,8 +1,8 @@
-"""``obs/hlo.py``: the readers behind ``chip_smoke.py``'s three compile-time
+"""``obs/hlo.py``: the readers behind ``chip_smoke.py``'s four compile-time
 guards of the learner call (the whole-arena convert, the batch-minor write of
-the sampled batch, an image convolution run once a scan step) and the fourth
-(a looped stack's products inside its loops, one copy a pass), on HLO text as
-the TPU compiler prints it.  Only the
+the sampled batch, a running sum as long as the arena, an image convolution
+run once a scan step) and the fifth (a looped stack's products inside its
+loops, one copy a pass), on HLO text as the TPU compiler prints it.  Only the
 chip's compiler makes either choice, so the CPU tests the readers alone, and
 the one thing that can be compiled here without a chip: ``ReplayArena.sample``
 for a described v5e."""
@@ -12,6 +12,7 @@ import pytest
 from r2d2dpg_tpu.obs.hlo import (
     arena_converts,
     batch_minor_writes,
+    capacity_scans,
     loop_convolutions,
     loop_products,
 )
@@ -68,6 +69,88 @@ def test_arena_converts_names_every_convert_with_the_capacity_leading(
     hlo, capacity, want
 ):
     assert arena_converts(hlo, capacity) == want
+
+
+# ``sample``'s draw at 524,288 slots compiled for a described v5e (JAX 0.9.0,
+# libtpu 0.0.34), cut to its running sums.  The parent of PR 32 summed the
+# whole vector (the ledger's ``reduce-window.38``, then the sums of its 4,096
+# rows and of their 32 rows); the draw in two levels keeps the two small ones
+# and sums inside the 64 drawn blocks; and the same sum carried through a loop
+# a slot at a time (``lax.scan``), whose length is its condition's constant.
+FLAT_CDF = """\
+%fused_computation.3 (param_0.9: f32[4096,128]) -> f32[4096,128] {
+  ROOT %reduce-window.7 = f32[4096,128]{0,1:T(8,128)} reduce-window(%param_0.9, %constant.3), window={size=1x128 pad=0_0x127_0}, to_apply=%region_2.5
+}
+
+ENTRY %main.2 (arena_priority.1: f32[524288]) -> s32[64] {
+  %copy.43 = f32[4096,128]{0,1:T(8,128)S(1)} copy(%bitcast.1311)
+  %reduce-window.38 = f32[4096,128]{0,1:T(8,128)S(1)} reduce-window(%copy.43, %constant.446), window={size=1x128 pad=0_0x127_0}, to_apply=%region_2.5, metadata={op_name="jit(timed)/while/body/closed_call/replay_sample/jit(cumsum)/reduce_window_sum"}
+  %reduce-window.39 = f32[32,128]{1,0:T(8,128)S(1)} reduce-window(%bitcast.1312, %constant.446), window={size=1x128 pad=0_0x127_0}, to_apply=%region_2.5.clone
+  %reduce-window.40 = f32[33,1]{1,0:T(8,128)S(1)} reduce-window(%slice.689, %constant.446), window={size=32x1 pad=32_0x0_0}, to_apply=%region_2.5.clone.1
+  %reduce-window.50 = f32[524288]{0:T(1024)} reduce-window(%pow.140, %constant.446), window={size=1}, to_apply=%region_2.5
+}
+"""
+
+TWO_LEVEL_CDF = """\
+ENTRY %main.2 (arena_priority.1: f32[524288]) -> s32[64] {
+  %reduce.14 = f32[4096]{0:T(1024)S(1)} reduce(%bitcast.22, %constant.115), dimensions={1}, to_apply=%region_0.3
+  %reduce-window.39 = f32[32,128]{1,0:T(8,128)S(1)} reduce-window(%bitcast.1290, %constant.434), window={size=1x128 pad=0_0x127_0}, to_apply=%region_2.6
+  %reduce-window.40 = f32[33,1]{1,0:T(8,128)S(1)} reduce-window(%slice.693, %constant.434), window={size=32x1 pad=32_0x0_0}, to_apply=%region_2.6.clone
+  %reduce-window.41 = f32[64,128]{1,0:T(8,128)S(1)} reduce-window(%fusion.722, %constant.434), window={size=1x128 pad=0_0x127_0}, to_apply=%region_5.13
+}
+"""
+
+CARRIED_SUM = """\
+%wide.region_0.2.sunk (wide.arg_tuple.0: (s32[], f32[], f32[524288], f32[524288])) -> (s32[], f32[], f32[524288], f32[524288]) {
+  %add.8 = f32[]{:T(128)} add(%get-tuple-element.47, %bitcast.4)
+  %dynamic_update_slice.2 = f32[524288]{0:T(1024)} dynamic-update-slice(%get-tuple-element.48, %bitcast.5, %get-tuple-element.46)
+  ROOT %tuple.13 = (s32[]{:T(128)}, f32[]{:T(128)}, f32[524288]{0:T(1024)}, f32[524288]{0:T(1024)}) tuple(%add.7, %add.8, %dynamic_update_slice.2, %get-tuple-element.54)
+}
+
+%wide.region_1.3 (wide.arg_tuple.3: (s32[], f32[], f32[524288], f32[524288])) -> pred[] {
+  %constant.2 = s32[]{:T(128)} constant(524288)
+  ROOT %lt.0 = pred[]{:T(512)} compare(%get-tuple-element.20, %constant.2), direction=LT
+}
+
+%scan_cond.4 (param.4: (s32[], f32[64,256])) -> pred[] {
+  %constant.4 = s32[]{:T(128)} constant(43)
+  ROOT %lt.4 = pred[]{:T(512)} compare(%i.4, %constant.4), direction=LT
+}
+
+ENTRY %main.4 (x.1: f32[524288]) -> f32[524288] {
+  %while = (s32[]{:T(128)}, f32[]{:T(128)}, f32[524288]{0:T(1024)}, f32[524288]{0:T(1024)}) while(%tuple.11), condition=%wide.region_1.3, body=%wide.region_0.2.sunk
+  %while.7 = (s32[]{:T(128)}, f32[64,256]{1,0:T(8,128)}) while(%tuple.12), condition=%scan_cond.4, body=%scan_body.4
+}
+"""
+
+
+@pytest.mark.parametrize(
+    "hlo, capacity, want",
+    [
+        (FLAT_CDF, CAPACITY, [
+            ("reduce-window.7", "f32[4096,128] window 1x128"),
+            ("reduce-window.38", "f32[4096,128] window 1x128"),
+        ]),
+        (TWO_LEVEL_CDF, CAPACITY, []),
+        (TWO_LEVEL_CDF, 8192, [("reduce-window.41", "f32[64,128] window 1x128")]),
+        (CARRIED_SUM, CAPACITY, [("while", "loop of 524288 steps")]),
+        (CARRIED_SUM, CAPACITY + 1, []),
+        (PINNED, CAPACITY, [("reduce-window.35", "f32[524288] window 524288")]),
+        ("", CAPACITY, []),
+    ],
+    ids=["flat", "two_level", "a_batch_as_long_as_the_arena", "carried",
+         "a_longer_arena", "one_window", "empty"],
+)
+def test_capacity_scans_names_every_running_sum_as_long_as_the_arena(
+    hlo, capacity, want
+):
+    """A ``reduce-window`` counts by its RESULT's elements, fused or not, and
+    only over more than one position (``reduce-window.50``'s window of 1 is a
+    copy); a ``while`` by the constant its condition holds the counter to (a
+    scan of 43 steps is no such loop).  Sums over the sums of blocks
+    (``[32,128]``, ``[33,1]``) and inside the drawn blocks (``[64,128]``) are
+    short of the arena unless the arena is as short as they."""
+    assert capacity_scans(hlo, capacity) == want
 
 
 # The pixel gather alone at ``cheetah_pixels``'s shapes, compiled for a
@@ -325,6 +408,49 @@ def no_compile_cache():
     cc.reset_cache()
 
 
+def _sample_shapes(capacity, frame, frame_dtype, length, one_chip):
+    """``(arena, state, key)``: an arena of ``capacity`` sequences of
+    ``length`` steps with LSTM carries, its state and a key as shapes on
+    ``one_chip``."""
+    import jax
+    import jax.numpy as jnp
+
+    from r2d2dpg_tpu.replay.arena import ReplayArena, SequenceBatch
+
+    def z(*shape, dtype=jnp.float32):
+        return jnp.zeros((1,) + shape, dtype)
+
+    def carry():
+        return (z(256), z(256))
+
+    arena = ReplayArena(capacity)
+    example = SequenceBatch(
+        obs=z(length, *frame, dtype=frame_dtype), action=z(length, 6),
+        reward=z(length), discount=z(length), reset=z(length),
+        carries={"actor": carry(), "critic": carry()})
+    state, key = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: (arena.init_state(example), jax.random.PRNGKey(0))))
+    return arena, state, key
+
+
+def test_sample_compiled_for_v5e_sums_nothing_as_long_as_the_arena(
+    one_chip, no_compile_cache
+):
+    """``ReplayArena.sample`` at the walker cell's capacity, row shapes and
+    batch, compiled for a described v5e: no running sum over 524,288 elements
+    (the flat CDF's was ``reduce-window.38`` of the learner call, 13 % of an
+    update), while the two-level draw's own short ones are there.  Nothing
+    runs: a compile says nothing about results or times."""
+    import jax
+
+    arena, state, key = _sample_shapes(CAPACITY, (24,), "float32", 43, one_chip)
+    hlo = jax.jit(lambda s, k: arena.sample(s, k, 64)).trace(state, key).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    assert capacity_scans(hlo, CAPACITY) == []
+    assert capacity_scans(hlo, 64 * 128)  # the reader does see this program's sums
+
+
 @pytest.mark.parametrize("consumer", ["alone", "frames_as_floats"])
 def test_sample_compiled_for_v5e_writes_no_batch_minor_buffer(
     consumer, one_chip, no_compile_cache
@@ -341,23 +467,8 @@ def test_sample_compiled_for_v5e_writes_no_batch_minor_buffer(
     import jax
     import jax.numpy as jnp
 
-    from r2d2dpg_tpu.replay.arena import ReplayArena, SequenceBatch
-
-    capacity, B, L, hidden = 256, 32, 45, 256
-
-    def z(*shape, dtype=jnp.float32):
-        return jnp.zeros((1,) + shape, dtype)
-
-    def carry():
-        return (z(hidden), z(hidden))
-
-    arena = ReplayArena(capacity)
-    example = SequenceBatch(
-        obs=z(L, 64, 64, 3, dtype=jnp.uint8), action=z(L, 6), reward=z(L),
-        discount=z(L), reset=z(L), carries={"actor": carry(), "critic": carry()})
-    state, key = jax.tree_util.tree_map(
-        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
-        jax.eval_shape(lambda: (arena.init_state(example), jax.random.PRNGKey(0))))
+    capacity, B, L = 256, 32, 45
+    arena, state, key = _sample_shapes(capacity, (64, 64, 3), "uint8", L, one_chip)
 
     def program(s, k):
         res = arena.sample(s, k, B)

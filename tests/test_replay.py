@@ -102,6 +102,84 @@ def test_uniform_sampling():
     np.testing.assert_allclose(np.asarray(res.probs), 0.1, rtol=1e-6)
 
 
+def _priorities(pattern, capacity):
+    """A ``[capacity]`` float32 priority vector for one of the draw's cases."""
+    rng = np.random.default_rng(capacity)
+    p = rng.uniform(0.1, 2.0, capacity).astype(np.float32)
+    if pattern == "tenth_empty":
+        p[rng.random(capacity) < 0.1] = 0.0
+    elif pattern == "all_mass_in_last_filled":
+        p[:] = 0.0
+        p[capacity // 2] = 3.0
+    elif pattern == "empty_tail":
+        p[capacity // 2:] = 0.0
+    elif pattern == "six_decades":
+        p = (10.0 ** rng.uniform(-3.0, 3.0, capacity)).astype(np.float32)
+    return p
+
+
+@pytest.mark.parametrize(
+    "pattern",
+    ["tenth_empty", "all_mass_in_last_filled", "empty_tail", "six_decades"],
+)
+@pytest.mark.parametrize("capacity", [100, 128, 12_288, 50_000, 524_288])
+def test_prioritized_draw_is_the_float64_inverse_cdf(capacity, pattern):
+    """For the uniforms its key gives, ``sample`` draws the slot a float64
+    inverse CDF over the same ``p^alpha`` draws, or a neighbour WITH mass that
+    the rounding of a float32 partial sum puts the uniform in: the uniform
+    lies within 4 float32 roundings of the total of the drawn slot's own
+    stretch of the CDF.  Never a slot of no mass, at any capacity (one block,
+    a ragged last block, the cells' 12,288 / 50,000 / 524,288).  ``probs`` is
+    the slot's mass over the total."""
+    batch = 64
+    arena = ReplayArena(capacity=capacity)
+    priority = _priorities(pattern, capacity)
+    one = jnp.zeros((1, 1))
+    state = dataclasses.replace(
+        arena.init_state(SequenceBatch(
+            obs=one, action=one, reward=one, discount=one, reset=one, carries={})),
+        priority=jnp.asarray(priority),
+        total_added=jnp.asarray(capacity, jnp.int32),
+    )
+    key = jax.random.PRNGKey(capacity)
+    res = jax.jit(arena.sample, static_argnums=2)(state, key, batch)
+    indices, probs = np.asarray(res.indices), np.asarray(res.probs)
+
+    # The mass as the device raised it (float32), summed in float64.
+    scaled = np.asarray(
+        jnp.where(state.priority > 0.0, state.priority**arena.alpha, 0.0)
+    ).astype(np.float64)
+    cdf = np.cumsum(scaled)
+    total = cdf[-1]
+    u = np.asarray(jax.random.uniform(key, (batch,))).astype(np.float64) * total
+    want = np.searchsorted(cdf, u, side="right")
+
+    assert (scaled[indices] > 0.0).all()
+    start = np.concatenate([[0.0], cdf[:-1]])[indices]
+    beside = np.maximum(np.maximum(start - u, u - cdf[indices]), 0.0)
+    assert beside.max() <= 4 * np.finfo(np.float32).eps * total
+    assert (indices == want).mean() >= 0.9
+    np.testing.assert_allclose(probs, scaled[indices] / total, rtol=1e-5)
+
+
+@pytest.mark.parametrize(
+    "u, want",
+    [
+        ([0.0, 0.5, 1.0, 2.9], [1, 1, 3, 3]),  # side="right": past entries of no mass
+        ([3.0, 5.0], [3, 3]),  # at or past the total: the last entry with mass
+    ],
+    ids=["inside", "past_the_total"],
+)
+def test_first_above_never_answers_an_entry_of_no_mass(u, want):
+    """Entries 0, 2 and 4 of this CDF add nothing, the last among them."""
+    from r2d2dpg_tpu.replay.arena import _first_above
+
+    cdf = jnp.array([0.0, 1.0, 1.0, 3.0, 3.0])
+    np.testing.assert_array_equal(_first_above(cdf, jnp.array(u)), want)
+    rows = jnp.broadcast_to(cdf, (len(u), 5))  # a CDF a draw, as inside the blocks
+    np.testing.assert_array_equal(_first_above(rows, jnp.array(u)), want)
+
+
 def test_priority_update_pallas_kernel():
     """update_priorities runs the Pallas kernel (interpret mode on CPU)."""
     arena = ReplayArena(capacity=8)
