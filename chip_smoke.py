@@ -254,19 +254,22 @@ def _built_trainers():
 
 
 def _require_learner_call_guards(trainer, state, rolled_width=None) -> dict:
-    """The four compile-time guards of the learner call
+    """The five compile-time guards of the learner call
     (docs/OBSERVABILITY.md): ``Trainer._learn_many``, state donated, compiled
     by this chip's compiler at the run's own shapes, rounds no
     ``[capacity, ...]`` value, inserts no sequence into a batch-minor
-    ``[batch, ...]`` buffer, keeps no running sum as long as the arena, and
-    runs no image convolution inside a scan of an update.  Only the TPU
-    compiler makes the first two choices and gives the third its cost (128
-    adds an element), so only a chip run can check that ``ReplayArena.sample``
-    still takes all three from it; the fourth says that
-    ``models/sequence.py::Stepped`` took the pixel torso out of its scans
-    (trivially so for a configuration without one).
+    ``[batch, ...]`` buffer, keeps no running sum as long as the arena, reads
+    no row out of the arena as a slice or copy of many rows' bytes in an
+    update, and runs no image convolution inside a scan of an update.  Only
+    the TPU compiler makes the first two choices, gives the third its cost
+    (128 adds an element) and lays the arena out (the fourth: a pixel leaf in
+    the rows' own shape lies slot minor-most and a row comes out padded 128
+    times), so only a chip run can check that ``ReplayArena`` still takes all
+    four from it; the fifth says that ``models/sequence.py::Stepped`` took the
+    pixel torso out of its scans (trivially so for a configuration without
+    one).
 
-    With ``rolled_width`` (the inner width of a looped stack's MLP) a fifth:
+    With ``rolled_width`` (the inner width of a looped stack's MLP) a sixth:
     the products of that width lie inside the stack's two scans (over the
     layers, inside over the loop steps), a copy a pass and not one an
     application: a block written out sixteen times compiles sixteen times as
@@ -275,6 +278,7 @@ def _require_learner_call_guards(trainer, state, rolled_width=None) -> dict:
 
     from r2d2dpg_tpu.obs.hlo import (
         arena_converts,
+        arena_reads,
         batch_minor_writes,
         capacity_scans,
         loop_convolutions,
@@ -302,6 +306,16 @@ def _require_learner_call_guards(trainer, state, rolled_width=None) -> dict:
     # which the compiler keeps where there are two or more), so an update's
     # own operations sit that one loop deep; deeper is a scan inside it.
     call_loops = int(trainer.config.learner_steps > 1)
+    # What the compiler re-lays once a call, outside that loop (the small
+    # leaves of every configuration here, 69 MB a call for cheetah's), is
+    # listed and not refused: no update pays it again.
+    reads = arena_reads(hlo, trainer.arena.capacity)
+    in_updates = [r for r in reads if r[4] >= call_loops]
+    _require(
+        not in_updates,
+        "the learner call reads the arena in slices or copies of many rows' "
+        f"bytes in every update: {in_updates}",
+    )
     convolutions = loop_convolutions(hlo)
     in_scans = [c for c in convolutions if c[3] > call_loops]
     _require(
@@ -328,6 +342,8 @@ def _require_learner_call_guards(trainer, state, rolled_width=None) -> dict:
         "batch_size": trainer.config.batch_size,
         "batch_minor_writes": writes,
         "capacity_scans": scans,
+        "arena_reads": reads,
+        "arena_reads_in_updates": in_updates,
         "loop_convolutions": convolutions,
         "convolutions_in_scans": in_scans,
     }
@@ -391,7 +407,8 @@ def _leg_train(work: str) -> dict:
     inside the jitted phase, the HBM arena at capacity 100,000, the Pallas
     write-back, donated state; then the learner call alone, compiled for the
     whole-arena convert guard, the batch-minor write guard, the
-    capacity-long running sum guard and the convolution-in-a-scan guard, for
+    capacity-long running sum guard, the padded arena read guard and the
+    convolution-in-a-scan guard, for
     ``walker_r2d2`` and, from shapes, for the whole-sequence cores'
     configurations ``humanoid_sdar_moe`` and ``humanoid_ouro_loop`` (the
     latter also for the rolled-stack guard) and the pixel replay's

@@ -35,6 +35,17 @@ the compiled program: ``loop_convolutions`` lists the image convolutions that
 sit inside ``while`` bodies and how many loops deep, and the same leg requires
 that none lies deeper than the learner call's own loop over its updates.
 
+``ReplayArena`` stores a large row as whole tiles behind a major-most slot
+axis, so that one sequence is one stretch of memory
+(``replay/arena.py::_storage_shape``).  Stored in the rows' own shape the
+pixel leaf lay slot minor-most, and each of the B rows of a batch was read as
+a slice padded to 128 times its bytes (70.8 MB for 0.55 MB; 9.4 of cheetah's
+11.5 ms an update, PERF.md PR 34); gathered from the tiles by ``buf[indices]``
+the compiler first slices the whole leaf in two.  ``arena_reads`` lists every
+slice or copy of a ``[capacity, ...]`` value that moves more than a few rows'
+bytes, with the loops around it, and the same leg requires that an update
+makes none.
+
 ``models/ouro_loop.py`` runs 4 layers 4 times by a scan inside a scan, so that
 the compiled learner call holds one copy of a block a pass and not sixteen
 (its compile is part of every process's set-up).  ``loop_products`` lists the
@@ -53,7 +64,7 @@ from typing import Dict, List, Tuple
 _INSTRUCTION = re.compile(
     r"^\s*(?:ROOT\s+)?%?(?P<name>[\w.\-]+)\s*=\s*"
     r"(?P<shape>\w+\[(?P<lead>\d+)(?P<rest>[\d,]*)\])"
-    r"(?:\{(?P<order>[\d,]*)[^}\s]*\})?\S*\s+(?P<opcode>[\w\-]+)\(",
+    r"(?:\{(?P<order>[\d,]*)(?P<tiling>[^}\s]*)\})?\S*\s+(?P<opcode>[\w\-]+)\(",
     re.MULTILINE,
 )
 
@@ -178,6 +189,67 @@ def capacity_scans(hlo_text: str, capacity: int) -> List[Tuple[str, str]]:
             ]
             if bounds and max(bounds) >= capacity:
                 found.append((loop["name"], f"loop of {max(bounds)} steps"))
+    return found
+
+
+_ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2,
+             "bf16": 2, "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8, "f64": 8}
+_TILE = re.compile(r"T\((\d+(?:,\d+)*)\)")
+_OPERAND = re.compile(r"\s*%?([\w.\-]+)")
+
+
+def _itemsize(m: "re.Match[str]") -> int:
+    """The bytes of one element of a matched instruction's result."""
+    return _ITEMSIZE.get(m["shape"].split("[")[0], 4)
+
+
+def _laid_out_bytes(m: "re.Match[str]") -> int:
+    """The bytes a matched instruction's result takes as laid out: its
+    minor-most dimensions padded to the layout's first tile (``T(8,128)``:
+    the second-minor to 8, the minor-most to 128; a tile's own sub-tiling,
+    ``(4,1)``, packs and does not pad).  Without a layout, the values'."""
+    dims = _dims(m)
+    order = [int(d) for d in (m["order"] or "").split(",") if d]
+    tile = _TILE.search(m["tiling"] or "")
+    if tile and len(order) == len(dims):
+        sizes = [int(t) for t in tile[1].split(",")]
+        for d, t in zip(order, reversed(sizes)):
+            dims[d] = -(-dims[d] // t) * t
+    return math.prod(dims) * _itemsize(m)
+
+
+def arena_reads(
+    hlo_text: str, capacity: int, rows: int = 4
+) -> List[Tuple[str, str, int, int, int]]:
+    """``(name, shape with its layout, bytes as laid out, bytes of one row,
+    loops around it)`` of every ``slice``, ``dynamic-slice`` or ``copy`` in
+    ``hlo_text`` whose operand has ``capacity`` as its leading dimension and
+    whose result, padding included, takes more than ``rows`` rows' bytes (a
+    row: the operand's other dimensions), fused or not, in the order printed.
+
+    Two things read this way: one row taken out of a slot-minor leaf, which
+    the TPU compiler pads to whole tiles of 128 slots
+    (``u8[1,45,64,64,3]{0,3,4,2,1:T(8,128)(4,1)}``: 70.8 MB for a row of 0.55
+    MB), and a leaf sliced or re-laid whole (``[capacity, ...]`` results).  A
+    row read as it is stored takes one row's bytes and is not listed."""
+    lines, depth = _computations(hlo_text)
+    found = []
+    for name, body in lines.items():
+        # The ``[capacity, ...]`` values of this computation, by name.
+        stored = {}
+        for line in body:
+            m = _INSTRUCTION.match(line)
+            if not m:
+                continue
+            if m["opcode"] in ("slice", "dynamic-slice", "copy"):
+                operand = _OPERAND.match(line, m.end())
+                row = stored.get(operand[1]) if operand else None
+                if row and _laid_out_bytes(m) > rows * row:
+                    layout = f"{{{m['order']}{m['tiling']}}}" if m["order"] else ""
+                    found.append((m["name"], m["shape"] + layout,
+                                  _laid_out_bytes(m), row, depth.get(name, 0)))
+            if int(m["lead"]) == capacity and m["rest"]:
+                stored[m["name"]] = math.prod(_dims(m)[1:]) * _itemsize(m)
     return found
 
 

@@ -35,7 +35,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from r2d2dpg_tpu.agents.ddpg import R2D2DPG
 from r2d2dpg_tpu.envs.core import Environment
 from r2d2dpg_tpu.parallel.mesh import DP_AXIS
-from r2d2dpg_tpu.replay.arena import ArenaState, ReplayArena
+from r2d2dpg_tpu.replay.arena import ArenaState
 from r2d2dpg_tpu.training.trainer import Trainer, TrainerConfig, TrainerState
 
 
@@ -131,19 +131,19 @@ class SPMDTrainer(Trainer):
     # ------------------------------------------------------------------ init
     def init(self, key: Optional[jax.Array] = None) -> TrainerState:
         """Build the *global* state on host, then lay it out over the mesh."""
-        local_cfg, local_arena = self.config, self.arena
+        local_cfg = self.config
         try:
-            # Trainer.init sizes everything from self.config/self.arena; use
-            # the global versions so the sharded axes have their full extent.
+            # Trainer.init sizes everything from self.config and the arena's
+            # capacity; use the global ones so the sharded axes have their
+            # full extent.  The arena stays this one: ``init_state`` is where
+            # it records the shapes of the rows it stores as tiles, which
+            # every device's ``sample`` needs.
             self.config = self.global_config
-            self.arena = ReplayArena(
-                self.global_config.capacity,
-                prioritized=self.global_config.prioritized,
-                alpha=self.global_config.priority_alpha,
-            )
+            self.arena.capacity = self.global_config.capacity
             state = super().init(key)
         finally:
-            self.config, self.arena = local_cfg, local_arena
+            self.config = local_cfg
+            self.arena.capacity = local_cfg.capacity
 
         shardings = jax.tree_util.tree_map(
             lambda s: NamedSharding(self.mesh, s),

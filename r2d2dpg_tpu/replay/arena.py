@@ -24,6 +24,14 @@ Sequence layout (SURVEY §2.2 "sequence format"): each slot stores a
 fixed-length window of ``burnin + unroll + n_step`` steps plus the initial
 recurrent carries of actor and critic nets captured at window start.
 
+Storage: one leaf a field, ``[capacity, ...]``.  A small row is stored in its
+own shape; a large one (pixels) as whole tiles, so that the slot axis is
+major-most on the device and one sequence is one stretch of memory
+(``_storage_shape``).  Rows go in and come out in their own shapes whatever
+the storage: ``add`` / ``write_contiguous`` write them, ``gather`` reads
+them, ``sample`` ends in the same gather, and nothing else may assume how a
+leaf of ``ArenaState.data`` is shaped behind its slot axis.
+
 The sampled batch is a boundary: ``sample`` hands its B rows back in the
 arena's own dtypes, behind ``_pin_storage_dtypes``.  Without it the TPU
 compiler rounds the whole arena once a call instead of the rows: the rows are
@@ -78,7 +86,9 @@ class SequenceBatch:
 class ArenaState:
     """Device-resident replay storage (a pytree of preallocated buffers)."""
 
-    data: SequenceBatch  # leaves [capacity, L, ...] / carries [capacity, ...]
+    # One storage leaf a field, [capacity, ...] in the row's dtype and element
+    # count; large rows as tiles (``_storage_shape``): read through ``gather``.
+    data: SequenceBatch
     priority: jnp.ndarray  # [capacity] raw priorities; 0 marks empty slots
     cursor: jnp.ndarray  # next write position
     total_added: jnp.ndarray  # monotone count of sequences ever added
@@ -241,31 +251,99 @@ def _pin_storage_dtypes(batch: SequenceBatch) -> SequenceBatch:
 # Rows of fewer elements the TPU compiler gathers in one fusion, with no
 # accumulator for a stated layout to help; the loop it expands ``buf[indices]``
 # into was seen from 105,840 elements a row up (compiles for a described v5e).
+# The same rows are the ones stored contiguously (``_storage_shape``).
 _LOOPED_GATHER_ROW_ELEMENTS = 1 << 16
 
+# A tile of the device's memory: 128 lanes by as many sublanes as hold 32
+# bytes a lane (8 float32, 16 bfloat16, 32 uint8).
+_LANES = 128
+_SUBLANE_BYTES = 32
+# The compiler's gather reads a row whose stored dimensions stay at or under
+# this in one piece; a longer one it first cuts out of the WHOLE leaf (a
+# ``[capacity, 128, ...]`` slice an update, seen for the first dimension
+# behind the slots and for the sublanes; compiles for a described v5e).
+_GATHER_DIMENSION = 128
 
-def _gather_rows(buf: jnp.ndarray, indices: jnp.ndarray) -> jnp.ndarray:
-    """``buf[indices]``, with the device layout of large rows stated here:
-    batch major-most, then time, the two longest of the other dimensions
-    minor-most (least padding; pixels: ``[B, L, C, H, W]`` on the device).
+
+def _storage_shape(row_shape: Tuple[int, ...], dtype) -> Tuple[int, ...]:
+    """The shape one stored row has behind the slot axis.
+
+    The device layout of a buffer follows from its shape, and the compiler
+    lays a ``[capacity, L, H, W, C]`` pixel leaf slot minor-most (least
+    padding): one sequence's 552,960 bytes spread over the whole buffer, each
+    read back as a 70.8 MB padded slice (9.4 of ``cheetah_pixels``' 11.5 ms
+    an update, PERF.md PR 34).  So a large row is stored as whole tiles,
+    nothing to pad and the slot axis major-most: the shortest run of its
+    trailing dimensions that is a whole number of tiles becomes ``[n_tiles,
+    sublanes, 128]``, the dimensions before it stay (pixels: a step's frame,
+    ``[L, 3, 32, 128]`` for ``[L, 64, 64, 3]``; keeping the time axis makes
+    the way back to the frames a re-lay of the three minor dimensions alone,
+    0.36 ms an update less than from ``[135, 32, 128]``).  Same dtype, same
+    elements in the same order, so ``buf.reshape(capacity, -1)`` is the rows.
+
+    Kept in its own shape: a small row (under ``_LOOPED_GATHER_ROW_ELEMENTS``;
+    its gather is one fusion whatever the order), and a large one that is no
+    whole number of tiles or would need a dimension over
+    ``_GATHER_DIMENSION``."""
+    row_shape = tuple(row_shape)
+    if math.prod(row_shape) < _LOOPED_GATHER_ROW_ELEMENTS:
+        return row_shape
+    sublanes = max(_SUBLANE_BYTES // jnp.dtype(dtype).itemsize, 1)
+    tile = sublanes * _LANES
+    for k in reversed(range(len(row_shape))):
+        inner = math.prod(row_shape[k:])
+        stored = row_shape[:k] + (inner // tile, sublanes, _LANES)
+        if inner % tile == 0 and max(stored) <= _GATHER_DIMENSION:
+            return stored
+    return row_shape
+
+
+def _as_stored(buf: jnp.ndarray, rows: jnp.ndarray) -> jnp.ndarray:
+    """``rows`` (``[n, ...]`` in their own shape) in ``buf``'s storage shape
+    and dtype."""
+    return rows.astype(buf.dtype).reshape(rows.shape[:1] + buf.shape[1:])
+
+
+def _gather_rows(
+    buf: jnp.ndarray, indices: jnp.ndarray, row_shape: Tuple[int, ...]
+) -> jnp.ndarray:
+    """``buf[indices]`` in the rows' own shape, with the device layout of
+    large rows stated here: batch major-most, then time, the two longest of
+    the other dimensions minor-most (least padding; pixels: ``[B, L, C, H,
+    W]`` on the device).
 
     The TPU compiler expands the gather of a large row into a loop of B
-    iterations over an accumulator ``[B, L, ...]``, and left to itself gives
-    that accumulator the arena's own order.  The arena lies slot minor-most,
-    so the batch does too: B of 128 lanes used, and every iteration rewrites
-    the whole buffer to fill one lane of each tile (``dynamic-update-slice``,
-    59 of ``cheetah_pixels``' 70 ms an update; PERF.md, PR 28).  Batch-major,
-    an iteration re-lays ONE sequence out and writes it once into a stretch
-    of its own.  The values are ``buf[indices]``'s, bit for bit; on the CPU
-    the constraint is the identity.  ``chip_smoke.py``'s train leg holds the
-    compiled learner call to it (``obs/hlo.py::batch_minor_writes``)."""
-    rows = buf[indices]
-    if math.prod(rows.shape[1:]) < _LOOPED_GATHER_ROW_ELEMENTS:
+    iterations over an accumulator, and left to itself hands the consumer a
+    batch minor-most in the batch: B of 128 lanes used, and, gathered from a
+    leaf in the rows' own shape, every iteration rewrote the whole buffer to
+    fill one lane of each tile (``dynamic-update-slice``, 59 of
+    ``cheetah_pixels``' 70 ms an update; PERF.md, PR 28).  From tiles the
+    loop copies one contiguous row an iteration and the stated layout costs
+    one re-lay of the batch (without it the update read 3.14 ms for 2.82, my
+    chip run, PR 34).  The values are the stored rows', bit for bit; on the
+    CPU the constraint is the identity.  ``chip_smoke.py``'s train leg holds
+    the compiled learner call to both (``obs/hlo.py::batch_minor_writes``,
+    ``arena_reads``)."""
+    rows = buf[indices].reshape(indices.shape[:1] + row_shape)
+    if math.prod(row_shape) < _LOOPED_GATHER_ROW_ELEMENTS:
         return rows
     by_length = sorted(range(2, rows.ndim), key=lambda d: rows.shape[d])
     return with_layout_constraint(
         rows, Layout(major_to_minor=(0, 1, *by_length))
     )
+
+
+def _gather(data: Any, indices: jnp.ndarray, row_shapes: Dict[str, Any]) -> Any:
+    """The stored rows of ``indices`` for every leaf of ``data`` (an
+    ``ArenaState.data``, or any part of one: an empty tree gives an empty
+    batch).  ``row_shapes`` names the rows' own shape for the leaves stored
+    in another (``ReplayArena.init_state``)."""
+
+    def rows(path, buf):
+        own = row_shapes.get(jax.tree_util.keystr(path), buf.shape[1:])
+        return _gather_rows(buf, indices, own)
+
+    return jax.tree_util.tree_map_with_path(rows, data)
 
 
 # Slots to a block of the two-level draw: the lane width, so that the vector
@@ -343,6 +421,9 @@ class ReplayArena:
         # Pallas needs single-device refs; trainers whose arena buffers carry
         # an explicit mesh sharding (parallel.hybrid) use the XLA scatter.
         self.use_pallas = use_pallas
+        # The rows' own shape for every leaf stored in another, by the
+        # leaf's path in ``ArenaState.data`` (``init_state`` fills it).
+        self._row_shapes: Dict[str, Tuple[int, ...]] = {}
         # Telemetry (obs/): the arena itself is pure device code, so the
         # host-side instruments are fed by whoever fetches the state —
         # trainer/pipeline log paths call ``observe_state_scalars`` with
@@ -384,13 +465,21 @@ class ReplayArena:
 
     # ------------------------------------------------------------------ init
     def init_state(self, example: SequenceBatch) -> ArenaState:
-        """Preallocate buffers from one example sequence batch (leading dim B)."""
+        """Preallocate buffers from one example sequence batch (leading dim B).
 
-        def alloc(x):
-            return jnp.zeros((self.capacity,) + x.shape[1:], x.dtype)
+        One storage leaf a field, in the row's dtype and element count; a
+        large row's leaf in ``_storage_shape``'s shape, and its own shape
+        recorded here for ``gather`` (static, like ``capacity``: the state
+        itself stays arrays alone)."""
+
+        def alloc(path, x):
+            stored = _storage_shape(x.shape[1:], x.dtype)
+            if stored != x.shape[1:]:
+                self._row_shapes[jax.tree_util.keystr(path)] = x.shape[1:]
+            return jnp.zeros((self.capacity,) + stored, x.dtype)
 
         return ArenaState(
-            data=jax.tree_util.tree_map(alloc, example),
+            data=jax.tree_util.tree_map_with_path(alloc, example),
             priority=jnp.zeros((self.capacity,), jnp.float32),
             cursor=jnp.zeros((), jnp.int32),
             total_added=jnp.zeros((), jnp.int32),
@@ -416,7 +505,9 @@ class ReplayArena:
         idx = (state.cursor + jnp.arange(b, dtype=jnp.int32)) % self.capacity
 
         data = jax.tree_util.tree_map(
-            lambda buf, new: buf.at[idx].set(new), state.data, batch
+            lambda buf, new: buf.at[idx].set(_as_stored(buf, new)),
+            state.data,
+            batch,
         )
         priority = state.priority.at[idx].set(
             jnp.maximum(priorities, PRIORITY_EPS)
@@ -431,6 +522,30 @@ class ReplayArena:
             cursor=(state.cursor + b) % self.capacity,
             total_added=state.total_added + b,
             meta=state.meta.at[idx].set(meta),
+        )
+
+    def write_contiguous(
+        self, state: ArenaState, batch: SequenceBatch, priorities: jnp.ndarray
+    ) -> ArenaState:
+        """``add`` for ``n`` rows that do not wrap: slots ``[cursor, cursor +
+        n)``, the caller's to ensure.  One ``dynamic_update_slice`` a storage
+        leaf, so under ``jit(..., donate_argnums=0)`` nothing is copied (a
+        scatter, as ``add``'s, re-laid a whole pixel buffer out: a second
+        copy that does not fit the chip, PERF.md PR 33); the state is
+        ``add``'s of the same rows without stamps, to the last bit."""
+        n = priorities.shape[0]
+
+        def put(buf, new):
+            return lax.dynamic_update_slice_in_dim(
+                buf, _as_stored(buf, new), state.cursor, 0
+            )
+
+        return ArenaState(
+            data=jax.tree_util.tree_map(put, state.data, batch),
+            priority=put(state.priority, jnp.maximum(priorities, PRIORITY_EPS)),
+            cursor=(state.cursor + n) % self.capacity,
+            total_added=state.total_added + n,
+            meta=put(state.meta, jnp.full((n, 2), PROVENANCE_ABSENT, jnp.int32)),
         )
 
     def staged_meta(self, staged: StagedSequences, stamp: Any = None) -> Any:
@@ -563,12 +678,19 @@ class ReplayArena:
                 (batch_size,), 1.0 / jnp.maximum(size.astype(jnp.float32), 1.0)
             )
 
-        batch = jax.tree_util.tree_map(
-            lambda buf: _gather_rows(buf, indices), state.data
-        )
+        # The same gather as ``gather``, reached as a function of
+        # ``state.data`` (a subclass that keeps its rows elsewhere hands
+        # ``sample`` an empty ``data`` and gets an empty batch).
+        batch = _gather(state.data, indices, self._row_shapes)
         return SampleResult(
             batch=_pin_storage_dtypes(batch), indices=indices, probs=probs
         )
+
+    def gather(self, state: ArenaState, indices: jnp.ndarray) -> SequenceBatch:
+        """The stored rows of the slots ``indices`` in the rows' own shapes
+        and dtypes, large rows batch-major on the device (``_gather_rows``):
+        what ``sample`` ends with, before ``_pin_storage_dtypes``."""
+        return _gather(state.data, indices, self._row_shapes)
 
     # ------------------------------------------------------- priority update
     def update_priorities(

@@ -18,6 +18,7 @@ initial warm-up — no corrupt sequences enter replay.
 from __future__ import annotations
 
 import dataclasses
+import math
 import os
 from typing import Any, Optional
 
@@ -174,11 +175,96 @@ class CheckpointManager:
             else x,
             target,
         )
-        out = self._mgr.restore(step, args=ocp.args.StandardRestore(abstract))
+        try:
+            out = self._mgr.restore(step, args=ocp.args.StandardRestore(abstract))
+        except ValueError as refused:
+            # orbax reads no leaf into another shape.  A replay saved in an
+            # older storage shape is read as saved and reshaped; whatever
+            # else was refused stays refused.
+            with ocp.StandardCheckpointer() as reader:
+                saved = reader.metadata(
+                    os.path.join(self.directory, str(step), "default")
+                ).item_metadata
+            as_saved = _replay_as_saved(abstract, saved)
+            if as_saved is None:
+                raise refused
+            out = _replay_as_stored(
+                self._mgr.restore(step, args=ocp.args.StandardRestore(as_saved)),
+                abstract,
+            )
         return out["train"] if self.light else out
 
     def close(self) -> None:
         self._mgr.close()
+
+
+def _names(path) -> tuple:
+    """A tree path as the names on it, whatever kind of node each names
+    (the checkpoint's metadata is dicts all the way down, the state is
+    dataclasses)."""
+    return tuple(
+        str(getattr(k, "key", getattr(k, "name", getattr(k, "idx", k))))
+        for k in path
+    )
+
+
+def _replay_as_saved(abstract: Any, metadata: Any) -> Any:
+    """``abstract`` (the restore target) with every replay leaf in the shape
+    the checkpoint holds it in; ``None`` where none has another.
+
+    The arena stores a large row as whole tiles since PR 34
+    (``replay/arena.py::_storage_shape``: a pixel leaf ``[capacity, L, 3, 32,
+    128]``); a checkpoint written before holds it in the rows' own shape
+    (``[capacity, L, 64, 64, 3]``), which orbax refuses to read into another.
+    Same dtype, same slots, same elements in the same order: such a leaf is
+    read as saved and ``_replay_as_stored`` reshapes it.  A replay leaf that
+    differs in anything else is refused by name."""
+    saved = {
+        _names(path): m
+        for path, m in jax.tree_util.tree_flatten_with_path(metadata)[0]
+    }
+    older = []
+
+    def fit(path, want):
+        names = _names(path)
+        m = saved.get(names)
+        if (
+            not ("arena" in names and "data" in names)
+            or m is None
+            or tuple(m.shape) == tuple(want.shape)
+        ):
+            return want
+        if (
+            m.dtype != want.dtype
+            or tuple(m.shape)[:1] != tuple(want.shape)[:1]
+            or math.prod(m.shape) != math.prod(want.shape)
+        ):
+            raise ValueError(
+                f"checkpoint replay leaf {jax.tree_util.keystr(path)} is "
+                f"{m.dtype}{list(m.shape)}; this arena stores {want.dtype}"
+                f"{list(want.shape)}: another capacity, row or dtype, not an "
+                "older storage shape of the same rows"
+            )
+        older.append(names)
+        return jax.ShapeDtypeStruct(
+            tuple(m.shape), want.dtype, sharding=want.sharding
+        )
+
+    as_saved = jax.tree_util.tree_map_with_path(fit, abstract)
+    return as_saved if older else None
+
+
+def _replay_as_stored(restored: Any, abstract: Any) -> Any:
+    """``restored`` with the leaves ``_replay_as_saved`` read in an older
+    storage shape reshaped to the arena's (``abstract``'s), on its sharding."""
+
+    def fit(got, want):
+        if not isinstance(want, jax.ShapeDtypeStruct) or got.shape == want.shape:
+            return got
+        stored = jnp.reshape(got, want.shape)
+        return stored if want.sharding is None else jax.device_put(stored, want.sharding)
+
+    return jax.tree_util.tree_map(fit, restored, abstract)
 
 
 def check_restored_leaves(restored: Any, template: Any, *, where: str, hint: str) -> None:

@@ -1,8 +1,9 @@
-"""``obs/hlo.py``: the readers behind ``chip_smoke.py``'s four compile-time
+"""``obs/hlo.py``: the readers behind ``chip_smoke.py``'s five compile-time
 guards of the learner call (the whole-arena convert, the batch-minor write of
-the sampled batch, a running sum as long as the arena, an image convolution
-run once a scan step) and the fifth (a looped stack's products inside its
-loops, one copy a pass), on HLO text as the TPU compiler prints it.  Only the
+the sampled batch, a running sum as long as the arena, a row read out of the
+arena as many rows' bytes, an image convolution run once a scan step) and the
+sixth (a looped stack's products inside its loops, one copy a pass), on HLO
+text as the TPU compiler prints it.  Only the
 chip's compiler makes either choice, so the CPU tests the readers alone, and
 the one thing that can be compiled here without a chip: ``ReplayArena.sample``
 for a described v5e."""
@@ -11,6 +12,7 @@ import pytest
 
 from r2d2dpg_tpu.obs.hlo import (
     arena_converts,
+    arena_reads,
     batch_minor_writes,
     capacity_scans,
     loop_convolutions,
@@ -374,6 +376,120 @@ def test_loop_products_names_every_product_of_a_width_with_the_loops_around_it(
     assert loop_products(hlo, width) == want
 
 
+# ``cheetah_pixels``' learner call at the cell's capacity (12,288 sequences of
+# 45 frames of 64x64x3 bytes, 2 updates a call) compiled for a described v5e
+# (JAX 0.9.0, libtpu 0.0.34), cut to the gather of the pixel rows.  PR 34's
+# parent stored the leaf in the rows' own shape: it lies slot minor-most, and
+# the gather's loop takes each row out as a slice padded to 128 slots.
+SLOT_MINOR_ROW_READ = """\
+%fused_computation.14.clone.clone (param_0.4309: u8[12288,45,64,64,3], param_1.5532: s32[]) -> u8[1,45,64,64,3] {
+  %param_0.4309 = u8[12288,45,64,64,3]{0,3,4,2,1:T(8,128)(4,1)} parameter(0)
+  %param_1.5532 = s32[]{:T(128)} parameter(1)
+  %constant.5378 = s32[]{:T(128)} constant(0)
+  ROOT %dynamic-slice.9 = u8[1,45,64,64,3]{0,3,4,2,1:T(8,128)(4,1)S(1)} dynamic-slice(%param_0.4309, %param_1.5532, %constant.5378, %constant.5378, %constant.5378, /*index=5*/%constant.5378), dynamic_slice_sizes={1,45,64,64,3}
+}
+
+%wide.while_body.sunk (wide.param.2: (s32[], u8[12288,45,64,64,3], s32[32,1], u8[32,45,64,64,3], s32[])) -> (s32[], u8[12288,45,64,64,3], s32[32,1], u8[32,45,64,64,3], s32[]) {
+  %wide.param.2 = (s32[]{:T(128)}, u8[12288,45,64,64,3]{0,3,4,2,1:T(8,128)(4,1)}, s32[32,1]{0,1:T(1,128)S(1)}, u8[32,45,64,64,3]{3,2,4,1,0:T(8,128)(4,1)}, s32[]{:T(128)}) parameter(0)
+  %get-tuple-element.18327 = u8[12288,45,64,64,3]{0,3,4,2,1:T(8,128)(4,1)} get-tuple-element(%wide.param.2), index=1
+  %constant_dynamic-slice_fusion.15 = u8[1,45,64,64,3]{0,3,4,2,1:T(8,128)(4,1)S(1)} fusion(%get-tuple-element.18327, %bitcast.1320), kind=kLoop, calls=%fused_computation.14.clone.clone
+  %copy.536 = u8[1,45,64,64,3]{3,2,4,1,0:T(8,128)(4,1)S(1)} copy(%constant_dynamic-slice_fusion.15)
+}
+
+%region_0.231 (arg_tuple.4: (s32[], u8[12288,45,64,64,3])) -> (s32[], u8[12288,45,64,64,3]) {
+  %while.863 = (s32[]{:T(128)}, u8[12288,45,64,64,3]{0,3,4,2,1:T(8,128)(4,1)}, s32[32,1]{0,1:T(1,128)S(1)}, u8[32,45,64,64,3]{3,2,4,1,0:T(8,128)(4,1)}, s32[]{:T(128)}) while(%tuple.1012), condition=%wide.while_cond, body=%wide.while_body.sunk
+}
+
+ENTRY %main.243 (arena_data_obs.1: u8[12288,45,64,64,3], arena_data_action.1: f32[12288,45,6]) -> f32[] {
+  %arena_data_obs.1 = u8[12288,45,64,64,3]{0,3,4,2,1:T(8,128)(4,1)} parameter(187), metadata={op_name="arena.data.obs"}
+  %arena_data_action.1 = f32[12288,45,6]{0,2,1:T(8,128)} parameter(186), metadata={op_name="arena.data.action"}
+  %copy.413 = f32[12288,45,6]{1,2,0:T(8,128)} copy(%arena_data_action.1)
+  %while.898 = (s32[]{:T(128)}, u8[12288,45,64,64,3]{0,3,4,2,1:T(8,128)(4,1)}) while(%tuple.1036), condition=%region_188.232, body=%region_0.231
+}
+"""
+
+# The same call from PR 34 on: a step's frame is stored as three whole tiles
+# behind a major-most slot axis, and the loop copies one row as it lies.
+TILED_ROW_READ = """\
+%fused_computation.13.clone.clone (param_0.4300: u8[32,45,3,32,128], param_1.5531: s32[], param_2.4626: u8[12288,45,3,32,128], param_3.4101: s32[]) -> u8[32,45,3,32,128] {
+  %param_0.4300 = u8[32,45,3,32,128]{4,3,2,1,0:T(8,128)(4,1)S(1)} parameter(0)
+  %param_2.4626 = u8[12288,45,3,32,128]{4,3,2,1,0:T(8,128)(4,1)} parameter(2)
+  %param_3.4101 = s32[]{:T(128)} parameter(3)
+  %constant.5375 = s32[]{:T(128)} constant(0)
+  %dynamic-slice.9 = u8[1,45,3,32,128]{4,3,2,1,0:T(8,128)(4,1)} dynamic-slice(%param_2.4626, %param_3.4101, %constant.5375, %constant.5375, %constant.5375, /*index=5*/%constant.5375), dynamic_slice_sizes={1,45,3,32,128}
+  %param_1.5531 = s32[]{:T(128)} parameter(1)
+  ROOT %dynamic-update-slice.52 = u8[32,45,3,32,128]{4,3,2,1,0:T(8,128)(4,1)S(1)} dynamic-update-slice(%param_0.4300, %dynamic-slice.9, %param_1.5531, %constant.5375, %constant.5375, /*index=5*/%constant.5375, %constant.5375)
+}
+
+%wide.while_body.sunk (wide.param.2: (s32[], u8[12288,45,3,32,128], s32[32,1], u8[32,45,3,32,128], s32[])) -> (s32[], u8[12288,45,3,32,128], s32[32,1], u8[32,45,3,32,128], s32[]) {
+  %get-tuple-element.18327 = u8[12288,45,3,32,128]{4,3,2,1,0:T(8,128)(4,1)} get-tuple-element(%wide.param.2), index=1
+  %dynamic-slice_dynamic-update-slice_fusion.2 = u8[32,45,3,32,128]{4,3,2,1,0:T(8,128)(4,1)S(1)} fusion(%get-tuple-element.18324, %get-tuple-element.18321, %get-tuple-element.18327, %bitcast.1324), kind=kLoop, calls=%fused_computation.13.clone.clone
+}
+
+%region_0.231 (arg_tuple.4: (s32[], u8[12288,45,3,32,128])) -> (s32[], u8[12288,45,3,32,128]) {
+  %while.863 = (s32[]{:T(128)}, u8[12288,45,3,32,128]{4,3,2,1,0:T(8,128)(4,1)}, s32[32,1]{0,1:T(1,128)S(1)}, u8[32,45,3,32,128]{4,3,2,1,0:T(8,128)(4,1)S(1)}, s32[]{:T(128)}) while(%tuple.1012), condition=%wide.while_cond, body=%wide.while_body.sunk
+  %get-tuple-element.17722 = u8[32,45,3,32,128]{4,3,2,1,0:T(8,128)(4,1)S(1)} get-tuple-element(%while.863), index=3
+  %copy.548 = u8[32,45,3,32,128]{1,0,4,3,2:T(8,128)(4,1)S(1)} copy(%get-tuple-element.17722)
+}
+
+ENTRY %main.243 (arena_data_obs.1: u8[12288,45,3,32,128], arena_data_action.1: f32[12288,45,6]) -> f32[] {
+  %arena_data_obs.1 = u8[12288,45,3,32,128]{4,3,2,1,0:T(8,128)(4,1)} parameter(187), metadata={op_name="arena.data.obs"}
+  %arena_data_action.1 = f32[12288,45,6]{0,2,1:T(8,128)} parameter(186), metadata={op_name="arena.data.action"}
+  %copy.416 = f32[12288,45,6]{1,2,0:T(8,128)} copy(%arena_data_action.1)
+  %while.898 = (s32[]{:T(128)}, u8[12288,45,3,32,128]{4,3,2,1,0:T(8,128)(4,1)}) while(%tuple.1036), condition=%region_188.232, body=%region_0.231
+}
+"""
+
+# Stored as a row's 135 tiles in one dimension, the compiler's gather first
+# cuts the WHOLE leaf in two (a dimension over 128), in every update.
+WHOLE_LEAF_SLICES = """\
+%region_0.231 (arg_tuple.4: (s32[], u8[12288,135,32,128])) -> (s32[], u8[12288,135,32,128]) {
+  %get-tuple-element.19570 = u8[12288,135,32,128]{3,2,1,0:T(8,128)(4,1)} get-tuple-element(%arg_tuple.4), index=200
+  %mini-gather-slice.8 = u8[12288,7,32,128]{3,2,1,0:T(8,128)(4,1)} slice(%get-tuple-element.19570), slice={[0:12288], [128:135], [0:32], [0:128]}
+  %mini-gather-slice.9 = u8[12288,128,32,128]{3,2,1,0:T(8,128)(4,1)} slice(%get-tuple-element.19570), slice={[0:12288], [0:128], [0:32], [0:128]}
+}
+
+ENTRY %main.243 (arena_data_obs.1: u8[12288,135,32,128]) -> f32[] {
+  %while.898 = (s32[]{:T(128)}, u8[12288,135,32,128]{3,2,1,0:T(8,128)(4,1)}) while(%tuple.1036), condition=%region_188.232, body=%region_0.231
+}
+"""
+
+
+def _relaid_once_a_call(name):
+    """The action leaf re-laid whole, once a call, outside the loop over the
+    updates: 128 x 48 x 12,288 floats as laid out, no loop around it."""
+    return (name, "f32[12288,45,6]{1,2,0:T(8,128)}", 50331648, 1080, 0)
+
+
+@pytest.mark.parametrize(
+    "hlo, capacity, want",
+    [
+        (SLOT_MINOR_ROW_READ, 12288, [
+            # 128 slots x 64 x 3 x 64 x 45 bytes for a row of 552,960.
+            ("dynamic-slice.9", "u8[1,45,64,64,3]{0,3,4,2,1:T(8,128)(4,1)S(1)}",
+             70778880, 552960, 2),
+            _relaid_once_a_call("copy.413"),
+        ]),
+        (TILED_ROW_READ, 12288, [_relaid_once_a_call("copy.416")]),
+        (WHOLE_LEAF_SLICES, 12288, [
+            ("mini-gather-slice.8", "u8[12288,7,32,128]{3,2,1,0:T(8,128)(4,1)}",
+             352321536, 552960, 1),
+            ("mini-gather-slice.9", "u8[12288,128,32,128]{3,2,1,0:T(8,128)(4,1)}",
+             6442450944, 552960, 1),
+        ]),
+        (SLOT_MINOR_ROW_READ, 8000, []),  # no value of that many slots
+        ("", 12288, []),
+    ],
+    ids=["parent_slot_minor", "tiles", "whole_leaf_slices", "another_capacity",
+         "empty"],
+)
+def test_arena_reads_names_every_slice_or_copy_of_many_rows_bytes(hlo, capacity, want):
+    assert arena_reads(hlo, capacity) == want
+    # What ``chip_smoke.py`` refuses: a read inside the loop over the updates.
+    assert [r[0] for r in arena_reads(hlo, capacity) if r[4] >= 1] == [
+        w[0] for w in want if w[4] >= 1]
+
+
 @pytest.fixture(scope="module")
 def one_chip():
     """A described v5e chip's sharding; skips where the TPU compiler cannot
@@ -452,18 +568,18 @@ def test_sample_compiled_for_v5e_sums_nothing_as_long_as_the_arena(
 
 
 @pytest.mark.parametrize("consumer", ["alone", "frames_as_floats"])
-def test_sample_compiled_for_v5e_writes_no_batch_minor_buffer(
+def test_sample_compiled_for_v5e_reads_rows_as_stored_into_a_batch_major_buffer(
     consumer, one_chip, no_compile_cache
 ):
     """``ReplayArena.sample`` at ``cheetah_pixels``'s row shape and batch,
-    compiled for a described v5e: no sequence is inserted into a batch-minor
-    buffer.  The capacity is cut to compile in seconds but is still the
-    largest dimension, so the arena lies slot-minor as the cell's does.
-    Alone, the batch is the program's result and takes the result's layout
-    whoever states it (the benchmark's probe ``jit_replay_sample`` has always
-    read that program); it is a consumer that takes the frames as floats, as
-    the conv torso does, that pulls an unstated layout back to the arena's
-    order.  Nothing runs: a compile says nothing about results or times."""
+    compiled for a described v5e: the pixel leaf lies slot major-most (a
+    step's frame as three whole tiles), no row is read out of it as more than
+    a row's bytes, and no sequence is inserted into a batch-minor buffer.
+    The capacity is cut to compile in seconds.  Alone, the batch is the
+    program's result and takes the result's layout whoever states it; it is
+    a consumer that takes the frames as floats, as the conv torso does, that
+    pulls an unstated layout back to a batch-minor order.  Nothing runs: a
+    compile says nothing about results or times."""
     import jax
     import jax.numpy as jnp
 
@@ -478,5 +594,6 @@ def test_sample_compiled_for_v5e_writes_no_batch_minor_buffer(
 
     hlo = jax.jit(program).trace(state, key).lower(
         lowering_platforms=("tpu",)).compile().as_text()
-    assert f"u8[{capacity},{L},64,64,3]{{0," in hlo  # slot-minor, as the cell's arena
+    assert f"u8[{capacity},{L},3,32,128]{{4,3,2,1,0:" in hlo  # slot-major tiles
+    assert [r for r in arena_reads(hlo, capacity) if r[1].startswith("u8[")] == []
     assert batch_minor_writes(hlo, B) == []

@@ -46,7 +46,7 @@ def test_ring_overwrite_fifo():
     for i in range(6):  # 6 adds into capacity 4 -> slots hold adds 2..5
         b = make_batch(1, value=float(i))
         state = arena.add(state, b, jnp.ones(1))
-    obs_vals = np.asarray(state.data.obs)[:, 0, 0]
+    obs_vals = np.asarray(arena.gather(state, jnp.arange(4)).obs)[:, 0, 0]
     # slot k holds add k for k in 4,5 (wrapped to 0,1) and 2,3 at slots 2,3
     np.testing.assert_allclose(sorted(obs_vals), [2.0, 3.0, 4.0, 5.0])
     assert int(arena.size(state)) == 4
@@ -505,8 +505,8 @@ def test_sampled_batch_contents_roundtrip():
     np.testing.assert_allclose(row0, 0.0)
 
 
-def _mixed_state(arena, n=12, frame=(2, 2, 3)):
-    """An arena whose leaves are float32 and uint8 (pixel observations), with
+def _mixed_rows(n=12, frame=(2, 2, 3)):
+    """Rows whose leaves are float32 and uint8 (pixel observations), with
     bit patterns a rounding or a float compare would lose: a NaN payload,
     -0.0, a subnormal, and mantissas bfloat16 cannot hold."""
     rng = np.random.default_rng(0)
@@ -525,8 +525,13 @@ def _mixed_state(arena, n=12, frame=(2, 2, 3)):
         reset=jnp.zeros((n, L)),
         carries={"actor": (carry(), carry()), "critic": (carry(), carry())},
     )
-    state = arena.init_state(batch)
-    return arena.add(state, batch, jnp.asarray(rng.random(n) + 0.1, jnp.float32))
+    return batch, jnp.asarray(rng.random(n) + 0.1, jnp.float32)
+
+
+def _mixed_state(arena, n=12, frame=(2, 2, 3)):
+    """An arena holding ``_mixed_rows``, slot ``i`` the row ``i``."""
+    batch, priorities = _mixed_rows(n, frame)
+    return arena.add(arena.init_state(batch), batch, priorities)
 
 
 def _bits(x):
@@ -543,10 +548,11 @@ def test_sample_is_the_plain_gather_in_the_arenas_own_dtypes(
     """What ``sample`` does to the rows it gathers (their device layout
     stated, the float ones pinned to the arena's dtypes) is the identity on
     bits: batch, indices and probs are what the sample without the pin
-    returns, and the batch is ``buf[indices]`` leaf by leaf, in the arena's
-    shapes and dtypes, for a rank-5 pixel leaf beside the float and carry
-    leaves: small frames, whose gather is left alone, and frames of unequal
-    sides (a transposed row would show) large enough for the stated layout."""
+    returns, and the batch is the rows added, leaf by leaf in their own shapes
+    and dtypes, for a rank-5 pixel leaf beside the float and carry leaves:
+    small frames, stored as they are and gathered in one piece, and frames
+    of unequal sides (a transposed row would show) large enough to be stored
+    as tiles and gathered with a stated layout."""
     from r2d2dpg_tpu.replay import arena as arena_mod
 
     B = 5
@@ -574,15 +580,16 @@ def test_sample_is_the_plain_gather_in_the_arenas_own_dtypes(
     monkeypatch.setattr(arena_mod, "_pin_storage_dtypes", lambda batch: batch)
     plain = draw()
 
-    want = jax.tree_util.tree_map(lambda buf: buf[got.indices], state.data)
+    rows, _ = _mixed_rows(frame=frame)
+    want = jax.tree_util.tree_map(lambda x: x[got.indices], rows)
     lead = (2, B) if mode in ("scan", "vmap") else (B,)
-    for g, w, buf in zip(
+    for g, w, row in zip(
         jax.tree_util.tree_leaves(got.batch),
         jax.tree_util.tree_leaves(want),
-        jax.tree_util.tree_leaves(state.data),
+        jax.tree_util.tree_leaves(rows),
     ):
-        assert g.dtype == buf.dtype
-        assert g.shape == lead + buf.shape[1:]
+        assert g.dtype == row.dtype
+        assert g.shape == lead + row.shape[1:]
         np.testing.assert_array_equal(_bits(g), _bits(w))
     assert {str(x.dtype) for x in jax.tree_util.tree_leaves(got.batch)} == {
         "float32", "uint8"}
@@ -606,7 +613,7 @@ def test_sample_states_its_rows_layout_under_a_sharded_arena(how):
     per = n // len(devices)
     arena = ReplayArena(capacity=n, prioritized=True, use_pallas=False)
     state = _mixed_state(arena, n=n, frame=(64, 96, 3))
-    pixels = np.asarray(state.data.obs)
+    pixels = np.asarray(_mixed_rows(n, (64, 96, 3))[0].obs)
     mesh = Mesh(np.array(devices), ("dp",))
 
     def slots(x):
@@ -621,7 +628,10 @@ def test_sample_states_its_rows_layout_under_a_sharded_arena(how):
             np.asarray(res.batch.obs), pixels[np.asarray(res.indices)])
         return
 
-    local = ReplayArena(capacity=per, prioritized=True, use_pallas=False)
+    # Each device's arena is the one that initialised the state, at a
+    # device's share of the capacity (``SPMDTrainer.init``).
+    local = arena
+    local.capacity = per
 
     def draw(s, k):
         s = dataclasses.replace(s, total_added=jnp.minimum(s.total_added, per))
@@ -635,3 +645,132 @@ def test_sample_states_its_rows_layout_under_a_sharded_arena(how):
     obs, idx = np.asarray(obs).reshape(len(devices), B, *pixels.shape[1:]), np.asarray(idx)
     for d in range(len(devices)):
         np.testing.assert_array_equal(obs[d], pixels[d * per + idx[d * B:(d + 1) * B]])
+
+
+# ------------------------------------ contiguous storage of large rows (PR 34)
+FRAMES = [(2, 2, 3), (64, 96, 3)]  # stored in its own shape; stored as tiles
+FRAME_IDS = ["small_row", "large_row"]
+
+
+def _rows_equal(got, want):
+    assert jax.tree_util.tree_structure(got) == jax.tree_util.tree_structure(want)
+    for g, w in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        np.testing.assert_array_equal(_bits(g), _bits(w))
+
+
+@pytest.mark.parametrize("frame", FRAMES, ids=FRAME_IDS)
+def test_gather_after_a_wrapped_ring_equals_the_rows_added(frame):
+    """Ten rows into six slots, four at a time: slot ``k`` holds the latest
+    row whose number is ``k`` modulo six, in the rows' own shapes and dtypes."""
+    rows, priorities = _mixed_rows(12, frame)
+    arena = ReplayArena(capacity=6)
+    state = arena.init_state(rows)
+    for start in (0, 4, 8):
+        part = jax.tree_util.tree_map(lambda x: x[start:start + 4], rows)
+        state = arena.add(state, part, priorities[start:start + 4])
+    assert int(state.cursor) == 0 and int(state.total_added) == 12
+    held = jnp.asarray([6, 7, 8, 9, 10, 11])  # slots 0..5
+    order = jnp.asarray([5, 0, 3, 3, 1])
+    _rows_equal(arena.gather(state, order),
+                jax.tree_util.tree_map(lambda x: x[held[order]], rows))
+
+
+@pytest.mark.parametrize("frame", FRAMES, ids=FRAME_IDS)
+def test_write_contiguous_at_a_cursor_equals_add(frame):
+    """The in-place write of rows that do not wrap is ``add``'s of the same
+    rows: priorities floored at the epsilon, stamps cleared, cursor and count
+    advanced; under ``jit`` with the state donated."""
+    from r2d2dpg_tpu.obs.quality import PROVENANCE_ABSENT
+    from r2d2dpg_tpu.ops.priority import PRIORITY_EPS
+
+    rows, priorities = _mixed_rows(12, frame)
+    arena = ReplayArena(capacity=16)
+    first = jax.tree_util.tree_map(lambda x: x[:5], rows)
+    partly = arena.add(arena.init_state(rows), first, priorities[:5],
+                       meta=jnp.full((5, 2), 7))
+    partly = dataclasses.replace(partly, meta=jnp.full_like(partly.meta, 7))
+    nxt = jax.tree_util.tree_map(lambda x: x[5:12], rows)
+    low = priorities[5:12].at[2].set(0.0)
+    added = arena.add(partly, nxt, low)
+    written = jax.jit(arena.write_contiguous, donate_argnums=0)(partly, nxt, low)
+    _rows_equal(written, added)
+    assert int(written.cursor) == 12 and int(written.total_added) == 12
+    assert float(written.priority[7]) == np.float32(PRIORITY_EPS)
+    np.testing.assert_array_equal(np.asarray(written.meta[5:12]), PROVENANCE_ABSENT)
+    np.testing.assert_array_equal(np.asarray(written.meta[:5]), 7)
+    _rows_equal(arena.gather(written, jnp.arange(12)), rows)
+
+
+@pytest.mark.parametrize(
+    "frame, dtype, stored",
+    [
+        ((64, 96, 3), "uint8", (18, 32, 128)),  # 73,728 B: 18 tiles of 32 x 128
+        ((64, 64, 4), "float32", (L, 16, 8, 128)),  # a step is 16 tiles of 8 x 128
+        ((64, 64, 4), "bfloat16", (L, 8, 16, 128)),
+        ((50, 111, 3), "uint8", (L, 50, 111, 3)),  # no whole number of tiles
+        ((2, 2, 3), "uint8", (L, 2, 2, 3)),  # a small row
+    ],
+    ids=["uint8_row_tiles", "float32_step_tiles", "bfloat16_step_tiles",
+         "no_whole_tiles", "small_row"],
+)
+def test_a_large_rows_storage_is_the_rows_elements_in_order(frame, dtype, stored):
+    """A large row's storage leaf has another shape behind the slot axis and
+    nothing else: the row's dtype, its element count, its elements in order,
+    so ``buf.reshape(capacity, -1)`` is the rows.  The shortest run of
+    trailing dimensions that is whole tiles is stored as tiles; a row that
+    has none, or is small, keeps its own shape."""
+    n, capacity = 3, 4
+    rng = np.random.default_rng(1)
+    obs = jnp.asarray(rng.integers(0, 200, (n, L) + frame)).astype(dtype)
+    rows = dataclasses.replace(make_batch(n), obs=obs)
+    arena = ReplayArena(capacity=capacity)
+    state = arena.add(arena.init_state(rows), rows, jnp.ones(n))
+    assert state.data.obs.shape == (capacity,) + stored
+    assert state.data.obs.dtype == obs.dtype
+    np.testing.assert_array_equal(
+        _bits(state.data.obs.reshape(capacity, -1)[:n]), _bits(obs.reshape(n, -1)))
+    # Every other leaf is small here and keeps the row's shape.
+    assert state.data.action.shape == (capacity, L, ACT)
+    _rows_equal(arena.gather(state, jnp.arange(n)), rows)
+
+
+def test_sample_under_jit_with_donated_state_returns_rows_in_their_own_shapes():
+    """The learner call's pattern: the state donated into a jitted program
+    that samples and hands the state back."""
+    B = 4
+    rows, _ = _mixed_rows(12, (64, 96, 3))
+    arena = ReplayArena(capacity=16)
+    state = _mixed_state(arena, frame=(64, 96, 3))
+
+    def program(s, k):
+        return s, arena.sample(s, k, B)
+
+    state, res = jax.jit(program, donate_argnums=0)(state, jax.random.PRNGKey(2))
+    assert res.batch.obs.shape == (B, L, 64, 96, 3) and res.batch.obs.dtype == jnp.uint8
+    _rows_equal(res.batch, jax.tree_util.tree_map(lambda x: x[res.indices], rows))
+    _rows_equal(res.batch, arena.gather(state, res.indices))
+
+
+def test_dp_sharded_arena_shards_a_large_rows_storage_over_its_slots():
+    """``P(DP_AXIS)`` on axis 0 shards the tiled leaf as it shards the others,
+    and the capacity-sharded arena samples the rows added."""
+    from jax.sharding import PartitionSpec as P
+
+    from r2d2dpg_tpu.parallel import make_mesh
+    from r2d2dpg_tpu.parallel.mesh import DP_AXIS
+
+    rows, priorities = _mixed_rows(12, (64, 96, 3))
+    arena = ReplayArena(capacity=16, use_pallas=False)
+    state = _dp_arena_state(arena, rows, priorities, make_mesh(2))
+    assert state.data.obs.shape == (16, 18, 32, 128)
+    assert state.data.obs.sharding.spec == P(DP_AXIS)
+    assert {s.data.shape for s in state.data.obs.addressable_shards} == {(8, 18, 32, 128)}
+    res = jax.jit(arena.sample, static_argnums=2)(state, jax.random.PRNGKey(4), 6)
+    want = jax.tree_util.tree_map(lambda x: x[res.indices], rows)
+    np.testing.assert_array_equal(np.asarray(res.batch.obs), np.asarray(want.obs))
+    # The float leaves come back through the partitioner's sum over shards,
+    # which keeps values and not every bit pattern (a subnormal goes to 0).
+    for g, w in zip(jax.tree_util.tree_leaves(res.batch), jax.tree_util.tree_leaves(want)):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=1e-30)

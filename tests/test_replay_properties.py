@@ -108,7 +108,7 @@ def test_ring_and_priority_invariants(ops, seed):
 
     # --- FIFO residency: slot k holds the latest add whose id % C == k.
     prio = np.asarray(state.priority)
-    obs = np.asarray(state.data.obs)[:, 0, 0]
+    obs = np.asarray(arena.gather(state, jnp.arange(CAPACITY)).obs)[:, 0, 0]
     for slot in range(CAPACITY):
         if slot in model:
             add_id, want_prio = model[slot]

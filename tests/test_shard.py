@@ -549,11 +549,11 @@ def test_chaos_kill_shard_stall_and_partition_e2e(tmp_path, fresh_obs):
     trainer = PENDULUM_TINY.build()
     state = trainer.init()
     _, lstate = split_state(state)
-    # The arena's storage tree IS the staged-batch template (leaves
-    # [capacity, L, ...]): synthetic actors emit exactly the structure
-    # the learn program expects, without paying a collect-program
-    # compile this drill does not test.
-    template = jax.device_get(lstate.arena.data)
+    # A stored row IS the staged-batch template (leaves [1, L, ...]):
+    # synthetic actors emit exactly the structure the learn program
+    # expects, without paying a collect-program compile this drill does
+    # not test.
+    template = jax.device_get(trainer.arena.gather(lstate.arena, np.zeros(1, np.int32)))
 
     def synth_staged(rng, b=4):
         data = jax.tree_util.tree_map(

@@ -2,6 +2,7 @@
 evaluator, and the CLI entry."""
 
 import csv
+import dataclasses
 import glob
 import os
 
@@ -101,6 +102,47 @@ def test_checkpoint_roundtrip_and_resume(tmp_path):
     s2, m2 = trainer.train_phase(restored)
     _tree_allclose(m1, m2)
     _tree_allclose(s1.train.actor_params, s2.train.actor_params)
+    ckpt.close()
+
+
+@pytest.mark.parametrize("saved", ["rows_own_shape", "another_row"])
+def test_restore_converts_an_arena_saved_in_the_rows_own_shape(tmp_path, saved):
+    """A checkpoint from before the arena stored large rows as tiles holds
+    the pixel leaf as ``[capacity, L, H, W, C]``: restored into today's
+    arena it is the same rows, reshaped; a replay leaf of another row size
+    is refused by name."""
+    import jax.numpy as jnp
+
+    from r2d2dpg_tpu.replay.arena import ReplayArena, SequenceBatch
+
+    n, L, frame = 3, 4, (64, 96, 3)
+    rng = np.random.default_rng(0)
+    rows = SequenceBatch(
+        obs=jnp.asarray(rng.integers(0, 256, (n, L) + frame, dtype=np.uint8)),
+        action=jnp.asarray(rng.standard_normal((n, L, 2)), jnp.float32),
+        reward=jnp.zeros((n, L)), discount=jnp.ones((n, L)),
+        reset=jnp.zeros((n, L)), carries={})
+    arena = ReplayArena(capacity=4)
+    state = {"arena": arena.add(arena.init_state(rows), rows, jnp.ones(n))}
+    assert state["arena"].data.obs.shape == (4, 18, 32, 128)
+    old_shape = (4, L) + (frame if saved == "rows_own_shape" else (64, 96, 4))
+    old_obs = jnp.zeros(old_shape, jnp.uint8).at[:n].set(
+        rows.obs if saved == "rows_own_shape" else 0)
+    old = {"arena": dataclasses.replace(
+        state["arena"], data=dataclasses.replace(state["arena"].data, obs=old_obs))}
+
+    ckpt = CheckpointManager(str(tmp_path / "ckpt"), save_every=1)
+    ckpt.save(1, old)
+    ckpt.wait()
+    if saved == "another_row":
+        with pytest.raises(ValueError, match=r"data\.obs.*older storage shape"):
+            ckpt.restore(state)
+    else:
+        restored = ckpt.restore(state)
+        _tree_allclose(restored, state)
+        assert restored["arena"].data.obs.shape == (4, 18, 32, 128)
+        got = arena.gather(restored["arena"], jnp.arange(n))
+        np.testing.assert_array_equal(np.asarray(got.obs), np.asarray(rows.obs))
     ckpt.close()
 
 
