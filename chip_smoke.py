@@ -253,6 +253,18 @@ def _built_trainers():
         topology.build_trainer = build_trainer
 
 
+# What ``models/torsos.py::ConvTorso.prepare`` leaves of re-laying between
+# the gather and the first convolution in an update of a pixel
+# configuration's learner call (``obs/hlo.py::frame_relays``): one
+# transposition of the sampled bytes with the conversion fused in, one pass
+# that pads the channels into the convolution's order; for ``cheetah_pixels``
+# 37.7 + 50.3 MB as laid out.  Its parent made eleven, 325 MB (PERF.md PR 35).
+_FRAME_RELAYS_AN_UPDATE = 2
+# Two bytes an element in runs of 128 frames, once as they are and once with
+# three channels padded to four: 88,080,384 B over 45 x 32 x 12,288 is 4.98.
+_FRAME_RELAY_BYTES_A_FRAME_ELEMENT = 5.0
+
+
 def _require_learner_call_guards(trainer, state, rolled_width=None) -> dict:
     """The five compile-time guards of the learner call
     (docs/OBSERVABILITY.md): ``Trainer._learn_many``, state donated, compiled
@@ -269,7 +281,17 @@ def _require_learner_call_guards(trainer, state, rolled_width=None) -> dict:
     pixel torso out of its scans (trivially so for a configuration without
     one).
 
-    With ``rolled_width`` (the inner width of a looped stack's MLP) a sixth:
+    For an image observation a sixth, which only a compile can show as well:
+    the sampled frames are prepared once an update and every pass of the
+    conv torso reads its window out of the one result
+    (``models/torsos.py::ConvTorso.prepare``), so an update writes no more
+    than ``_FRAME_RELAYS_AN_UPDATE`` copies, slices, transposes or reshapes of
+    a window's frames or more, and no more bytes than the prepared frames
+    take; the list is reported for every configuration (for a flat
+    observation a window is a few thousand floats and the list says nothing
+    about frames).
+
+    With ``rolled_width`` (the inner width of a looped stack's MLP) a seventh:
     the products of that width lie inside the stack's two scans (over the
     layers, inside over the loop steps), a copy a pass and not one an
     application: a block written out sixteen times compiles sixteen times as
@@ -281,6 +303,7 @@ def _require_learner_call_guards(trainer, state, rolled_width=None) -> dict:
         arena_reads,
         batch_minor_writes,
         capacity_scans,
+        frame_relays,
         loop_convolutions,
         loop_products,
     )
@@ -322,6 +345,22 @@ def _require_learner_call_guards(trainer, state, rolled_width=None) -> dict:
         not in_scans,
         f"the learner call runs an image convolution once a scan step: {in_scans}",
     )
+    # A window of the sampled batch's observations: the shorter of burn-in
+    # and unroll, of every sequence.
+    agent, obs_shape = trainer.agent.config, trainer.env.spec.obs_shape
+    steps = min(w for w in (agent.burnin, agent.unroll) if w)
+    window = trainer.config.batch_size * steps * math.prod(obs_shape)
+    relays = [r for r in frame_relays(hlo, window) if r[3] >= call_loops]
+    if len(obs_shape) == 3:  # an image: the conv torso's frames
+        allowed = int(_FRAME_RELAY_BYTES_A_FRAME_ELEMENT * window / steps
+                      * agent.seq_len)
+        _require(
+            len(relays) <= _FRAME_RELAYS_AN_UPDATE
+            and sum(r[2] for r in relays) <= allowed,
+            f"the learner call re-lays its sampled frames {len(relays)} times "
+            f"an update, {sum(r[2] for r in relays)} bytes (at most "
+            f"{_FRAME_RELAYS_AN_UPDATE}, {allowed}): {relays}",
+        )
     rolled = {}
     if rolled_width is not None:
         products = loop_products(hlo, rolled_width)
@@ -346,6 +385,10 @@ def _require_learner_call_guards(trainer, state, rolled_width=None) -> dict:
         "arena_reads_in_updates": in_updates,
         "loop_convolutions": convolutions,
         "convolutions_in_scans": in_scans,
+        "frame_window_elements": window,
+        "frame_relays_in_updates": len(relays),
+        "frame_relay_bytes_in_updates": sum(r[2] for r in relays),
+        "frame_relays": relays[:16],  # the first of them, as printed
     }
 
 
@@ -407,8 +450,8 @@ def _leg_train(work: str) -> dict:
     inside the jitted phase, the HBM arena at capacity 100,000, the Pallas
     write-back, donated state; then the learner call alone, compiled for the
     whole-arena convert guard, the batch-minor write guard, the
-    capacity-long running sum guard, the padded arena read guard and the
-    convolution-in-a-scan guard, for
+    capacity-long running sum guard, the padded arena read guard, the
+    convolution-in-a-scan guard and the frame re-lay guard, for
     ``walker_r2d2`` and, from shapes, for the whole-sequence cores'
     configurations ``humanoid_sdar_moe`` and ``humanoid_ouro_loop`` (the
     latter also for the rolled-stack guard) and the pixel replay's

@@ -33,7 +33,7 @@ import jax.numpy as jnp
 import optax
 from jax import lax
 
-from r2d2dpg_tpu.models.sequence import sequence_runner
+from r2d2dpg_tpu.models.sequence import sequence_runner, window
 from r2d2dpg_tpu.ops import (
     huber,
     n_step_targets,
@@ -223,14 +223,21 @@ class R2D2DPG:
         # scope(): the stages of utils/profiling.py::LEARN_STAGES, which
         # obs/stages.py reads back from the chip's trace.  ``forward`` wraps
         # the two value_and_grad calls, so that the operations JAX names
-        # ``transpose(...)`` under it read as ``backward``.
+        # ``transpose(...)`` under it read as ``backward``.  ``frames``
+        # (``PREPARE_STAGES``) is what the nets want done once to the whole
+        # batch's observations (pixels: scaled and re-laid for the conv
+        # torso; flat: nothing); every pass below cuts its window out of
+        # that one result.
+        with scope("frames"):
+            batch = self.seq.prepare(batch)
+
         with scope("burn_in"):
             ca_on, ca_tg, cc_on, cc_tg = self.seq.burn_in(state, batch)
 
         with scope("forward"):
             # Training window: [burnin, burnin+U+n) — time-major for the scans.
             w = slice(cfg.burnin, cfg.seq_len)
-            obs_w = _tm(batch.obs[:, w])
+            obs_w = window(batch.obs, w.start, w.stop)
             act_w = _tm(batch.action[:, w])
             reset_w = _tm(batch.reset[:, w])
             rew_w = batch.reward[:, w]  # batch-major [B, U+n]

@@ -337,10 +337,18 @@ class ActorNet(nn.Module):
         y, carry = self.step(self.encode(obs), carry, reset)
         return self.readout(y), carry
 
+    def prepare(self, obs: jnp.ndarray):
+        """What the torso wants done ONCE to the observations of a whole
+        sampled batch ``[B, L, *obs_shape]`` before any pass cuts a window
+        (``models/torsos.py``); it reads no parameter.  ``encode`` takes the
+        windows of what comes back."""
+        return self.torso.prepare(obs)
+
     def encode(self, obs: jnp.ndarray) -> jnp.ndarray:
         """What of a step does not depend on the carry: the torso and the
         core's input projection.  ``obs [..., *obs_shape]``, any leading
-        dimensions: the learner runs it once over ``[T, B]``."""
+        dimensions (or a window of what ``prepare`` made): the learner runs
+        it once over ``[T, B]``."""
         return self.core.project(self.torso(obs))
 
     def step(self, z: jnp.ndarray, carry: Carry, reset: jnp.ndarray):
@@ -410,6 +418,10 @@ class CriticNet(nn.Module):
 
     def _mixed(self, x: jnp.ndarray, action: jnp.ndarray) -> jnp.ndarray:
         return nn.relu(self.mix(jnp.concatenate([x, action.astype(x.dtype)], axis=-1)))
+
+    def prepare(self, obs: jnp.ndarray):
+        """``ActorNet.prepare``: the torsos are one kind."""
+        return self.torso.prepare(obs)
 
     def encode(
         self, obs: jnp.ndarray, action: Optional[jnp.ndarray] = None

@@ -6,6 +6,11 @@ here and nowhere else: ``agents/ddpg.py`` holds one of these objects
 (``sequence_runner`` picks it from the nets it is given) and calls its five
 operations —
 
+- ``prepare``: what the nets want done ONCE an update to the observations of
+  the whole sampled batch before any pass reads a window of them (a conv
+  torso: scaled and re-laid frames, ``models/torsos.py``; else nothing);
+  the module's ``window(obs, start, stop)`` then cuts the steps of a pass,
+  time-major, out of whichever it is handed;
 - ``unroll_actor`` / ``unroll_critic``: one net over time-major inputs from a
   carry; back come the outputs ``[T, B, ...]`` and what the pass left behind;
 - ``unroll_pi_q``: the actor, then the critic on the actor's actions;
@@ -30,6 +35,7 @@ with these operations.
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Dict, Tuple
 
 import jax
@@ -64,6 +70,37 @@ def _unstack2(t: Any) -> Tuple[Any, Any]:
         jax.tree_util.tree_map(lambda x: x[0], t),
         jax.tree_util.tree_map(lambda x: x[1], t),
     )
+
+
+def window(obs, start: int, stop: int):
+    """Steps ``[start, stop)`` of a sampled batch's observations, time-major:
+    of the batch-major array the replay handed over, or of what a net
+    prepared of it, which is no array and is cut by steps
+    (``prepared[start:stop]``)."""
+    if hasattr(obs, "shape"):
+        return time_major(obs[:, start:stop])
+    return obs[start:stop]
+
+
+def _prepare(actor, critic):
+    """``obs [B, L, ...] -> `` what both nets' passes cut their windows from:
+    the nets' own ``prepare`` (one preparation serves both, so they have to
+    make the same of it), the identity for nets that offer none."""
+    if not (hasattr(actor, "prepare") and hasattr(critic, "prepare")):
+        return lambda obs: obs
+
+    def prepare(obs):
+        shape = jax.ShapeDtypeStruct(obs.shape, obs.dtype)
+        a, c = (
+            jax.eval_shape(lambda o: net.apply({}, o, method="prepare"), shape)
+            for net in (actor, critic)
+        )
+        if a != c:
+            raise ValueError(
+                f"actor and critic prepare a batch differently: {a} and {c}")
+        return actor.apply({}, obs, method="prepare")
+
+    return prepare
 
 
 def _parts(net):
@@ -109,6 +146,14 @@ class Stepped:
     def __init__(self, actor, critic, config):
         self.actor, self.critic, self.config = actor, critic, config
         self._actor, self._critic = _parts(actor), _parts(critic)
+        self._prepare = _prepare(actor, critic)
+
+    def prepare(self, batch):
+        """``batch`` with its observations as the nets prepared them, all
+        stored steps at once: ``burn_in`` and the learner's passes cut their
+        windows out of the one result (``window``).  A flat observation comes
+        back as it is."""
+        return dataclasses.replace(batch, obs=self._prepare(batch.obs))
 
     @staticmethod
     def _unroll(parts, params, carry, reset_tm, *inputs_tm):
@@ -192,7 +237,7 @@ class Stepped:
         cc0e = _stack_n(cc0, nq) if cfg.twin_critic else cc0
         if cfg.burnin == 0:
             return ca0, ca0, cc0e, cc0e
-        obs_b = time_major(batch.obs[:, : cfg.burnin])
+        obs_b = window(batch.obs, 0, cfg.burnin)
         act_b = time_major(batch.action[:, : cfg.burnin])
         reset_b = time_major(batch.reset[:, : cfg.burnin])
         ca_on = ca_tg = ca0
@@ -258,6 +303,10 @@ class Whole:
                 "whole-sequence core"
             )
         self.actor, self.critic, self.config = actor, critic, config
+
+    def prepare(self, batch):
+        """Nothing: the nets take whole batch-major sequences as sampled."""
+        return batch
 
     @staticmethod
     def _left(aux):

@@ -445,24 +445,31 @@ class DeviceMonitor:
         learner call (``obs/stages.py``): the whole table goes to
         ``<logdir>/stages.json``, its seconds into ``profile_stop``."""
         from r2d2dpg_tpu.obs.stages import stage_table
-        from r2d2dpg_tpu.utils.profiling import CORE_STAGES, LEARN_STAGES
+        from r2d2dpg_tpu.utils.profiling import (
+            CORE_STAGES,
+            LEARN_STAGES,
+            PREPARE_STAGES,
+        )
 
         found = glob.glob(
             os.path.join(logdir, "plugins", "profile", "*", "*.xplane.pb")
         )
         if not found:
             raise FileNotFoundError(f"no .xplane.pb under {logdir}")
-        # Read with the sequence core's scopes too: where the config's core
-        # has them the table is the wider one (a core scope keeps both of its
-        # passes, ``forward`` / ``backward`` / ``burn_in`` what lies outside
-        # the core); where it has none they read 0 and are left out, and the
-        # table is the learner's own, as ever.
+        # Read with the scopes only some configurations have too: the
+        # once-an-update preparation of a pixel batch, and the sequence
+        # core's (a core scope keeps both of its passes, ``forward`` /
+        # ``backward`` / ``burn_in`` what lies outside the core).  A group
+        # that reads 0 throughout is left out, and the table is the
+        # learner's own, as ever.
+        optional = (PREPARE_STAGES, CORE_STAGES)
         table = stage_table(
-            max(found, key=os.path.getmtime), LEARN_STAGES + CORE_STAGES
+            max(found, key=os.path.getmtime), LEARN_STAGES + sum(optional, ())
         )
-        if not any(table[k] for k in CORE_STAGES):
-            for k in CORE_STAGES:
-                del table[k]
+        for group in optional:
+            if not any(table[k] for k in group):
+                for k in group:
+                    del table[k]
         with open(os.path.join(logdir, "stages.json"), "w") as f:
             json.dump(table, f, indent=1)
         return {

@@ -46,6 +46,17 @@ slice or copy of a ``[capacity, ...]`` value that moves more than a few rows'
 bytes, with the loops around it, and the same leg requires that an update
 makes none.
 
+The learner brings the sampled frames of a pixel batch to the order its first
+convolution reads ONCE an update, for all stored steps, and every pass of the
+torso reads its window as a range of that one array
+(``models/torsos.py::ConvTorso.prepare``, ``models/sequence.py::Stepped``).
+Cut first and prepared by every pass, the same 17.7 MB of frames went through
+eleven copies and slices an update, a third of cheetah's 2.78 ms (PERF.md PR
+35).  ``frame_relays`` lists every ``copy``, ``slice``, ``transpose`` or
+``reshape`` that writes a value of a window's frames or more, with its bytes
+as laid out and the loops around it; the same leg holds the pixel
+configuration's learner call to the few the preparation needs.
+
 ``models/ouro_loop.py`` runs 4 layers 4 times by a scan inside a scan, so that
 the compiled learner call holds one copy of a block a pass and not sixteen
 (its compile is part of every process's set-up).  ``loop_products`` lists the
@@ -250,6 +261,55 @@ def arena_reads(
                                   _laid_out_bytes(m), row, depth.get(name, 0)))
             if int(m["lead"]) == capacity and m["rest"]:
                 stored[m["name"]] = math.prod(_dims(m)[1:]) * _itemsize(m)
+    return found
+
+
+def _written(lines: Dict[str, List[str]]) -> Dict[str, bool]:
+    """Whether the instructions of each computation write their results: not
+    those of a fusion's body reached only from inside another fusion's (a
+    producer fused into its consumer's operand, which the consumer reads
+    through and nobody writes)."""
+    fused_by: Dict[str, List[str]] = {}
+    for name, body in lines.items():
+        for line in body:
+            for m in _CALLED.finditer(line):
+                if m["how"] == "calls":
+                    fused_by.setdefault(m["one"] or "", []).append(name)
+    return {
+        name: any(caller not in fused_by for caller in fused_by.get(name, [name]))
+        for name in lines
+    }
+
+
+_RELAYS = ("copy", "slice", "transpose", "reshape")
+
+
+def frame_relays(hlo_text: str, elements: int) -> List[Tuple[str, str, int, int]]:
+    """``(name, shape with its layout, bytes as laid out, loops around it)``
+    of every ``copy``, ``slice``, ``transpose`` or ``reshape`` in ``hlo_text``
+    whose result holds ``elements`` elements or more and is written to
+    memory, in the order printed.  ``elements`` is a window of a sampled
+    batch's frames (``cheetah_pixels``: 32 sequences x 20 steps x 12,288).
+
+    What the TPU compiler prints under these four names moves bytes and
+    computes nothing (a reshape that moves none is printed as a ``bitcast``).
+    On its own or as part of a fusion that a program's own computation
+    calls, its result is written: a window cut out of the batch, a pass's
+    frames brought to another order.  Inside a fusion called from inside
+    another (a convolution's fusion reading its window as a slice of the
+    prepared frames) nothing is written, and it is not listed."""
+    lines, depth = _computations(hlo_text)
+    written = _written(lines)
+    found = []
+    for name, body in lines.items():
+        if not written[name]:
+            continue
+        for line in body:
+            m = _INSTRUCTION.match(line)
+            if m and m["opcode"] in _RELAYS and math.prod(_dims(m)) >= elements:
+                layout = f"{{{m['order']}{m['tiling']}}}" if m["order"] else ""
+                found.append((m["name"], m["shape"] + layout,
+                              _laid_out_bytes(m), depth.get(name, 0)))
     return found
 
 

@@ -42,8 +42,9 @@ read and 1.6 GB written in every call of 4 updates, 1.88 of 2.79 ms an update
 (PERF.md, PR 23 and PR 25), and no CPU test can see it:
 ``chip_smoke.py``'s train leg guards it (``obs/hlo.py``).  The boundary also
 states where the compiler would choose badly: the device layout of large
-gathered rows is ``sample``'s, batch major-most (``_gather_rows``), not the
-arena's slot-minor order carried over to the batch.
+gathered rows is ``sample``'s, batch and time major-most (``_gather_rows``:
+time first for a row stored as tiles, which is how the learner's conv torso
+takes it), not the arena's slot-minor order carried over to the batch.
 """
 
 from __future__ import annotations
@@ -308,25 +309,40 @@ def _gather_rows(
     buf: jnp.ndarray, indices: jnp.ndarray, row_shape: Tuple[int, ...]
 ) -> jnp.ndarray:
     """``buf[indices]`` in the rows' own shape, with the device layout of
-    large rows stated here: batch major-most, then time, the two longest of
-    the other dimensions minor-most (least padding; pixels: ``[B, L, C, H,
-    W]`` on the device).
+    large rows stated here: batch and time major-most, in the order their
+    consumer wants them, so that a sequence is written once into stretches
+    of its own.
 
     The TPU compiler expands the gather of a large row into a loop of B
     iterations over an accumulator, and left to itself hands the consumer a
     batch minor-most in the batch: B of 128 lanes used, and, gathered from a
     leaf in the rows' own shape, every iteration rewrote the whole buffer to
     fill one lane of each tile (``dynamic-update-slice``, 59 of
-    ``cheetah_pixels``' 70 ms an update; PERF.md, PR 28).  From tiles the
-    loop copies one contiguous row an iteration and the stated layout costs
-    one re-lay of the batch (without it the update read 3.14 ms for 2.82, my
-    chip run, PR 34).  The values are the stored rows', bit for bit; on the
-    CPU the constraint is the identity.  ``chip_smoke.py``'s train leg holds
-    the compiled learner call to both (``obs/hlo.py::batch_minor_writes``,
-    ``arena_reads``)."""
-    rows = buf[indices].reshape(indices.shape[:1] + row_shape)
+    ``cheetah_pixels``' 70 ms an update; PERF.md, PR 28).  A row stored as
+    tiles behind its time axis (``_storage_shape``) is stated as it is
+    stored, TIME major-most: the loop copies a sequence as L runs of a step's
+    tiles, and the learner takes the steps of all sequences as rows of one
+    time-major array without another pass (``models/torsos.py::ConvTorso
+    .prepare``; stated on the rows' own shape, batch or time major-most, the
+    loop writes batch-major and that is one more copy of the batch, 0.016 ms
+    an update: PERF.md, PR 35); a consumer that takes the rows' own shape
+    pays the one re-lay it paid before.  A large row kept in its own shape
+    is stated batch major-most, then time, the two longest of the other
+    dimensions minor-most (least padding).  The values are the stored rows',
+    bit for bit; on the CPU the constraint is the identity.
+    ``chip_smoke.py``'s train leg holds the compiled learner call to all of
+    it (``obs/hlo.py::batch_minor_writes``, ``arena_reads``,
+    ``frame_relays``)."""
+    rows = buf[indices]
+    own = indices.shape[:1] + row_shape
     if math.prod(row_shape) < _LOOPED_GATHER_ROW_ELEMENTS:
-        return rows
+        return rows.reshape(own)
+    if rows.shape != own and rows.shape[1] == row_shape[0]:
+        stored = with_layout_constraint(
+            rows, Layout(major_to_minor=(1, 0, *range(2, rows.ndim)))
+        )
+        return stored.reshape(own)
+    rows = rows.reshape(own)
     by_length = sorted(range(2, rows.ndim), key=lambda d: rows.shape[d])
     return with_layout_constraint(
         rows, Layout(major_to_minor=(0, 1, *by_length))
@@ -688,7 +704,7 @@ class ReplayArena:
 
     def gather(self, state: ArenaState, indices: jnp.ndarray) -> SequenceBatch:
         """The stored rows of the slots ``indices`` in the rows' own shapes
-        and dtypes, large rows batch-major on the device (``_gather_rows``):
+        and dtypes, the layout of large rows stated (``_gather_rows``):
         what ``sample`` ends with, before ``_pin_storage_dtypes``."""
         return _gather(state.data, indices, self._row_shapes)
 

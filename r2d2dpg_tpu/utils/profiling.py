@@ -69,6 +69,19 @@ LEARN_STAGES = (
 )
 
 
+# The scope of what ``learner_step`` does ONCE an update to the whole sampled
+# batch's observations before any pass cuts a window (``agent.seq.prepare``:
+# a conv torso's frames scaled and re-laid, ``models/torsos.py``; for a flat
+# observation nothing, and then no operation carries the name).  Read with
+# ``stage_table(path, LEARN_STAGES + PREPARE_STAGES)``, as ``--profile-window``
+# does; read with ``LEARN_STAGES`` alone its time is ``rest`` (and leads
+# ``rest_paths``).  It is not one of ``LEARN_STAGES`` yet because the
+# benchmark's ``learn_stage_ms.*`` metric files mirror that table's keys one
+# for one (``tests/chipbench/test_chipbench_stages.py``): the name joins the
+# tuple in the ``benchmark`` PR that brings ``learn_stage_ms.frames``.
+PREPARE_STAGES = ("frames",)
+
+
 # Scopes INSIDE a whole-sequence core (``models/sdar_moe.py``,
 # ``models/ouro_loop.py``), under whichever learner stage runs it: attention
 # (norms, projections, RoPE, scores, output projection); the sdar core's

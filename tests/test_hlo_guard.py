@@ -15,6 +15,7 @@ from r2d2dpg_tpu.obs.hlo import (
     arena_reads,
     batch_minor_writes,
     capacity_scans,
+    frame_relays,
     loop_convolutions,
     loop_products,
 )
@@ -490,6 +491,132 @@ def test_arena_reads_names_every_slice_or_copy_of_many_rows_bytes(hlo, capacity,
         w[0] for w in want if w[4] >= 1]
 
 
+# ``cheetah_pixels``' learner call compiled for a described v5e, cut to what
+# moves the sampled frames between the gather and ``Conv_0``.  PR 35's parent
+# cut the two windows out of the batch first and every pass of the torso
+# converted and re-laid its own: eleven operations an update over the same
+# 17.7 MB of frames (one of them, ``slice.766``, the first step of a fusion
+# that writes its 20 steps as bfloat16).
+PER_PASS_RELAYS = """\
+%fused_computation.409.clone.clone.clone (param_0.4264: u8[25,32,64,64,3]) -> bf16[20,32,64,64,3] {
+  %param_0.4264 = u8[25,32,64,64,3]{3,2,4,0,1:T(8,128)(4,1)S(1)} parameter(0)
+  %slice.766 = u8[20,32,64,64,3]{3,2,4,0,1:T(8,128)(4,1)} slice(%param_0.4264), slice={[0:20], [0:32], [0:64], [0:64], [0:3]}
+  %convert.1500 = f32[20,32,64,64,3]{3,2,4,0,1:T(8,128)} convert(%slice.766)
+  ROOT %convert.1501 = bf16[20,32,64,64,3]{3,2,4,0,1:T(8,128)(2,1)S(1)} convert(%multiply.700)
+}
+
+%region_0.231 (arg_tuple.4: (s32[], u8[8000,45,3,32,128])) -> (s32[], u8[8000,45,3,32,128]) {
+  %copy.548 = u8[32,45,3,32,128]{1,0,4,3,2:T(8,128)(4,1)S(1)} copy(%get-tuple-element.17708), metadata={op_name="jit(_learn_many)/while/body/closed_call/replay_sample/gather"}
+  %bitcast.1644 = u8[32,20,64,64,3]{1,0,4,3,2:T(8,128)(4,1)S(1)} bitcast(%slice.772)
+  %copy.549 = u8[32,20,64,64,3]{3,2,4,1,0:T(8,128)(4,1)S(1)} copy(%bitcast.1644)
+  %copy.550 = bf16[20,32,64,64,3]{3,2,4,1,0:T(8,128)(2,1)S(1)} copy(%multiply_bitcast_fusion.3), metadata={op_name="jit(_learn_many)/while/body/closed_call/burn_in/vmap(ActorNet.encode)/torso/div"}
+  %copy.551 = bf16[640,64,64,3]{0,3,2,1:T(4,128)(2,1)S(1)} copy(%bitcast.1650), metadata={op_name="jit(_learn_many)/while/body/closed_call/burn_in/vmap(ActorNet.encode)/torso/Conv_0/reshape"}
+  %slice.784 = u8[32,25,64,64,3]{1,0,4,3,2:T(8,128)(4,1)S(1)} slice(%bitcast.1645), slice={[0:32], [20:45], [0:64], [0:64], [0:3]}
+  %copy.558 = u8[32,25,64,64,3]{3,2,4,1,0:T(8,128)(4,1)S(1)} copy(%slice.784)
+  %copy.565 = bf16[25,32,64,64,3]{3,2,4,1,0:T(8,128)(2,1)S(1)} copy(%convert_multiply_fusion.7)
+  %copy.566 = bf16[800,64,64,3]{0,3,2,1:T(4,128)(2,1)S(1)} copy(%bitcast.1646)
+  %convert_multiply_fusion.6 = bf16[20,32,64,64,3]{3,2,4,0,1:T(8,128)(2,1)S(1)} fusion(%bitcast.1647), kind=kLoop, calls=%fused_computation.409.clone.clone.clone
+  %copy.559 = bf16[20,32,64,64,3]{3,2,4,1,0:T(8,128)(2,1)S(1)} copy(%convert_multiply_fusion.6)
+  %copy.560 = bf16[640,64,64,3]{0,3,2,1:T(4,128)(2,1)S(1)} copy(%bitcast.1651)
+  %copy.1832 = s32[]{:T(128)} copy(%constant.12)
+  %bitcast.1650 = bf16[640,64,64,3]{3,2,4,0:T(8,128)(2,1)S(1)} bitcast(%copy.550)
+}
+
+ENTRY %main.243 (arena_data_obs.1: u8[8000,45,3,32,128]) -> f32[] {
+  %copy.416 = f32[8000,45,6]{1,2,0:T(8,128)} copy(%arena_data_action.1)
+  %while.898 = (s32[]{:T(128)}, u8[8000,45,3,32,128]{4,3,2,1,0:T(8,128)(4,1)}) while(%tuple.1036), condition=%region_188.232, body=%region_0.231
+}
+"""
+
+
+# The same call from PR 35 on: the torso prepares all 45 steps once (the
+# sampled bytes, time-major as the gather's loop wrote them, transposed into
+# ``[96, 128, L·B]`` and converted in one pass, then the channels padded into
+# ``Conv_0``'s order) and every convolution reads its window as a slice fused
+# into its own fusion, which writes nothing.
+PREPARED_ONCE = """\
+%fused_computation.415.clone.clone (param_0.3887: bf16[64,64,3,1440]) -> bf16[64,64,3,640] {
+  %param_0.3887 = bf16[64,64,3,1440]{3,2,1,0:T(4,128)(2,1)S(1)} parameter(0)
+  ROOT %slice.789 = bf16[64,64,3,640]{3,2,1,0:T(4,128)(2,1)} slice(%param_0.3887), slice={[0:64], [0:64], [0:3], [0:640]}
+}
+
+%fused_computation.414.clone.clone (param_0.3888: bf16[8,8,3,64], param_1.5072: bf16[64,64,3,1440]) -> f32[640,15,15,64] {
+  %param_1.5072 = bf16[64,64,3,1440]{3,2,1,0:T(4,128)(2,1)S(1)} parameter(1)
+  %fusion.910 = bf16[64,64,3,640]{3,2,1,0:T(4,128)(2,1)} fusion(%param_1.5072), kind=kLoop, calls=%fused_computation.415.clone.clone
+  %param_0.3888 = bf16[8,8,3,64]{3,2,1,0:T(4,128)(2,1)S(1)} parameter(0)
+  ROOT %conv_general_dilated.346 = f32[640,15,15,64]{0,3,2,1:T(8,128)S(1)} convolution(%fusion.910, %param_0.3888), window={size=8x8 stride=4x4}, dim_labels=01fb_01io->b01f
+}
+
+%region_0.231 (arg_tuple.4: (s32[], u8[8000,45,3,32,128])) -> (s32[], u8[8000,45,3,32,128]) {
+  %get-tuple-element.16887 = u8[32,45,3,32,128]{4,3,2,0,1:T(8,128)(4,1)S(1)} get-tuple-element(%while.828), index=3
+  %bitcast.1259 = u8[96,128,1440]{1,0,2:T(8,128)(4,1)S(1)} bitcast(%get-tuple-element.16887)
+  %copy.434 = bf16[96,128,1440]{2,1,0:T(8,128)(2,1)S(1)} copy(%multiply_bitcast_fusion.2), metadata={op_name="jit(_learn_many)/while/body/closed_call/frames/div"}
+  %reshape.1037 = bf16[64,64,3,1440]{3,2,1,0:T(4,128)(2,1)S(1)} reshape(%copy.434), metadata={op_name="jit(_learn_many)/while/body/closed_call/frames/div"}
+  %fusion.967 = f32[640,15,15,64]{0,3,2,1:T(8,128)S(1)} fusion(%reshape.1036, %reshape.1037), kind=kOutput, calls=%fused_computation.414.clone.clone
+  %reshape.1036 = bf16[8,8,3,64]{3,2,1,0:T(4,128)(2,1)S(1)} reshape(%copy.433)
+}
+
+ENTRY %main.243 (arena_data_obs.1: u8[8000,45,3,32,128]) -> f32[] {
+  %copy.399 = f32[8000,45,6]{1,2,0:T(8,128)} copy(%arena_data_action.1)
+  %while.898 = (s32[]{:T(128)}, u8[8000,45,3,32,128]{4,3,2,1,0:T(8,128)(4,1)}) while(%tuple.1036), condition=%region_188.232, body=%region_0.231
+}
+"""
+
+WINDOW = 32 * 20 * 64 * 64 * 3  # the least window of the cell's batch, in elements
+# What the change reaches (``chip_smoke.py`` holds ``learner_call_pixels`` to it).
+PREPARED_RELAYS = [
+    ("copy.434", "bf16[96,128,1440]{2,1,0:T(8,128)(2,1)S(1)}", 37748736, 1),
+    ("reshape.1037", "bf16[64,64,3,1440]{3,2,1,0:T(4,128)(2,1)S(1)}", 50331648, 1),
+]
+
+
+@pytest.mark.parametrize(
+    "hlo, elements, want",
+    [
+        (PER_PASS_RELAYS, WINDOW, [
+            ("slice.766", "u8[20,32,64,64,3]{3,2,4,0,1:T(8,128)(4,1)}", 15728640, 1),
+            # Batch and time minor-most: 45 steps padded to 128 lanes.
+            ("copy.548", "u8[32,45,3,32,128]{1,0,4,3,2:T(8,128)(4,1)S(1)}", 50331648, 1),
+            ("copy.549", "u8[32,20,64,64,3]{3,2,4,1,0:T(8,128)(4,1)S(1)}", 15728640, 1),
+            ("copy.550", "bf16[20,32,64,64,3]{3,2,4,1,0:T(8,128)(2,1)S(1)}", 31457280, 1),
+            ("copy.551", "bf16[640,64,64,3]{0,3,2,1:T(4,128)(2,1)S(1)}", 20971520, 1),
+            ("slice.784", "u8[32,25,64,64,3]{1,0,4,3,2:T(8,128)(4,1)S(1)}", 50331648, 1),
+            ("copy.558", "u8[32,25,64,64,3]{3,2,4,1,0:T(8,128)(4,1)S(1)}", 19660800, 1),
+            ("copy.565", "bf16[25,32,64,64,3]{3,2,4,1,0:T(8,128)(2,1)S(1)}", 39321600, 1),
+            ("copy.566", "bf16[800,64,64,3]{0,3,2,1:T(4,128)(2,1)S(1)}", 29360128, 1),
+            ("copy.559", "bf16[20,32,64,64,3]{3,2,4,1,0:T(8,128)(2,1)S(1)}", 31457280, 1),
+            ("copy.560", "bf16[640,64,64,3]{0,3,2,1:T(4,128)(2,1)S(1)}", 20971520, 1),
+        ]),
+        (PREPARED_ONCE, WINDOW, PREPARED_RELAYS),
+        # Counted by elements: of the 25-step window or more, five of the eleven.
+        (PER_PASS_RELAYS, 32 * 25 * 64 * 64 * 3, [
+            ("copy.548", "u8[32,45,3,32,128]{1,0,4,3,2:T(8,128)(4,1)S(1)}", 50331648, 1),
+            ("slice.784", "u8[32,25,64,64,3]{1,0,4,3,2:T(8,128)(4,1)S(1)}", 50331648, 1),
+            ("copy.558", "u8[32,25,64,64,3]{3,2,4,1,0:T(8,128)(4,1)S(1)}", 19660800, 1),
+            ("copy.565", "bf16[25,32,64,64,3]{3,2,4,1,0:T(8,128)(2,1)S(1)}", 39321600, 1),
+            ("copy.566", "bf16[800,64,64,3]{0,3,2,1:T(4,128)(2,1)S(1)}", 29360128, 1),
+        ]),
+        # The small leaf re-laid once a call is no window of frames; with a
+        # threshold under it, it is listed at depth 0.
+        (PREPARED_ONCE, 8000 * 45 * 6, PREPARED_RELAYS + [
+            # 45 steps padded to 128 lanes, 6 values to 8 sublanes.
+            ("copy.399", "f32[8000,45,6]{1,2,0:T(8,128)}", 8000 * 128 * 8 * 4, 0),
+        ]),
+        ("", WINDOW, []),
+    ],
+    ids=["parent_eleven", "prepared_once", "larger_window", "smaller_threshold",
+         "empty"],
+)
+def test_frame_relays_names_every_written_copy_slice_or_reshape_of_a_window(
+    hlo, elements, want
+):
+    assert frame_relays(hlo, elements) == want
+    # 325 MB laid out an update on the parent, 88 MB from PR 35 on.
+    if elements == WINDOW and hlo:
+        assert sum(r[2] for r in frame_relays(hlo, elements)) == (
+            325320704 if hlo is PER_PASS_RELAYS else 88080384)
+
+
 @pytest.fixture(scope="module")
 def one_chip():
     """A described v5e chip's sharding; skips where the TPU compiler cannot
@@ -597,3 +724,48 @@ def test_sample_compiled_for_v5e_reads_rows_as_stored_into_a_batch_major_buffer(
     assert f"u8[{capacity},{L},3,32,128]{{4,3,2,1,0:" in hlo  # slot-major tiles
     assert [r for r in arena_reads(hlo, capacity) if r[1].startswith("u8[")] == []
     assert batch_minor_writes(hlo, B) == []
+
+
+def test_learner_call_compiled_for_v5e_prepares_its_frames_once(
+    one_chip, no_compile_cache, monkeypatch
+):
+    """``cheetah_pixels``' learner call from shapes (batch 32, 45 steps of
+    64 x 64 x 3 bytes, 2 updates a call), compiled for a described v5e: an
+    update writes two re-lays of a window's frames or more, the prepared
+    frames' two passes over all 45 steps (88 MB as laid out where the
+    compiler keeps them as bfloat16, as the chip's does; the parent's eleven
+    wrote 325; under this suite's ``--xla_backend_optimization_level=0`` they
+    stay float32, twice that), and every convolution reads the one array
+    they leave.  Nothing runs: a compile says nothing about results or
+    times."""
+    import jax
+
+    import chip_smoke
+
+    # ``priority_scatter`` picks its branch from the backend it runs on.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    trainer, state = chip_smoke._learner_call_from_shapes(
+        "cheetah_pixels", (64, 64, 3), "uint8", 6)
+    train, arena, rng = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        (state.train, state.arena, state.rng))
+    hlo = jax.jit(trainer._learn_many, donate_argnums=(0, 1)).trace(
+        train, arena, rng).lower(lowering_platforms=("tpu",)).compile().as_text()
+
+    relays = frame_relays(hlo, WINDOW)
+    itemsize = {"bf16": 2, "f32": 4}[relays[0][1].split("[")[0]]
+    # 1,440 frames in runs of 128: 1,536; three channels in tiles of four.
+    assert [(r[1].split("[")[1].split("]")[0], r[2], r[3]) for r in relays] == [
+        ("96,128,1440", 96 * 128 * 1536 * itemsize, 1),
+        ("64,64,3,1440", 64 * 64 * 4 * 1536 * itemsize, 1)]
+    assert [r[0].split(".")[0] for r in relays] == ["copy", "reshape"]
+    # The gather's loop writes the sampled bytes time-major itself, and all
+    # six passes' first convolutions take the prepared frames as an operand.
+    assert "u8[32,45,3,32,128]{4,3,2,0,1:" in hlo
+    readers = [line for line in hlo.splitlines()
+               if " fusion(" in line and f"%{relays[1][0]}" in line.split(" fusion(")[1]]
+    assert len(readers) >= 6
+    assert arena_reads(hlo, trainer.arena.capacity, rows=4) == [
+        r for r in arena_reads(hlo, trainer.arena.capacity) if r[4] == 0]
+    assert batch_minor_writes(hlo, 32) == [] and [
+        c for c in loop_convolutions(hlo) if c[3] > 1] == []

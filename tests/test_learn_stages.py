@@ -9,7 +9,9 @@ import jax
 import pytest
 
 from r2d2dpg_tpu.obs.stages import BACKWARD, REST, UNSCOPED, stage_of, table_keys
-from r2d2dpg_tpu.utils.profiling import LEARN_STAGES
+from r2d2dpg_tpu.utils.profiling import LEARN_STAGES, PREPARE_STAGES
+
+WITH_FRAMES = LEARN_STAGES + PREPARE_STAGES
 
 
 @pytest.fixture(scope="module")
@@ -38,6 +40,9 @@ def op_names():
 def test_every_stage_is_on_a_path_of_the_compiled_learner_call(op_names, prefetch):
     found = {stage_of(p) for p in op_names[prefetch]}
     assert set(LEARN_STAGES) | {BACKWARD} <= found
+    # ``frames`` holds nothing here: for a flat observation the preparation
+    # is the identity (the pixel case is below).
+    assert "frames" not in {stage_of(p, WITH_FRAMES) for p in op_names[prefetch]}
     # The prefetched branch samples before the loop and inside it.
     sample = [p for p in op_names[prefetch] if stage_of(p) == "replay_sample"]
     assert any("/while/body/" in p.split("replay_sample")[0] for p in sample)
@@ -57,7 +62,45 @@ def test_forward_is_differentiated_and_burn_in_is_not(op_names):
     assert any(p.endswith("/burn_in/transpose") for p in burn_in)
 
 
+def test_frames_is_a_stage_of_a_pixel_update_and_holds_the_preparation():
+    """With a conv torso ``learner_step`` prepares the sampled frames once,
+    under ``frames``: the conversion and the re-lay are on its paths, and no
+    pass converts pixels again under ``burn_in`` or ``forward``."""
+    import jax.numpy as jnp
+
+    from r2d2dpg_tpu.agents.ddpg import AgentConfig, R2D2DPG
+    from r2d2dpg_tpu.models.actor_critic import ActorNet, CriticNet
+    from r2d2dpg_tpu.replay.arena import SequenceBatch
+
+    agent = R2D2DPG(
+        ActorNet(action_dim=2, hidden=8, pixels=True),
+        CriticNet(hidden=8, pixels=True),
+        AgentConfig(burnin=2, unroll=2, n_step=1),
+    )
+    B, L = 2, agent.config.seq_len
+    obs = jnp.zeros((B, L, 36, 36, 3), jnp.uint8)
+    carry = (jnp.zeros((B, 8)), jnp.zeros((B, 8)))
+    batch = SequenceBatch(
+        obs=obs, action=jnp.zeros((B, L, 2)), reward=jnp.zeros((B, L)),
+        discount=jnp.ones((B, L)), reset=jnp.zeros((B, L)),
+        carries={"actor": carry, "critic": carry})
+    state = agent.init(jax.random.PRNGKey(0), obs[:, 0], batch.action[:, 0])
+    text = jax.jit(agent.learner_step).lower(
+        state, batch, jnp.ones(B)).compile().as_text()
+    paths = set(re.findall(r'op_name="([^"]*)"', text))
+    frames = [p for p in paths if stage_of(p, WITH_FRAMES) == "frames"]
+    assert any(p.endswith("/div") for p in frames)
+    assert any(p.endswith("/transpose") for p in frames)
+    assert not [p for p in paths if p.endswith("/div") and "/torso/" in p]
+    assert {"burn_in", "forward", BACKWARD, "optimizer"} <= {
+        stage_of(p, WITH_FRAMES) for p in paths}
+    # Read with the learner's five alone, the preparation is ``rest``.
+    assert {stage_of(p) for p in frames} == {REST}
+
+
 @pytest.mark.parametrize("path, stage", [
+    ("jit(timed)/while/body/closed_call/frames/div", REST),
+    ("jit(timed)/while/body/closed_call/frames/optimization_barrier", REST),
     ("jit(timed)/while/body/closed_call/forward/jvp()/while/body/dot_general", "forward"),
     ("jit(timed)/while/body/closed_call/forward/transpose(jvp())/while/body/dot_general", BACKWARD),
     ("jit(f)/learn/transpose(jvp(forward))/while/body/closed_call/mul", BACKWARD),
@@ -79,6 +122,10 @@ def test_stage_of_a_path(path, stage):
 
 def test_table_keys_are_the_stages_and_what_is_derived():
     assert table_keys() == LEARN_STAGES + (BACKWARD, UNSCOPED, REST)
+    # The once-an-update preparation (PR 35) is a key of the wider table only.
+    assert "frames" not in table_keys() and table_keys(WITH_FRAMES) == (
+        WITH_FRAMES + (BACKWARD, UNSCOPED, REST))
+    assert stage_of("jit(timed)/while/body/closed_call/frames/div", WITH_FRAMES) == "frames"
     assert table_keys(("learn", "alpha")) == ("learn", "alpha", UNSCOPED, REST)
     assert stage_of("jit(f)/transpose(jvp(learn))/mul", ("learn",)) == "learn"
 
