@@ -224,7 +224,7 @@ class R2D2DPG:
         # obs/stages.py reads back from the chip's trace.  ``forward`` wraps
         # the two value_and_grad calls, so that the operations JAX names
         # ``transpose(...)`` under it read as ``backward``.  ``frames``
-        # (``PREPARE_STAGES``) is what the nets want done once to the whole
+        # (``SIDE_STAGES``) is what the nets want done once to the whole
         # batch's observations (pixels: scaled and re-laid for the conv
         # torso; flat: nothing); every pass below cuts its window out of
         # that one result.
@@ -357,26 +357,30 @@ class R2D2DPG:
                 step=state.step + 1,
             )
         priorities = sequence_priority(td, eta=cfg.eta)
-        metrics = {
-            "critic_loss": critic_loss,
-            "actor_loss": actor_loss,
-            "q_mean": q_pred.mean(),
-            "td_abs_mean": jnp.abs(td).mean(),
-            "target_mean": y.mean(),
-            # Divergence-watchdog inputs (obs/watchdog.py): global norms of
-            # this step's gradients and the updated params, computed
-            # in-graph and fetched with the SAME batched device_get as the
-            # losses on the log cadence — no extra host syncs.
-            "grad_norm": optax.global_norm((actor_grads, critic_grads)),
-            "param_norm": optax.global_norm((actor_params, critic_params)),
-        }
-        if cfg.twin_critic:
-            metrics["q_spread"] = q_spread  # |Q1-Q2|: overestimation proxy
-        # What the passes left behind (a core's counters; nothing for a scan).
-        metrics.update(self.seq.metrics(
-            burn=(ca_on, ca_tg, cc_on, cc_tg),
-            target=left_tg, critic=left_q, pi=left_pi,
-        ))
+        # The update's in-graph counters, under a scope of their own
+        # (``SIDE_STAGES``): what they cost on the device is read back as
+        # ``scopes["diagnostics"]`` of the stage table.
+        with scope("diagnostics"):
+            metrics = {
+                "critic_loss": critic_loss,
+                "actor_loss": actor_loss,
+                "q_mean": q_pred.mean(),
+                "td_abs_mean": jnp.abs(td).mean(),
+                "target_mean": y.mean(),
+                # Divergence-watchdog inputs (obs/watchdog.py): global norms of
+                # this step's gradients and the updated params, computed
+                # in-graph and fetched with the SAME batched device_get as the
+                # losses on the log cadence — no extra host syncs.
+                "grad_norm": optax.global_norm((actor_grads, critic_grads)),
+                "param_norm": optax.global_norm((actor_params, critic_params)),
+            }
+            if cfg.twin_critic:
+                metrics["q_spread"] = q_spread  # |Q1-Q2|: overestimation proxy
+            # What the passes left behind (a core's counters; nothing for a scan).
+            metrics.update(self.seq.metrics(
+                burn=(ca_on, ca_tg, cc_on, cc_tg),
+                target=left_tg, critic=left_q, pi=left_pi,
+            ))
         return new_state, priorities, metrics
 
     # ------------------------------------------------------- initial priority

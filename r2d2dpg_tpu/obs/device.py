@@ -40,8 +40,10 @@ the capture is findable from the run's own evidence, not tribal memory.
 When the window closes the capture is reduced to device seconds by stage
 of the learner call (``obs/stages.py::stage_table``: the
 ``utils/profiling.py::LEARN_STAGES`` scopes as the chip's trace carries
-them), written to ``<logdir>/profile_window/stages.json`` and carried by
-the ``profile_stop`` event.
+them; its entry ``scopes`` has every scope of the program by pass, and
+``truncated`` says whether the capture lost the tail of its device events),
+written to ``<logdir>/profile_window/stages.json`` and carried by the
+``profile_stop`` event.
 
 Lifecycle: ``install()`` registers the (idempotent) listener;
 ``begin_run()`` opens a run window (baselines for ``run_stats()``, steady
@@ -58,7 +60,7 @@ import json
 import os
 import threading
 import time
-from typing import Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 from r2d2dpg_tpu.obs.flight import flight_event
 from r2d2dpg_tpu.obs.registry import Registry, get_registry
@@ -440,40 +442,30 @@ class DeviceMonitor:
         )
 
     @staticmethod
-    def _reduce_capture(logdir: str) -> Dict[str, float]:
+    def _reduce_capture(logdir: str) -> Dict[str, Any]:
         """The capture just closed, as device seconds by stage of the
         learner call (``obs/stages.py``): the whole table goes to
-        ``<logdir>/stages.json``, its seconds into ``profile_stop``."""
+        ``<logdir>/stages.json``, its seconds (and ``truncated``: the
+        capture lost the tail of its device events) into ``profile_stop``."""
         from r2d2dpg_tpu.obs.stages import stage_table
-        from r2d2dpg_tpu.utils.profiling import (
-            CORE_STAGES,
-            LEARN_STAGES,
-            PREPARE_STAGES,
-        )
 
         found = glob.glob(
             os.path.join(logdir, "plugins", "profile", "*", "*.xplane.pb")
         )
         if not found:
             raise FileNotFoundError(f"no .xplane.pb under {logdir}")
-        # Read with the scopes only some configurations have too: the
-        # once-an-update preparation of a pixel batch, and the sequence
-        # core's (a core scope keeps both of its passes, ``forward`` /
-        # ``backward`` / ``burn_in`` what lies outside the core).  A group
-        # that reads 0 throughout is left out, and the table is the
-        # learner's own, as ever.
-        optional = (PREPARE_STAGES, CORE_STAGES)
-        table = stage_table(
-            max(found, key=os.path.getmtime), LEARN_STAGES + sum(optional, ())
-        )
-        for group in optional:
-            if not any(table[k] for k in group):
-                for k in group:
-                    del table[k]
+        # The learner's own table; every other name the program has (the
+        # once-an-update scopes, a sequence core's, each by pass) is a row
+        # of its entry ``scopes``, less the rows that read 0 throughout.
+        table = stage_table(max(found, key=os.path.getmtime))
+        table["scopes"] = {
+            row: passes for row, passes in table["scopes"].items()
+            if passes["all"]
+        }
         with open(os.path.join(logdir, "stages.json"), "w") as f:
             json.dump(table, f, indent=1)
         return {
-            k: round(v, 9)
+            k: v if isinstance(v, bool) else round(v, 9)
             for k, v in table.items()
             if isinstance(v, (int, float))
         }

@@ -24,6 +24,18 @@ and nothing in the next, PERF.md PR 30).
 ``--profile-window`` capture, and the benchmark's ``learn_stage_ms.*`` metrics
 read it (``chipbench/reducers/stage_ms.py``).  The file is read with a small
 wire-format reader of the few fields needed: no ``tensorflow``, no ``protobuf``.
+
+Beside the stage keys the table holds ``scopes``: the same events folded once
+more in the same pass by EVERY name the program has (``ALL_SCOPES``, the
+innermost wins) and by pass.  JAX writes the pass into the path: a ``grad``
+through ``jax.checkpoint`` gives ``.../jvp(forward)/core_mlp/tanh`` (forward),
+``.../transpose(jvp(forward))/.../checkpoint/core_mlp/mul`` (backward) and
+``.../checkpoint/rematted_computation/core_mlp/dot_general`` (the forward pass
+recomputed).  (A clone the compiler's own rematerialisation made,
+``fusion.12.remat2``, goes by its path like any other: the original may or
+may not run as well.)  And it holds ``programs`` / ``truncated``:
+what the capture holds of the stretch it was taken over, counted from the
+device's own events (the line ``XLA Modules``: one event an execution).
 """
 
 from __future__ import annotations
@@ -31,7 +43,7 @@ from __future__ import annotations
 import re
 from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
-from r2d2dpg_tpu.utils.profiling import LEARN_STAGES
+from r2d2dpg_tpu.utils.profiling import CORE_STAGES, LEARN_STAGES, SIDE_STAGES
 
 # Derived keys of the table, beside the stage names themselves.
 BACKWARD = "backward"  # ``forward`` under a ``transpose(...)``
@@ -39,9 +51,35 @@ REST = "rest"  # a path with none of the stage names on it
 UNSCOPED = "unscoped"  # no path at all
 DIFFERENTIATED = "forward"  # the stage whose transposes read as BACKWARD
 
+# ``scopes``: every name the program has, and the rows beside them.
+ALL_SCOPES = LEARN_STAGES + SIDE_STAGES + CORE_STAGES
+LOOPS = "loops"  # control flow's own time under no scope (``_CONTROL``)
+SCOPE_ROWS = ALL_SCOPES + (LOOPS, UNSCOPED, REST)
+RECOMPUTED = "recomputed"  # the forward pass again, for the backward pass
+PASSES = ("forward", RECOMPUTED, BACKWARD)
+ALL = "all"  # a row's passes summed
+
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 METADATA_PLANE = "/host:metadata"
+HOST_PLANE = "/host:CPU"
 OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"  # one event an execution of a program
+# The host spans a capture is taken over, and whether the span closes on a
+# drained device: the benchmark's window waits for its last result; the
+# trainer's train phases (``--profile-window``) are dispatched ahead of the
+# device, so there the capture may well stop inside an execution.
+CAPTURED_SPANS = {"chipbench/window": True, "trainer/train_phase": False}
+# The device line may end this long before the host's span does and still
+# hold all of it: the host's clock runs ahead of the device's and the span
+# closes after the last result is back (0.65-2.64 ms apart at the end of
+# twenty-one whole captures of the four benchmark cells, PERF.md PR 36; a
+# capture that overran the profiler's buffer ended 837 ms early).
+CLOCK_SLACK_NS = 5_000_000
+# An execution's event outlasts its last operation's (by 6-7.5 us in those
+# captures): it is whole if the device line ends within a hundredth of the
+# execution's own length of it.
+_WHOLE_SHARE = 0.01
+_ROUNDING_NS = 10  # the file keeps picoseconds; ends are whole nanoseconds
 # The device only waits for the host in these (the halves of a host
 # callback's transfers): not busy time, as the benchmark's idle share has it.
 HOST_WAIT = re.compile(r"^(recv|send)(-done)?(\.\d+)?$")
@@ -49,12 +87,40 @@ HOST_WAIT = re.compile(r"^(recv|send)(-done)?(\.\d+)?$")
 # one: it names a function, not a scope.
 _WRAPPER = re.compile(r"^(?:jvp|transpose|vmap)\((.*)\)$")
 _TRANSPOSED = "transpose("
+# Last segments that name a control-flow construct and no operation of the
+# program: the ``while`` a scan becomes, its ``body`` and ``cond``, the
+# ``closed_call`` a scan's step is.  What the compiler inserts inside a loop
+# or at a call's boundary (copies, the rounding of a matmul's operand) it
+# names by the construct: ``jit(f)/while``, ``jit(f)/while/body/closed_call``.
+_CONTROL = re.compile(r"^(while|body|cond|closed_call|call|branch_\d+_fun)$")
+_REMATTED = "rematted_computation"  # ``jax.checkpoint``'s recomputation
 
 
 def table_keys(stages: Sequence[str] = LEARN_STAGES) -> Tuple[str, ...]:
     """Every key of ``stage_table``'s table that holds seconds of a stage."""
     derived = (BACKWARD,) if DIFFERENTIATED in stages else ()
     return tuple(stages) + derived + (UNSCOPED, REST)
+
+
+def _bare(segment: str) -> str:
+    """A path segment with its transform wrappers stripped."""
+    while True:
+        m = _WRAPPER.match(segment)
+        if m is None:
+            return segment
+        segment = m.group(1)
+
+
+def _innermost(
+    segments: Sequence[str], names: Sequence[str]
+) -> Tuple[int, Optional[str]]:
+    """(index, name) of the innermost segment that, with its transform
+    wrappers stripped, is one of ``names``; ``(-1, None)`` without one."""
+    for i in range(len(segments) - 1, -1, -1):
+        name = _bare(segments[i])
+        if name in names:
+            return i, name
+    return -1, None
 
 
 def stage_of(path: Optional[str], stages: Sequence[str] = LEARN_STAGES) -> str:
@@ -64,20 +130,39 @@ def stage_of(path: Optional[str], stages: Sequence[str] = LEARN_STAGES) -> str:
     if not path:
         return UNSCOPED
     segments = path.split("/")
-    for i in range(len(segments) - 1, -1, -1):
-        name = segments[i]
-        while True:
-            m = _WRAPPER.match(name)
-            if m is None:
-                break
-            name = m.group(1)
-        if name in stages:
-            if name == DIFFERENTIATED and any(
-                _TRANSPOSED in s for s in segments[i:]
-            ):
-                return BACKWARD
-            return name
-    return REST
+    i, name = _innermost(segments, stages)
+    if name is None:
+        return REST
+    if name == DIFFERENTIATED and any(_TRANSPOSED in s for s in segments[i:]):
+        return BACKWARD
+    return name
+
+
+def scope_of(path: Optional[str]) -> str:
+    """The row of ``scopes`` a path belongs to: the innermost of
+    ``ALL_SCOPES`` on it, whatever its pass; with none on it ``loops`` if the
+    path ends in a control-flow construct's own name (``_CONTROL``: the
+    construct itself, or what the compiler put inside it under its name),
+    else ``rest``."""
+    if not path:
+        return UNSCOPED
+    segments = path.split("/")
+    name = _innermost(segments, ALL_SCOPES)[1]
+    if name is None:
+        return LOOPS if _CONTROL.match(_bare(segments[-1])) else REST
+    return name
+
+
+def pass_of(path: Optional[str]) -> str:
+    """The pass an operation belongs to: ``recomputed`` if a segment of its
+    path holds ``rematted_computation``, else ``backward`` if a segment holds
+    ``transpose(``, else ``forward``."""
+    segments = (path or "").split("/")
+    if any(_REMATTED in s for s in segments):
+        return RECOMPUTED
+    if any(_TRANSPOSED in s for s in segments):
+        return BACKWARD
+    return "forward"
 
 
 # ------------------------------------------------------------- wire format
@@ -177,8 +262,9 @@ class _Plane:
             out[k] = rec
         return out
 
-    def events(self, line_name: str) -> List[Tuple[int, int, int]]:
-        """(start_ns, end_ns, metadata id) of every event of a line, in the
+    def events(self, line_name: Optional[str]) -> List[Tuple[int, int, int]]:
+        """(start_ns, end_ns, metadata id) of every event of a line (of
+        every line, for ``None``), in the
         whole nanoseconds ``jax.profiler.ProfileData`` gives (the file keeps
         picoseconds), so that this reader and one built on ``ProfileData``
         add up the same numbers."""
@@ -192,7 +278,7 @@ class _Plane:
                     t0_ns = v
                 elif f == 4:
                     events.append(v)
-            if name != line_name:
+            if line_name is not None and name != line_name:
                 continue
             for ev in events:
                 mid = off = dur = 0
@@ -238,6 +324,30 @@ def _top(d: Dict[str, float], n: int, scale: float) -> List[List[Any]]:
     return [[k, v * scale] for k, v in sorted(d.items(), key=lambda kv: -kv[1])[:n]]
 
 
+def _span_end(planes: Sequence[_Plane]) -> Tuple[Optional[int], bool]:
+    """The end, on the host's clock, of the last span the capture was taken
+    over (``CAPTURED_SPANS``), and whether it closed on a drained device;
+    ``(None, False)`` where the host wrote none."""
+    ends = []
+    for p in planes:
+        if p.name != HOST_PLANE:
+            continue
+        wanted = {k: CAPTURED_SPANS[rec["name"]]
+                  for k, rec in p.event_metadata(()).items()
+                  if rec["name"] in CAPTURED_SPANS}
+        ends += [(e, wanted[k]) for _, e, k in p.events(None) if k in wanted]
+    return max(ends, default=(None, False))
+
+
+def _whole_executions(plane: _Plane, ops_end: int) -> List[Tuple[int, int, int]]:
+    """The events of the line ``XLA Modules`` whose operations the line
+    ``XLA Ops``, which ends at ``ops_end``, holds to the end."""
+    return [
+        (s, e, k) for s, e, k in plane.events(MODULES_LINE)
+        if e - ops_end <= max(_WHOLE_SHARE * (e - s), _ROUNDING_NS)
+    ]
+
+
 def stage_table(
     xplane_path: str, stages: Sequence[str] = LEARN_STAGES
 ) -> Dict[str, Any]:
@@ -253,6 +363,24 @@ def stage_table(
     their intervals less the waits for the host inside them), and for the
     reader of the table ``devices``, ``rest_paths`` and ``unscoped_ops`` (the
     five largest of each, ``[name, seconds]``).
+
+    ``scopes`` is the same self time folded by ``ALL_SCOPES`` whatever
+    ``stages`` is, and by pass: ``scopes[row]`` holds ``forward``,
+    ``recomputed``, ``backward`` (``pass_of``) and ``all``, for every row of
+    ``SCOPE_ROWS``; under no scope, a control-flow event (one with events
+    inside it) and an operation named by a control-flow construct
+    (``scope_of``) are the row ``loops``; the rows' ``all`` add up to
+    ``busy``.  ``scope_ops[row][pass]`` lists the five operations with
+    most self time there.  ``programs`` lists each program's name, its whole
+    ``executions`` inside the capture (events of the line ``XLA Modules``
+    whose operations the line ``XLA Ops`` holds to the end) and their
+    ``seconds``, most seconds first: the updates a capture holds are the
+    first one's executions times the updates of a call, which the caller
+    knows.  ``truncated`` says the capture lost the tail of its device
+    events: a device line that ends more than ``CLOCK_SLACK_NS`` before
+    the host's span does (``host_after_ops``: by how many seconds it ended
+    before it), or, under a span that closes on a drained device, one
+    that holds operations after the last whole execution.
     """
     with open(xplane_path, "rb") as f:
         space = f.read()
@@ -274,9 +402,22 @@ def stage_table(
             op_names[program_id] = _hlo_op_names(hlo_protos[program_id])
         return op_names[program_id].get(instruction)
 
+    folds: Dict[Optional[str], Tuple[str, str, str]] = {}
+
+    def fold(path: Optional[str]) -> Tuple[str, str, str]:
+        """(stage, row of ``scopes``, pass) of a path, parsed once."""
+        if path not in folds:
+            folds[path] = (stage_of(path, stages), scope_of(path), pass_of(path))
+        return folds[path]
+
     seconds = dict.fromkeys(table_keys(stages), 0.0)
+    scopes = {row: dict.fromkeys(PASSES, 0.0) for row in SCOPE_ROWS}
+    scope_ops: Dict[Tuple[str, str], Dict[str, float]] = {}
     rest_paths: Dict[str, float] = {}
     unscoped_ops: Dict[str, float] = {}
+    programs: Dict[str, List[int]] = {}  # name -> [whole executions, ns]
+    span_end, drained = _span_end(planes) if devices else (None, False)
+    truncated, host_after_ops = False, None
     for plane in devices:
         meta = plane.event_metadata(("tf_op", "program_id"))
         ops: Dict[int, Tuple[str, Optional[str], bool]] = {}
@@ -297,14 +438,20 @@ def stage_table(
             if host_wait:
                 return
             own = max(dur - kids, 0)
-            stage = stage_of(path, stages)
+            stage, row, pass_ = fold(path)
             seconds[stage] += own
             if stage == REST:
                 rest_paths[path] = rest_paths.get(path, 0.0) + own
             elif stage == UNSCOPED:
                 unscoped_ops[short] = unscoped_ops.get(short, 0.0) + own
+            if row == REST and kids:
+                row = LOOPS
+            scopes[row][pass_] += own
+            by_op = scope_ops.setdefault((row, pass_), {})
+            by_op[short] = by_op.get(short, 0.0) + own
 
-        for s, e, k in sorted(events, key=lambda x: (x[0], -x[1])):
+        events.sort(key=lambda x: (x[0], -x[1]))
+        for s, e, k in events:
             while stack and s >= stack[-1][0]:
                 close()
             path = ops[k][1]
@@ -315,10 +462,46 @@ def stage_table(
         while stack:
             close()
 
-    scale = 1e-9 / max(len(devices), 1)
+        if not events:
+            continue
+        # Where the line ends: with the event that starts last.  (An
+        # enclosing ``while`` may have been kept whole where the tail of
+        # its body was lost.)
+        ops_end = events[-1][1]
+        whole = _whole_executions(plane, ops_end)
+        for s, e, k in whole:
+            program = programs.setdefault(meta[k]["name"], [0, 0])
+            program[0] += 1
+            program[1] += e - s
+        if drained and whole:
+            last_whole = max(e for _, e, _ in whole)
+            truncated = truncated or ops_end > last_whole + _ROUNDING_NS
+        if span_end is not None:
+            late = span_end - ops_end
+            if host_after_ops is None or late > host_after_ops:
+                host_after_ops = late
+            truncated = truncated or late > CLOCK_SLACK_NS
+
+    n = max(len(devices), 1)
+    scale = 1e-9 / n
     table: Dict[str, Any] = {k: v * scale for k, v in seconds.items()}
     table["busy"] = sum(table.values())
     table["devices"] = len(devices)
     table["rest_paths"] = _top(rest_paths, 5, scale)
     table["unscoped_ops"] = _top(unscoped_ops, 5, scale)
+    table["scopes"] = {}
+    for row, passes in scopes.items():
+        by_pass = {p: v * scale for p, v in passes.items()}
+        by_pass[ALL] = sum(by_pass.values())
+        table["scopes"][row] = by_pass
+    table["scope_ops"] = {}
+    for (row, pass_), by_op in scope_ops.items():
+        table["scope_ops"].setdefault(row, {})[pass_] = _top(by_op, 5, scale)
+    table["programs"] = [
+        {"name": name.rsplit("(", 1)[0], "executions": round(count / n),
+         "seconds": ns * scale}
+        for name, (count, ns) in sorted(programs.items(), key=lambda kv: -kv[1][1])
+    ]
+    table["truncated"] = truncated
+    table["host_after_ops"] = None if host_after_ops is None else host_after_ops * 1e-9
     return table

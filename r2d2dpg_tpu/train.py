@@ -325,7 +325,10 @@ def parse_args(argv=None) -> argparse.Namespace:
         "capture, and 'obs.flight merge --trace-out' stamps it as a "
         "labelled profile_window span in the fused Perfetto timeline; "
         "the capture's device time by stage of the learner call lands in "
-        "<logdir>/profile_window/stages.json and in profile_stop.  "
+        "<logdir>/profile_window/stages.json and in profile_stop (its entry "
+        "'scopes' has every scope by pass, 'programs' the executions the "
+        "capture holds, 'truncated' whether it lost the tail of its device "
+        "events: docs/OBSERVABILITY.md).  "
         "Mutually exclusive with --profile-phases (one jax profiler "
         "session per process); requires --logdir"
     )

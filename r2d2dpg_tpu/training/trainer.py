@@ -468,20 +468,23 @@ class Trainer:
         # w'=1/p (the constant cancels); saturation counts weights at the
         # max-normalized ceiling; replay age reads the arena's entry
         # stamp (learner-step units), masked where provenance is absent.
-        inv = 1.0 / jnp.maximum(res.probs, 1e-12)
-        metrics = dict(metrics)
-        metrics["quality_ess_frac"] = (inv.sum() ** 2) / (
-            res.probs.shape[0] * jnp.square(inv).sum()
-        )
-        metrics["quality_is_saturation"] = (w >= 1.0 - 1e-9).mean()
-        entry = arena.meta[res.indices, 1]
-        armed = entry >= 0
-        age = jnp.where(
-            armed, jnp.maximum(train.step.astype(jnp.int32) - entry, 0), 0
-        )
-        metrics["quality_replay_age"] = age.sum() / jnp.maximum(
-            armed.sum(), 1
-        )
+        # Under the scope ``diagnostics`` (utils/profiling.py::SIDE_STAGES),
+        # with the learner step's own counters.
+        with scope("diagnostics"):
+            inv = 1.0 / jnp.maximum(res.probs, 1e-12)
+            metrics = dict(metrics)
+            metrics["quality_ess_frac"] = (inv.sum() ** 2) / (
+                res.probs.shape[0] * jnp.square(inv).sum()
+            )
+            metrics["quality_is_saturation"] = (w >= 1.0 - 1e-9).mean()
+            entry = arena.meta[res.indices, 1]
+            armed = entry >= 0
+            age = jnp.where(
+                armed, jnp.maximum(train.step.astype(jnp.int32) - entry, 0), 0
+            )
+            metrics["quality_replay_age"] = age.sum() / jnp.maximum(
+                armed.sum(), 1
+            )
         return train, arena, metrics
 
     def _learn_step(self, train, arena, key):

@@ -69,17 +69,22 @@ LEARN_STAGES = (
 )
 
 
-# The scope of what ``learner_step`` does ONCE an update to the whole sampled
+# The scopes that stand BESIDE the five stages, inside the same learner call:
+# ``frames`` is what ``learner_step`` does ONCE an update to the whole sampled
 # batch's observations before any pass cuts a window (``agent.seq.prepare``:
 # a conv torso's frames scaled and re-laid, ``models/torsos.py``; for a flat
-# observation nothing, and then no operation carries the name).  Read with
-# ``stage_table(path, LEARN_STAGES + PREPARE_STAGES)``, as ``--profile-window``
-# does; read with ``LEARN_STAGES`` alone its time is ``rest`` (and leads
-# ``rest_paths``).  It is not one of ``LEARN_STAGES`` yet because the
-# benchmark's ``learn_stage_ms.*`` metric files mirror that table's keys one
-# for one (``tests/chipbench/test_chipbench_stages.py``): the name joins the
-# tuple in the ``benchmark`` PR that brings ``learn_stage_ms.frames``.
-PREPARE_STAGES = ("frames",)
+# observation nothing, and then no operation carries the name);
+# ``diagnostics`` is the program's own in-graph instrumentation of an update
+# (the metrics dictionary of ``R2D2DPG.learner_step``, the walks over both
+# nets' trees for ``grad_norm`` / ``param_norm`` among it, and the quality
+# gauges of ``Trainer._update_step``): always on, on every update's path.
+# ``stage_table``'s entry ``scopes`` folds by these names too; its five
+# stage keys do not, so there their time is ``rest``.  They are not in
+# ``LEARN_STAGES`` because the benchmark's ``learn_stage_ms.*`` metric files
+# mirror that table's keys one for one
+# (``tests/chipbench/test_chipbench_stages.py``); the benchmark reads them
+# as ``learn_scope_ms.*`` (``chipbench/reducers/scope_ms.py``).
+SIDE_STAGES = ("frames", "diagnostics")
 
 
 # Scopes INSIDE a whole-sequence core (``models/sdar_moe.py``,
@@ -88,9 +93,11 @@ PREPARE_STAGES = ("frames",)
 # routing (router, top-k, gates) and held experts' products; the looped
 # stack's dense MLP (its norms and three products).
 # ``stage_table(path, LEARN_STAGES + CORE_STAGES)``
-# folds both passes of a core scope into its stage (the innermost name wins)
+# folds every pass of a core scope into its stage (the innermost name wins)
 # and leaves ``forward`` / ``backward`` / ``burn_in`` what lies outside the
 # core; read with ``LEARN_STAGES`` alone the core's time stays in those.
+# The entry ``scopes`` of either table has a core scope's three passes apart
+# (forward, recomputed under ``jax.checkpoint``, backward).
 CORE_STAGES = ("core_attention", "moe_route", "moe_experts", "core_mlp")
 
 

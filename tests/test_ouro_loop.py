@@ -23,7 +23,7 @@ from chipbench import compare, harness, reference_ouro_loop as ref_ouro  # noqa:
 from r2d2dpg_tpu.configs import OURO_TINY  # noqa: E402
 from r2d2dpg_tpu.models import ouro_loop, policy_step_fn  # noqa: E402
 from r2d2dpg_tpu.models.sequence import Whole, sequence_runner  # noqa: E402
-from r2d2dpg_tpu.obs.stages import stage_of  # noqa: E402
+from r2d2dpg_tpu.obs.stages import PASSES, pass_of, scope_of, stage_of  # noqa: E402
 from r2d2dpg_tpu.replay.arena import SequenceBatch  # noqa: E402
 from r2d2dpg_tpu.utils.metrics import host_scalars  # noqa: E402
 from r2d2dpg_tpu.utils.profiling import CORE_STAGES, LEARN_STAGES  # noqa: E402
@@ -338,6 +338,10 @@ def test_core_scopes_reach_the_learner_calls_hlo_inside_its_loops():
         # Inside the scan over the layers inside the scan over the loop steps
         # inside the call's own loop over its updates, named all the same.
         assert any(p.count("while/body") >= 3 for p in mine)
+        # All three passes are on its paths: the forward pass, the one
+        # recomputed under ``jax.checkpoint``, the backward pass.
+        assert {pass_of(p) for p in mine} == set(PASSES)
+        assert {scope_of(p) for p in mine} == {name}
         # Read with the learner's stages alone, the products stay in the five
         # (what does not depend on a scan's carry, RoPE's table, is hoisted
         # and loses the stage: nothing in time).

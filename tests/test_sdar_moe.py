@@ -22,7 +22,8 @@ from chipbench import compare, harness, reference_sdar_moe as ref_moe  # noqa: E
 from r2d2dpg_tpu.configs import SDAR_TINY  # noqa: E402
 from r2d2dpg_tpu.models import policy_step_fn, sdar_moe  # noqa: E402
 from r2d2dpg_tpu.models.sequence import PASSES, Stepped, Whole  # noqa: E402
-from r2d2dpg_tpu.obs.stages import stage_of, table_keys  # noqa: E402
+from r2d2dpg_tpu.obs import stages  # noqa: E402
+from r2d2dpg_tpu.obs.stages import pass_of, scope_of, stage_of, table_keys  # noqa: E402
 from r2d2dpg_tpu.replay.arena import SequenceBatch  # noqa: E402
 from r2d2dpg_tpu.utils.metrics import host_scalars  # noqa: E402
 from r2d2dpg_tpu.utils.profiling import CORE_STAGES, LEARN_STAGES  # noqa: E402
@@ -259,6 +260,12 @@ def test_core_scopes_reach_the_learner_calls_hlo_and_fold_both_passes():
     experts = [p for p in paths if stage_of(p, both) == "moe_experts"]
     assert any("transpose(" in p for p in experts) and any("transpose(" not in p for p in experts)
     assert any("/burn_in/" in p for p in experts)
+    # All three passes are on its paths: the forward pass, the one
+    # recomputed under ``jax.checkpoint``, the backward pass; and the routing
+    # counters of an update are ``diagnostics``, not the core's.
+    assert {pass_of(p) for p in experts} == set(stages.PASSES)
+    assert {scope_of(p) for p in experts} == {"moe_experts"}
+    assert any(scope_of(p) == "diagnostics" for p in paths)
     # Read with the learner's stages alone, the core's time stays in the five.
     assert {stage_of(p) for p in experts} <= {"burn_in", "forward", "backward"}
     assert set(CORE_STAGES) <= set(table_keys(both)) and not set(CORE_STAGES) & set(table_keys())
