@@ -44,8 +44,10 @@ BUDGET_S = 1100.0
 EXIT_NO_TPU = 3
 TRAIN_PHASES = 3
 # The capacities the configs use (pendulum_tiny, cheetah_pixels,
-# pendulum_*, walker/humanoid) at the learner batch they all share.
-SCATTER_CAPACITIES = (256, 8_000, 50_000, 100_000)
+# pendulum_*, walker/humanoid) at the learner batch they all share, the
+# benchmark's walker arena (524,288) and twice that, which the kernel could
+# not hold in VMEM while it held the whole vector there (PR 37).
+SCATTER_CAPACITIES = (256, 8_000, 50_000, 100_000, 524_288, 1_048_576)
 SCATTER_BATCH = 64
 
 
@@ -170,8 +172,10 @@ def _serve(ckpt: str, *flags: str) -> tuple:
 
 def scatter_case(capacity: int) -> tuple:
     """``(priority, indices, values, want)``: one seeded write-back at the
-    learner batch — both ends of the vector written, one slot written four
-    times — and what a sequential loop makes of it (the last write wins).
+    learner batch — both ends of the vector written, two neighbouring slots
+    (the kernel fetches their lane-row twice and writes both copies back),
+    one slot written four times — and what a sequential loop makes of it
+    (the last write wins).
     ``tests/test_replay.py`` checks the interpreted kernel on these same
     cases, so the shapes the chip compiles are the shapes the CPU checks."""
     import numpy as np
@@ -180,6 +184,7 @@ def scatter_case(capacity: int) -> tuple:
     priority = rng.random(capacity, dtype=np.float32)
     indices = rng.integers(0, capacity, SCATTER_BATCH).astype(np.int32)
     indices[:2] = (0, capacity - 1)  # both ends of the padded tile
+    indices[3] = indices[4] ^ 1  # two slots of one lane-row (even capacities)
     indices[-3:] = indices[5]  # four writes to one slot
     values = rng.random(SCATTER_BATCH, dtype=np.float32) + 1.0
     want = priority.copy()
@@ -291,6 +296,15 @@ def _require_learner_call_guards(trainer, state, rolled_width=None) -> dict:
     observation a window is a few thousand floats and the list says nothing
     about frames).
 
+    The write-back of a batch's priorities (``ops/pallas/scatter.py``) is a
+    Mosaic call inside the loop over the updates that takes the vector as an
+    operand aliased to its result, and, where the vector's length is a
+    multiple of 128, no copy, pad or slice of the whole vector that the
+    update waits for stands under the scope ``priority_update`` or next to
+    the call (``obs/hlo.py::priority_writes``; another length keeps its pad
+    and slice, and what the compiler moves asynchronously between HBM and
+    VMEM is listed, not refused).
+
     With ``rolled_width`` (the inner width of a looped stack's MLP) a seventh:
     the products of that width lie inside the stack's two scans (over the
     layers, inside over the loop steps), a copy a pass and not one an
@@ -306,6 +320,7 @@ def _require_learner_call_guards(trainer, state, rolled_width=None) -> dict:
         frame_relays,
         loop_convolutions,
         loop_products,
+        priority_writes,
     )
 
     call = jax.jit(trainer._learn_many, donate_argnums=(0, 1))
@@ -338,6 +353,17 @@ def _require_learner_call_guards(trainer, state, rolled_width=None) -> dict:
         not in_updates,
         "the learner call reads the arena in slices or copies of many rows' "
         f"bytes in every update: {in_updates}",
+    )
+    written_back = priority_writes(hlo, trainer.arena.capacity)
+    kernels = [w for w in written_back if w[1].startswith("kernel")]
+    waited_for = [w for w in written_back
+                  if w[1] in ("copy", "pad", "slice", "dynamic-slice")]
+    _require(
+        kernels
+        and all(w[1] == "kernel in place" and w[3] >= call_loops for w in kernels)
+        and (trainer.arena.capacity % 128 != 0 or not waited_for),
+        "the learner call does not write its priorities back in place, inside "
+        f"its loop over the updates, the vector left where it is: {written_back}",
     )
     convolutions = loop_convolutions(hlo)
     in_scans = [c for c in convolutions if c[3] > call_loops]
@@ -381,6 +407,7 @@ def _require_learner_call_guards(trainer, state, rolled_width=None) -> dict:
         "batch_size": trainer.config.batch_size,
         "batch_minor_writes": writes,
         "capacity_scans": scans,
+        "priority_writes": written_back,
         "arena_reads": reads,
         "arena_reads_in_updates": in_updates,
         "loop_convolutions": convolutions,
@@ -451,7 +478,8 @@ def _leg_train(work: str) -> dict:
     write-back, donated state; then the learner call alone, compiled for the
     whole-arena convert guard, the batch-minor write guard, the
     capacity-long running sum guard, the padded arena read guard, the
-    convolution-in-a-scan guard and the frame re-lay guard, for
+    in-place priority write-back guard, the convolution-in-a-scan guard and
+    the frame re-lay guard, for
     ``walker_r2d2`` and, from shapes, for the whole-sequence cores'
     configurations ``humanoid_sdar_moe`` and ``humanoid_ouro_loop`` (the
     latter also for the rolled-stack guard) and the pixel replay's

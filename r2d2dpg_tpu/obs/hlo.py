@@ -57,6 +57,19 @@ eleven copies and slices an update, a third of cheetah's 2.78 ms (PERF.md PR
 as laid out and the loops around it; the same leg holds the pixel
 configuration's learner call to the few the preparation needs.
 
+``ops/pallas/scatter.py`` writes a batch's priorities back by the lane-rows
+they land in and leaves the vector where it is: the Mosaic call takes it as
+an operand aliased to its result, so a vector whose length is a multiple of
+128 is neither padded nor copied on its way in (a vector held whole in the
+kernel's VMEM cost ``B x capacity`` selects, 10.6 % of walker's update,
+PERF.md PR 37).  Whether the call updates in place, and what the compiler
+moves of the vector around it, is in the compiled text alone:
+``priority_writes`` lists the call and every copy, pad or slice of a vector's
+length under the scope ``priority_update`` or next to the call, and the same
+leg holds every learner call to a call in place inside the loop over the
+updates and, where the length is a multiple of 128, to no such move that the
+program waits for.
+
 ``models/ouro_loop.py`` runs 4 layers 4 times by a scan inside a scan, so that
 the compiled learner call holds one copy of a block a pass and not sixteen
 (its compile is part of every process's set-up).  ``loop_products`` lists the
@@ -366,4 +379,85 @@ def loop_products(hlo_text: str, width: int) -> List[Tuple[str, str, int]]:
                 continue
             if width in _dims(m):
                 found.append((m["name"], m["shape"], depth.get(name, 0)))
+    return found
+
+
+# ``%copy-start = (f32[4096,128]{1,0:T(8,128)}, f32[4096,128]{...S(1)}, u32[]) copy-start(%x)``:
+# an asynchronous move's result is a tuple, its first shape what travels.
+_ASYNC_MOVE = re.compile(
+    r"^\s*%?(?P<name>[\w.\-]+)\s*=\s*\(+(?P<shape>\w+\[(?P<lead>\d+)(?P<rest>[\d,]*)\])"
+    r".*?\s(?P<opcode>copy-start|slice-start)\(")
+_MOVES = ("copy", "pad", "slice", "dynamic-slice", "copy-start", "slice-start")
+_ALIASED = re.compile(r"output_to_operand_aliasing=\{\{\}: \((\d+), \{\}\)\}")
+
+
+def priority_writes(
+    hlo_text: str, capacity: int, scope: str = "priority_update"
+) -> List[Tuple[str, str, str, int]]:
+    """``(name, what, shape, loops around it)`` of what ``hlo_text`` does to
+    the priority vector in the write-back, in the order printed:
+
+    - every Mosaic call (``tpu_custom_call``) whose path holds ``scope``:
+      ``what`` is ``kernel in place`` where the call carries
+      ``output_to_operand_aliasing`` (the vector is its operand AND its
+      result: the kernel moves the rows it writes and nothing else), else
+      ``kernel out of place`` (the whole vector goes through the kernel);
+    - every ``copy``, ``pad``, ``slice`` or ``dynamic-slice`` whose result
+      holds ``capacity`` elements or more and whose path holds ``scope``,
+      fused or not (``what`` is the opcode): the pad and slice of a length
+      that is no multiple of 128;
+    - the instruction that makes the call's vector operand and those that read
+      its result, bitcasts looked through, where they are such a move or its
+      asynchronous form (``copy-start``, ``slice-start``: the compiler's own
+      traffic between HBM and VMEM carries no path).  A synchronous one is a
+      pass over the vector that every update waits for; an asynchronous one
+      may hide behind other work."""
+    lines, depth = _computations(hlo_text)
+    found = []
+
+    def operands(m, line):
+        return re.findall(r"%([\w.\-]+)", line[m.end():].split("), ")[0])
+
+    for name, body in lines.items():
+        made, kernels = {}, []
+
+        def note(m, what=None):
+            row = (m["name"], what or m["opcode"], m["shape"], depth.get(name, 0))
+            if row not in found:
+                found.append(row)
+
+        def moves(m):
+            return m["opcode"] in _MOVES and math.prod(_dims(m)) >= capacity
+
+        for line in body:
+            m = _INSTRUCTION.match(line) or _ASYNC_MOVE.match(line)
+            if not m:
+                continue
+            made[m["name"]] = (m, line)
+            if f"/{scope}/" in line or f"/{scope}\"" in line:
+                if m["opcode"] == "custom-call" and "tpu_custom_call" in line:
+                    kernels.append(m["name"])
+                elif moves(m):
+                    note(m)
+
+        def maker(value):
+            """What makes ``value``, bitcasts looked through."""
+            while value in made and made[value][0]["opcode"] == "bitcast":
+                value = operands(*made[value])[0]
+            return [made[value][0]] if value in made else []
+
+        def readers(value):
+            """What reads ``value``, bitcasts looked through."""
+            for m, line in made.values():
+                if value in operands(m, line):
+                    yield from readers(m["name"]) if m["opcode"] == "bitcast" else [m]
+
+        for kernel in kernels:
+            m, line = made[kernel]
+            aliased = _ALIASED.search(line)
+            note(m, "kernel in place" if aliased else "kernel out of place")
+            vector = operands(m, line)[int(aliased[1]) if aliased else -1]
+            for near in maker(vector) + list(readers(kernel)):
+                if moves(near):
+                    note(near)
     return found

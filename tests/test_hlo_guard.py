@@ -1,7 +1,8 @@
 """``obs/hlo.py``: the readers behind ``chip_smoke.py``'s five compile-time
 guards of the learner call (the whole-arena convert, the batch-minor write of
 the sampled batch, a running sum as long as the arena, a row read out of the
-arena as many rows' bytes, an image convolution run once a scan step) and the
+arena as many rows' bytes, an image convolution run once a scan step), the
+priority write-back in place (PR 37) and the
 sixth (a looped stack's products inside its loops, one copy a pass), on HLO
 text as the TPU compiler prints it.  Only the
 chip's compiler makes either choice, so the CPU tests the readers alone, and
@@ -18,6 +19,7 @@ from r2d2dpg_tpu.obs.hlo import (
     frame_relays,
     loop_convolutions,
     loop_products,
+    priority_writes,
 )
 
 CAPACITY = 524288
@@ -154,6 +156,111 @@ def test_capacity_scans_names_every_running_sum_as_long_as_the_arena(
     (``[32,128]``, ``[33,1]``) and inside the drawn blocks (``[64,128]``) are
     short of the arena unless the arena is as short as they."""
     assert capacity_scans(hlo, capacity) == want
+
+
+# ``walker_r2d2``'s learner call at the cell's 524,288 slots compiled for a
+# described v5e (JAX 0.9.0, libtpu 0.0.34), cut to the priority vector's way
+# through an update.  The compiler brings the vector into VMEM (``S(1)``) at
+# the top of the loop's body for ``sample``'s passes, four asynchronous
+# slices glued by a ``ConcatBitcast``; the kernel of PR 37 takes that copy as
+# an operand aliased to its result and the 2 MB go back behind it
+# (``copy-start``), under no path.
+_BODY = ("%wide.region_0.180 (wide.arg_tuple.4: (s32[], f32[524288])) -> "
+         "(s32[], f32[524288]) {\n")
+_ENTRY = """\
+}
+
+%wide.region_146.181 (wide.arg_tuple.3: (s32[], f32[524288])) -> pred[] {
+  ROOT %lt.9 = pred[]{:T(512)} compare(%get-tuple-element.1, %constant.9), direction=LT
+}
+
+ENTRY %main.192 (arena_priority.1: f32[524288]) -> f32[524288] {
+  %while.844 = (s32[]{:T(128)}, f32[524288]{0:T(1024)}) while(%tuple.841), condition=%wide.region_146.181, body=%wide.region_0.180
+}
+"""
+_PREFETCH = """\
+  %get-tuple-element.13495 = f32[524288]{0:T(1024)} get-tuple-element(%wide.arg_tuple.4), index=1
+  %slice-start = ((f32[524288]{0:T(1024)}), f32[131072]{0:T(1024)S(1)}, s32[]{:S(2)}) slice-start(%get-tuple-element.13495), slice={[0:131072]}
+  %slice-start.1 = ((f32[524288]{0:T(1024)}), f32[131072]{0:T(1024)S(1)}, s32[]{:S(2)}) slice-start(%get-tuple-element.13495), slice={[131072:524288]}
+  %slice-done = f32[131072]{0:T(1024)S(1)} slice-done(%slice-start)
+  %slice-done.1 = f32[393216]{0:T(1024)S(1)} slice-done(%slice-start.1)
+  %custom-call.202 = f32[524288]{0:T(1024)S(1)} custom-call(%slice-done, %slice-done.1), custom_call_target="ConcatBitcast"
+  %add_maximum_fusion.3 = f32[64]{0:T(128)S(1)} fusion(%get-tuple-element.12329, %get-tuple-element.12330), kind=kLoop, calls=%fused_computation.864, metadata={op_name="jit(_learn_many)/while/body/closed_call/priority_update/max"}
+  %bitcast.1291 = f32[4096,128]{1,0:T(8,128)S(1)} bitcast(%custom-call.202)
+"""
+WRITTEN_IN_PLACE = _BODY + _PREFETCH + """\
+  %_pallas_scatter.14 = f32[4096,128]{1,0:T(8,128)S(1)} custom-call(%add_clamp_fusion.3, %add_maximum_fusion.3, %bitcast.1291), custom_call_target="tpu_custom_call", operand_layout_constraints={s32[64]{0}, f32[64]{0}, f32[4096,128]{1,0}}, output_to_operand_aliasing={{}: (2, {})}, metadata={op_name="jit(_learn_many)/while/body/closed_call/priority_update/jit(_pallas_scatter)/pallas_call"}
+  %copy-start = (f32[4096,128]{1,0:T(8,128)}, f32[4096,128]{1,0:T(8,128)S(1)}, u32[]{:S(2)}) copy-start(%_pallas_scatter.14)
+  %copy-done = f32[4096,128]{1,0:T(8,128)} copy-done(%copy-start)
+  %bitcast.1292 = f32[524288]{0:T(1024)} bitcast(%copy-done)
+  ROOT %tuple.843 = (s32[]{:T(128)}, f32[524288]{0:T(1024)}) tuple(%add.1, %bitcast.1292)
+""" + _ENTRY
+
+# The same lines of its parent: the kernel held the whole vector in its own
+# VMEM, so the compiler copied it there first and nothing is aliased.
+WRITTEN_OUT_OF_PLACE = _BODY + _PREFETCH + """\
+  %copy.1140 = f32[4096,128]{1,0:T(8,128)S(1)} copy(%bitcast.1291)
+  %_pallas_scatter.14 = f32[4096,128]{1,0:T(8,128)} custom-call(%add_clamp_fusion.3, %add_maximum_fusion.3, %copy.1140), custom_call_target="tpu_custom_call", operand_layout_constraints={s32[64]{0}, f32[64]{0}, f32[4096,128]{1,0}}, metadata={op_name="jit(_learn_many)/while/body/closed_call/priority_update/jit(_pallas_scatter)/pallas_call"}
+  %bitcast.1292 = f32[524288]{0:T(1024)} bitcast(%_pallas_scatter.14)
+  ROOT %tuple.843 = (s32[]{:T(128)}, f32[524288]{0:T(1024)}) tuple(%add.1, %bitcast.1292)
+""" + _ENTRY
+
+# The configuration's own 100,000 slots, no multiple of 128: the vector is
+# padded into the ``[782, 128]`` view inside a fusion (the slice back is a
+# bitcast under the padded tiling), and the call is in place on the result.
+WRITTEN_RAGGED = """\
+%fused_computation.510 (param_0.3521: f32[100000]) -> f32[782,128] {
+  %param_0.3521 = f32[100000]{0:T(1024)} parameter(0)
+  %pad.1034 = f32[100096]{0:T(1024)} pad(%param_0.3521, %constant.4425), padding=0_96, metadata={op_name="jit(_learn_many)/while/body/closed_call/priority_update/jit(_pallas_scatter)/jit(_pad)/pad"}
+  ROOT %bitcast.1203 = f32[782,128]{1,0:T(8,128)S(1)} bitcast(%pad.1034), metadata={op_name="jit(_learn_many)/while/body/closed_call/priority_update/jit(_pallas_scatter)/reshape"}
+}
+
+""" + _BODY.replace("524288", "100000") + """\
+  %copy.1200 = f32[100000]{0:T(1024)} copy(%get-tuple-element.14642)
+  %pad_bitcast_fusion.11 = f32[782,128]{1,0:T(8,128)S(1)} fusion(%copy.1200), kind=kLoop, calls=%fused_computation.510, metadata={op_name="jit(_learn_many)/while/body/closed_call/priority_update/jit(_pallas_scatter)/reshape"}
+  %_pallas_scatter.14 = f32[782,128]{1,0:T(8,128)S(1)} custom-call(%add_clamp_fusion.3, %add_maximum_fusion.3, %pad_bitcast_fusion.11), custom_call_target="tpu_custom_call", operand_layout_constraints={s32[64]{0}, f32[64]{0}, f32[782,128]{1,0}}, output_to_operand_aliasing={{}: (2, {})}, metadata={op_name="jit(_learn_many)/while/body/closed_call/priority_update/jit(_pallas_scatter)/pallas_call"}
+  %bitcast.1344 = f32[100000]{0:T(1024)S(1)} bitcast(%_pallas_scatter.14)
+  ROOT %tuple.843 = (s32[]{:T(128)}, f32[100000]{0:T(1024)}) tuple(%add.1, %bitcast.1344)
+""" + _ENTRY.replace("524288", "100000")
+
+
+@pytest.mark.parametrize(
+    "hlo, capacity, scope, want",
+    [
+        (WRITTEN_IN_PLACE, CAPACITY, "priority_update", [
+            ("_pallas_scatter.14", "kernel in place", "f32[4096,128]", 1),
+            ("copy-start", "copy-start", "f32[4096,128]", 1),
+        ]),
+        (WRITTEN_OUT_OF_PLACE, CAPACITY, "priority_update", [
+            ("_pallas_scatter.14", "kernel out of place", "f32[4096,128]", 1),
+            ("copy.1140", "copy", "f32[4096,128]", 1),
+        ]),
+        (WRITTEN_RAGGED, 100000, "priority_update", [
+            ("pad.1034", "pad", "f32[100096]", 1),
+            ("_pallas_scatter.14", "kernel in place", "f32[782,128]", 1),
+        ]),
+        (WRITTEN_IN_PLACE, 2 * CAPACITY, "priority_update", [
+            ("_pallas_scatter.14", "kernel in place", "f32[4096,128]", 1),
+        ]),
+        (WRITTEN_OUT_OF_PLACE, CAPACITY, "priority", []),
+        (PINNED, CAPACITY, "priority_update", []),
+        ("", CAPACITY, "priority_update", []),
+    ],
+    ids=["in_place", "out_of_place", "a_length_no_multiple_of_128",
+         "a_longer_arena", "another_scope", "no_kernel", "empty"],
+)
+def test_priority_writes_names_the_kernel_and_every_move_of_the_vector_beside_it(
+    hlo, capacity, scope, want
+):
+    """The Mosaic call under the scope counts as in place by its
+    ``output_to_operand_aliasing``; a move of the vector counts by its path
+    (the fused ``pad``) or by standing next to the call, bitcasts looked
+    through (the parent's ``copy.1140`` into the kernel's VMEM, the
+    ``copy-start`` that carries the updated vector back to HBM), if it holds
+    ``capacity`` elements or more; the prefetch's slices further up and the
+    copy that feeds the pad's fusion are the compiler's own and carry no
+    path: not listed.  A scope is a whole segment of the path."""
+    assert priority_writes(hlo, capacity, scope) == want
 
 
 # The pixel gather alone at ``cheetah_pixels``'s shapes, compiled for a
@@ -769,3 +876,75 @@ def test_learner_call_compiled_for_v5e_prepares_its_frames_once(
         r for r in arena_reads(hlo, trainer.arena.capacity) if r[4] == 0]
     assert batch_minor_writes(hlo, 32) == [] and [
         c for c in loop_convolutions(hlo) if c[3] > 1] == []
+
+
+@pytest.mark.parametrize(
+    "config, obs_shape, obs_dtype, capacity",
+    [("walker_r2d2", (24,), "float32", 524288),
+     ("cheetah_pixels", (64, 64, 3), "uint8", 12288)],
+    ids=["walker_r2d2", "cheetah_pixels"],
+)
+def test_learner_call_compiled_for_v5e_writes_its_priorities_back_in_place(
+    config, obs_shape, obs_dtype, capacity, one_chip, no_compile_cache, monkeypatch
+):
+    """The learner call of each LSTM cell from shapes at the CELL's capacity
+    (a multiple of 128; the configurations' own, which ``chip_smoke.py``
+    compiles on the chip, are not), compiled for a described v5e: the
+    write-back is one Mosaic call inside the loop over the updates, in place
+    on the vector, and no copy, pad or slice of the vector that an update
+    waits for stands beside it.  Nothing runs: a compile says nothing about
+    results or times."""
+    import dataclasses
+
+    import jax
+
+    import chip_smoke
+    from r2d2dpg_tpu import configs
+
+    get_config = configs.get_config
+
+    def at_the_cells_capacity(name):
+        exp = get_config(name)
+        return dataclasses.replace(
+            exp, trainer=dataclasses.replace(exp.trainer, capacity=capacity))
+
+    monkeypatch.setattr(configs, "get_config", at_the_cells_capacity)
+    # ``priority_scatter`` picks its branch from the backend it runs on.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    trainer, state = chip_smoke._learner_call_from_shapes(
+        config, obs_shape, obs_dtype, 6)
+    assert trainer.arena.capacity == capacity
+    train, arena, rng = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        (state.train, state.arena, state.rng))
+    hlo = jax.jit(trainer._learn_many, donate_argnums=(0, 1)).trace(
+        train, arena, rng).lower(lowering_platforms=("tpu",)).compile().as_text()
+    written_back = priority_writes(hlo, capacity)
+    assert [w[1:] for w in written_back if w[1].startswith("kernel")] == [
+        ("kernel in place", f"f32[{capacity // 128},128]", 1)]
+    assert [w for w in written_back
+            if w[1] in ("copy", "pad", "slice", "dynamic-slice")] == []
+
+
+def test_priority_kernel_compiles_for_v5e_at_twice_the_cells_capacity(
+    one_chip, no_compile_cache
+):
+    """2**20 slots at batch 64, which the kernel could not hold while it
+    kept the whole vector in VMEM (PR 37): nothing in its VMEM grows with the
+    vector now.  A Mosaic call, in place.  Here and not with the benchmark's
+    own compiles (``tests/chipbench/test_chipbench_aot.py``), beside the
+    fixtures the other compiles of this file use.  Nothing runs: a compile
+    says nothing about results or times."""
+    import jax
+    import jax.numpy as jnp
+
+    from r2d2dpg_tpu.ops.pallas.scatter import _pallas_scatter
+
+    avals = (
+        jax.ShapeDtypeStruct((1 << 20,), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((64,), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((64,), jnp.float32, sharding=one_chip),
+    )
+    hlo = _pallas_scatter.trace(*avals).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    assert "tpu_custom_call" in hlo and "output_to_operand_aliasing" in hlo

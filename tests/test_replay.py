@@ -197,13 +197,71 @@ def test_priority_update_pallas_kernel():
 def test_priority_scatter_at_config_capacities(capacity):
     """The interpreted kernel on the very cases chip_smoke.py's kernel leg
     compiles on the chip: every capacity a config uses (256, 8,000, 50,000,
-    100,000 — all but the first short of a whole 8x128 float32 tile) at the
-    learner batch of 64, with a slot written four times: last write wins."""
+    100,000 — all but the first short of a whole 8x128 float32 tile), the
+    walker cell's 524,288 and twice that, at the learner batch of 64, with a
+    slot written four times: last write wins."""
     from r2d2dpg_tpu.ops.pallas.scatter import priority_scatter
 
     priority, indices, values, want = scatter_case(capacity)
     got = np.asarray(jax.jit(priority_scatter)(priority, indices, values))
     np.testing.assert_array_equal(got, want)
+
+
+def _written_slots(capacity):
+    """The slots the write-back cases plant among a batch's random ones, by
+    what each is there to show."""
+    row = capacity // 2 // 128 * 128
+    return {
+        "a_slot_written_four_times": [capacity // 3] * 4,
+        "two_slots_of_one_lane_row": [row + 3, row + 77],
+        "the_first_and_the_last_row": [
+            5, 127, (capacity - 1) // 128 * 128, capacity - 1],
+        "the_last_slot": [capacity - 1],
+        "an_index_equal_to_the_capacity": [capacity],
+    }
+
+
+def _write_back_cases():
+    """``(capacity, batch, what)``: every capacity a config or a cell uses
+    (the cells' 524,288 and 12,288 are multiples of 128, the others end
+    inside a lane-row) at both learner batches with all of the planted slots
+    at once, then each kind of planted slot alone at a short ragged vector
+    and at walker's."""
+    kinds = list(_written_slots(256))
+    for capacity in (256, 8_000, 12_288, 50_000, 100_000, 524_288):
+        for batch in (64, 32):
+            yield capacity, batch, "all"
+    for capacity in (8_000, 524_288):
+        for kind in kinds:
+            yield capacity, 64, kind
+
+
+@pytest.mark.parametrize(
+    "capacity, batch, what", list(_write_back_cases()), ids=str)
+def test_priority_scatter_is_the_sequential_write_back(capacity, batch, what):
+    """The interpreted kernel against a loop that writes one slot after
+    another (the last write wins; an index outside the vector writes
+    nothing), bit for bit: the B lane-rows travel as B copies, so two slots
+    of one row, a slot written four times, both ends of the vector and the
+    last slot of a length that is no multiple of 128 are the cases a copy
+    written back late, or a row fetched from beyond the vector, would
+    break."""
+    from r2d2dpg_tpu.ops.pallas.scatter import priority_scatter
+
+    planted = _written_slots(capacity)
+    slots = sum(planted.values(), []) if what == "all" else planted[what]
+    rng = np.random.default_rng([capacity, batch, len(slots)])
+    priority = rng.random(capacity, dtype=np.float32)
+    indices = rng.integers(0, capacity, batch).astype(np.int32)
+    indices[rng.choice(batch, len(slots), replace=False)] = slots
+    values = rng.random(batch, dtype=np.float32) + 1.0
+    want = priority.copy()
+    for i, v in zip(indices, values):
+        if i < capacity:
+            want[i] = v
+    got = np.asarray(jax.jit(priority_scatter)(priority, indices, values))
+    np.testing.assert_array_equal(got, want)
+    assert (want != priority).sum() == len(set(indices[indices < capacity]))
 
 
 def test_priority_update_inside_jit():
