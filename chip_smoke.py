@@ -260,14 +260,53 @@ def _built_trainers():
 
 # What ``models/torsos.py::ConvTorso.prepare`` leaves of re-laying between
 # the gather and the first convolution in an update of a pixel
-# configuration's learner call (``obs/hlo.py::frame_relays``): one
-# transposition of the sampled bytes with the conversion fused in, one pass
-# that pads the channels into the convolution's order; for ``cheetah_pixels``
-# 37.7 + 50.3 MB as laid out.  Its parent made eleven, 325 MB (PERF.md PR 35).
-_FRAME_RELAYS_AN_UPDATE = 2
-# Two bytes an element in runs of 128 frames, once as they are and once with
-# three channels padded to four: 88,080,384 B over 45 x 32 x 12,288 is 4.98.
-_FRAME_RELAY_BYTES_A_FRAME_ELEMENT = 5.0
+# configuration's learner call (``obs/hlo.py::frame_relays``): the
+# transposition of the sampled bytes, and the gather of its rows into
+# ``Conv_0``'s blocks, which the reader counts twice (the gather's fusion
+# transposes and reshapes); for ``cheetah_pixels`` 18.9 MB each as laid out
+# (the conversion to bfloat16 after them, 37.7 MB, is no re-lay).  Its
+# parent made two, 88 MB, and the parent's parent eleven, 325 MB (PERF.md
+# PR 35, PR 39).
+_FRAME_RELAYS_AN_UPDATE = 3
+# One byte an element, in runs of 128 frames: 56,623,104 B over 45 x 32 x
+# 12,288 is 3.2.
+_FRAME_RELAY_BYTES_A_FRAME_ELEMENT = 3.5
+
+
+def _small_leaf_bytes(arena_state, obs_shape: tuple) -> int:
+    """The bytes of the arena's ``[capacity, ...]`` leaves but an image
+    observation's: for ``cheetah_pixels`` at 8,000 slots 45.8 MB beside a
+    4.4 GB pixel leaf."""
+    import jax
+
+    capacity = arena_state.priority.shape[0]
+    image = arena_state.data.obs if len(obs_shape) == 3 else None
+    return sum(
+        math.prod(x.shape) * x.dtype.itemsize
+        for x in jax.tree_util.tree_leaves(arena_state)
+        if x is not image and x.ndim >= 2 and x.shape[0] == capacity
+    )
+
+
+def _arena_reads_refused(reads: list, call_loops: int, small_bytes: int) -> list:
+    """Of ``obs/hlo.py::arena_reads``' list, the reads the guard refuses:
+    those an update makes, that is inside the learner call's loop over its
+    updates (``call_loops`` deep) or deeper.
+
+    One kind is let through while it stays small: reads into VMEM (memory
+    space 1, ``S(1)`` in the layout) once an update that together take no
+    more bytes than the small leaves (``small_bytes``, ``_small_leaf_bytes``).
+    The compiler stages a small leaf there to gather the batch's rows from
+    when the update leaves VMEM free: at 8,000 slots the pixel
+    configuration's learner call slices two ``[8000, 45]`` leaves into VMEM a
+    quarter at a time, 8.2 MB an update as laid out (PERF.md PR 39).  A
+    large leaf staged so, a staged read inside a scan, or a read into HBM is
+    refused."""
+    in_updates = [r for r in reads if r[4] >= call_loops]
+    staged = [r for r in in_updates if r[4] == call_loops and "S(1)" in r[1]]
+    if sum(r[2] for r in staged) > small_bytes:
+        return in_updates
+    return [r for r in in_updates if r not in staged]
 
 
 def _require_learner_call_guards(trainer, state, rolled_width=None) -> dict:
@@ -277,7 +316,8 @@ def _require_learner_call_guards(trainer, state, rolled_width=None) -> dict:
     ``[capacity, ...]`` value, inserts no sequence into a batch-minor
     ``[batch, ...]`` buffer, keeps no running sum as long as the arena, reads
     no row out of the arena as a slice or copy of many rows' bytes in an
-    update, and runs no image convolution inside a scan of an update.  Only
+    update (``_arena_reads_refused``), and runs no image convolution inside a
+    scan of an update.  Only
     the TPU compiler makes the first two choices, gives the third its cost
     (128 adds an element) and lays the arena out (the fourth: a pixel leaf in
     the rows' own shape lies slot minor-most and a row comes out padded 128
@@ -294,7 +334,10 @@ def _require_learner_call_guards(trainer, state, rolled_width=None) -> dict:
     a window's frames or more, and no more bytes than the prepared frames
     take; the list is reported for every configuration (for a flat
     observation a window is a few thousand floats and the list says nothing
-    about frames).
+    about frames).  And everything that reads the prepared frames is a
+    stride-1 convolution (``obs/hlo.py::frame_contractions``): ``Conv_0``
+    over the frames cut into blocks of its stride, forward and weight
+    gradient, and no contraction on the vector unit.
 
     The write-back of a batch's priorities (``ops/pallas/scatter.py``) is a
     Mosaic call inside the loop over the updates that takes the vector as an
@@ -317,6 +360,7 @@ def _require_learner_call_guards(trainer, state, rolled_width=None) -> dict:
         arena_reads,
         batch_minor_writes,
         capacity_scans,
+        frame_contractions,
         frame_relays,
         loop_convolutions,
         loop_products,
@@ -348,7 +392,8 @@ def _require_learner_call_guards(trainer, state, rolled_width=None) -> dict:
     # leaves of every configuration here, 69 MB a call for cheetah's), is
     # listed and not refused: no update pays it again.
     reads = arena_reads(hlo, trainer.arena.capacity)
-    in_updates = [r for r in reads if r[4] >= call_loops]
+    in_updates = _arena_reads_refused(
+        reads, call_loops, _small_leaf_bytes(state.arena, trainer.env.spec.obs_shape))
     _require(
         not in_updates,
         "the learner call reads the arena in slices or copies of many rows' "
@@ -387,6 +432,14 @@ def _require_learner_call_guards(trainer, state, rolled_width=None) -> dict:
             f"an update, {sum(r[2] for r in relays)} bytes (at most "
             f"{_FRAME_RELAYS_AN_UPDATE}, {allowed}): {relays}",
         )
+        contractions = frame_contractions(hlo, window)
+        _require(
+            contractions and all(c[1] == "convolution" for c in contractions),
+            "the learner call reads its prepared frames otherwise than by a "
+            f"stride-1 convolution: {contractions}",
+        )
+    else:
+        contractions = []
     rolled = {}
     if rolled_width is not None:
         products = loop_products(hlo, rolled_width)
@@ -416,6 +469,7 @@ def _require_learner_call_guards(trainer, state, rolled_width=None) -> dict:
         "frame_relays_in_updates": len(relays),
         "frame_relay_bytes_in_updates": sum(r[2] for r in relays),
         "frame_relays": relays[:16],  # the first of them, as printed
+        "frame_contractions": contractions,
     }
 
 

@@ -55,7 +55,11 @@ eleven copies and slices an update, a third of cheetah's 2.78 ms (PERF.md PR
 35).  ``frame_relays`` lists every ``copy``, ``slice``, ``transpose`` or
 ``reshape`` that writes a value of a window's frames or more, with its bytes
 as laid out and the loops around it; the same leg holds the pixel
-configuration's learner call to the few the preparation needs.
+configuration's learner call to the few the preparation needs.  Since PR 39
+the prepared frames are cut into blocks of ``Conv_0``'s stride, so that it
+reads them as a stride-1 convolution over 16·C channels;
+``frame_contractions`` says how everything that reads them contracts, and
+the same leg requires a stride-1 convolution of each.
 
 ``ops/pallas/scatter.py`` writes a batch's priorities back by the lane-rows
 they land in and leaves the vector where it is: the Mosaic call takes it as
@@ -326,6 +330,79 @@ def frame_relays(hlo_text: str, elements: int) -> List[Tuple[str, str, int, int]
     return found
 
 
+_SCOPE = "frames"
+# Any instruction, a tuple result too: its opcode is the word before the
+# parenthesis that opens its operands (``fusion(%a, %b)``).
+_READER = re.compile(
+    r"^\s*(?:ROOT\s+)?%?(?P<name>[\w.\-]+)\s*=.*?\s(?P<opcode>[\w\-]+)\((?=%)")
+
+
+def _operands(m: "re.Match[str]", line: str) -> List[str]:
+    """The names a matched instruction's line takes as its operands."""
+    return re.findall(r"%([\w.\-]+)", line[m.end():].split("), ")[0])
+
+
+def _contraction(lines: Dict[str, List[str]], line: str) -> Tuple[str, str]:
+    """How the instruction on ``line`` and what it calls contract:
+    ``("convolution", window)``; ``("strided convolution", window)``, a
+    stride or a kernel dilation over the input (the weight gradient of a
+    strided convolution); else ``("multiply-reduce", "")`` where a
+    ``reduce`` stands in for a product, or ``("no product", "")``."""
+    text, todo = [], [line]
+    while todo:
+        text.append(todo.pop())
+        todo.extend(l for c in _CALLED.finditer(text[-1]) if c["one"]
+                    for l in lines.get(c["one"], []))
+    for held in text:
+        if re.search(r"\s(?:convolution|dot)\(", held):
+            window = re.search(r"window=\{([^}]*)\}", held)
+            window = window[1] if window else ""
+            strided = "stride=" in window or "rhs_dilate=" in window
+            return ("strided " if strided else "") + "convolution", window
+    if any(re.search(r"\sreduce\(", held) for held in text):
+        return "multiply-reduce", ""
+    return "no product", ""
+
+
+def frame_contractions(hlo_text: str, elements: int) -> List[Tuple[str, str, str, int]]:
+    """``(name, how it contracts, window, loops around it)`` of every
+    instruction of a program's own computation in ``hlo_text`` that reads the
+    prepared frames (a value of ``elements`` elements or more made under the
+    scope ``frames``, bitcasts looked through) and is not itself under that
+    scope, in the order printed; ``how`` is ``_contraction``'s.
+
+    ``models/torsos.py::ConvTorso.prepare`` cuts the frames into blocks of
+    ``Conv_0``'s stride so that every pass reads them with a stride-1
+    convolution of 16·C channels.  Over the raw order the weight gradient is
+    a convolution with the three channels as its batch (``window={size=15x15
+    rhs_dilate=4x4}``): three of the MXU's rows, 0.122 ms an update each in
+    ``cheetah_pixels``, named ``multiply_reduce_fusion`` because the
+    optimizer's clipping sum of squares is fused into it (PERF.md PR 39).  A
+    reader that contracts the frames with a stride, or on the vector unit,
+    says the blocks are not what it reads."""
+    lines, depth = _computations(hlo_text)
+    fused = {c["one"] for body in lines.values() for line in body
+             for c in _CALLED.finditer(line) if c["how"] == "calls"}
+    found = []
+    for name, body in lines.items():
+        if name in fused:  # a fusion's body: its caller is the reader
+            continue
+        made = set()
+        for line in body:
+            m, value = _READER.match(line), _INSTRUCTION.match(line)
+            if not m:
+                continue
+            scoped = f"/{_SCOPE}/" in line
+            if m["opcode"] == "bitcast" and made.intersection(_operands(m, line)):
+                made.add(m["name"])  # the same bytes under another shape
+            elif scoped and value and math.prod(_dims(value)) >= elements:
+                made.add(m["name"])
+            elif not scoped and made.intersection(_operands(m, line)):
+                found.append((m["name"], *_contraction(lines, line),
+                              depth.get(name, 0)))
+    return found
+
+
 def loop_convolutions(hlo_text: str) -> List[Tuple[str, str, str, int]]:
     """``(name, shape, window size, loops around it)`` of every image
     ``convolution`` in ``hlo_text`` that sits inside a ``while`` body, in a
@@ -415,9 +492,6 @@ def priority_writes(
     lines, depth = _computations(hlo_text)
     found = []
 
-    def operands(m, line):
-        return re.findall(r"%([\w.\-]+)", line[m.end():].split("), ")[0])
-
     for name, body in lines.items():
         made, kernels = {}, []
 
@@ -443,20 +517,20 @@ def priority_writes(
         def maker(value):
             """What makes ``value``, bitcasts looked through."""
             while value in made and made[value][0]["opcode"] == "bitcast":
-                value = operands(*made[value])[0]
+                value = _operands(*made[value])[0]
             return [made[value][0]] if value in made else []
 
         def readers(value):
             """What reads ``value``, bitcasts looked through."""
             for m, line in made.values():
-                if value in operands(m, line):
+                if value in _operands(m, line):
                     yield from readers(m["name"]) if m["opcode"] == "bitcast" else [m]
 
         for kernel in kernels:
             m, line = made[kernel]
             aliased = _ALIASED.search(line)
             note(m, "kernel in place" if aliased else "kernel out of place")
-            vector = operands(m, line)[int(aliased[1]) if aliased else -1]
+            vector = _operands(m, line)[int(aliased[1]) if aliased else -1]
             for near in maker(vector) + list(readers(kernel)):
                 if moves(near):
                     note(near)

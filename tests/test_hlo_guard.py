@@ -2,7 +2,8 @@
 guards of the learner call (the whole-arena convert, the batch-minor write of
 the sampled batch, a running sum as long as the arena, a row read out of the
 arena as many rows' bytes, an image convolution run once a scan step), the
-priority write-back in place (PR 37) and the
+priority write-back in place (PR 37), the prepared frames' re-lays and what
+reads them (PR 35, PR 39) and the
 sixth (a looped stack's products inside its loops, one copy a pass), on HLO
 text as the TPU compiler prints it.  Only the
 chip's compiler makes either choice, so the CPU tests the readers alone, and
@@ -16,6 +17,7 @@ from r2d2dpg_tpu.obs.hlo import (
     arena_reads,
     batch_minor_writes,
     capacity_scans,
+    frame_contractions,
     frame_relays,
     loop_convolutions,
     loop_products,
@@ -592,10 +594,85 @@ def _relaid_once_a_call(name):
          "empty"],
 )
 def test_arena_reads_names_every_slice_or_copy_of_many_rows_bytes(hlo, capacity, want):
+    import chip_smoke
+
     assert arena_reads(hlo, capacity) == want
-    # What ``chip_smoke.py`` refuses: a read inside the loop over the updates.
-    assert [r[0] for r in arena_reads(hlo, capacity) if r[4] >= 1] == [
-        w[0] for w in want if w[4] >= 1]
+    # What ``chip_smoke.py`` refuses: a read inside the loop over the updates
+    # (none of these is into VMEM once an update).
+    refused = chip_smoke._arena_reads_refused(
+        arena_reads(hlo, capacity), 1, SMALL_LEAF_BYTES)
+    assert [r[0] for r in refused] == [w[0] for w in want if w[4] >= 1]
+
+
+# The pixel configuration's learner call at its own 8,000 slots, as the chip's
+# compiler made it once ``Conv_0`` read blocks (PR 39: names, shapes and
+# layouts from the train leg's refusal, the lines written in the printer's
+# form): two ``[8000, 45]`` leaves sliced into VMEM a quarter at a time inside
+# the loop over the updates, 8 x 1,024,000 B an update as laid out.
+STAGED_SMALL_LEAVES = (
+    "%region_0.231 (arg_tuple.4: (s32[], f32[8000,45], f32[8000,45])) -> "
+    "(s32[], f32[8000,45], f32[8000,45]) {\n"
+    + "".join(
+        f"  %get-tuple-element.{9001 + leaf} = f32[8000,45]{{1,0:T(8,128)}} "
+        f"get-tuple-element(%arg_tuple.4), index={1 + leaf}\n"
+        for leaf in range(2))
+    + "".join(
+        f"  %slice.{871 + 2 * i} = f32[2000,45]{{1,0:T(8,128)S(1)}} "
+        f"slice(%get-tuple-element.{9001 + i // 4}), "
+        f"slice={{[{2000 * (i % 4)}:{2000 * (i % 4 + 1)}], [0:45]}}\n"
+        for i in range(8))
+    + "}\n\nENTRY %main.243 (arena_data_reward.1: f32[8000,45]) -> f32[] {\n"
+    "  %while.898 = (s32[]{:T(128)}, f32[8000,45]{1,0:T(8,128)}) while(%tuple.1036), "
+    "condition=%region_188.232, body=%region_0.231\n}\n")
+# A slice of the pixel leaf staged the same way: 2,000 rows of 552,960 B.
+STAGED_PIXEL_ROWS = STAGED_SMALL_LEAVES.replace(
+    "%slice.871 = f32[2000,45]{1,0:T(8,128)S(1)} slice(%get-tuple-element.9001)",
+    "%get-tuple-element.9003 = u8[8000,45,3,32,128]{4,3,2,1,0:T(8,128)(4,1)} "
+    "get-tuple-element(%arg_tuple.4), index=3\n"
+    "  %slice.871 = u8[2000,45,3,32,128]{4,3,2,1,0:T(8,128)(4,1)S(1)} "
+    "slice(%get-tuple-element.9003)")
+# The arena's leaves but the pixels, ``cheetah_pixels`` at 8,000 slots:
+# action, reward, discount, reset, four LSTM carries, the slots' stamps.
+SMALL_LEAF_BYTES = 8000 * (45 * 6 + 3 * 45 + 4 * 256 + 2) * 4
+
+
+@pytest.mark.parametrize(
+    "hlo, small_bytes, refused",
+    [
+        (STAGED_SMALL_LEAVES, SMALL_LEAF_BYTES, []),
+        (STAGED_SMALL_LEAVES, 8 * 1024000 - 1, [f"slice.{871 + 2 * i}" for i in range(8)]),
+        (STAGED_PIXEL_ROWS, SMALL_LEAF_BYTES, [f"slice.{871 + 2 * i}" for i in range(8)]),
+        (STAGED_SMALL_LEAVES.replace("S(1)", ""), SMALL_LEAF_BYTES,
+         [f"slice.{871 + 2 * i}" for i in range(8)]),
+    ],
+    ids=["small_leaves_in_vmem", "more_than_the_small_leaves", "pixel_rows_in_vmem",
+         "small_leaves_in_hbm"],
+)
+def test_the_arena_read_guard_lets_small_leaves_staged_in_vmem_through(
+    hlo, small_bytes, refused
+):
+    """Reads into VMEM once an update pass while together they take no more
+    bytes than the arena's small leaves; a read of the pixel leaf, more bytes
+    than that, or a read into HBM is refused, and all of an update's reads
+    with it."""
+    import chip_smoke
+
+    reads = arena_reads(hlo, 8000)
+    assert [(r[2], r[3], r[4]) for r in reads if r[1].startswith("f32")] == [
+        (1024000, 180, 1)] * (7 if hlo is STAGED_PIXEL_ROWS else 8)
+    assert [r[0] for r in chip_smoke._arena_reads_refused(reads, 1, small_bytes)] == refused
+
+
+def test_the_small_leaves_are_the_arenas_leaves_but_the_pixels():
+    """``cheetah_pixels``' arena from shapes: every ``[8000, ...]`` leaf but
+    the 4.4 GB of frames."""
+    import chip_smoke
+
+    _, shapes = chip_smoke._learner_call_from_shapes(
+        "cheetah_pixels", (64, 64, 3), "uint8", 6)
+    assert chip_smoke._small_leaf_bytes(shapes.arena, (64, 64, 3)) == SMALL_LEAF_BYTES
+    assert chip_smoke._small_leaf_bytes(shapes.arena, (12288,)) == (
+        SMALL_LEAF_BYTES + 8000 * 45 * 64 * 64 * 3)
 
 
 # ``cheetah_pixels``' learner call compiled for a described v5e, cut to what
@@ -724,6 +801,123 @@ def test_frame_relays_names_every_written_copy_slice_or_reshape_of_a_window(
             325320704 if hlo is PER_PASS_RELAYS else 88080384)
 
 
+# What reads the prepared frames in ``cheetah_pixels``' learner call before
+# ``Conv_0`` read them as blocks (compiled for a described v5e, PR 37's tree;
+# one of the six forward passes and one of the two weight gradients): the
+# strided convolution, and its weight gradient, a convolution with the three
+# channels as its batch and a dilated kernel, in a fusion that the optimizer's
+# clipping sum of squares names ``multiply_reduce_fusion``.
+STRIDED_READERS = """\
+%fused_computation.415 (param_0.3887: bf16[64,64,3,1440]) -> bf16[64,64,3,640] {
+  %param_0.3887 = bf16[64,64,3,1440]{3,2,1,0:T(4,128)(2,1)S(1)} parameter(0)
+  ROOT %slice.789 = bf16[64,64,3,640]{3,2,1,0:T(4,128)(2,1)} slice(%param_0.3887), slice={[0:64], [0:64], [0:3], [0:640]}
+}
+
+%fused_computation.414.clone.clone (param_0.3888: bf16[8,8,3,64], param_1.5072: bf16[64,64,3,1440]) -> f32[640,15,15,64] {
+  %param_1.5072 = bf16[64,64,3,1440]{3,2,1,0:T(4,128)(2,1)S(1)} parameter(1)
+  %fusion.910 = bf16[64,64,3,640]{3,2,1,0:T(4,128)(2,1)} fusion(%param_1.5072), kind=kLoop, calls=%fused_computation.415, metadata={op_name="jit(_learn_many)/while/body/closed_call/burn_in/slice"}
+  %param_0.3888 = bf16[8,8,3,64]{3,2,1,0:T(4,128)(2,1)S(1)} parameter(0)
+  ROOT %conv_general_dilated.346 = f32[640,15,15,64]{0,3,2,1:T(8,128)S(1)} convolution(%fusion.910, %param_0.3888), window={size=8x8 stride=4x4}, dim_labels=01fb_01io->b01f, metadata={op_name="jit(_learn_many)/while/body/closed_call/burn_in/vmap(ActorNet.encode)/torso/Conv_0/conv_general_dilated"}
+}
+
+%fused_computation.825.clone.clone (param_0.4030: bf16[640,15,15,32], param_1.5170: bf16[64,64,3,1440]) -> (f32[], f32[8,8,3,32]) {
+  %param_1.5170 = bf16[64,64,3,1440]{3,2,1,0:T(4,128)(2,1)S(1)} parameter(1)
+  %fusion.266.clone.3 = bf16[64,64,3,640]{3,2,1,0:T(4,128)(2,1)} fusion(%param_1.5170), kind=kLoop, calls=%fused_computation.415, metadata={op_name="jit(_learn_many)/while/body/closed_call/forward/slice"}
+  %param_0.4030 = bf16[640,15,15,32]{0,3,2,1:T(8,128)(2,1)S(1)} parameter(0)
+  %conv_general_dilated.290.clone.3 = f32[8,8,3,32]{3,2,1,0:T(4,128)S(1)} convolution(%fusion.266.clone.3, %param_0.4030), window={size=15x15 rhs_dilate=4x4}, dim_labels=01bf_i01o->01bf
+  %mul.8278 = f32[8,8,3,32]{3,2,1,0:T(4,128)} multiply(%conv_general_dilated.290.clone.3, %conv_general_dilated.290.clone.3), metadata={op_name="jit(_learn_many)/while/body/closed_call/optimizer/mul"}
+  %constant.5061 = f32[]{:T(128)} constant(0)
+  %reduce.1854 = f32[]{:T(128)} reduce(%mul.8278, %constant.5061), dimensions={0,1,2,3}, to_apply=%region_79.121, metadata={op_name="jit(_learn_many)/while/body/closed_call/optimizer/reduce_sum"}
+  ROOT %tuple.839 = (f32[]{:T(128)}, f32[8,8,3,32]{3,2,1,0:T(4,128)S(1)}) tuple(%reduce.1854, %conv_general_dilated.290.clone.3)
+}
+
+%region_0.231 (arg_tuple.4: (s32[], u8[8000,45,3,32,128])) -> (s32[], u8[8000,45,3,32,128]) {
+  %copy.435 = bf16[96,128,1440]{2,1,0:T(8,128)(2,1)S(1)} copy(%multiply_bitcast_fusion.2), metadata={op_name="jit(_learn_many)/while/body/closed_call/frames/ActorNet.prepare/torso.prepare/div"}
+  %reshape.1037 = bf16[64,64,3,1440]{3,2,1,0:T(4,128)(2,1)S(1)} reshape(%copy.435), metadata={op_name="jit(_learn_many)/while/body/closed_call/frames/ActorNet.prepare/torso.prepare/div"}
+  %fusion.967 = f32[640,15,15,64]{0,3,2,1:T(8,128)S(1)} fusion(%reshape.1036, %reshape.1037), kind=kOutput, calls=%fused_computation.414.clone.clone, metadata={op_name="jit(_learn_many)/while/body/closed_call/burn_in/vmap(ActorNet.encode)/torso/Conv_0/conv_general_dilated"}
+  %multiply_reduce_fusion.216 = (f32[]{:T(128)}, f32[8,8,3,32]{3,2,1,0:T(4,128)S(1)}) fusion(%get-tuple-element.16987, %reshape.1037), kind=kOutput, calls=%fused_computation.825.clone.clone, metadata={op_name="jit(_learn_many)/while/body/closed_call/forward/transpose(jvp(ActorNet.encode))/torso/Conv_0/conv_general_dilated"}
+  %fusion.1008 = bf16[640,15,15,32]{0,3,2,1:T(8,128)(2,1)S(1)} fusion(%get-tuple-element.16986), kind=kLoop, calls=%fused_computation.900, metadata={op_name="jit(_learn_many)/while/body/closed_call/forward/transpose(jvp(ActorNet.encode))/torso/Conv_1/conv_general_dilated"}
+}
+
+ENTRY %main.243 (arena_data_obs.1: u8[8000,45,3,32,128]) -> f32[] {
+  %while.898 = (s32[]{:T(128)}, u8[8000,45,3,32,128]{4,3,2,1,0:T(8,128)(4,1)}) while(%tuple.1036), condition=%region_188.232, body=%region_0.231
+}
+"""
+
+# The same readers from PR 39 on: the frames in 4 x 4 blocks of 48 channels,
+# made by a gather of the transposed bytes' rows and converted
+# (``convert_multiply_fusion.2``, read through a bitcast), and ``Conv_0`` a
+# stride-1 convolution over them, forward and weight gradient.
+BLOCK_READERS = """\
+%fused_computation.421.clone.clone (param_0.3907: bf16[2,2,48,64], param_1.5083: bf16[16,16,48,1440]) -> f32[640,15,15,64] {
+  %param_1.5083 = bf16[16,16,48,1440]{3,2,1,0:T(8,128)(2,1)S(1)} parameter(1)
+  %fusion.913 = bf16[16,16,48,640]{3,2,1,0:T(8,128)(2,1)} fusion(%param_1.5083), kind=kLoop, calls=%fused_computation.422.clone.clone, metadata={op_name="jit(_learn_many)/while/body/closed_call/burn_in/slice"}
+  %param_0.3907 = bf16[2,2,48,64]{2,3,1,0:T(8,128)(2,1)S(1)} parameter(0)
+  ROOT %conv_general_dilated.348 = f32[640,15,15,64]{0,3,2,1:T(8,128)S(1)} convolution(%fusion.913, %param_0.3907), window={size=2x2}, dim_labels=01fb_01io->b01f, metadata={op_name="jit(_learn_many)/while/body/closed_call/burn_in/vmap(ActorNet.encode)/torso/conv_general_dilated"}
+}
+
+%fused_computation.425.clone.clone (param_0.4052: bf16[640,15,15,32], param_1.5181: bf16[16,16,48,1440]) -> f32[2,2,48,32] {
+  %param_1.5181 = bf16[16,16,48,1440]{3,2,1,0:T(8,128)(2,1)S(1)} parameter(1)
+  %fusion.959 = bf16[16,16,48,640]{3,2,1,0:T(8,128)(2,1)} fusion(%param_1.5181), kind=kLoop, calls=%fused_computation.426.clone.clone, metadata={op_name="jit(_learn_many)/while/body/closed_call/forward/slice"}
+  %param_0.4052 = bf16[640,15,15,32]{0,3,2,1:T(8,128)(2,1)S(1)} parameter(0)
+  ROOT %conv_general_dilated.356 = f32[2,2,48,32]{2,3,1,0:T(8,128)S(1)} convolution(%fusion.959, %param_0.4052), window={size=15x15}, dim_labels=01bf_i01o->01bf, metadata={op_name="jit(_learn_many)/while/body/closed_call/forward/transpose(jvp(ActorNet.encode))/torso/conv_general_dilated"}
+}
+
+%region_0.231 (arg_tuple.4: (s32[], u8[8000,45,3,32,128])) -> (s32[], u8[8000,45,3,32,128]) {
+  %convert_multiply_fusion.2 = bf16[12288,1440]{1,0:T(8,128)(2,1)S(1)} fusion(%fusion.972), kind=kLoop, calls=%fused_computation.408.clone.clone, metadata={op_name="jit(_learn_many)/while/body/closed_call/frames/ActorNet.prepare/torso.prepare/div"}
+  %bitcast.1354 = bf16[16,16,48,1440]{3,2,1,0:T(8,128)(2,1)S(1)} bitcast(%convert_multiply_fusion.2)
+  %fusion.973 = f32[640,15,15,64]{0,3,2,1:T(8,128)S(1)} fusion(%bitcast.1462, %bitcast.1354), kind=kOutput, calls=%fused_computation.421.clone.clone, metadata={op_name="jit(_learn_many)/while/body/closed_call/burn_in/vmap(ActorNet.encode)/torso/conv_general_dilated"}
+  %bitcast.1361 = bf16[16,16,48,1440]{3,2,1,0:T(8,128)(2,1)S(1)} bitcast(%convert_multiply_fusion.2)
+  %fusion.1016 = f32[2,2,48,32]{2,3,1,0:T(8,128)S(1)} fusion(%get-tuple-element.17709, %bitcast.1361), kind=kOutput, calls=%fused_computation.425.clone.clone, metadata={op_name="jit(_learn_many)/while/body/closed_call/forward/transpose(jvp(ActorNet.encode))/torso/conv_general_dilated"}
+}
+"""
+
+# A weight gradient over the prepared frames that the compiler contracts on
+# the vector unit: products and a sum over the frames, no convolution
+# (written for the test, in the printer's own form).
+VECTOR_UNIT_READER = """\
+%fused_computation.9 (param_0: bf16[640,15,15,32], param_1: bf16[16,16,48,1440]) -> f32[48,32] {
+  %param_1 = bf16[16,16,48,1440]{3,2,1,0:T(8,128)(2,1)S(1)} parameter(1)
+  %param_0 = bf16[640,15,15,32]{0,3,2,1:T(8,128)(2,1)S(1)} parameter(0)
+  %broadcast.1 = f32[48,32,640]{2,1,0:T(8,128)} broadcast(%param_1), dimensions={0,2}
+  %multiply.1 = f32[48,32,640]{2,1,0:T(8,128)} multiply(%broadcast.1, %broadcast.2)
+  ROOT %reduce.1 = f32[48,32]{1,0:T(8,128)} reduce(%multiply.1, %constant.1), dimensions={2}, to_apply=%add
+}
+
+ENTRY %main.1 (arena_data_obs.1: u8[8000,45,3,32,128]) -> f32[] {
+  %copy.528 = bf16[16,16,48,1440]{3,2,1,0:T(8,128)(2,1)S(1)} copy(%bitcast.1354), metadata={op_name="jit(_learn_many)/while/body/closed_call/frames/ActorNet.prepare/torso.prepare/div"}
+  %bitcast.9 = bf16[16,16,48,1440]{3,2,1,0:T(8,128)(2,1)S(1)} bitcast(%copy.528)
+  %multiply_reduce_fusion.9 = f32[48,32]{1,0:T(8,128)} fusion(%get-tuple-element.1, %bitcast.9), kind=kLoop, calls=%fused_computation.9, metadata={op_name="jit(_learn_many)/while/body/closed_call/forward/transpose(jvp(ActorNet.encode))/torso/dot_general"}
+}
+"""
+
+
+@pytest.mark.parametrize(
+    "hlo, want",
+    [
+        (STRIDED_READERS, [
+            ("fusion.967", "strided convolution", "size=8x8 stride=4x4", 1),
+            ("multiply_reduce_fusion.216", "strided convolution",
+             "size=15x15 rhs_dilate=4x4", 1),
+        ]),
+        (BLOCK_READERS, [
+            ("fusion.973", "convolution", "size=2x2", 0),
+            ("fusion.1016", "convolution", "size=15x15", 0),
+        ]),
+        (VECTOR_UNIT_READER, [("multiply_reduce_fusion.9", "multiply-reduce", "", 0)]),
+        ("", []),
+    ],
+    ids=["strided", "blocks", "vector_unit", "empty"],
+)
+def test_frame_contractions_names_how_every_reader_of_the_prepared_frames_contracts(
+    hlo, want
+):
+    """Readers under the scope ``frames`` (``reshape.1037`` of ``copy.435``)
+    and readers of other values (``fusion.1008``) are not listed; a bitcast is
+    looked through."""
+    assert frame_contractions(hlo, WINDOW) == want
+
+
 @pytest.fixture(scope="module")
 def one_chip():
     """A described v5e chip's sharding; skips where the TPU compiler cannot
@@ -838,13 +1032,13 @@ def test_learner_call_compiled_for_v5e_prepares_its_frames_once(
 ):
     """``cheetah_pixels``' learner call from shapes (batch 32, 45 steps of
     64 x 64 x 3 bytes, 2 updates a call), compiled for a described v5e: an
-    update writes two re-lays of a window's frames or more, the prepared
-    frames' two passes over all 45 steps (88 MB as laid out where the
-    compiler keeps them as bfloat16, as the chip's does; the parent's eleven
-    wrote 325; under this suite's ``--xla_backend_optimization_level=0`` they
-    stay float32, twice that), and every convolution reads the one array
-    they leave.  Nothing runs: a compile says nothing about results or
-    times."""
+    update re-lays the sampled bytes of all 45 steps twice, the
+    transposition and the gather of its rows into ``Conv_0``'s 4 x 4 blocks
+    (which ``frame_relays`` counts twice: the gather's fusion transposes and
+    reshapes), 18.9 MB each as laid out (PR 35's two passes wrote 88 MB as
+    bfloat16, its parent's eleven 325), and every convolution that reads the
+    one array they leave is a stride-1 convolution over 48 channels.
+    Nothing runs: a compile says nothing about results or times."""
     import jax
 
     import chip_smoke
@@ -860,18 +1054,18 @@ def test_learner_call_compiled_for_v5e_prepares_its_frames_once(
         train, arena, rng).lower(lowering_platforms=("tpu",)).compile().as_text()
 
     relays = frame_relays(hlo, WINDOW)
-    itemsize = {"bf16": 2, "f32": 4}[relays[0][1].split("[")[0]]
-    # 1,440 frames in runs of 128: 1,536; three channels in tiles of four.
-    assert [(r[1].split("[")[1].split("]")[0], r[2], r[3]) for r in relays] == [
-        ("96,128,1440", 96 * 128 * 1536 * itemsize, 1),
-        ("64,64,3,1440", 64 * 64 * 4 * 1536 * itemsize, 1)]
-    assert [r[0].split(".")[0] for r in relays] == ["copy", "reshape"]
-    # The gather's loop writes the sampled bytes time-major itself, and all
-    # six passes' first convolutions take the prepared frames as an operand.
+    # 1,440 frames in runs of 128: 1,536 bytes a row.
+    assert sorted((r[1].split("{")[0], r[2], r[3]) for r in relays) == [
+        ("u8[12288,1440]", 12288 * 1536, 1), ("u8[12288,1440]", 12288 * 1536, 1),
+        ("u8[96,128,1440]", 96 * 128 * 1536, 1)]
+    assert sorted(r[0].split(".")[0] for r in relays) == ["copy", "reshape", "transpose"]
+    # The gather's loop writes the sampled bytes time-major itself, and the
+    # six passes' first convolutions and both weight gradients read the
+    # prepared frames, each over 2 x 2 positions of 48 channels.
     assert "u8[32,45,3,32,128]{4,3,2,0,1:" in hlo
-    readers = [line for line in hlo.splitlines()
-               if " fusion(" in line and f"%{relays[1][0]}" in line.split(" fusion(")[1]]
-    assert len(readers) >= 6
+    contractions = frame_contractions(hlo, WINDOW)
+    assert sorted(c[1:] for c in contractions) == (
+        [("convolution", "size=15x15", 1)] * 2 + [("convolution", "size=2x2", 1)] * 6)
     assert arena_reads(hlo, trainer.arena.capacity, rows=4) == [
         r for r in arena_reads(hlo, trainer.arena.capacity) if r[4] == 0]
     assert batch_minor_writes(hlo, 32) == [] and [

@@ -9,6 +9,7 @@ a ``prepare`` that hands the batch back) run ``learner_step`` as the parent
 did: windows cut out of the raw uint8 batch, every pass converting its own."""
 
 import dataclasses
+import re
 from typing import Any
 
 import flax.linen as nn
@@ -19,7 +20,7 @@ import pytest
 
 from r2d2dpg_tpu.agents import ddpg
 from r2d2dpg_tpu.agents.ddpg import AgentConfig, R2D2DPG
-from r2d2dpg_tpu.models import actor_critic, sequence
+from r2d2dpg_tpu.models import actor_critic, sequence, torsos
 from r2d2dpg_tpu.models.actor_critic import ActorNet, CriticNet, policy_step_fn
 from r2d2dpg_tpu.models.sequence import Stepped, window
 from r2d2dpg_tpu.models.torsos import ConvTorso, Frames, fan_in_uniform
@@ -132,8 +133,11 @@ def test_learner_step_on_prepared_frames_is_the_parents_update(
 
     got, want = update(ConvTorso, Frames), update(ParentConvTorso, jax.Array)
     assert jax.tree_util.tree_structure(got) == jax.tree_util.tree_structure(want)
+    # ``Conv_0`` over prepared frames sums each output's 192 products in
+    # another order (four taps of 48 for 64 of 3): near-zero elements differ
+    # by the float32 rounding of the larger terms, 1.6e-7 at most here.
     for g, w in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
-        np.testing.assert_allclose(g, w, rtol=2e-5, atol=1e-7)
+        np.testing.assert_allclose(g, w, rtol=2e-5, atol=3e-7)
     # The update is one: priorities and Adam's first moments are not zeros.
     _, prios, metrics = got
     assert np.all(np.asarray(prios) > 0) and float(metrics["grad_norm"]) > 0
@@ -187,28 +191,46 @@ def test_raw_frames_go_through_the_torso_as_before(monkeypatch, path):
         assert g.dtype == w.dtype and np.array_equal(np.asarray(g), np.asarray(w))
 
 
-@pytest.mark.parametrize(
+FRAME_CASES = pytest.mark.parametrize(
     "frame, dtype",
-    [((64, 64, 3), jnp.uint8), ((FRAME, FRAME, 3), jnp.uint8), ((8, 8, 2), jnp.float32)],
-    ids=("whole_tiles", "no_whole_tile", "float_frames"),
+    [((64, 64, 3), jnp.uint8), ((FRAME, FRAME, 3), jnp.uint8), ((8, 8, 2), jnp.float32),
+     ((FRAME + 2, FRAME + 7, 3), jnp.uint8)],
+    ids=("whole_tiles", "no_whole_tile", "float_frames", "cropped"),
 )
+
+
+def _frames(frame, dtype, seed=1):
+    """``[B, 5, *frame]`` frames of ``dtype``: bytes, or floats off the grid."""
+    raw = jax.random.randint(jax.random.PRNGKey(seed), (B, 5) + frame, 0, 256)
+    return raw.astype(dtype) if dtype == jnp.uint8 else raw.astype(dtype) / 7.0
+
+
+@FRAME_CASES
 def test_frames_are_the_scaled_frames_time_major_and_cut_by_steps(frame, dtype):
-    """``prepare`` makes ``[H, W, C, L·B]`` with frame ``t·B + b`` minor-most,
-    the torso's own conversion applied; ``frames[a:b]`` is those steps of
-    every sequence, and ``window`` cuts either form time-major."""
+    """``prepare`` makes ``[H/4, W/4, 16·C, L·B]``: the rows and columns
+    ``Conv_0`` reads (``(out - 1)·4 + 8`` of each, which drops two rows and
+    seven columns of a 38 x 43 frame), cut into 4 x 4 blocks, channel
+    ``(4a + b)·C + c`` of block ``(i, j)`` being pixel ``(4i + a, 4j + b)``'s
+    channel ``c``, with frame ``t·B + b`` minor-most and the torso's own
+    conversion applied; ``frames[a:b]`` is those steps of every sequence, and
+    ``window`` cuts either form time-major."""
     L = 5
-    raw = jax.random.randint(jax.random.PRNGKey(1), (B, L) + frame, 0, 256)
-    obs = raw.astype(dtype) if dtype == jnp.uint8 else raw.astype(dtype) / 7.0
+    obs = _frames(frame, dtype)
     frames = ConvTorso(out_size=HID).prepare(obs)
-    assert frames.batch == B and frames.pixels.shape == frame + (L * B,)
+    (height, width, channels), read = frame, [(n - 8) // 4 * 4 + 8 for n in frame[:2]]
+    blocks = (read[0] // 4, read[1] // 4, 16 * channels)
+    assert frames.batch == B and frames.block == 4
+    assert frames.pixels.shape == blocks + (L * B,)
     assert frames.pixels.dtype == jnp.float32
 
     scaled = obs.astype(jnp.float32) / 255.0 if dtype == jnp.uint8 else obs
-    back = jnp.moveaxis(frames.pixels, -1, 0).reshape((L, B) + frame)
-    assert np.array_equal(np.asarray(back), np.asarray(jnp.swapaxes(scaled, 0, 1)))
+    back = frames.pixels.reshape(blocks[:2] + (4, 4, channels, L, B))
+    back = back.transpose(5, 6, 0, 2, 1, 3, 4).reshape((L, B, *read, channels))
+    want = jnp.swapaxes(scaled, 0, 1)[:, :, : read[0], : read[1]]
+    assert np.array_equal(np.asarray(back), np.asarray(want))
 
     cut = window(frames, 1, 4)
-    assert isinstance(cut, Frames) and cut.pixels.shape == frame + (3 * B,)
+    assert isinstance(cut, Frames) and cut.pixels.shape == blocks + (3 * B,)
     assert np.array_equal(
         np.asarray(cut.pixels), np.asarray(frames.pixels[..., B : 4 * B]))
     assert np.array_equal(np.asarray(cut[:2].pixels), np.asarray(frames[1:3].pixels))
@@ -216,17 +238,75 @@ def test_frames_are_the_scaled_frames_time_major_and_cut_by_steps(frame, dtype):
         np.asarray(window(obs, 1, 4)), np.asarray(jnp.swapaxes(obs[:, 1:4], 0, 1)))
 
 
-def test_the_torso_reads_prepared_windows_as_it_reads_raw_ones():
+@FRAME_CASES
+def test_the_torso_reads_prepared_windows_as_it_reads_raw_ones(
+    monkeypatch, frame, dtype
+):
     """``ConvTorso`` on a window of ``Frames`` is ``ConvTorso`` on the same raw
-    steps ``[T, B, H, W, C]``, features ``[T, B, out]``."""
+    steps ``[T, B, H, W, C]``, features ``[T, B, out]``.  A frame under 36 x
+    36 is too small for the whole stack: the torso is then ``Conv_0`` (the
+    layer that reads the blocks) and the dense layer."""
+    if min(frame[:2]) < FRAME:
+        monkeypatch.setattr(torsos, "_CONVS", torsos._CONVS[:1])
     torso = ConvTorso(out_size=HID)
-    obs = jax.random.randint(
-        jax.random.PRNGKey(2), (B, 5, FRAME, FRAME, 3), 0, 256).astype(jnp.uint8)
+    obs = _frames(frame, dtype, seed=2)
     params = torso.init(jax.random.PRNGKey(0), obs[:, 0])
     got = torso.apply(params, torso.prepare(obs)[1:4])
     want = torso.apply(params, jnp.swapaxes(obs[:, 1:4], 0, 1))
     assert got.shape == want.shape == (3, B, HID)
-    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-7)
+    # ``Conv_0`` sums each output's products in another order over blocks
+    # (four taps of 16·C for 64 of C): an element near zero differs by the
+    # float32 rounding of the largest terms, 5.5e-7 of the largest output at
+    # most here.
+    np.testing.assert_allclose(
+        got, want, rtol=1e-6, atol=2e-6 * float(jnp.abs(want).max()))
+
+
+def test_conv_0_on_blocks_has_the_strided_convolutions_kernel_gradient():
+    """The gradient of every parameter through the torso over prepared frames
+    is its gradient through the strided ``Conv_0`` over the raw frames
+    (seeded weights, a seeded cotangent), ``Conv_0/kernel`` staying ``[8, 8,
+    3, 32]``."""
+    torso = ConvTorso(out_size=HID)
+    obs = _frames((64, 64, 3), jnp.uint8, seed=4)
+    params = torso.init(jax.random.PRNGKey(5), obs[:, 0])
+    frames = torso.prepare(obs)
+    cotangent = jax.random.normal(jax.random.PRNGKey(6), (3, B, HID))
+
+    def grad(x):
+        return jax.grad(lambda p: jnp.sum(torso.apply(p, x) * cotangent))(params)
+
+    got, want = grad(frames[1:4]), grad(jnp.swapaxes(obs[:, 1:4], 0, 1))
+    kernel = got["params"]["Conv_0"]["kernel"]
+    assert kernel.shape == want["params"]["Conv_0"]["kernel"].shape == (8, 8, 3, 32)
+    assert float(jnp.abs(kernel).max()) > 0
+    # The layers after ``Conv_0`` see its outputs summed in another order:
+    # 6e-7 of a leaf's largest element at most here.
+    for g, w in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(g, w, rtol=1e-6, atol=2e-6 * float(jnp.abs(w).max()))
+
+
+def test_conv_0_on_blocks_keeps_its_name_and_its_parameters_come_from_raw_frames():
+    """Over prepared frames ``Conv_0``'s convolution, forward and weight
+    gradient, still carries ``torso/Conv_0`` in its path (what a trace names
+    it by); the torso is initialised from raw frames, and refuses ``Frames``."""
+    torso = ConvTorso(out_size=HID)
+    obs = _frames((64, 64, 3), jnp.uint8, seed=7)
+    params = torso.init(jax.random.PRNGKey(8), obs[:, 0])
+    frames = torso.prepare(obs)
+
+    def loss(p, x):
+        return jnp.sum(torso.apply(p, x))
+
+    text = jax.jit(jax.grad(loss)).lower(params, frames).as_text(debug_info=True)
+    paths = re.findall(r'"([^"]*conv_general_dilated)"', text)
+    assert any(p.endswith("Conv_0/conv_general_dilated") and "transpose(" not in p
+               for p in paths)
+    assert any(p.endswith("Conv_0/conv_general_dilated") and "transpose(" in p
+               for p in paths)
+    assert not any(p.endswith("/torso/conv_general_dilated") for p in paths)
+    with pytest.raises(ValueError, match="raw frames"):
+        torso.init(jax.random.PRNGKey(8), frames)
 
 
 def test_nets_that_prepare_differently_are_refused(monkeypatch):
