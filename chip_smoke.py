@@ -281,10 +281,11 @@ def _small_leaf_bytes(arena_state, obs_shape: tuple) -> int:
 
     capacity = arena_state.priority.shape[0]
     image = arena_state.data.obs if len(obs_shape) == 3 else None
+    image = {id(x) for x in jax.tree_util.tree_leaves(image)}
     return sum(
         math.prod(x.shape) * x.dtype.itemsize
         for x in jax.tree_util.tree_leaves(arena_state)
-        if x is not image and x.ndim >= 2 and x.shape[0] == capacity
+        if id(x) not in image and x.ndim >= 2 and x.shape[0] == capacity
     )
 
 
@@ -316,8 +317,10 @@ def _require_learner_call_guards(trainer, state, rolled_width=None) -> dict:
     ``[capacity, ...]`` value, inserts no sequence into a batch-minor
     ``[batch, ...]`` buffer, keeps no running sum as long as the arena, reads
     no row out of the arena as a slice or copy of many rows' bytes in an
-    update (``_arena_reads_refused``), and runs no image convolution inside a
-    scan of an update.  Only
+    update (``_arena_reads_refused``) nor re-lays a whole arena leaf in HBM
+    (``arena_relays``: a small row stored in its own shape lies slot
+    minor-most and was copied whole once a call), and runs no image
+    convolution inside a scan of an update.  Only
     the TPU compiler makes the first two choices, gives the third its cost
     (128 adds an element) and lays the arena out (the fourth: a pixel leaf in
     the rows' own shape lies slot minor-most and a row comes out padded 128
@@ -358,6 +361,7 @@ def _require_learner_call_guards(trainer, state, rolled_width=None) -> dict:
     from r2d2dpg_tpu.obs.hlo import (
         arena_converts,
         arena_reads,
+        arena_relays,
         batch_minor_writes,
         capacity_scans,
         frame_contractions,
@@ -388,9 +392,9 @@ def _require_learner_call_guards(trainer, state, rolled_width=None) -> dict:
     # which the compiler keeps where there are two or more), so an update's
     # own operations sit that one loop deep; deeper is a scan inside it.
     call_loops = int(trainer.config.learner_steps > 1)
-    # What the compiler re-lays once a call, outside that loop (the small
-    # leaves of every configuration here, 69 MB a call for cheetah's), is
-    # listed and not refused: no update pays it again.
+    # What the compiler slices or copies once a call, outside that loop, is
+    # listed here and not refused; a whole leaf re-laid in HBM is refused
+    # below (``arena_relays``).
     reads = arena_reads(hlo, trainer.arena.capacity)
     in_updates = _arena_reads_refused(
         reads, call_loops, _small_leaf_bytes(state.arena, trainer.env.spec.obs_shape))
@@ -398,6 +402,11 @@ def _require_learner_call_guards(trainer, state, rolled_width=None) -> dict:
         not in_updates,
         "the learner call reads the arena in slices or copies of many rows' "
         f"bytes in every update: {in_updates}",
+    )
+    relaid = arena_relays(hlo, trainer.arena.capacity)
+    _require(
+        not relaid,
+        f"the learner call re-lays a whole arena leaf in HBM: {relaid}",
     )
     written_back = priority_writes(hlo, trainer.arena.capacity)
     kernels = [w for w in written_back if w[1].startswith("kernel")]
@@ -463,6 +472,11 @@ def _require_learner_call_guards(trainer, state, rolled_width=None) -> dict:
         "priority_writes": written_back,
         "arena_reads": reads,
         "arena_reads_in_updates": in_updates,
+        "arena_relays": relaid,
+        "arena_storage": {
+            jax.tree_util.keystr(path): list(leaf.shape)
+            for path, leaf in jax.tree_util.tree_leaves_with_path(state.arena.data)
+        },
         "loop_convolutions": convolutions,
         "convolutions_in_scans": in_scans,
         "frame_window_elements": window,
@@ -532,8 +546,8 @@ def _leg_train(work: str) -> dict:
     write-back, donated state; then the learner call alone, compiled for the
     whole-arena convert guard, the batch-minor write guard, the
     capacity-long running sum guard, the padded arena read guard, the
-    in-place priority write-back guard, the convolution-in-a-scan guard and
-    the frame re-lay guard, for
+    whole-leaf re-lay guard, the in-place priority write-back guard, the
+    convolution-in-a-scan guard and the frame re-lay guard, for
     ``walker_r2d2`` and, from shapes, for the whole-sequence cores'
     configurations ``humanoid_sdar_moe`` and ``humanoid_ouro_loop`` (the
     latter also for the rolled-stack guard) and the pixel replay's
@@ -547,6 +561,9 @@ def _leg_train(work: str) -> dict:
     for name, (*args, rolled_width) in _LEARNER_CALLS_FROM_SHAPES.items():
         checks[name] = _require_learner_call_guards(
             *_learner_call_from_shapes(*args), rolled_width=rolled_width)
+    for name in ["learner_call", *_LEARNER_CALLS_FROM_SHAPES]:
+        print(f"chip_smoke: {name} arena storage: "
+              + json.dumps(checks[name]["arena_storage"]), flush=True)
     return checks
 
 

@@ -67,7 +67,7 @@ class MLPTorso(nn.Module):
 
 
 # The lanes of a tile of the device's memory: the replay stores a frame's
-# bytes as whole tiles (``replay/arena.py::_storage_shape``), and a row of one
+# bytes as whole tiles (``replay/arena.py::_storage_parts``), and a row of one
 # is what the frames are transposed by.
 _LANES = 128
 

@@ -37,7 +37,7 @@ that none lies deeper than the learner call's own loop over its updates.
 
 ``ReplayArena`` stores a large row as whole tiles behind a major-most slot
 axis, so that one sequence is one stretch of memory
-(``replay/arena.py::_storage_shape``).  Stored in the rows' own shape the
+(``replay/arena.py::_storage_parts``).  Stored in the rows' own shape the
 pixel leaf lay slot minor-most, and each of the B rows of a batch was read as
 a slice padded to 128 times its bytes (70.8 MB for 0.55 MB; 9.4 of cheetah's
 11.5 ms an update, PERF.md PR 34); gathered from the tiles by ``buf[indices]``
@@ -278,6 +278,39 @@ def arena_reads(
                                   _laid_out_bytes(m), row, depth.get(name, 0)))
             if int(m["lead"]) == capacity and m["rest"]:
                 stored[m["name"]] = math.prod(_dims(m)[1:]) * _itemsize(m)
+    return found
+
+
+def arena_relays(hlo_text: str, capacity: int) -> List[Tuple[str, str, int, int]]:
+    """``(name, shape with its layout, bytes as laid out, loops around it)``
+    of every ``copy`` in ``hlo_text`` whose result is a whole ``[capacity,
+    ...]`` value of rank two or more, of 128 elements or more a slot, laid
+    out in HBM (no ``S(1)``), fused or not, in the order printed: an arena
+    leaf re-laid whole.
+
+    A small row stored in its own shape lies slot minor-most, and where the
+    leaf is small enough the TPU compiler copies all of it into a slot-major
+    order once a call before it gathers (``f32[12288,45,6]{0,1,2}`` to
+    ``{1,2,0}``: 0.054 ms of ``cheetah_pixels``' 1.41 ms update; the two
+    whole-sequence cells' ``obs`` and ``action``, 6.4 ms a call; PERF.md PR
+    40).  Stored as its whole lane-rows and the rest
+    (``replay/arena.py::_storage_parts``) the whole lane-rows lie slot-major
+    and are gathered where they lie.  A part of fewer than 128 elements a
+    slot has no shape of its own bytes that lies slot-major, and the
+    compiler may re-lay it (under 512 B a slot of float32): not listed.  Nor
+    is a copy into VMEM (``S(1)``), the compiler staging a leaf next to the
+    gather."""
+    lines, depth = _computations(hlo_text)
+    found = []
+    for name, body in lines.items():
+        for line in body:
+            m = _INSTRUCTION.match(line)
+            if (m and m["opcode"] == "copy" and int(m["lead"]) == capacity
+                    and m["rest"] and math.prod(_dims(m)[1:]) >= 128
+                    and "S(1)" not in (m["tiling"] or "")):
+                layout = f"{{{m['order']}{m['tiling']}}}" if m["order"] else ""
+                found.append((m["name"], m["shape"] + layout,
+                              _laid_out_bytes(m), depth.get(name, 0)))
     return found
 
 

@@ -24,13 +24,14 @@ Sequence layout (SURVEY §2.2 "sequence format"): each slot stores a
 fixed-length window of ``burnin + unroll + n_step`` steps plus the initial
 recurrent carries of actor and critic nets captured at window start.
 
-Storage: one leaf a field, ``[capacity, ...]``.  A small row is stored in its
-own shape; a large one (pixels) as whole tiles, so that the slot axis is
-major-most on the device and one sequence is one stretch of memory
-(``_storage_shape``).  Rows go in and come out in their own shapes whatever
-the storage: ``add`` / ``write_contiguous`` write them, ``gather`` reads
-them, ``sample`` ends in the same gather, and nothing else may assume how a
-leaf of ``ArenaState.data`` is shaped behind its slot axis.
+Storage: every field ``[capacity, ...]``, each row's own bytes and no more,
+shaped so that the device lays the slot axis major-most where it can
+(``_storage_parts``): a small row as its whole 128-lane rows flat and the
+rest beside them, a large one (pixels) as whole tiles, either kept as a
+``StoredRows`` that knows the rows' own shape.  Rows go in and come out in
+their own shapes whatever the storage: ``add`` / ``write_contiguous`` write
+them, ``gather`` reads them, ``sample`` ends in the same gather, and nothing
+else may assume how a field of ``ArenaState.data`` is stored.
 
 The sampled batch is a boundary: ``sample`` hands its B rows back in the
 arena's own dtypes, behind ``_pin_storage_dtypes``.  Without it the TPU
@@ -50,6 +51,7 @@ takes it), not the arena's slot-minor order carried over to the batch.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import threading
 from typing import Any, Dict, Sequence, Tuple
@@ -87,8 +89,8 @@ class SequenceBatch:
 class ArenaState:
     """Device-resident replay storage (a pytree of preallocated buffers)."""
 
-    # One storage leaf a field, [capacity, ...] in the row's dtype and element
-    # count; large rows as tiles (``_storage_shape``): read through ``gather``.
+    # A field's rows [capacity, ...] in their own shape and dtype, or as a
+    # ``StoredRows`` (``_storage_parts``): read through ``gather``.
     data: SequenceBatch
     priority: jnp.ndarray  # [capacity] raw priorities; 0 marks empty slots
     cursor: jnp.ndarray  # next write position
@@ -99,6 +101,22 @@ class ArenaState:
     # replay-age clock).  PROVENANCE_ABSENT (-1) where unknown; survives
     # exactly as long as its slot (the ring scatter overwrites both).
     meta: jnp.ndarray
+
+
+@functools.partial(
+    jax.tree_util.register_dataclass, data_fields=["parts"], meta_fields=["row"]
+)
+@dataclasses.dataclass(frozen=True)
+class StoredRows:
+    """A field of ``ArenaState.data`` stored in other shapes than its rows'
+    (``_storage_parts``): ``parts`` are ``[capacity, ...]`` leaves in the
+    row's dtype that hold each row's elements in order, part after part.
+    ``row``, the rows' own shape, is static, part of the tree's structure
+    like a field's name: any arena reads the rows of a state another made,
+    and a checkpoint is restored into it."""
+
+    parts: Tuple[jnp.ndarray, ...]
+    row: Tuple[int, ...]
 
 
 @jax.tree_util.register_dataclass
@@ -249,69 +267,104 @@ def _pin_storage_dtypes(batch: SequenceBatch) -> SequenceBatch:
     return jax.tree_util.tree_map(pin, batch)
 
 
-# Rows of fewer elements the TPU compiler gathers in one fusion, with no
-# accumulator for a stated layout to help; the loop it expands ``buf[indices]``
-# into was seen from 105,840 elements a row up (compiles for a described v5e).
-# The same rows are the ones stored contiguously (``_storage_shape``).
+# Rows of fewer elements the TPU compiler gathers in one fusion that reads
+# each row where it lies; the loop it expands ``buf[indices]`` into was seen
+# from 105,840 elements a row up (compiles for a described v5e).  One fusion
+# is not one cost: stored in its own shape such a row lies slot minor-most and
+# the fusion reads one lane of every tile the row touches, or the compiler
+# re-lays the whole leaf once a call first.  So these rows are stored as their
+# whole lane-rows and a rest, and the larger ones as tiles
+# (``_storage_parts``).
 _LOOPED_GATHER_ROW_ELEMENTS = 1 << 16
 
 # A tile of the device's memory: 128 lanes by as many sublanes as hold 32
 # bytes a lane (8 float32, 16 bfloat16, 32 uint8).
 _LANES = 128
 _SUBLANE_BYTES = 32
-# The compiler's gather reads a row whose stored dimensions stay at or under
-# this in one piece; a longer one it first cuts out of the WHOLE leaf (a
-# ``[capacity, 128, ...]`` slice an update, seen for the first dimension
-# behind the slots and for the sublanes; compiles for a described v5e).
+# The compiler's looped gather of a large row reads it in one piece while its
+# stored dimensions stay at or under this; a longer one it first cuts out of
+# the WHOLE leaf (a ``[capacity, 128, ...]`` slice an update, seen for the
+# first dimension behind the slots and for the sublanes; compiles for a
+# described v5e).  The one-fusion gather of a small row has no such limit: a
+# flat stretch of 1,024 lanes (walker's observations) is read in place.
 _GATHER_DIMENSION = 128
 
 
-def _storage_shape(row_shape: Tuple[int, ...], dtype) -> Tuple[int, ...]:
-    """The shape one stored row has behind the slot axis.
+def _storage_parts(row_shape: Tuple[int, ...], dtype) -> Tuple[Tuple[int, ...], ...]:
+    """The shapes, behind the slot axis, of the leaves a row is stored in;
+    ``(row_shape,)`` where it is stored in its own shape.
 
-    The device layout of a buffer follows from its shape, and the compiler
-    lays a ``[capacity, L, H, W, C]`` pixel leaf slot minor-most (least
-    padding): one sequence's 552,960 bytes spread over the whole buffer, each
-    read back as a 70.8 MB padded slice (9.4 of ``cheetah_pixels``' 11.5 ms
-    an update, PERF.md PR 34).  So a large row is stored as whole tiles,
-    nothing to pad and the slot axis major-most: the shortest run of its
-    trailing dimensions that is a whole number of tiles becomes ``[n_tiles,
-    sublanes, 128]``, the dimensions before it stay (pixels: a step's frame,
-    ``[L, 3, 32, 128]`` for ``[L, 64, 64, 3]``; keeping the time axis makes
-    the way back to the frames a re-lay of the three minor dimensions alone,
-    0.36 ms an update less than from ``[135, 32, 128]``).  Same dtype, same
-    elements in the same order, so ``buf.reshape(capacity, -1)`` is the rows.
+    The device layout of a buffer follows from its shape: the compiler lays
+    a leaf out with the least padding, which for a row in its own shape puts
+    the slot axis minor-most, and the B rows of a batch are read a lane of
+    every tile they touch.  For ``cheetah_pixels``' ``[capacity, L, H, W,
+    C]`` pixel leaf each was a 70.8 MB padded slice (9.4 of 11.5 ms an
+    update, PERF.md PR 34); for walker's ``f32[524288,43,24]`` observations
+    528 KB of tiles for a row of 4 KB, where a smaller leaf
+    (``f32[12288,45,6]``) was re-laid whole once a call instead (PERF.md PR
+    40).  A leaf whose minor dimension is whole lane-rows, a multiple of 128,
+    lies slot major-most with nothing to pad, and the compiler lays it so.
 
-    Kept in its own shape: a small row (under ``_LOOPED_GATHER_ROW_ELEMENTS``;
-    its gather is one fusion whatever the order), and a large one that is no
-    whole number of tiles or would need a dimension over
-    ``_GATHER_DIMENSION``."""
+    A small row (under ``_LOOPED_GATHER_ROW_ELEMENTS``) of n elements is
+    stored as two leaves: its first ``128 · floor(n / 128)`` elements flat,
+    slot major-most, read in place; the other ``n mod 128`` flat beside
+    them, a leaf that lies slot minor-most with each row in one column of a
+    few tiles (walker's observations: 1,024 and 8).  A row that is whole
+    lane-rows is only flattened (a carry of 256 keeps its shape), and one
+    under 128 elements keeps its own shape: no shape of its own bytes lies
+    slot major-most.  Padding each row to whole lane-rows would keep one
+    leaf, at 1 GB more of walker's arena: the arena holds the rows' bytes.
+
+    A large row is stored as whole tiles, nothing to pad and the slot axis
+    major-most: the shortest run of its trailing dimensions that is a whole
+    number of tiles becomes ``[n_tiles, sublanes, 128]``, the dimensions
+    before it stay (pixels: a step's frame, ``[L, 3, 32, 128]`` for ``[L, 64,
+    64, 3]``; keeping the time axis makes the way back to the frames a re-lay
+    of the three minor dimensions alone, 0.36 ms an update less than from
+    ``[135, 32, 128]``).  A large row that is no whole number of tiles, or
+    would need a dimension over ``_GATHER_DIMENSION``, keeps its own shape.
+
+    The parts hold the row's elements in order, in the row's dtype:
+    ``concatenate([p.reshape(capacity, -1) for p in parts], 1)`` is the
+    rows."""
     row_shape = tuple(row_shape)
-    if math.prod(row_shape) < _LOOPED_GATHER_ROW_ELEMENTS:
-        return row_shape
+    elements = math.prod(row_shape)
+    if elements < _LOOPED_GATHER_ROW_ELEMENTS:
+        whole, rest = divmod(elements, _LANES)
+        if not whole or (row_shape == (elements,) and not rest):
+            return (row_shape,)
+        return ((whole * _LANES,), (rest,)) if rest else ((elements,),)
     sublanes = max(_SUBLANE_BYTES // jnp.dtype(dtype).itemsize, 1)
     tile = sublanes * _LANES
     for k in reversed(range(len(row_shape))):
         inner = math.prod(row_shape[k:])
         stored = row_shape[:k] + (inner // tile, sublanes, _LANES)
         if inner % tile == 0 and max(stored) <= _GATHER_DIMENSION:
-            return stored
-    return row_shape
+            return (stored,)
+    return (row_shape,)
 
 
-def _as_stored(buf: jnp.ndarray, rows: jnp.ndarray) -> jnp.ndarray:
-    """``rows`` (``[n, ...]`` in their own shape) in ``buf``'s storage shape
-    and dtype."""
-    return rows.astype(buf.dtype).reshape(rows.shape[:1] + buf.shape[1:])
+def _as_stored(stored: Any, rows: jnp.ndarray) -> Any:
+    """``rows`` (``[n, ...]`` in their own shape) as ``stored`` (a field of
+    ``ArenaState.data``, or its shapes) holds them: in its dtype, and cut
+    into its parts where it is a ``StoredRows``."""
+    n = rows.shape[0]
+    if not isinstance(stored, StoredRows):
+        return rows.astype(stored.dtype).reshape((n,) + stored.shape[1:])
+    flat = rows.astype(stored.parts[0].dtype).reshape(n, -1)
+    parts, at = [], 0
+    for part in stored.parts:
+        width = math.prod(part.shape[1:])
+        parts.append(flat[:, at : at + width].reshape((n,) + part.shape[1:]))
+        at += width
+    return dataclasses.replace(stored, parts=tuple(parts))
 
 
-def _gather_rows(
-    buf: jnp.ndarray, indices: jnp.ndarray, row_shape: Tuple[int, ...]
-) -> jnp.ndarray:
-    """``buf[indices]`` in the rows' own shape, with the device layout of
-    large rows stated here: batch and time major-most, in the order their
-    consumer wants them, so that a sequence is written once into stretches
-    of its own.
+def _gather_rows(stored: Any, indices: jnp.ndarray) -> jnp.ndarray:
+    """The rows of ``indices`` of one field of ``ArenaState.data`` in their
+    own shape, with the device layout of large rows stated here: batch and
+    time major-most, in the order their consumer wants them, so that a
+    sequence is written once into stretches of its own.
 
     The TPU compiler expands the gather of a large row into a loop of B
     iterations over an accumulator, and left to itself hands the consumer a
@@ -319,7 +372,7 @@ def _gather_rows(
     leaf in the rows' own shape, every iteration rewrote the whole buffer to
     fill one lane of each tile (``dynamic-update-slice``, 59 of
     ``cheetah_pixels``' 70 ms an update; PERF.md, PR 28).  A row stored as
-    tiles behind its time axis (``_storage_shape``) is stated as it is
+    tiles behind its time axis (``_storage_parts``) is stated as it is
     stored, TIME major-most: the loop copies a sequence as L runs of a step's
     tiles, and the learner takes the steps of all sequences as rows of one
     time-major array without another pass (``models/torsos.py::ConvTorso
@@ -328,15 +381,21 @@ def _gather_rows(
     an update: PERF.md, PR 35); a consumer that takes the rows' own shape
     pays the one re-lay it paid before.  A large row kept in its own shape
     is stated batch major-most, then time, the two longest of the other
-    dimensions minor-most (least padding).  The values are the stored rows',
-    bit for bit; on the CPU the constraint is the identity.
-    ``chip_smoke.py``'s train leg holds the compiled learner call to all of
-    it (``obs/hlo.py::batch_minor_writes``, ``arena_reads``,
-    ``frame_relays``)."""
-    rows = buf[indices]
+    dimensions minor-most (least padding).  A small row's parts are gathered
+    each where it lies and joined: B rows, no layout to state.  The values
+    are the stored rows', bit for bit; on the CPU the constraint is the
+    identity.  ``chip_smoke.py``'s train leg holds the compiled learner call
+    to all of it (``obs/hlo.py::batch_minor_writes``, ``arena_reads``,
+    ``arena_relays``, ``frame_relays``)."""
+    if isinstance(stored, StoredRows):
+        parts, row_shape = stored.parts, stored.row
+    else:
+        parts, row_shape = (stored,), tuple(stored.shape[1:])
     own = indices.shape[:1] + row_shape
     if math.prod(row_shape) < _LOOPED_GATHER_ROW_ELEMENTS:
-        return rows.reshape(own)
+        flat = [part[indices].reshape(indices.shape[:1] + (-1,)) for part in parts]
+        return jnp.concatenate(flat, axis=1).reshape(own)
+    rows = parts[0][indices]
     if rows.shape != own and rows.shape[1] == row_shape[0]:
         stored = with_layout_constraint(
             rows, Layout(major_to_minor=(1, 0, *range(2, rows.ndim)))
@@ -349,17 +408,25 @@ def _gather_rows(
     )
 
 
-def _gather(data: Any, indices: jnp.ndarray, row_shapes: Dict[str, Any]) -> Any:
-    """The stored rows of ``indices`` for every leaf of ``data`` (an
+def _is_field(x: Any) -> bool:
+    return isinstance(x, StoredRows)
+
+
+def _stored_like(data: Any, batch: SequenceBatch) -> Any:
+    """``batch``'s rows as ``data`` (an ``ArenaState.data``) stores them,
+    field by field (``_as_stored``): a tree of ``data``'s structure."""
+    return jax.tree_util.tree_map(
+        lambda rows, stored: _as_stored(stored, rows), batch, data
+    )
+
+
+def _gather(data: Any, indices: jnp.ndarray) -> Any:
+    """The stored rows of ``indices`` for every field of ``data`` (an
     ``ArenaState.data``, or any part of one: an empty tree gives an empty
-    batch).  ``row_shapes`` names the rows' own shape for the leaves stored
-    in another (``ReplayArena.init_state``)."""
-
-    def rows(path, buf):
-        own = row_shapes.get(jax.tree_util.keystr(path), buf.shape[1:])
-        return _gather_rows(buf, indices, own)
-
-    return jax.tree_util.tree_map_with_path(rows, data)
+    batch), each in its own shape."""
+    return jax.tree_util.tree_map(
+        lambda stored: _gather_rows(stored, indices), data, is_leaf=_is_field
+    )
 
 
 # Slots to a block of the two-level draw: the lane width, so that the vector
@@ -437,9 +504,6 @@ class ReplayArena:
         # Pallas needs single-device refs; trainers whose arena buffers carry
         # an explicit mesh sharding (parallel.hybrid) use the XLA scatter.
         self.use_pallas = use_pallas
-        # The rows' own shape for every leaf stored in another, by the
-        # leaf's path in ``ArenaState.data`` (``init_state`` fills it).
-        self._row_shapes: Dict[str, Tuple[int, ...]] = {}
         # Telemetry (obs/): the arena itself is pure device code, so the
         # host-side instruments are fed by whoever fetches the state —
         # trainer/pipeline log paths call ``observe_state_scalars`` with
@@ -483,19 +547,24 @@ class ReplayArena:
     def init_state(self, example: SequenceBatch) -> ArenaState:
         """Preallocate buffers from one example sequence batch (leading dim B).
 
-        One storage leaf a field, in the row's dtype and element count; a
-        large row's leaf in ``_storage_shape``'s shape, and its own shape
-        recorded here for ``gather`` (static, like ``capacity``: the state
-        itself stays arrays alone)."""
+        Every field in the row's dtype and ``_storage_parts``' shapes: a
+        row kept in its own shape as one ``[capacity, ...]`` leaf, another
+        as a ``StoredRows`` of its parts."""
 
-        def alloc(path, x):
-            stored = _storage_shape(x.shape[1:], x.dtype)
-            if stored != x.shape[1:]:
-                self._row_shapes[jax.tree_util.keystr(path)] = x.shape[1:]
-            return jnp.zeros((self.capacity,) + stored, x.dtype)
+        def alloc(x):
+            row = tuple(x.shape[1:])
+            parts = _storage_parts(row, x.dtype)
+            if parts == (row,):
+                return jnp.zeros((self.capacity,) + row, x.dtype)
+            return StoredRows(
+                parts=tuple(
+                    jnp.zeros((self.capacity,) + part, x.dtype) for part in parts
+                ),
+                row=row,
+            )
 
         return ArenaState(
-            data=jax.tree_util.tree_map_with_path(alloc, example),
+            data=jax.tree_util.tree_map(alloc, example),
             priority=jnp.zeros((self.capacity,), jnp.float32),
             cursor=jnp.zeros((), jnp.int32),
             total_added=jnp.zeros((), jnp.int32),
@@ -521,9 +590,9 @@ class ReplayArena:
         idx = (state.cursor + jnp.arange(b, dtype=jnp.int32)) % self.capacity
 
         data = jax.tree_util.tree_map(
-            lambda buf, new: buf.at[idx].set(_as_stored(buf, new)),
+            lambda buf, new: buf.at[idx].set(new),
             state.data,
-            batch,
+            _stored_like(state.data, batch),
         )
         priority = state.priority.at[idx].set(
             jnp.maximum(priorities, PRIORITY_EPS)
@@ -557,7 +626,7 @@ class ReplayArena:
             )
 
         return ArenaState(
-            data=jax.tree_util.tree_map(put, state.data, batch),
+            data=jax.tree_util.tree_map(put, state.data, _stored_like(state.data, batch)),
             priority=put(state.priority, jnp.maximum(priorities, PRIORITY_EPS)),
             cursor=(state.cursor + n) % self.capacity,
             total_added=state.total_added + n,
@@ -697,7 +766,7 @@ class ReplayArena:
         # The same gather as ``gather``, reached as a function of
         # ``state.data`` (a subclass that keeps its rows elsewhere hands
         # ``sample`` an empty ``data`` and gets an empty batch).
-        batch = _gather(state.data, indices, self._row_shapes)
+        batch = _gather(state.data, indices)
         return SampleResult(
             batch=_pin_storage_dtypes(batch), indices=indices, probs=probs
         )
@@ -706,7 +775,7 @@ class ReplayArena:
         """The stored rows of the slots ``indices`` in the rows' own shapes
         and dtypes, the layout of large rows stated (``_gather_rows``):
         what ``sample`` ends with, before ``_pin_storage_dtypes``."""
-        return _gather(state.data, indices, self._row_shapes)
+        return _gather(state.data, indices)
 
     # ------------------------------------------------------- priority update
     def update_priorities(

@@ -209,16 +209,22 @@ def _names(path) -> tuple:
 
 
 def _replay_as_saved(abstract: Any, metadata: Any) -> Any:
-    """``abstract`` (the restore target) with every replay leaf in the shape
+    """``abstract`` (the restore target) with every replay field in the shape
     the checkpoint holds it in; ``None`` where none has another.
 
-    The arena stores a large row as whole tiles since PR 34
-    (``replay/arena.py::_storage_shape``: a pixel leaf ``[capacity, L, 3, 32,
-    128]``); a checkpoint written before holds it in the rows' own shape
-    (``[capacity, L, 64, 64, 3]``), which orbax refuses to read into another.
-    Same dtype, same slots, same elements in the same order: such a leaf is
-    read as saved and ``_replay_as_stored`` reshapes it.  A replay leaf that
+    The arena stores a large row as whole tiles since PR 34, and a small row
+    of 128 elements or more as its whole lane-rows and the rest since PR 40,
+    either as a ``StoredRows`` of its parts (``replay/arena.py::
+    _storage_parts``: a pixel field ``[capacity, L, 3, 32, 128]``, walker's
+    observations ``[capacity, 1024]`` and ``[capacity, 8]``).  A checkpoint
+    written before holds such a field as one leaf in the rows' own shape
+    (``[capacity, L, 64, 64, 3]``, ``[capacity, 43, 24]``) or, in between, as
+    tiles, which orbax refuses to read into another shape or tree.  Same
+    dtype, same slots, same elements in the same order: such a leaf is read
+    as saved and ``_replay_as_stored`` stores its rows.  A replay field that
     differs in anything else is refused by name."""
+    from r2d2dpg_tpu.replay.arena import StoredRows
+
     saved = {
         _names(path): m
         for path, m in jax.tree_util.tree_flatten_with_path(metadata)[0]
@@ -228,43 +234,58 @@ def _replay_as_saved(abstract: Any, metadata: Any) -> Any:
     def fit(path, want):
         names = _names(path)
         m = saved.get(names)
+        parts = isinstance(want, StoredRows)
         if (
             not ("arena" in names and "data" in names)
             or m is None
-            or tuple(m.shape) == tuple(want.shape)
+            or (not parts and tuple(m.shape) == tuple(want.shape))
         ):
             return want
+        first = want.parts[0] if parts else want
+        row = want.row if parts else tuple(want.shape[1:])
         if (
-            m.dtype != want.dtype
-            or tuple(m.shape)[:1] != tuple(want.shape)[:1]
-            or math.prod(m.shape) != math.prod(want.shape)
+            m.dtype != first.dtype
+            or tuple(m.shape)[:1] != tuple(first.shape)[:1]
+            or math.prod(m.shape[1:]) != math.prod(row)
         ):
             raise ValueError(
                 f"checkpoint replay leaf {jax.tree_util.keystr(path)} is "
-                f"{m.dtype}{list(m.shape)}; this arena stores {want.dtype}"
-                f"{list(want.shape)}: another capacity, row or dtype, not an "
-                "older storage shape of the same rows"
+                f"{m.dtype}{list(m.shape)}; this arena stores {first.dtype}"
+                f"{[first.shape[0], *row]}: another capacity, row or dtype, not "
+                "an older storage shape of the same rows"
             )
         older.append(names)
         return jax.ShapeDtypeStruct(
-            tuple(m.shape), want.dtype, sharding=want.sharding
+            tuple(m.shape), first.dtype, sharding=first.sharding
         )
 
-    as_saved = jax.tree_util.tree_map_with_path(fit, abstract)
+    as_saved = jax.tree_util.tree_map_with_path(
+        fit, abstract, is_leaf=lambda x: isinstance(x, StoredRows)
+    )
     return as_saved if older else None
 
 
 def _replay_as_stored(restored: Any, abstract: Any) -> Any:
     """``restored`` with the leaves ``_replay_as_saved`` read in an older
-    storage shape reshaped to the arena's (``abstract``'s), on its sharding."""
+    storage shape stored as the arena stores them (``abstract``'s shapes),
+    on its sharding."""
+    from r2d2dpg_tpu.replay.arena import StoredRows, _as_stored
+
+    def put(x, want):
+        return x if want.sharding is None else jax.device_put(x, want.sharding)
 
     def fit(got, want):
-        if not isinstance(want, jax.ShapeDtypeStruct) or got.shape == want.shape:
+        if isinstance(got, StoredRows) or not isinstance(
+            want, (StoredRows, jax.ShapeDtypeStruct)
+        ):
             return got
-        stored = jnp.reshape(got, want.shape)
-        return stored if want.sharding is None else jax.device_put(stored, want.sharding)
+        if not isinstance(want, StoredRows) and got.shape == want.shape:
+            return got
+        return jax.tree_util.tree_map(put, _as_stored(want, got), want)
 
-    return jax.tree_util.tree_map(fit, restored, abstract)
+    return jax.tree_util.tree_map(
+        fit, restored, abstract, is_leaf=lambda x: isinstance(x, StoredRows)
+    )
 
 
 def check_restored_leaves(restored: Any, template: Any, *, where: str, hint: str) -> None:

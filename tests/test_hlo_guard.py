@@ -3,18 +3,21 @@ guards of the learner call (the whole-arena convert, the batch-minor write of
 the sampled batch, a running sum as long as the arena, a row read out of the
 arena as many rows' bytes, an image convolution run once a scan step), the
 priority write-back in place (PR 37), the prepared frames' re-lays and what
-reads them (PR 35, PR 39) and the
+reads them (PR 35, PR 39), a whole arena leaf re-laid in HBM (PR 40) and the
 sixth (a looped stack's products inside its loops, one copy a pass), on HLO
 text as the TPU compiler prints it.  Only the
 chip's compiler makes either choice, so the CPU tests the readers alone, and
 the one thing that can be compiled here without a chip: ``ReplayArena.sample``
 for a described v5e."""
 
+import math
+
 import pytest
 
 from r2d2dpg_tpu.obs.hlo import (
     arena_converts,
     arena_reads,
+    arena_relays,
     batch_minor_writes,
     capacity_scans,
     frame_contractions,
@@ -675,6 +678,67 @@ def test_the_small_leaves_are_the_arenas_leaves_but_the_pixels():
         SMALL_LEAF_BYTES + 8000 * 45 * 64 * 64 * 3)
 
 
+# ``cheetah_pixels``' learner call at the cell's 12,288 slots compiled for a
+# described v5e (JAX 0.9.0, libtpu 0.0.34; PR 39's tree), cut to its small
+# leaves: stored in the rows' own shape they lie slot minor-most, and the
+# compiler re-lays three of them whole once a call before the update loop
+# gathers from them (``copy.420``, the trace's 0.054 ms an update), one of
+# them into VMEM (``copy.421``, ``S(1)``).
+RELAID_SMALL_LEAVES = """\
+ENTRY %main.243 (arena_data_action.1: f32[12288,45,6], arena_data_reward.1: f32[12288,45], arena_data_discount.1: f32[12288,45], arena_data_reset.1: f32[12288,45]) -> f32[] {
+  %arena_data_action.1 = f32[12288,45,6]{0,1,2:T(8,128)} parameter(188), metadata={op_name="arena.data.action"}
+  %arena_data_reward.1 = f32[12288,45]{0,1:T(8,128)} parameter(189), metadata={op_name="arena.data.reward"}
+  %arena_data_discount.1 = f32[12288,45]{0,1:T(8,128)} parameter(190), metadata={op_name="arena.data.discount"}
+  %arena_data_reset.1 = f32[12288,45]{0,1:T(8,128)} parameter(191), metadata={op_name="arena.data.reset"}
+  %copy.420 = f32[12288,45,6]{1,2,0:T(8,128)} copy(%arena_data_action.1)
+  %custom-call.172 = f32[12288,45]{0,1:T(8,128)S(1)} custom-call(%slice-done.36, %slice-done.37, %slice-done.38), custom_call_target="ConcatBitcast"
+  %copy.421 = f32[12288,45]{1,0:T(8,128)S(1)} copy(%custom-call.172)
+  %custom-call.173 = f32[12288,45]{0,1:T(8,128)S(1)} custom-call(%slice-done.39, %slice-done.40, %slice-done.41), custom_call_target="ConcatBitcast"
+  %copy.422 = f32[12288,45]{1,0:T(8,128)} copy(%custom-call.173)
+  %copy.423 = f32[12288,45]{1,0:T(8,128)} copy(%arena_data_reset.1)
+  %copy.424 = f32[64,45]{1,0:T(8,128)} copy(%gather.12)
+  %while.898 = (s32[]{:T(128)}, f32[12288,45,6]{1,2,0:T(8,128)}) while(%tuple.1036), condition=%region_188.232, body=%region_0.231
+}
+"""
+
+# The same leaves from PR 40 on: the action's whole lane-rows flat and
+# slot-major, gathered where they lie; its last 14 values and the [45] leaves
+# have no shape of their own bytes that lies slot-major, and the compiler may
+# re-lay them (``copy.346`` / ``.349``, under 128 elements a slot).
+SPLIT_SMALL_LEAVES = """\
+ENTRY %main.243 (arena_data_action_parts_0_.1: f32[12288,256], arena_data_action_parts_1_.1: f32[12288,14], arena_data_reset.1: f32[12288,45]) -> f32[] {
+  %arena_data_action_parts_0_.1 = f32[12288,256]{1,0:T(8,128)} parameter(188), metadata={op_name="arena.data.action.parts[0]"}
+  %arena_data_action_parts_1_.1 = f32[12288,14]{0,1:T(8,128)} parameter(189), metadata={op_name="arena.data.action.parts[1]"}
+  %arena_data_reset.1 = f32[12288,45]{0,1:T(8,128)} parameter(190), metadata={op_name="arena.data.reset"}
+  %copy.346 = f32[12288,14]{1,0:T(8,128)} copy(%arena_data_action_parts_1_.1)
+  %copy.349 = f32[12288,45]{1,0:T(8,128)} copy(%arena_data_reset.1)
+  %while.898 = (s32[]{:T(128)}, f32[12288,256]{1,0:T(8,128)}) while(%tuple.1036), condition=%region_188.232, body=%region_0.231
+}
+"""
+
+
+@pytest.mark.parametrize(
+    "hlo, capacity, want",
+    [
+        (RELAID_SMALL_LEAVES, 12288, [
+            # 45 steps padded to 128 lanes, 6 values to 8 sublanes; the
+            # [45] leaves (copy.422 / .423) are rows of under 128 elements.
+            ("copy.420", "f32[12288,45,6]{1,2,0:T(8,128)}", 12288 * 128 * 8 * 4, 0),
+        ]),
+        (SPLIT_SMALL_LEAVES, 12288, []),
+        (RELAID_SMALL_LEAVES, 8000, []),  # no value of that many slots
+        ("", 12288, []),
+    ],
+    ids=["parent_relaid", "split", "another_capacity", "empty"],
+)
+def test_arena_relays_names_every_whole_leaf_copied_in_hbm(hlo, capacity, want):
+    """A copy of a whole ``[capacity, ...]`` value of 128 elements a slot or
+    more is listed where its result lies in HBM; the copy into VMEM
+    (``copy.421``), a copy of a leaf of fewer elements a slot and a copy of
+    the batch are not."""
+    assert arena_relays(hlo, capacity) == want
+
+
 # ``cheetah_pixels``' learner call compiled for a described v5e, cut to what
 # moves the sampled frames between the gather and ``Conv_0``.  PR 35's parent
 # cut the two windows out of the batch first and every pass of the torso
@@ -1025,6 +1089,63 @@ def test_sample_compiled_for_v5e_reads_rows_as_stored_into_a_batch_major_buffer(
     assert f"u8[{capacity},{L},3,32,128]{{4,3,2,1,0:" in hlo  # slot-major tiles
     assert [r for r in arena_reads(hlo, capacity) if r[1].startswith("u8[")] == []
     assert batch_minor_writes(hlo, B) == []
+
+
+@pytest.mark.parametrize(
+    "capacity, frame, frame_dtype, length, batch",
+    [(524288, (24,), "float32", 43, 64), (12288, (64, 64, 3), "uint8", 45, 32)],
+    ids=["walker_r2d2", "cheetah_pixels"],
+)
+def test_sample_compiled_for_v5e_gathers_every_leaf_where_it_lies(
+    capacity, frame, frame_dtype, length, batch, one_chip, no_compile_cache
+):
+    """``ReplayArena.sample`` at the cells' row shapes, batches and
+    capacities (an ahead-of-time compile allocates nothing), its rows
+    consumed as floats, compiled for a described v5e: every ``arena.data``
+    leaf of 128 elements a slot or more lies slot major-most (a small row's
+    whole lane-rows flat, ``{1,0:...}``; the carries; cheetah's pixels as
+    tiles), and no copy or slice takes a whole ``[capacity, ...]`` leaf of
+    them, in HBM or into VMEM.  What lies slot minor-most is what has no
+    shape of its own bytes that lies slot-major: the rest of a row after its
+    whole lane-rows (walker's observation 8 values, its action 2, cheetah's
+    action 14) and the ``[L]`` leaves.  Stored in the rows' own shape,
+    walker's observation lay slot minor-most (``{0,2,1:...}``) and cheetah's
+    action leaf was re-laid whole once a call.  Nothing runs: a compile says
+    nothing about results or times."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    arena, state, key = _sample_shapes(capacity, frame, frame_dtype, length, one_chip)
+
+    def program(s, k):
+        rows = arena.sample(s, k, batch).batch
+        return jax.tree_util.tree_map(lambda x: x.astype(jnp.float32).sum(), rows)
+
+    hlo = jax.jit(program).trace(state, key).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    data = re.findall(r"%\w*_data_\S+ = \w+\[(\d+)((?:,\d+)*)\]\{([\d,]+)", hlo)
+    minor = []
+    for lead, rest, order in data:
+        dims = [int(d) for d in rest.split(",") if d]
+        assert int(lead) == capacity
+        if math.prod(dims) >= 128:
+            assert order == ",".join(str(d) for d in reversed(range(1 + len(dims)))), dims
+        else:
+            minor.append(dims)
+    # obs and action in two parts where they have a rest, reward, discount,
+    # reset, four carries.
+    if frame == (24,):
+        assert len(data) == 11 and sorted(minor) == [[2], [8], [43], [43], [43]]
+    else:
+        assert len(data) == 10 and sorted(minor) == [[14], [45], [45], [45]]
+    assert arena_relays(hlo, capacity) == []
+
+    def slot(shape):
+        return math.prod(int(d) for d in re.match(r"\w+\[(\d+(?:,\d+)*)\]", shape)[1].split(",")[1:])
+
+    assert [r for r in arena_reads(hlo, capacity, rows=4) if slot(r[1]) >= 128] == []
 
 
 def test_learner_call_compiled_for_v5e_prepares_its_frames_once(

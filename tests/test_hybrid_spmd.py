@@ -61,7 +61,9 @@ def test_hybrid_runs_and_learns_shapes():
     # The window stays sharded; the arena is replicated by design (see
     # hybrid.py layout note).
     assert state.window.obs.sharding.spec[0] == DP_AXIS
-    assert state.arena.data.obs.sharding.is_fully_replicated
+    # (a field stored in parts, ``StoredRows``, replicates every part).
+    for leaf in jax.tree_util.tree_leaves(state.arena.data.obs):
+        assert leaf.sharding.is_fully_replicated
     # Params stay replicated (pjit keeps them unsharded across the mesh).
     leaf = jax.tree_util.tree_leaves(state.train.actor_params)[0]
     assert leaf.sharding.is_fully_replicated

@@ -2,6 +2,7 @@
 write-back via the Pallas kernel (interpret mode) — SURVEY.md §4.1/§4.5."""
 
 import dataclasses
+import math
 import os
 
 os.environ["R2D2DPG_PALLAS_INTERPRET"] = "1"  # exercise the kernel on CPU
@@ -13,6 +14,7 @@ import pytest
 
 from chip_smoke import SCATTER_CAPACITIES, scatter_case
 from r2d2dpg_tpu.replay import ReplayArena, SequenceBatch
+from r2d2dpg_tpu.replay.arena import StoredRows
 
 L, OBS, ACT, HID = 4, 3, 2, 8
 
@@ -767,7 +769,7 @@ def test_write_contiguous_at_a_cursor_equals_add(frame):
         ((64, 64, 4), "float32", (L, 16, 8, 128)),  # a step is 16 tiles of 8 x 128
         ((64, 64, 4), "bfloat16", (L, 8, 16, 128)),
         ((50, 111, 3), "uint8", (L, 50, 111, 3)),  # no whole number of tiles
-        ((2, 2, 3), "uint8", (L, 2, 2, 3)),  # a small row
+        ((2, 2, 3), "uint8", (L, 2, 2, 3)),  # a small row of 48 elements
     ],
     ids=["uint8_row_tiles", "float32_step_tiles", "bfloat16_step_tiles",
          "no_whole_tiles", "small_row"],
@@ -775,22 +777,35 @@ def test_write_contiguous_at_a_cursor_equals_add(frame):
 def test_a_large_rows_storage_is_the_rows_elements_in_order(frame, dtype, stored):
     """A large row's storage leaf has another shape behind the slot axis and
     nothing else: the row's dtype, its element count, its elements in order,
-    so ``buf.reshape(capacity, -1)`` is the rows.  The shortest run of
-    trailing dimensions that is whole tiles is stored as tiles; a row that
-    has none, or is small, keeps its own shape."""
+    so ``buf.reshape(capacity, -1)`` is the rows, kept as a ``StoredRows``
+    that knows the rows' own shape.  The shortest run of trailing dimensions
+    that is whole tiles is stored as tiles; a row that has none, or is small
+    and under one lane-row, keeps its own shape."""
     n, capacity = 3, 4
     rng = np.random.default_rng(1)
     obs = jnp.asarray(rng.integers(0, 200, (n, L) + frame)).astype(dtype)
     rows = dataclasses.replace(make_batch(n), obs=obs)
     arena = ReplayArena(capacity=capacity)
     state = arena.add(arena.init_state(rows), rows, jnp.ones(n))
-    assert state.data.obs.shape == (capacity,) + stored
-    assert state.data.obs.dtype == obs.dtype
+    buf = _one_leaf(state.data.obs, (L,) + frame)
+    assert buf.shape == (capacity,) + stored
+    assert buf.dtype == obs.dtype
     np.testing.assert_array_equal(
-        _bits(state.data.obs.reshape(capacity, -1)[:n]), _bits(obs.reshape(n, -1)))
-    # Every other leaf is small here and keeps the row's shape.
+        _bits(buf.reshape(capacity, -1)[:n]), _bits(obs.reshape(n, -1)))
+    # Every other leaf is small here, under one lane-row, and keeps the
+    # row's shape.
     assert state.data.action.shape == (capacity, L, ACT)
     _rows_equal(arena.gather(state, jnp.arange(n)), rows)
+
+
+def _one_leaf(field, row):
+    """The one leaf a field is stored in: the ``StoredRows``' single part,
+    which knows the rows' shape, or the field itself in the rows' shape."""
+    if isinstance(field, StoredRows):
+        assert field.row == row and len(field.parts) == 1
+        return field.parts[0]
+    assert field.shape[1:] == row
+    return field
 
 
 def test_sample_under_jit_with_donated_state_returns_rows_in_their_own_shapes():
@@ -821,9 +836,10 @@ def test_dp_sharded_arena_shards_a_large_rows_storage_over_its_slots():
     rows, priorities = _mixed_rows(12, (64, 96, 3))
     arena = ReplayArena(capacity=16, use_pallas=False)
     state = _dp_arena_state(arena, rows, priorities, make_mesh(2))
-    assert state.data.obs.shape == (16, 18, 32, 128)
-    assert state.data.obs.sharding.spec == P(DP_AXIS)
-    assert {s.data.shape for s in state.data.obs.addressable_shards} == {(8, 18, 32, 128)}
+    tiles = _one_leaf(state.data.obs, (L, 64, 96, 3))
+    assert tiles.shape == (16, 18, 32, 128)
+    assert tiles.sharding.spec == P(DP_AXIS)
+    assert {s.data.shape for s in tiles.addressable_shards} == {(8, 18, 32, 128)}
     res = jax.jit(arena.sample, static_argnums=2)(state, jax.random.PRNGKey(4), 6)
     want = jax.tree_util.tree_map(lambda x: x[res.indices], rows)
     np.testing.assert_array_equal(np.asarray(res.batch.obs), np.asarray(want.obs))
@@ -832,3 +848,119 @@ def test_dp_sharded_arena_shards_a_large_rows_storage_over_its_slots():
     for g, w in zip(jax.tree_util.tree_leaves(res.batch), jax.tree_util.tree_leaves(want)):
         assert g.shape == w.shape and g.dtype == w.dtype
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=1e-30)
+
+
+# ------------------ small rows as their whole lane-rows and the rest (PR 40)
+# Row shapes of the benchmark's configurations: walker's observation and
+# action (43 x 24, 43 x 6) and its [43] leaves, the whole-sequence cells'
+# (85 x 67, 85 x 21), and cheetah's pixels beside its actions (a large row,
+# stored as tiles).  A carry of 256 is whole lane-rows already.
+ROW_SETS = {
+    "walker_rows": (43, (24,), "float32", 6),
+    "whole_sequence_rows": (85, (67,), "float32", 21),
+    "pixel_rows": (45, (64, 64, 3), "uint8", 6),
+}
+
+
+def _odd_rows(n, steps, frame, frame_dtype, act):
+    """``n`` rows at these shapes, the float leaves with bit patterns a
+    rounding would lose, ``reset`` a ``bool`` leaf."""
+    rng = np.random.default_rng(7)
+
+    def floats(*shape):
+        x = rng.standard_normal(shape).astype(np.float32)
+        x.flat[0] = np.array(0x7FC00123, np.uint32).view(np.float32)  # NaN payload
+        x.flat[-1] = np.float32(-0.0)
+        return jnp.asarray(x)
+
+    obs = (jnp.asarray(rng.integers(0, 256, (n, steps) + frame, dtype=np.uint8))
+           if frame_dtype == "uint8" else floats(n, steps, *frame))
+    batch = SequenceBatch(
+        obs=obs, action=floats(n, steps, act), reward=floats(n, steps),
+        discount=floats(n, steps),
+        reset=jnp.asarray(rng.random((n, steps)) < 0.3),
+        carries={"actor": (floats(n, 256), floats(n, 256)),
+                 "critic": (floats(n, 256), floats(n, 256))},
+    )
+    return batch, jnp.asarray(rng.random(n) + 0.1, jnp.float32)
+
+
+@pytest.mark.parametrize("entry", ["add", "add_staged", "write_contiguous"])
+@pytest.mark.parametrize("rows_at", list(ROW_SETS), ids=list(ROW_SETS))
+def test_small_rows_go_in_and_come_out_bit_for_bit(rows_at, entry):
+    """A small row of n elements is stored as its first ``128 * (n // 128)``
+    elements flat and the other ``n % 128`` beside them, in its own dtype
+    and in order; one of whole lane-rows is only flattened (a carry of 256
+    keeps its shape), one under 128 elements keeps its shape, a large one is
+    tiles.  ``gather`` and ``sample`` give every row back bit for bit in its
+    own shape and dtype, whichever entry wrote it."""
+    from r2d2dpg_tpu.replay import StagedSequences
+
+    steps, frame, frame_dtype, act = ROW_SETS[rows_at]
+    n, capacity = 5, 8
+    rows, priorities = _odd_rows(n, steps, frame, frame_dtype, act)
+    arena = ReplayArena(capacity=capacity)
+    state = arena.init_state(rows)
+    if entry == "add":
+        state = arena.add(state, rows, priorities)
+    elif entry == "add_staged":
+        state = arena.add_staged(state, StagedSequences(seq=rows, priorities=priorities))
+    else:
+        state = jax.jit(arena.write_contiguous, donate_argnums=0)(state, rows, priorities)
+
+    fields = jax.tree_util.tree_leaves(state.data, is_leaf=lambda x: isinstance(x, StoredRows))
+    for field, row in zip(fields, jax.tree_util.tree_leaves(rows)):
+        elements = math.prod(row.shape[1:])
+        whole, rest = divmod(elements, 128)
+        parts = field.parts if isinstance(field, StoredRows) else (field,)
+        if isinstance(field, StoredRows):
+            assert field.row == row.shape[1:]
+        if elements >= 1 << 16:  # the pixels: a step's frame as 3 tiles
+            assert [p.shape for p in parts] == [(capacity, steps, 3, 32, 128)]
+        elif not whole or row.ndim == 2 and not rest:
+            assert parts == (field,) and field.shape == (capacity,) + row.shape[1:]
+        else:
+            want = [(capacity, 128 * whole)] + [(capacity, rest)] * bool(rest)
+            assert [p.shape for p in parts] == want
+        assert all(p.dtype == row.dtype for p in parts)
+        flat = jnp.concatenate([p.reshape(capacity, -1) for p in parts], axis=1)
+        np.testing.assert_array_equal(_bits(flat[:n]), _bits(row.reshape(n, -1)))
+
+    order = jnp.asarray([4, 0, 3, 3, 1])
+    _rows_equal(arena.gather(state, order),
+                jax.tree_util.tree_map(lambda x: x[order], rows))
+    res = jax.jit(lambda s, k: arena.sample(s, k, 6))(state, jax.random.PRNGKey(9))
+    assert int(np.asarray(res.indices).max()) < n
+    _rows_equal(res.batch, jax.tree_util.tree_map(lambda x: x[res.indices], rows))
+
+
+@pytest.mark.parametrize("rows_at", list(ROW_SETS), ids=list(ROW_SETS))
+def test_the_arena_stores_each_rows_bytes_and_no_more(rows_at):
+    """A slot's leaves hold its row's bytes, whatever shapes they are
+    stored in: walker's rows are 9,772 B a slot, and the arena adds its
+    float32 priority and two int32 stamps."""
+    steps, frame, frame_dtype, act = ROW_SETS[rows_at]
+    rows, _ = _odd_rows(1, steps, frame, frame_dtype, act)
+    capacity = 16
+    state = jax.eval_shape(ReplayArena(capacity=capacity).init_state, rows)
+    stored = sum(x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(state)
+                 if x.shape[:1] == (capacity,))
+    row = sum(x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(rows))
+    assert stored == capacity * (row + 4 + 8)
+    if rows_at == "walker_rows":
+        assert row == 43 * (24 + 6 + 2) * 4 + 43 + 4 * 256 * 4 == 9772 - 3 * 43
+
+
+def test_an_arena_reads_the_rows_of_a_state_another_arena_made():
+    """The rows' own shapes are part of the state's structure
+    (``StoredRows.row``, static): an arena that never ran ``init_state``
+    gathers and samples the state another arena made, each row in its own
+    shape."""
+    rows, priorities = _odd_rows(4, 37, (10,), "float32", 5)
+    first = ReplayArena(capacity=8)
+    state = first.add(first.init_state(rows), rows, priorities)
+    assert isinstance(state.data.obs, StoredRows) and state.data.obs.row == (37, 10)
+    twin = ReplayArena(capacity=8)
+    _rows_equal(twin.gather(state, jnp.arange(4)), rows)
+    res = jax.jit(lambda s, k: twin.sample(s, k, 6))(state, jax.random.PRNGKey(3))
+    _rows_equal(res.batch, jax.tree_util.tree_map(lambda x: x[res.indices], rows))

@@ -34,6 +34,29 @@ def test_phase_schedule_and_replay_fill():
     assert int(t.arena.size(s.arena)) == 4
 
 
+def test_a_trainer_built_beside_another_reads_its_arena():
+    """The rows' shapes travel with the arena's state: a second trainer of
+    the same configuration (one update a call, as the benchmark's twin),
+    whose arena never ran ``init_state``, reads the rows of the state the
+    first filled in their own shapes and learns from them."""
+    cfg = small(PENDULUM_R2D2, num_envs=2, min_replay=4, capacity=64)
+    t = cfg.build()
+    twin = small(PENDULUM_R2D2, num_envs=2, min_replay=4, capacity=64,
+                 learner_steps=1).build()
+    s = t.init()
+    for _ in range(t.window_fill_phases + 1):
+        s = t.collect_phase(s)
+    s = t.fill_phase(s)
+    idx = jax.numpy.arange(2)
+    for a, b in zip(jax.tree_util.tree_leaves(twin.arena.gather(s.arena, idx)),
+                    jax.tree_util.tree_leaves(t.arena.gather(s.arena, idx))):
+        assert a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    train, _, metrics = jax.jit(twin._learn_many)(s.train, s.arena, jax.random.PRNGKey(5))
+    assert int(train.step) == 1
+    assert np.isfinite(np.asarray(metrics["critic_loss"])).all()
+
+
 def test_run_schedule_counts_env_steps():
     cfg = small(PENDULUM_DDPG, num_envs=2, min_replay=8, capacity=64)
     t = cfg.build()

@@ -124,7 +124,7 @@ def test_restore_converts_an_arena_saved_in_the_rows_own_shape(tmp_path, saved):
         reset=jnp.zeros((n, L)), carries={})
     arena = ReplayArena(capacity=4)
     state = {"arena": arena.add(arena.init_state(rows), rows, jnp.ones(n))}
-    assert state["arena"].data.obs.shape == (4, 18, 32, 128)
+    assert state["arena"].data.obs.parts[0].shape == (4, 18, 32, 128)
     old_shape = (4, L) + (frame if saved == "rows_own_shape" else (64, 96, 4))
     old_obs = jnp.zeros(old_shape, jnp.uint8).at[:n].set(
         rows.obs if saved == "rows_own_shape" else 0)
@@ -140,7 +140,60 @@ def test_restore_converts_an_arena_saved_in_the_rows_own_shape(tmp_path, saved):
     else:
         restored = ckpt.restore(state)
         _tree_allclose(restored, state)
-        assert restored["arena"].data.obs.shape == (4, 18, 32, 128)
+        assert restored["arena"].data.obs.parts[0].shape == (4, 18, 32, 128)
+        got = arena.gather(restored["arena"], jnp.arange(n))
+        np.testing.assert_array_equal(np.asarray(got.obs), np.asarray(rows.obs))
+    ckpt.close()
+
+
+@pytest.mark.parametrize(
+    "saved", ["rows_own_shape", "another_row", "another_dtype", "another_capacity"])
+def test_restore_splits_a_small_leaf_saved_in_the_rows_own_shape(tmp_path, saved):
+    """A checkpoint from before the arena stored a small row as its whole
+    lane-rows and the rest holds walker's observations as ``[capacity, 43,
+    24]``: restored into today's ``[capacity, 1024]`` and ``[capacity, 8]``
+    parts it is the same rows, in the same dtype at the same capacity.  A
+    leaf of another row (``[43, 25]``), of another dtype or of another
+    capacity is refused by name."""
+    import jax.numpy as jnp
+
+    from r2d2dpg_tpu.replay.arena import ReplayArena, SequenceBatch
+
+    n, L, capacity = 3, 43, 4
+    rng = np.random.default_rng(2)
+    rows = SequenceBatch(
+        obs=jnp.asarray(rng.standard_normal((n, L, 24)), jnp.float32),
+        action=jnp.asarray(rng.standard_normal((n, L, 6)), jnp.float32),
+        reward=jnp.zeros((n, L)), discount=jnp.ones((n, L)),
+        reset=jnp.zeros((n, L)), carries={})
+    arena = ReplayArena(capacity=capacity)
+    state = {"arena": arena.add(arena.init_state(rows), rows, jnp.ones(n))}
+    assert [p.shape for p in state["arena"].data.obs.parts] == [
+        (capacity, 1024), (capacity, 8)]
+    old_obs = {
+        "rows_own_shape": jnp.zeros((capacity, L, 24)).at[:n].set(rows.obs),
+        "another_row": jnp.zeros((capacity, L, 25)),
+        "another_dtype": jnp.zeros((capacity, L, 24), jnp.bfloat16),
+        "another_capacity": jnp.zeros((2 * capacity, L, 24)),
+    }[saved]
+    old = {"arena": dataclasses.replace(
+        state["arena"], data=dataclasses.replace(state["arena"].data, obs=old_obs))}
+
+    ckpt = CheckpointManager(str(tmp_path / "ckpt"), save_every=1)
+    ckpt.save(1, old)
+    ckpt.wait()
+    if saved != "rows_own_shape":
+        with pytest.raises(ValueError, match=r"data\.obs.*older storage shape"):
+            ckpt.restore(state)
+    else:
+        restored = ckpt.restore(state)
+        _tree_allclose(restored, state)
+        parts = restored["arena"].data.obs.parts
+        assert [(p.shape, p.dtype) for p in parts] == [
+            ((capacity, 1024), jnp.float32), ((capacity, 8), jnp.float32)]
+        np.testing.assert_array_equal(
+            np.concatenate([np.asarray(p) for p in parts], axis=1),
+            np.asarray(old_obs).reshape(capacity, -1))
         got = arena.gather(restored["arena"], jnp.arange(n))
         np.testing.assert_array_equal(np.asarray(got.obs), np.asarray(rows.obs))
     ckpt.close()
