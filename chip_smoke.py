@@ -355,7 +355,11 @@ def _require_learner_call_guards(trainer, state, rolled_width=None) -> dict:
     the products of that width lie inside the stack's two scans (over the
     layers, inside over the loop steps), a copy a pass and not one an
     application: a block written out sixteen times compiles sixteen times as
-    long, in every process's set-up."""
+    long, in every process's set-up.
+
+    Reported, not refused: the compiler's own count of the call's peak memory
+    and of its temporaries, and the instructions its rematerialisation cloned
+    to fit the chip (``obs/hlo.py::remat_clones``)."""
     import jax
 
     from r2d2dpg_tpu.obs.hlo import (
@@ -369,10 +373,12 @@ def _require_learner_call_guards(trainer, state, rolled_width=None) -> dict:
         loop_convolutions,
         loop_products,
         priority_writes,
+        remat_clones,
     )
 
     call = jax.jit(trainer._learn_many, donate_argnums=(0, 1))
-    hlo = call.lower(state.train, state.arena, state.rng).compile().as_text()
+    compiled = call.lower(state.train, state.arena, state.rng).compile()
+    hlo, memory = compiled.as_text(), compiled.memory_analysis()
     converts = arena_converts(hlo, trainer.arena.capacity)
     _require(
         not converts,
@@ -461,9 +467,14 @@ def _require_learner_call_guards(trainer, state, rolled_width=None) -> dict:
             f"{rolled_width}, outside its two scans: {outside}",
         )
         rolled = {"rolled_width": rolled_width, "loop_products": products}
+    clones = remat_clones(hlo)
     return {
         **rolled,
         "learner_call_hlo_lines": hlo.count("\n"),
+        "memory_peak_bytes": memory.peak_memory_in_bytes,
+        "memory_temp_bytes": memory.temp_size_in_bytes,
+        "remat_clones": len(clones),
+        "remat_clone_names": clones[:16],  # the first of them, as printed
         "arena_capacity": trainer.arena.capacity,
         "arena_converts": converts,
         "batch_size": trainer.config.batch_size,
@@ -527,6 +538,36 @@ def _learner_call_from_shapes(config: str, obs_shape: tuple, obs_dtype: str,
     return trainer, types.SimpleNamespace(**shapes)
 
 
+def _held_expert_residuals(config: str) -> dict:
+    """What the backward pass of a sparse-expert core's held experts keeps of
+    one layer's forward pass over a window (``models/sdar_moe.py::moe`` at
+    batch x unroll tokens): the ``[E, N, W]`` residuals ``jax.vjp`` holds,
+    traced from shapes (nothing runs), with their bytes."""
+    import jax
+    import jax.numpy as jnp
+
+    from r2d2dpg_tpu.configs import get_config
+    from r2d2dpg_tpu.models import sdar_moe
+
+    exp = get_config(config)
+    cfg, tokens = exp.sdar, exp.trainer.batch_size * exp.agent.unroll
+    E, H, W = cfg.experts_held, cfg.hidden, cfg.expert_width
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
+    weights = {"router": f32(H, cfg.router_experts), "w_gate": f32(E, H, W),
+               "w_up": f32(E, H, W), "w_down": f32(E, W, H)}
+
+    def residuals(h2, p):
+        _, backward = jax.vjp(lambda h2, p: sdar_moe.moe(cfg, p, h2)[0], h2, p)
+        return jax.tree_util.tree_leaves(backward)
+
+    held = [r for r in jax.eval_shape(residuals, f32(tokens, H), weights)
+            if r.shape == (E, tokens, W)]
+    return {
+        "held_residuals": [f"{r.dtype}{list(r.shape)}" for r in held],
+        "held_residual_bytes": sum(r.size * r.dtype.itemsize for r in held),
+    }
+
+
 # The configurations whose learner call the train leg compiles from shapes
 # beside ``walker_r2d2``'s own: the whole-sequence cores' (460 M and 416 M
 # parameters, a 1.5 GB arena; DM-Control humanoid-run) and the pixel replay's
@@ -558,12 +599,21 @@ def _leg_train(work: str) -> dict:
     _require_native_pool()
     _require(len(built) == 1, f"{len(built)} trainers were initialised")
     checks["learner_call"] = _require_learner_call_guards(*built[0])
+    from r2d2dpg_tpu.configs import get_config
+
     for name, (*args, rolled_width) in _LEARNER_CALLS_FROM_SHAPES.items():
         checks[name] = _require_learner_call_guards(
             *_learner_call_from_shapes(*args), rolled_width=rolled_width)
+        if get_config(args[0]).sdar is not None:
+            checks[name].update(_held_expert_residuals(args[0]))
     for name in ["learner_call", *_LEARNER_CALLS_FROM_SHAPES]:
         print(f"chip_smoke: {name} arena storage: "
               + json.dumps(checks[name]["arena_storage"]), flush=True)
+        print(f"chip_smoke: {name} memory: " + json.dumps({
+            k: checks[name][k] for k in (
+                "memory_peak_bytes", "memory_temp_bytes", "remat_clones",
+                "held_residuals", "held_residual_bytes") if k in checks[name]}),
+            flush=True)
     return checks
 
 

@@ -38,6 +38,17 @@ held here lies anywhere between 0 and several times its expectation, and at
 those loads they were slower than the dense products on the chip; the TPU
 lowering of ``ragged_dot`` also left the rows past its groups unwritten.
 
+**The backward pass keeps the up product** (``moe``): of the three
+``[E, N, W]`` values of ``held_ffn``, ``u = h2 Wu`` is saved by name (float32,
+62.9 MB a layer and differentiated pass at the published sizes: N = 64 x 40
+tokens; 755 MB an update over 4 layers and the 3 passes an update
+differentiates), and the backward pass runs ``g = h2 Wg`` again and
+``silu(g) * u * gates`` element-wise.  Under a plain ``jax.checkpoint`` it ran
+both products again, in every layer of every differentiated pass.  Keeping g
+as well does not pay: the learner call is at the compiler's memory limit, and
+the compiler then clones products and attention's scores back to fit, which
+on the chip cost more than the second product saves (PERF.md section 6).
+
 **Three ways in**, all one set of weights:
 
 - ``sequence``: ``[B, T, H]`` whole, no scan over time.  ``memory`` is the
@@ -61,6 +72,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from r2d2dpg_tpu.utils.profiling import scope
 
@@ -190,11 +202,18 @@ def held_ffn(h2, w_gate, w_up, w_down, gates):
     """The held experts' gated feed-forward over EVERY token ``h2 [N, H]``,
     each expert's output weighed by ``gates [N, E]`` (0 where the token did
     not choose it) and summed: dense products, ``E`` times the FLOPs of the
-    pairs routed here at an even load (the module's note says why)."""
+    pairs routed here at an even load (the module's note says why).  The up
+    product carries the name ``HELD_KEPT`` saves."""
     g = jnp.einsum("nh,ehw->enw", h2, w_gate.astype(h2.dtype))
-    u = jnp.einsum("nh,ehw->enw", h2, w_up.astype(h2.dtype))
+    u = checkpoint_name(jnp.einsum("nh,ehw->enw", h2, w_up.astype(h2.dtype)),
+                        "held_up")
     a = jax.nn.silu(g) * u * gates.T.astype(h2.dtype)[:, :, None]
     return jnp.einsum("enw,ewh->nh", a, w_down.astype(h2.dtype))
+
+
+# What the backward pass of ``held_ffn`` keeps of its forward pass: the up
+# product, by the name ``held_ffn`` gives it.
+HELD_KEPT = jax.checkpoint_policies.save_only_these_names("held_up")
 
 
 def moe(cfg: SdarMoeConfig, p: Dict[str, Any], h2) -> Tuple[Any, Any]:
@@ -209,10 +228,12 @@ def moe(cfg: SdarMoeConfig, p: Dict[str, Any], h2) -> Tuple[Any, Any]:
         gates = jnp.sum(jnp.where(mine, gate[:, :, None], 0.0), axis=1)
         sizes = jnp.sum(jnp.any(mine, axis=1), axis=0, dtype=jnp.int32)
     with scope("moe_experts"):
-        # Recomputed in the backward pass: the three [E, N, W] products of
-        # every layer of every differentiated pass (2.3 GB) do not fit the
-        # chip beside 7.4 GB of learner state and the replay.
-        out = jax.checkpoint(held_ffn)(
+        # The backward pass reads u as the forward pass wrote it, one
+        # [E, N, W] float32 value a layer and differentiated pass (755 MB an
+        # update at the published sizes), and runs g = h2 Wg again with
+        # a = silu(g) u gates.  ``held_ffn`` is looked up when ``moe`` is
+        # traced.
+        out = jax.checkpoint(held_ffn, policy=HELD_KEPT)(
             h2, p["w_gate"], p["w_up"], p["w_down"], gates
         )
     return out, sizes

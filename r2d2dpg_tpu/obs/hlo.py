@@ -79,6 +79,15 @@ the compiled learner call holds one copy of a block a pass and not sixteen
 (its compile is part of every process's set-up).  ``loop_products`` lists the
 products of a given width with the loops around each; the same leg requires
 the looped configuration's to lie inside both scans, and to be few.
+
+The TPU compiler fits a program to the chip's memory by cloning instructions
+and running them again where their results are needed (its own
+rematerialisation, beside what ``jax.checkpoint`` asks for): a clone carries
+the name of what it copies with ``.remat`` after it.  A value a program
+keeps for its backward pass past the compiler's limit comes back as such
+clones (``models/sdar_moe.py::moe``'s held experts).  ``remat_clones`` lists
+them; the same leg prints their number beside the compiler's own count of
+the call's peak memory, and refuses nothing.
 """
 
 from __future__ import annotations
@@ -568,3 +577,18 @@ def priority_writes(
                 if moves(near):
                     note(near)
     return found
+
+
+# ``%fusion.8466.remat = (bf16[...], f32[...]) fusion(...)``, ``%gte.remat.1 =
+# f32[...] get-tuple-element(%fusion.8466.remat)``: the clone's own name ends
+# in ``.remat``, ``.remat2``... and maybe a number; ``%remat2.869`` (a value
+# ``jax.checkpoint`` named) is no clone.
+_REMAT_CLONE = re.compile(
+    r"^\s*(?:ROOT\s+)?%?(?P<name>[\w.\-]+\.remat\d*(?:\.\d+)?)\s*=", re.MULTILINE)
+
+
+def remat_clones(hlo_text: str) -> List[str]:
+    """The names of the instructions in ``hlo_text`` that the compiler's own
+    rematerialisation cloned, each once, in the order printed (a clone's
+    uses as an operand are not counted)."""
+    return [m["name"] for m in _REMAT_CLONE.finditer(hlo_text)]

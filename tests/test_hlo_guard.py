@@ -25,6 +25,7 @@ from r2d2dpg_tpu.obs.hlo import (
     loop_convolutions,
     loop_products,
     priority_writes,
+    remat_clones,
 )
 
 CAPACITY = 524288
@@ -487,6 +488,36 @@ def test_loop_products_names_every_product_of_a_width_with_the_loops_around_it(
     not, ``dot`` or the TPU printer's ``convolution``, and carries the
     ``while`` bodies between the entry and itself."""
     assert loop_products(hlo, width) == want
+
+
+# ``humanoid_sdar_moe``'s learner call compiled for a described v5e (JAX
+# 0.9.0, libtpu 0.0.34), cut to its clones: the compiler's rematerialisation
+# copied a fusion of the held experts' products and the two results taken out
+# of another; ``%remat2.869`` is a value ``jax.checkpoint`` named, and a
+# clone's uses as an operand are no further clones.
+REMAT_CLONES = """\
+%wide.region_0.231.clone (wide.wide.wide.arg_tuple.0: (s32[], f32[8,2048,768])) -> (s32[], f32[8,2048,768]) {
+  %remat2.869 = f32[8,2048,768]{1,2,0:T(8,128)} get-tuple-element(%wide.wide.wide.arg_tuple.0), index=97
+  %fusion.8691.remat2 = f32[8,768,2560]{2,1,0:T(8,128)} fusion(%custom-call.99, %bitcast.12474), kind=kOutput, calls=%fused_computation.5737.clone.clone.clone.clone
+  %fusion.8466.remat = (bf16[8,768,2560]{2,1,0:T(8,128)(2,1)}, f32[8,768,2560]{2,1,0:T(8,128)}) fusion(%copy-done.65, %fusion.8465, %bitcast.12754, %custom-call.100, %bitcast.12209), kind=kOutput, calls=%fused_computation.121.clone.clone.clone
+  %gte.remat = bf16[8,768,2560]{2,1,0:T(8,128)(2,1)} get-tuple-element(%fusion.8466.remat), index=0
+  ROOT %gte.remat.1 = f32[8,768,2560]{2,1,0:T(8,128)} get-tuple-element(%fusion.8466.remat), index=1
+}
+"""
+
+
+@pytest.mark.parametrize(
+    "hlo, want",
+    [
+        (REMAT_CLONES, ["fusion.8691.remat2", "fusion.8466.remat", "gte.remat",
+                        "gte.remat.1"]),
+        (HOISTED, []),
+        ("", []),
+    ],
+    ids=["clones", "none", "empty"],
+)
+def test_remat_clones_names_every_instruction_the_compiler_cloned_once(hlo, want):
+    assert remat_clones(hlo) == want
 
 
 # ``cheetah_pixels``' learner call at the cell's capacity (12,288 sequences of

@@ -190,6 +190,101 @@ def test_every_token_routed_to_the_same_held_experts_loses_none(winners, loads):
     np.testing.assert_allclose(jax.grad(f)(h2), jax.grad(g)(h2), rtol=1e-4, atol=1e-5)
 
 
+# ------------------------------------ what the held experts' backward keeps
+def _held_case(monkeypatch, held_ffn):
+    """One layer's share of the experts over 40 tokens, and the function of
+    ``h2`` and the three expert kernels whose gradient is taken, with
+    ``held_ffn`` the module's own or the ``expert_unapplied`` fault's wrapper
+    around it (a seam looked up when ``moe`` is traced)."""
+    monkeypatch.setattr(sdar_moe, "held_ffn", sdar_moe.held_ffn)  # restored after
+    if held_ffn == "expert_unapplied":
+        DRIVER._plant("expert_unapplied")
+    cfg = SDAR_TINY.sdar
+    p, h2 = _layer(jax.random.PRNGKey(2), cfg)
+    E = cfg.experts_held
+    p = {"router": p["router"], **{k: p[k][:E] for k in ("w_gate", "w_up", "w_down")}}
+
+    def f(h2, w_gate, w_up, w_down):
+        out, _ = sdar_moe.moe(cfg, dict(p, w_gate=w_gate, w_up=w_up, w_down=w_down), h2)
+        return out
+
+    return cfg, f, (h2, p["w_gate"], p["w_up"], p["w_down"])
+
+
+def _dot_generals(jaxpr) -> int:
+    """The ``dot_general`` equations of a jaxpr and of every jaxpr inside it."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == "dot_general"
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, ClosedJaxpr):
+                    n += _dot_generals(sub.jaxpr)
+                elif isinstance(sub, Jaxpr):
+                    n += _dot_generals(sub)
+    return n
+
+
+def _held_grads_and_counts(f, args, cfg):
+    """Gradients of ``sum(sin(f))`` by all four arguments; the ``[E, N, W]``
+    residuals the backward pass keeps; the gradient's ``dot_general``s."""
+    loss = lambda *a: jnp.sum(jnp.sin(f(*a)))  # noqa: E731
+    grads = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3)))(*args)
+    _, backward = jax.vjp(f, *args)
+    enw = (cfg.experts_held, args[0].shape[0], cfg.expert_width)
+    kept = [r.shape for r in jax.tree_util.tree_leaves(backward) if r.shape == enw]
+    dots = _dot_generals(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2, 3)))(*args).jaxpr)
+    return grads, kept, dots
+
+
+@pytest.mark.parametrize("held_ffn", ["own", "expert_unapplied"])
+def test_keeping_the_up_product_changes_no_gradient(monkeypatch, held_ffn):
+    """By ``h2`` and the three expert kernels, under the policy ``moe``
+    applies and under a plain ``jax.checkpoint`` (no policy): the same
+    products of the same operands, so equal to the last bit on the CPU."""
+    cfg, f, args = _held_case(monkeypatch, held_ffn)
+    kept, _, _ = _held_grads_and_counts(f, args, cfg)
+    monkeypatch.setattr(sdar_moe, "HELD_KEPT", None)
+    plain, _, _ = _held_grads_and_counts(f, args, cfg)
+    for k, p in zip(kept, plain):
+        assert float(jnp.abs(p).max()) > 0
+        np.testing.assert_array_equal(k, p)
+
+
+@pytest.mark.parametrize("held_ffn", ["own", "expert_unapplied"])
+def test_the_backward_pass_keeps_the_up_product_and_runs_one_product_fewer(
+    monkeypatch, held_ffn
+):
+    """The differentiated layer keeps exactly one ``[E, N, W]`` value, the up
+    product, where a plain ``jax.checkpoint`` keeps none, and its gradient
+    holds one ``dot_general`` fewer: the backward pass does not run that
+    product again.  With ``held_ffn`` a wrapper that calls the module's own
+    (as the ``expert_unapplied`` fault is), the name still reaches the
+    policy."""
+    cfg, f, args = _held_case(monkeypatch, held_ffn)
+    _, kept, dots = _held_grads_and_counts(f, args, cfg)
+    monkeypatch.setattr(sdar_moe, "HELD_KEPT", None)
+    _, plain_kept, plain_dots = _held_grads_and_counts(f, args, cfg)
+    enw = (cfg.experts_held, args[0].shape[0], cfg.expert_width)
+    assert kept == [enw] and plain_kept == []
+    assert dots == plain_dots - 1
+
+
+def test_the_smoke_reports_the_residual_a_layer_keeps_at_the_learners_window():
+    """``chip_smoke.py``'s report of what the held experts keep, from shapes:
+    the up product over batch x unroll tokens, float32."""
+    import chip_smoke
+
+    c, exp = SDAR_TINY.sdar, SDAR_TINY
+    shape = [c.experts_held, exp.trainer.batch_size * exp.agent.unroll, c.expert_width]
+    assert chip_smoke._held_expert_residuals("sdar_tiny") == {
+        "held_residuals": [f"float32{shape}"],
+        "held_residual_bytes": 4 * int(np.prod(shape)),
+    }
+
+
 def test_ring_step_equals_the_whole_sequence_call_with_a_reset_inside(agent, weights):
     actor_params = weights[0]
     T = L  # from a cleared ring, one stored sequence's worth of steps
