@@ -358,8 +358,9 @@ def _require_learner_call_guards(trainer, state, rolled_width=None) -> dict:
     long, in every process's set-up.
 
     Reported, not refused: the compiler's own count of the call's peak memory
-    and of its temporaries, and the instructions its rematerialisation cloned
-    to fit the chip (``obs/hlo.py::remat_clones``)."""
+    and of its temporaries, the instructions its rematerialisation cloned
+    to fit the chip (``obs/hlo.py::remat_clones``), and the steps an update's
+    scans run one after another, by pass (``obs/hlo.py::scan_steps``)."""
     import jax
 
     from r2d2dpg_tpu.obs.hlo import (
@@ -374,6 +375,7 @@ def _require_learner_call_guards(trainer, state, rolled_width=None) -> dict:
         loop_products,
         priority_writes,
         remat_clones,
+        scan_steps,
     )
 
     call = jax.jit(trainer._learn_many, donate_argnums=(0, 1))
@@ -475,6 +477,7 @@ def _require_learner_call_guards(trainer, state, rolled_width=None) -> dict:
         "memory_temp_bytes": memory.temp_size_in_bytes,
         "remat_clones": len(clones),
         "remat_clone_names": clones[:16],  # the first of them, as printed
+        "scan_steps": scan_steps(hlo),
         "arena_capacity": trainer.arena.capacity,
         "arena_converts": converts,
         "batch_size": trainer.config.batch_size,
@@ -614,6 +617,9 @@ def _leg_train(work: str) -> dict:
                 "memory_peak_bytes", "memory_temp_bytes", "remat_clones",
                 "held_residuals", "held_residual_bytes") if k in checks[name]}),
             flush=True)
+        steps = checks[name]["scan_steps"]
+        print(f"chip_smoke: {name} scan steps: "
+              + json.dumps({**steps, "total": sum(steps.values())}), flush=True)
     return checks
 
 

@@ -33,7 +33,7 @@ import jax.numpy as jnp
 import optax
 from jax import lax
 
-from r2d2dpg_tpu.models.sequence import sequence_runner, window
+from r2d2dpg_tpu.models.sequence import _member, sequence_runner, window
 from r2d2dpg_tpu.ops import (
     huber,
     n_step_targets,
@@ -101,11 +101,6 @@ class AgentConfig:
 
 def _tm(x: jnp.ndarray) -> jnp.ndarray:
     return jnp.swapaxes(x, 0, 1)
-
-
-def _member(tree: Any, i: int) -> Any:
-    """Member ``i`` of an ensemble-stacked pytree."""
-    return jax.tree_util.tree_map(lambda x: x[i], tree)
 
 
 class R2D2DPG:
@@ -222,7 +217,7 @@ class R2D2DPG:
 
         # scope(): the stages of utils/profiling.py::LEARN_STAGES, which
         # obs/stages.py reads back from the chip's trace.  ``forward`` wraps
-        # the two value_and_grad calls, so that the operations JAX names
+        # the gradient call(s), so that the operations JAX names
         # ``transpose(...)`` under it read as ``backward``.  ``frames``
         # (``SIDE_STAGES``) is what the nets want done once to the whole
         # batch's observations (pixels: scaled and re-laid for the conv
@@ -278,17 +273,14 @@ class R2D2DPG:
             # forward+backward compute.
             obs_u, act_u, reset_u = obs_w[:U], act_w[:U], reset_w[:U]
 
-            # --- critic update (IS-weighted; SURVEY §2.4 "weighted by IS weights").
-            # Twin mode trains both members against the same min-bootstrapped y
-            # (TD3); td/q metrics and priorities come from member 0.
-            def critic_loss_fn(critic_params):
+            # --- the losses.  Critic: IS-weighted (SURVEY §2.4 "weighted by IS
+            # weights"); twin mode trains both members against the same
+            # min-bootstrapped y (TD3), td/q metrics and priorities come from
+            # member 0.  Actor: -Q(s, mu(s)) through the FROZEN online critic
+            # (member 0 in twin mode, the TD3 convention).
+            def critic_loss_of(q_tm, left):
                 if cfg.twin_critic:
-                    q_tm2, left = jax.vmap(
-                        lambda p, c: self.seq.unroll_critic(
-                            p, c, obs_u, act_u, reset_u
-                        )
-                    )(critic_params, cc_on)
-                    q2 = jnp.swapaxes(q_tm2, 1, 2)  # [2, B, U]
+                    q2 = jnp.swapaxes(q_tm, 1, 2)  # [2, B, U]
                     td2 = jax.vmap(td_errors, in_axes=(0, None))(q2, y)
                     per_step = huber(td2) if cfg.use_huber else 0.5 * td2**2
                     # SUM over members (TD3's L = L1 + L2): each member's
@@ -297,36 +289,71 @@ class R2D2DPG:
                     loss = (is_weights[:, None] * per_step.sum(axis=0)).mean()
                     spread = jnp.abs(q2[0] - q2[1]).mean()
                     return loss, (td2[0], q2[0], spread, left)
-                q_tm, left = self.seq.unroll_critic(
-                    critic_params, cc_on, obs_u, act_u, reset_u
-                )
                 q = _tm(q_tm)  # [B, U]
                 td = td_errors(q, y)
                 per_step = huber(td) if cfg.use_huber else 0.5 * td**2
                 loss = (is_weights[:, None] * per_step).mean()
                 return loss, (td, q, None, left)
 
-            (critic_loss, (td, q_pred, q_spread, left_q)), critic_grads = jax.value_and_grad(
-                critic_loss_fn, has_aux=True
-            )(state.critic_params)
+            if self.seq.joint_online:
+                # Both passes in ONE scan under ONE gradient call
+                # (``Stepped.unroll_online``): the critic's loss pass and the
+                # actor's do not depend on each other, and a step of either
+                # costs its chain of operations, not their work.  The actor's
+                # pass reads the critic through ``stop_gradient``, so the
+                # critic's gradient is the critic loss's alone and the
+                # actor's the actor loss's, as two calls gave them.
+                def loss_fn(params):
+                    q_tm, q_pi_tm, (left_c, left_a) = self.seq.unroll_online(
+                        *params, ca_on, cc_on, obs_u, act_u, reset_u,
+                        ensemble=cfg.twin_critic,
+                    )
+                    c_loss, aux = critic_loss_of(q_tm, left_c)
+                    a_loss = -q_pi_tm.mean()
+                    return c_loss + a_loss, (c_loss, a_loss, aux, left_a)
 
-            # --- actor update: -Q(s, mu(s)) through the frozen online critic
-            # (member 0 in twin mode, the TD3 convention).
-            cp_pi = (
-                _member(state.critic_params, 0) if cfg.twin_critic
-                else state.critic_params
-            )
-            cc_on_pi = _member(cc_on, 0) if cfg.twin_critic else cc_on
-
-            def actor_loss_fn(actor_params):
-                _, q_pi_tm, left = self.seq.unroll_pi_q(
-                    actor_params, cp_pi, ca_on, cc_on_pi, obs_u, reset_u
+                (_, (critic_loss, actor_loss, (td, q_pred, q_spread, left_q), left_pi)), (
+                    actor_grads, critic_grads
+                ) = jax.value_and_grad(loss_fn, has_aux=True)(
+                    (state.actor_params, state.critic_params)
                 )
-                return -q_pi_tm.mean(), left
+            else:
+                # Two passes under two gradient calls (``Whole``: a
+                # whole-sequence pass is no chain of small steps, and its
+                # learner call sits at the compiler's memory limit).
+                def critic_loss_fn(critic_params):
+                    if cfg.twin_critic:
+                        q_tm, left = jax.vmap(
+                            lambda p, c: self.seq.unroll_critic(
+                                p, c, obs_u, act_u, reset_u
+                            )
+                        )(critic_params, cc_on)
+                    else:
+                        q_tm, left = self.seq.unroll_critic(
+                            critic_params, cc_on, obs_u, act_u, reset_u
+                        )
+                    return critic_loss_of(q_tm, left)
 
-            (actor_loss, left_pi), actor_grads = jax.value_and_grad(
-                actor_loss_fn, has_aux=True
-            )(state.actor_params)
+                (critic_loss, (td, q_pred, q_spread, left_q)), critic_grads = (
+                    jax.value_and_grad(critic_loss_fn, has_aux=True)(
+                        state.critic_params
+                    )
+                )
+                cp_pi = (
+                    _member(state.critic_params, 0) if cfg.twin_critic
+                    else state.critic_params
+                )
+                cc_on_pi = _member(cc_on, 0) if cfg.twin_critic else cc_on
+
+                def actor_loss_fn(actor_params):
+                    _, q_pi_tm, left = self.seq.unroll_pi_q(
+                        actor_params, cp_pi, ca_on, cc_on_pi, obs_u, reset_u
+                    )
+                    return -q_pi_tm.mean(), left
+
+                (actor_loss, left_pi), actor_grads = jax.value_and_grad(
+                    actor_loss_fn, has_aux=True
+                )(state.actor_params)
 
         # --- gradient sync over the mesh (SURVEY §2.8: psum over ICI).
         if cfg.axis_name is not None:

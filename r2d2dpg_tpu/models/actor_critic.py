@@ -429,24 +429,21 @@ class CriticNet(nn.Module):
         """What of a step does not depend on the carry, over any leading
         dimensions.  With the action (the replay's): torso, ``mix`` and the
         core's input projection.  Without (the action is a policy's, made in
-        the same step): the torso's features alone, and ``step`` takes the
-        action."""
+        the same step): the torso's features alone, and ``with_action`` adds
+        the action in the step."""
         x = self.torso(obs)
-        return x if action is None else self.core.project(self._mixed(x, action))
+        return x if action is None else self.with_action(x, action)
 
-    def step(
-        self,
-        z: jnp.ndarray,
-        carry: Carry,
-        reset: jnp.ndarray,
-        action: Optional[jnp.ndarray] = None,
-    ):
+    def with_action(self, x: jnp.ndarray, action: jnp.ndarray) -> jnp.ndarray:
+        """Rows ``encode`` made without an action, and the action -> the rows
+        it makes with one: ``mix`` and the core's input projection (``mix``
+        runs whole: its matmul is not split by columns, which would reorder a
+        reduction)."""
+        return self.core.project(self._mixed(x, action))
+
+    def step(self, z: jnp.ndarray, carry: Carry, reset: jnp.ndarray):
         """What needs the carry: ``encode``'s rows of one step -> (the core's
-        output ``y [B, H]``, new carry).  ``action`` goes with rows encoded
-        without one; ``mix`` then runs whole here (its matmul is not split by
-        columns: that would reorder a reduction)."""
-        if action is not None:
-            z = self.core.project(self._mixed(z, action))
+        output ``y [B, H]``, new carry)."""
         return self.core.step(z, carry, reset)
 
     def readout(self, y: jnp.ndarray) -> jnp.ndarray:

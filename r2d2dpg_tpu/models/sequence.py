@@ -3,7 +3,7 @@
 R2D2's learner warms its nets over a burn-in prefix and unrolls them over the
 training window (SURVEY.md §3.3).  HOW depends on the core, and it is decided
 here and nowhere else: ``agents/ddpg.py`` holds one of these objects
-(``sequence_runner`` picks it from the nets it is given) and calls its five
+(``sequence_runner`` picks it from the nets it is given) and calls its
 operations —
 
 - ``prepare``: what the nets want done ONCE an update to the observations of
@@ -14,6 +14,8 @@ operations —
 - ``unroll_actor`` / ``unroll_critic``: one net over time-major inputs from a
   carry; back come the outputs ``[T, B, ...]`` and what the pass left behind;
 - ``unroll_pi_q``: the actor, then the critic on the actor's actions;
+- ``unroll_online`` (where the runner says ``joint_online``): the critic's
+  loss pass and the actor's in one scan, the critic frozen in the second;
 - ``burn_in``: the four nets' carries (online and target actor, online and
   target critic) into the window, from a batch's prefix, no gradient;
 - ``metrics``: what the update reports from what its passes left behind.
@@ -65,6 +67,17 @@ def _stack2(a: Any, b: Any) -> Any:
     return jax.tree_util.tree_map(lambda x, y: jnp.stack([x, y]), a, b)
 
 
+def _append(members: Any, one: Any) -> Any:
+    """``members`` (a leading member axis) with ``one`` as a further member."""
+    return jax.tree_util.tree_map(
+        lambda x, y: jnp.concatenate([x, y[None]]), members, one)
+
+
+def _member(tree: Any, i: int) -> Any:
+    """Member ``i`` of an ensemble-stacked pytree."""
+    return jax.tree_util.tree_map(lambda x: x[i], tree)
+
+
 def _unstack2(t: Any) -> Tuple[Any, Any]:
     return (
         jax.tree_util.tree_map(lambda x: x[0], t),
@@ -104,23 +117,29 @@ def _prepare(actor, critic):
 
 
 def _parts(net):
-    """``net`` as ``(encode, step, readout)``, each taking the parameters first.
+    """``net`` as ``(encode, with_action, step, readout)``, each taking the
+    parameters first.
 
     A net that says what of its step does not depend on the carry (``encode``,
     with ``step`` on the encoded rows and ``readout`` on the core's outputs:
-    ``ActorNet``, ``CriticNet``) is taken apart there.  A net that offers
-    ``apply`` alone is all step: its "encoded" rows are its inputs as they
-    are, and its outputs are final.
+    ``ActorNet``, ``CriticNet``) is taken apart there; a critic's
+    ``with_action`` makes of rows encoded without an action the rows
+    ``encode`` gives with one (``mix`` and the input projection), for an
+    action made in the same step.  A net that offers ``apply`` alone is all
+    step: its "encoded" rows are its inputs as they are, an action joins
+    them, and its outputs are final.
     """
     if hasattr(net, "encode"):
         return (
             lambda p, *xs: net.apply(p, *xs, method="encode"),
-            lambda p, z, c, r, *a: net.apply(p, z, c, r, *a, method="step"),
+            lambda p, z, a: net.apply(p, z, a, method="with_action"),
+            lambda p, z, c, r: net.apply(p, z, c, r, method="step"),
             lambda p, y: net.apply(p, y, method="readout"),
         )
     return (
         lambda p, *xs: xs,
-        lambda p, xs, c, r, *a: net.apply(p, *xs, *a, c, r),
+        lambda p, xs, a: xs + (a,),
+        lambda p, xs, c, r: net.apply(p, *xs, c, r),
         lambda p, y: y,
     )
 
@@ -135,13 +154,27 @@ class Stepped:
     whole time-major ``[T, B, ...]`` input, so its matmuls and convolutions
     see T·B rows; the scan body keeps the reset, ``h·W_h + b``, the gates and,
     where the critic follows the actor, the actor's head and the critic's
-    ``mix``; ``readout`` runs once over the stacked outputs.  Same products,
-    same precision: only the order of the sums over rows changes.  A net
-    offering ``apply`` alone (``actor.apply(params, obs, carry, reset)``,
-    ``critic.apply(params, obs, action, carry, reset)``, each one step
-    returning its output and the new carry) is scanned whole; a net that has
-    nothing to warm is one whose stored carry has no leaves.
+    ``mix`` (``with_action``); ``readout`` runs once over the stacked outputs.
+    Same products, same precision: only the order of the sums over rows
+    changes.  A net offering ``apply`` alone (``actor.apply(params, obs,
+    carry, reset)``, ``critic.apply(params, obs, action, carry, reset)``, each
+    one step returning its output and the new carry) is scanned whole; a net
+    that has nothing to warm is one whose stored carry has no leaves.
+
+    A step costs the chain of its operations, not their work: at 64 rows one
+    ``h·W_h`` is a fraction of a microsecond of the MXU (PERF.md section 5).
+    So where passes do not depend on each other they run as members of ONE
+    scan, whose batched operations replace one chain of each: burn-in stacks
+    online and target, and the learner's two online passes (the critic's
+    loss and the actor's, both from the burnt-in state over the same window)
+    are ``unroll_online``, one scan under one gradient call
+    (``joint_online``): 40 steps an update fewer at unroll 20, forward and
+    transposed, and two loops fewer to enter.
     """
+
+    # ``agents/ddpg.py::learner_step`` asks the runner for both online passes
+    # in one ``unroll_online`` under one gradient call.
+    joint_online = True
 
     def __init__(self, actor, critic, config):
         self.actor, self.critic, self.config = actor, critic, config
@@ -157,7 +190,7 @@ class Stepped:
 
     @staticmethod
     def _unroll(parts, params, carry, reset_tm, *inputs_tm):
-        encode, step, readout = parts
+        encode, _, step, readout = parts
         y_tm, carry = unroll(
             lambda c, z, r: step(params, z, c, r),
             carry,
@@ -188,8 +221,11 @@ class Stepped:
         critic sees it; ``q_min`` takes ``critic_params`` and ``cc`` with a
         leading ensemble axis and returns the least member's Q.
         """
-        a_encode, a_step, a_readout = self._actor
-        c_encode, c_step, c_readout = self._critic
+        a_encode, _, a_step, a_readout = self._actor
+        c_encode, c_with_action, c_step, c_readout = self._critic
+
+        def q_step(p, z, c, r, a):
+            return c_step(p, c_with_action(p, z, a), c, r)
 
         def step(carry, za, zc, r, *e):
             ca, cc = carry
@@ -198,11 +234,11 @@ class Stepped:
             if e:
                 a = jnp.clip(a + e[0], -1.0, 1.0)
             if q_min:
-                yq, cc = jax.vmap(lambda p, z, c: c_step(p, z, c, r, a))(
+                yq, cc = jax.vmap(lambda p, z, c: q_step(p, z, c, r, a))(
                     critic_params, zc, cc
                 )
             else:
-                yq, cc = c_step(critic_params, zc, cc, r, a)
+                yq, cc = q_step(critic_params, zc, cc, r, a)
             return (a, yq), (ca, cc)
 
         if q_min:  # members on axis 1 of the encoded rows: the scan runs over axis 0
@@ -217,6 +253,81 @@ class Stepped:
         else:
             q_tm = c_readout(critic_params, yq_tm)
         return a_tm, q_tm, carry
+
+    def unroll_online(
+        self, actor_params, critic_params, ca, cc, obs_tm, act_tm, reset_tm,
+        ensemble=False,
+    ):
+        """The critic's loss pass and the actor's loss pass in ONE scan:
+        ``unroll_critic(critic_params, cc, obs, act)`` and ``unroll_pi_q(
+        actor_params, critic_params, ca, cc, obs)``, from the same carries
+        over the same window, which do not depend on each other.
+
+        A step advances the actor (cell, head, the critic's ``mix`` and
+        ``W_i`` over the action it just made, as ``unroll_pi_q``'s step does)
+        and then the critic's cell as members of one vmapped step, as
+        ``burn_in`` stacks online and target: the first on the replay's
+        actions (``encode`` with ``mix`` ran outside the scan), the last on
+        the policy's.  ``h·W_h + b`` and the gates of all members are one
+        batched dot and one gate fusion a step.
+
+        The last member, and everything the actor's pass reads of the critic
+        (its torso, ``mix``, ``W_i``, readout), is ``stop_gradient`` of the
+        critic: the actor's loss goes through the FROZEN online critic, so a
+        gradient call over both nets' parameters gives the critic the
+        critic loss's gradient alone and the actor the actor loss's, as two
+        calls did.  With ``ensemble``, ``critic_params`` and ``cc`` have a
+        leading member axis (twin critics), every member runs on the replay's
+        actions and the policy is judged by a frozen copy of member 0 (the
+        TD3 convention).
+
+        Returns ``(q [T, B] ([n, T, B] with ensemble), q of the policy's
+        actions [T, B], (what the critic's pass left, what the actor's
+        left))``.
+        """
+        a_encode, _, a_step, a_readout = self._actor
+        c_encode, c_with_action, c_step, c_readout = self._critic
+
+        # Outside the scan: the critic's torso ONCE over the window (the
+        # frozen member reads member 0's features through ``stop_gradient``),
+        # then ``mix`` and ``W_i`` over the replay's actions.
+        def encode(p):
+            x = c_encode(p, obs_tm)
+            return x, c_with_action(p, x, act_tm)
+
+        if ensemble:  # members on axis 1 of the encoded rows
+            x_tm, zq_tm = jax.vmap(encode, out_axes=1)(critic_params)
+            x_tm = jax.tree_util.tree_map(lambda v: v[:, 0], x_tm)
+            trained = critic_params
+        else:
+            x_tm, zq_tm = encode(critic_params)
+            zq_tm = jax.tree_util.tree_map(lambda v: v[:, None], zq_tm)
+            trained, cc = _stack_n(critic_params, 1), _stack_n(cc, 1)
+        n = jax.tree_util.tree_leaves(trained)[0].shape[0]
+        frozen = lax.stop_gradient(_member(trained, 0))
+
+        # The members' parameters are stacked inside the step (the compiler
+        # hoists the stack): outside, the stack would be one input of the
+        # scan with a gradient, and the transposed scan would carry a weight
+        # gradient for the frozen member too.
+        def step(carry, za, zq, x_pi, r):
+            ca, cc = carry
+            y, ca = a_step(actor_params, za, ca, r)
+            z_pi = c_with_action(frozen, x_pi, a_readout(actor_params, y))
+            yq, cc = jax.vmap(lambda p, z, c: c_step(p, z, c, r))(
+                _append(trained, frozen), _append(zq, z_pi), cc
+            )
+            return yq, (ca, cc)
+
+        xs = (a_encode(actor_params, obs_tm), zq_tm, lax.stop_gradient(x_tm), reset_tm)
+        yq_tm, (ca, cc) = unroll(step, (ca, _append(cc, _member(cc, 0))), *xs)
+        left_q = jax.tree_util.tree_map(lambda x: x[:n], cc)
+        if ensemble:
+            q_tm = jax.vmap(c_readout, in_axes=(0, 1))(critic_params, yq_tm[:, :n])
+        else:
+            q_tm, left_q = c_readout(critic_params, yq_tm[:, 0]), _member(left_q, 0)
+        q_pi_tm = c_readout(frozen, yq_tm[:, n])
+        return q_tm, q_pi_tm, (left_q, (ca, _member(cc, n)))
 
     def burn_in(self, state, batch) -> Tuple[Carry, Carry, Carry, Carry]:
         """SURVEY §3.3 hot loop: `no_grad: (h,c) = burn_in(seq[:B_len])` — online
@@ -244,7 +355,7 @@ class Stepped:
         cc_on = cc_tg = cc0e
 
         def fused(parts, p_all, c0_single, n_all, *inputs_tm):
-            encode, step, _ = parts
+            encode, _, step, _ = parts
             z_tm = jax.vmap(lambda p: encode(p, *inputs_tm), out_axes=1)(p_all)
             v = jax.vmap(lambda p, z, c, r: step(p, z, c, r)[1], in_axes=(0, 0, 0, None))
             cN, _ = lax.scan(
@@ -294,7 +405,15 @@ class Whole:
     the core's own names, what the pass leaves to report.  The replay stores
     no carry for such a core; what the update reports from what its passes
     left is the core's to say (``pass_metrics``).
+
+    The critic's loss pass and the actor's stay two passes under two gradient
+    calls (no ``unroll_online``): a whole-sequence pass is not a chain of
+    small steps, so running them together saves no latency, and it would keep
+    both passes' activations alive at once in a learner call that sits at the
+    compiler's memory limit (``humanoid_sdar_moe``; PERF.md section 6).
     """
+
+    joint_online = False
 
     def __init__(self, actor, critic, config):
         if config.twin_critic or config.target_policy_sigma > 0:

@@ -80,6 +80,16 @@ the compiled learner call holds one copy of a block a pass and not sixteen
 products of a given width with the loops around each; the same leg requires
 the looped configuration's to lie inside both scans, and to be few.
 
+A recurrent pass costs its chain of steps, not their work: at 64 rows a
+step of the LSTM is a fraction of a microsecond of the MXU, and the step
+costs the fusions it runs one after another (PERF.md section 5), so the
+steps an update runs in sequence, times the fusions a step chains, are what
+the learner's scans cost.  ``models/sequence.py::Stepped`` runs independent
+passes as members of one scan (burn-in's online and target nets; the two
+online passes of the losses, ``unroll_online``).
+``scan_steps`` counts the steps of an update's loops from the trip counts the
+compiler states, by pass; the same leg prints them for every learner call.
+
 The TPU compiler fits a program to the chip's memory by cloning instructions
 and running them again where their results are needed (its own
 rematerialisation, beside what ``jax.checkpoint`` asks for): a clone carries
@@ -95,6 +105,8 @@ from __future__ import annotations
 import math
 import re
 from typing import Dict, List, Tuple
+
+from r2d2dpg_tpu.obs.stages import stage_of
 
 # ``  %convert.390 = bf16[524288,43,24]{0,2,1:T(8,128)(2,1)} convert(%x), ...``
 # (``ROOT`` before the name inside a fusion, no ``%`` in some printers).
@@ -150,6 +162,27 @@ _WINDOW = re.compile(
     r"window=\{size=(?P<size>\d+(?:x\d+)*)(?:[^}]*?lhs_dilate=(?P<dilate>\d+(?:x\d+)*))?")
 
 
+def _called(lines: Dict[str, List[str]]) -> Dict[str, List[Tuple[str, bool]]]:
+    """The computations each computation calls, each with whether it is a
+    loop's body or condition."""
+    return {
+        name: [
+            (callee, m["how"] in ("body", "condition"))
+            for line in body
+            for m in _CALLED.finditer(line)
+            for callee in re.findall(r"[\w.\-]+", m["many"] or m["one"])
+        ]
+        for name, body in lines.items()
+    }
+
+
+def _roots(called: Dict[str, List[Tuple[str, bool]]]) -> List[str]:
+    """The computations nothing calls: the entry, more in a text cut out of a
+    program."""
+    callees = {callee for edges in called.values() for callee, _ in edges}
+    return [name for name in called if name not in callees]
+
+
 def _computations(hlo_text: str) -> Tuple[Dict[str, List[str]], Dict[str, int]]:
     """The lines of every computation of ``hlo_text`` by its name, and the
     ``while`` bodies between the program's entry and each computation, the
@@ -168,18 +201,9 @@ def _computations(hlo_text: str) -> Tuple[Dict[str, List[str]], Dict[str, int]]:
 
     # Loops around each computation: a walk down from the computations that
     # nothing calls (the entry; more in a text cut out of a program).
-    called = {
-        name: [
-            (callee, m["how"] in ("body", "condition"))
-            for line in body
-            for m in _CALLED.finditer(line)
-            for callee in re.findall(r"[\w.\-]+", m["many"] or m["one"])
-        ]
-        for name, body in lines.items()
-    }
-    callees = {callee for edges in called.values() for callee, _ in edges}
+    called = _called(lines)
     depth: Dict[str, int] = {}
-    stack = [(name, 0) for name in lines if name not in callees]
+    stack = [(name, 0) for name in _roots(called)]
     while stack:
         name, d = stack.pop()
         if name not in lines or depth.get(name, -1) >= d:
@@ -196,6 +220,13 @@ _LOOP = re.compile(
     r"^\s*(?:ROOT\s+)?%?(?P<name>[\w.\-]+)\s*=.*\swhile\(.*"
     r"\bcondition=%?(?P<condition>[\w.\-]+)")
 _BOUND = re.compile(r"=\s*[su]\d+\[\]\S*\s+constant\((?P<bound>\d+)\)")
+
+
+def _bound(lines: Dict[str, List[str]], condition: str) -> int:
+    """The largest constant a loop's condition holds its counter to; 0 for
+    none."""
+    return max((int(b["bound"]) for held in lines.get(condition, [])
+                for b in _BOUND.finditer(held)), default=0)
 
 
 def capacity_scans(hlo_text: str, capacity: int) -> List[Tuple[str, str]]:
@@ -218,15 +249,50 @@ def capacity_scans(hlo_text: str, capacity: int) -> List[Tuple[str, str]]:
             w = _WINDOW.search(line)
             if w and any(int(n) > 1 for n in w["size"].split("x")):
                 found.append((m["name"], f"{m['shape']} window {w['size']}"))
-        if loop:
-            bounds = [
-                int(b["bound"])
-                for held in lines.get(loop["condition"], [])
-                for b in _BOUND.finditer(held)
-            ]
-            if bounds and max(bounds) >= capacity:
-                found.append((loop["name"], f"loop of {max(bounds)} steps"))
+        if loop and (bound := _bound(lines, loop["condition"])) >= capacity:
+            found.append((loop["name"], f"loop of {bound} steps"))
     return found
+
+
+# ``while(%tuple), condition=%cond, body=%body, ..., backend_config={"known_trip_count":{"n":"20"}}``
+_BODY = re.compile(r"\bbody=%?(?P<body>[\w.\-]+)")
+_TRIPS = re.compile(r'"known_trip_count":\{"n":"(?P<n>\d+)"\}')
+_OP_NAME = re.compile(r'\bop_name="(?P<path>[^"]*)"')
+# The passes of a learner update whose loops ``scan_steps`` counts, as
+# ``obs/stages.py::stage_of`` names them.
+SCAN_PASSES = ("burn_in", "forward", "backward")
+
+
+def scan_steps(hlo_text: str) -> Dict[str, int]:
+    """The steps the loops of one learner update run one after another, by
+    pass: ``burn_in``, ``forward`` (the forward passes under the gradient
+    call and the target pass), ``backward`` (their transposes), each the
+    ``stage_of`` of a ``while``'s ``op_name``.  A loop's steps are its trip
+    count (the compiler's ``known_trip_count``, else the constant its
+    condition holds the counter to), times the trip counts of the counted
+    loops around it.  Loops of no pass (the call's own loop over its updates,
+    a sample's or a key's loops) are not counted, and do not multiply what
+    lies inside them."""
+    lines, _ = _computations(hlo_text)
+    called = _called(lines)
+    steps = dict.fromkeys(SCAN_PASSES, 0)
+    stack = [(name, 1) for name in _roots(called)]
+    while stack:
+        name, times = stack.pop()
+        loop_bodies = {}
+        for line in lines.get(name, []):
+            loop, body = _LOOP.match(line), _BODY.search(line)
+            if not (loop and body):
+                continue
+            path = _OP_NAME.search(line)
+            stage = stage_of(path["path"] if path else None)
+            trips = _TRIPS.search(line)
+            n = int(trips["n"]) if trips else _bound(lines, loop["condition"])
+            if stage in steps:
+                steps[stage] += n * times
+                loop_bodies[body["body"]] = n * times
+        stack.extend((c, loop_bodies.get(c, times)) for c, _ in called.get(name, ()))
+    return steps
 
 
 _ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2,

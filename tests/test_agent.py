@@ -11,6 +11,7 @@ import pytest
 
 from r2d2dpg_tpu.agents import AgentConfig, R2D2DPG
 from r2d2dpg_tpu.models import ActorNet, CriticNet, unroll
+from r2d2dpg_tpu.models.sequence import Stepped
 from r2d2dpg_tpu.replay.arena import SequenceBatch
 
 B, OBS, ACT, HID = 4, 3, 2, 16
@@ -316,7 +317,7 @@ SEAM_NETS = {
 }
 SEAM_OPS = (
     "unroll_actor", "unroll_critic", "unroll_pi_q", "unroll_pi_q_eps",
-    "unroll_pi_q_min", "burn_in",
+    "unroll_pi_q_min", "unroll_online", "burn_in",
 )
 
 
@@ -390,6 +391,24 @@ def seam_run(op, seq, state, batch, hoisted):
         (_, out), grads = jax.value_and_grad(f, has_aux=True)(state.critic_params)
         return out, grads
 
+    if op == "unroll_online":
+        def f(params):
+            pa, pc = params
+            if hoisted:
+                out = seq.unroll_online(pa, pc, ca, cc, obs, act, reset)
+            else:
+                # Three scans: the critic on the replay's actions, the actor,
+                # the frozen critic on the actor's actions.
+                q, cc_q = unroll(q_step(pc), cc, obs, act, reset)
+                a, ca_n = unroll(a_step(pa), ca, obs, reset)
+                frozen = jax.lax.stop_gradient(pc)
+                q_pi, cc_pi = unroll(q_step(frozen), cc, obs, a, reset)
+                out = (q, q_pi, (cc_q, (ca_n, cc_pi)))
+            return (out[0] ** 2).sum() - out[1].mean(), out
+        (_, out), grads = jax.value_and_grad(f, has_aux=True)(
+            (state.actor_params, state.critic_params))
+        return out, grads
+
     e = eps if op == "unroll_pi_q_eps" else None
     q_min = op == "unroll_pi_q_min"
     pc0 = state.critic_params
@@ -424,7 +443,9 @@ def seam_run(op, seq, state, batch, hoisted):
 @pytest.mark.parametrize("op", SEAM_OPS)
 def test_hoisted_pass_equals_the_scan_of_single_steps(op, kind):
     """Outputs, last carries and the gradients of a loss on the outputs (the
-    critic's through ``unroll_critic``, the actor's through ``unroll_pi_q``).
+    critic's through ``unroll_critic``, the actor's through ``unroll_pi_q``,
+    both through ``unroll_online``, whose critic on the policy's actions is
+    frozen).
     The forward pass is the same products row by row, in either dtype.  The
     gradients sum them in another order: float32 rounding; under bfloat16 a
     weight's gradient is rounded once over T·B rows where the scan rounded it
@@ -444,3 +465,86 @@ def test_hoisted_pass_equals_the_scan_of_single_steps(op, kind):
         lambda a, b: np.testing.assert_allclose(a, b, **tol), f32(got_g), f32(want_g))
     if op != "burn_in":
         assert any(np.abs(g).max() > 0 for g in jax.tree_util.tree_leaves(f32(got_g)))
+
+
+# ------------------------------------------ the two online passes, one scan
+# ``Stepped.unroll_online`` steps the critic's loss pass and the actor's in
+# one scan under one gradient call; a runner that does not (``Whole``) keeps
+# two passes under two calls.  The same update either way, net by net.
+
+
+class TwoPasses(Stepped):
+    """``Stepped`` with the online passes as two scans under two gradient
+    calls, as a runner that does not step them together runs them."""
+
+    joint_online = False
+
+
+ONLINE_NETS = {
+    "walker_like": dict(),
+    "pixels": dict(pixels=True),
+    "twin_critic": dict(twin_critic=True),
+    "dense_core": dict(use_lstm=False),
+    "apply_alone": None,  # the Leaky nets: ``apply`` is the whole step
+}
+
+
+def online_update(kind, runner):
+    """One ``learner_step`` of nets of ``kind`` through ``runner``: stored
+    carries that are not zero, a reset in the burn-in prefix and one in the
+    training window, desynced targets, IS weights that are not ones."""
+    kw = ONLINE_NETS[kind]
+    cfg = AgentConfig(
+        burnin=3, unroll=4, n_step=2, twin_critic=bool(kw and kw.get("twin_critic")))
+    if kw is None:
+        actor, critic = LeakyActor(action_dim=ACT, hidden=HID), LeakyCritic(hidden=HID)
+    else:
+        nets = {k: v for k, v in kw.items() if k != "twin_critic"}
+        actor = ActorNet(action_dim=ACT, hidden=HID, **nets)
+        critic = CriticNet(hidden=HID, **nets)
+    agent = R2D2DPG(actor, critic, cfg)
+    assert type(agent.seq) is Stepped
+    agent.seq = runner(actor, critic, cfg)
+    L = cfg.seq_len
+    ks = jax.random.split(jax.random.PRNGKey(13), 5)
+    if kw and kw.get("pixels"):
+        obs = jax.random.randint(ks[0], (B, L, FRAME, FRAME, 3), 0, 256).astype(jnp.uint8)
+    else:
+        obs = jax.random.normal(ks[0], (B, L, OBS))
+    h = 0.5 * jax.random.normal(ks[1], (B, HID))
+    carry = lambda net, x: jax.tree_util.tree_map(  # noqa: E731
+        lambda z: x + z, net.initial_carry(B))
+    batch = SequenceBatch(
+        obs=obs,
+        action=jax.random.uniform(ks[2], (B, L, ACT), minval=-1, maxval=1),
+        reward=jax.random.normal(ks[3], (B, L)),
+        discount=jnp.full((B, L), 0.99),
+        reset=jnp.zeros((B, L)).at[1, 1].set(1.0).at[2, cfg.burnin + 2].set(1.0),
+        carries={"actor": carry(actor, h), "critic": carry(critic, -h)},
+    )
+    state = desynced(agent.init(jax.random.PRNGKey(0), obs[:, 0], batch.action[:, 0]))
+    args = (state, batch, jnp.linspace(0.5, 1.0, B))
+    scans = str(jax.make_jaxpr(agent.learner_step)(*args)).count("scan[")
+    return jax.jit(agent.learner_step)(*args), scans
+
+
+@pytest.mark.parametrize("kind", list(ONLINE_NETS))
+def test_one_scan_of_both_online_passes_is_the_two_passes_update(kind):
+    """Both losses, the td errors (their mean and the priorities made of
+    them), the critic's and the actor's gradients (Adam's first moments: the
+    first step's is a tenth of the clipped gradient), the new parameters and
+    targets: the two-pass update's, to float32 rounding.  Where the core
+    carries something, the joint form has one forward scan and one transposed
+    scan fewer (a Dense core's scans carry nothing, and JAX's count of them is
+    the same either way)."""
+    (got, got_scans), (want, want_scans) = (
+        online_update(kind, r) for r in (Stepped, TwoPasses))
+    assert jax.tree_util.tree_structure(got) == jax.tree_util.tree_structure(want)
+    for g, w in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-6)
+    new_state, prios, metrics = got
+    for opt_state in (new_state.actor_opt_state, new_state.critic_opt_state):
+        assert any(float(jnp.abs(m).max()) > 0
+                   for m in jax.tree_util.tree_leaves(opt_state) if m.ndim)
+    assert np.all(np.asarray(prios) > 0) and float(metrics["actor_loss"]) != 0
+    assert got_scans == want_scans - (0 if kind == "dense_core" else 2)

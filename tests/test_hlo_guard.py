@@ -4,8 +4,9 @@ the sampled batch, a running sum as long as the arena, a row read out of the
 arena as many rows' bytes, an image convolution run once a scan step), the
 priority write-back in place (PR 37), the prepared frames' re-lays and what
 reads them (PR 35, PR 39), a whole arena leaf re-laid in HBM (PR 40) and the
-sixth (a looped stack's products inside its loops, one copy a pass), on HLO
-text as the TPU compiler prints it.  Only the
+sixth (a looped stack's products inside its loops, one copy a pass), and the
+count of an update's sequential scan steps by pass, on HLO text as the TPU
+compiler prints it.  Only the
 chip's compiler makes either choice, so the CPU tests the readers alone, and
 the one thing that can be compiled here without a chip: ``ReplayArena.sample``
 for a described v5e."""
@@ -26,6 +27,7 @@ from r2d2dpg_tpu.obs.hlo import (
     loop_products,
     priority_writes,
     remat_clones,
+    scan_steps,
 )
 
 CAPACITY = 524288
@@ -1294,3 +1296,147 @@ def test_priority_kernel_compiles_for_v5e_at_twice_the_cells_capacity(
     hlo = _pallas_scatter.trace(*avals).lower(
         lowering_platforms=("tpu",)).compile().as_text()
     assert "tpu_custom_call" in hlo and "output_to_operand_aliasing" in hlo
+
+
+# ------------------------------------------------------------ scan steps
+# A learner call's loop over its updates, holding one loop of each
+# ``(path under the update, trips, the compiler states the count)``; a
+# ``(path, trips, stated, inner)`` holds the loops of ``inner`` in its body.
+def loops_hlo(loops):
+    text, n = [], [0]
+
+    def loop(path, trips, stated, inner=()):
+        n[0] += 1
+        i = n[0]
+        inside = [loop(*x) for x in inner]
+        text.append(
+            f"%cond.{i} (p.{i}: (s32[])) -> pred[] {{\n"
+            f"  %p.{i} = (s32[]) parameter(0)\n"
+            f"  %constant.{i} = s32[]{{:T(128)}} constant({trips})\n"
+            f"  ROOT %lt.{i} = pred[] compare(%p.{i}, %constant.{i}), direction=LT\n"
+            f"}}\n\n"
+            f"%body.{i} (q.{i}: (s32[])) -> (s32[]) {{\n"
+            f"  %q.{i} = (s32[]) parameter(0)\n"
+            + "".join(inside)
+            + f"  ROOT %t.{i} = (s32[]) tuple(%q.{i})\n}}\n\n")
+        stated = f', backend_config={{"known_trip_count":{{"n":"{trips}"}}}}' if stated else ""
+        return (
+            f"  %while.{i} = (s32[]{{:T(128)}}) while(%q.{i}), condition=%cond.{i}, "
+            f'body=%body.{i}, metadata={{op_name="jit(timed)/{path}" '
+            f"stack_frame_id={i}}}{stated}\n")
+
+    update = loop("while", 4, False, [
+        ("while/body/closed_call/" + path, trips, stated, inner)
+        for path, trips, stated, *rest in loops for inner in (rest[0] if rest else (),)])
+    return "".join(text) + (
+        "ENTRY %main.1 (x: s32[]) -> (s32[]) {\n" + update
+        + "  ROOT %r = (s32[]) tuple(%while.1)\n}\n")
+
+
+ONLINE = [("burn_in/while", 20, False), ("burn_in/while", 20, True),
+          ("forward/while", 23, False), ("forward/jvp()/while", 20, False),
+          ("forward/transpose(jvp())/while", 20, True),
+          ("replay_sample/while", 64, False), ("while", 3, False)]
+ROLLED = [("forward/while", 4, False, [("forward/while/body/while", 4, False)]),
+          ("forward/transpose(jvp())/while", 4, True,
+           [("forward/transpose(jvp())/while/body/while", 4, False)])]
+
+
+@pytest.mark.parametrize(
+    "loops, want",
+    [
+        (ONLINE, {"burn_in": 40, "forward": 43, "backward": 20}),
+        (ONLINE + [("forward/jvp()/while", 20, True),
+                   ("forward/transpose(jvp())/while", 20, False)],
+         {"burn_in": 40, "forward": 63, "backward": 40}),
+        (ROLLED, {"burn_in": 0, "forward": 20, "backward": 20}),
+        ([], {"burn_in": 0, "forward": 0, "backward": 0}),
+    ],
+    ids=["one_scan", "two_passes", "nested", "none"],
+)
+def test_scan_steps_counts_an_updates_loops_by_pass(loops, want):
+    """Every loop inside the call's loop over its updates, by the pass its
+    path names (``backward`` under a ``transpose(``): its trip count, stated
+    by the compiler or held in its condition, times the counted loops around
+    it.  A sample's loop and a loop under no stage are not counted, nor is
+    the loop over the updates."""
+    assert scan_steps(loops_hlo(loops)) == want
+
+
+def _tiny_learner_call_steps(monkeypatch, runner):
+    """``scan_steps`` of ``pendulum_tiny``'s learner call at two updates a
+    call (burn-in 2, unroll 4, n-step 2), compiled for the CPU with
+    ``runner`` running the sequence core."""
+    import dataclasses
+
+    import jax
+
+    import chip_smoke
+    from r2d2dpg_tpu import configs
+
+    get_config = configs.get_config
+
+    def two_updates(name):
+        exp = get_config(name)
+        return dataclasses.replace(
+            exp, trainer=dataclasses.replace(exp.trainer, learner_steps=2))
+
+    with monkeypatch.context() as m:
+        m.setattr(configs, "get_config", two_updates)
+        trainer, state = chip_smoke._learner_call_from_shapes(
+            "pendulum_tiny", (3,), "float32", 1)
+    agent = trainer.agent
+    agent.seq = runner(agent.actor, agent.critic, agent.config)
+    hlo = jax.jit(trainer._learn_many, donate_argnums=(0, 1)).lower(
+        state.train, state.arena, state.rng).compile().as_text()
+    return scan_steps(hlo)
+
+
+def test_the_tiny_learner_call_runs_both_online_passes_in_one_scan(monkeypatch):
+    """Compiled for the CPU, ``pendulum_tiny``'s update runs burn-in's two
+    scans of 2 steps, the target pass of 6 and ONE scan of the two online
+    passes (4 steps) and its transpose: 2 x unroll steps fewer than the
+    same update with the online passes as two scans under two gradient
+    calls."""
+    from r2d2dpg_tpu.models.sequence import Stepped
+
+    class TwoPasses(Stepped):
+        joint_online = False
+
+    one = _tiny_learner_call_steps(monkeypatch, Stepped)
+    two = _tiny_learner_call_steps(monkeypatch, TwoPasses)
+    assert one == {"burn_in": 4, "forward": 10, "backward": 4}
+    assert two == {"burn_in": 4, "forward": 14, "backward": 8}
+    assert sum(two.values()) - sum(one.values()) == 2 * 4
+
+
+@pytest.mark.parametrize(
+    "config, obs_shape, obs_dtype, steps",
+    [("walker_r2d2", (24,), "float32", {"burn_in": 40, "forward": 43, "backward": 20}),
+     ("cheetah_pixels", (64, 64, 3), "uint8",
+      {"burn_in": 40, "forward": 45, "backward": 20})],
+    ids=["walker_r2d2", "cheetah_pixels"],
+)
+def test_learner_call_compiled_for_v5e_runs_both_online_passes_in_one_scan(
+    config, obs_shape, obs_dtype, steps, one_chip, no_compile_cache, monkeypatch
+):
+    """The LSTM cells' learner calls from shapes, compiled for a described
+    v5e, keep their scans as loops: burn-in's two of 20 steps, the target
+    pass of 20 + n-step, one scan of both online passes and its transpose
+    (103 steps an update for walker, 105 for cheetah; 143 and 145 as two
+    passes).  Nothing runs: a compile says nothing about results or
+    times."""
+    import jax
+
+    import chip_smoke
+
+    # ``priority_scatter`` picks its branch from the backend it runs on.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    trainer, state = chip_smoke._learner_call_from_shapes(
+        config, obs_shape, obs_dtype, 6)
+    train, arena, rng = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        (state.train, state.arena, state.rng))
+    hlo = jax.jit(trainer._learn_many, donate_argnums=(0, 1)).trace(
+        train, arena, rng).lower(lowering_platforms=("tpu",)).compile().as_text()
+    assert scan_steps(hlo) == steps
